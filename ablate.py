@@ -89,12 +89,8 @@ def measure_fused(n_lanes=None, limit=None, chunk=512):
     and the kernel occupancy.  On a real TPU this times the actual Mosaic
     kernel at campaign scale (1024 lanes); elsewhere the kernel runs
     under Pallas interpret mode — grid-point-by-grid-point emulation — so
-    the default run scales down to stay minutes-scale, and jax builds
-    without pallas support skip with a reason instead of aborting the
-    remaining default configs."""
+    the default run scales down to stay minutes-scale."""
     import jax
-
-    from wtf_tpu.interp.pstep import fused_available
 
     on_tpu = jax.default_backend() == "tpu"
     if n_lanes is None:
@@ -103,10 +99,6 @@ def measure_fused(n_lanes=None, limit=None, chunk=512):
         limit = 20_000 if on_tpu else 5_000
     report = {"config": "fused", "n_lanes": n_lanes, "limit": limit,
               "chunk": chunk, "platform": jax.devices()[0].platform}
-    if not fused_available():
-        report["skipped"] = "this jax build cannot run pallas kernels"
-        print(json.dumps(report), flush=True)
-        return
     cols = fused_ab(n_lanes, limit, chunk, b"\x01\x08AAAAAAAA" * 200)
     report["fused_off"] = cols["off"]
     report["fused_on"] = cols["on"]
@@ -240,16 +232,11 @@ def measure_fused_mega(n_lanes=8, limit=20_000, window=3, batches=32,
 
     from wtf_tpu.analysis.rules import BUDGET_ENTRY, load_budgets
     from wtf_tpu.analysis.trace import build_tlv_campaign
-    from wtf_tpu.interp.pstep import fused_available
     from wtf_tpu.utils.hashing import hex_digest
 
     report = {"config": "fused-mega", "n_lanes": n_lanes, "limit": limit,
               "window": window, "batches": batches,
               "platform": jax.devices()[0].platform}
-    if not fused_available():
-        report["skipped"] = "this jax build cannot run pallas kernels"
-        print(json.dumps(report), flush=True)
-        return report
     # kernels per XLA ladder sweep: the checked-in step-graph pin
     per_sweep = int(load_budgets()[BUDGET_ENTRY]["total"])
     cols, fps = {}, {}

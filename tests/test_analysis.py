@@ -128,19 +128,22 @@ def test_budget_rule_fires_on_improvement_too():
 
 
 def test_checked_in_budget_matches_perf_record():
-    """analysis/budgets.json pins the step ladder at the PERF.md
-    round-18 math: 165 surviving data-dependent kernels (78/59/28 —
-    round 16's 166 minus the uop-fetch rip_l gather that the packed
-    one-gather lookup made dead)."""
+    """analysis/budgets.json pins the step ladder at what PERF.md
+    records (PR 22 rebaseline, CPU compile of the installed XLA): 108
+    surviving data-dependent ops, 78 gathers / 3 dynamic-slices / 13
+    dynamic-update-slices / 14 scatters.  The 165 (78/59/28/0) of the
+    earlier record was the same step under an older XLA, which kept as
+    slices what this one lowers to scatters or folds away."""
     budget = load_budgets()["xla_step"]
-    assert budget["total"] == 165
+    assert budget["total"] == 108
     assert (budget["gather"], budget["dynamic-slice"],
-            budget["dynamic-update-slice"]) == (78, 59, 28)
+            budget["dynamic-update-slice"], budget["scatter"]) == (
+        78, 3, 13, 14)
     # the tenant ladder is the SAME program over a stacked image table
-    assert load_budgets()["tenant_chunk"]["total"] == 165
+    assert load_budgets()["tenant_chunk"]["total"] == 108
     # the in-graph decode service compiles as its own pinned graph
     # (round 18) so decoder growth is a lint finding, not silent fusion
-    assert load_budgets()["decode_service"]["total"] == 268
+    assert load_budgets()["decode_service"]["total"] == 248
 
 
 def test_rebaseline_is_a_ratchet():

@@ -1,0 +1,249 @@
+"""The main path's programs compile for a TPU v5e — from this sandbox, with
+no chip: the TPU compiler is installed here and compiles for a chip that
+is DESCRIBED, not attached (`topologies.get_topology_desc`).  A compile
+that passes is not a chip run and says nothing about results or times;
+what it guards is every later PR against the refusals PR 22 found (the
+f64 -> u64 bitcast in the step, the fused kernel's block specs).
+
+Shapes are chip_smoke.py's: demo_tlv, 1024 lanes, the campaign's default
+runner (256-step base chunk, 128 overlay slots).  Compile time is set by
+the step body (~75-90 s per program that contains it, whatever its
+shape), so ONE tier-1 case contains `step_lane`; the window, the
+four-chip mesh chunk and the four-chip mesh window carry the `slow` mark
+and are run by hand before a chip call
+(`pytest tests/test_chip_compile.py -m slow`, about nine minutes).
+
+Everything that touches the topology lives in fixtures of THIS file (one
+process loads libtpu; see the on-chip-measurement guide, section 2), and
+the persistent compile cache is off around each compile: an executable
+for a described chip is written to it but can never be read back.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+LANES = 1024
+HBM_BYTES = 16 * 2 ** 30    # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compile_for():
+    """compile_for(fn, args, shardings, **jit_kwargs): `fn` jitted and
+    compiled on the shapes of `args` placed by `shardings` (one sharding
+    for every leaf, or a pytree prefix of `args`), cache off around it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    def run(fn, args, shardings, **jit_kwargs):
+        def spec(sharding, subtree):
+            return jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(
+                    np.shape(x), jnp.result_type(x), sharding=sharding),
+                subtree)
+
+        specs = jax.tree.map(
+            spec, shardings, args,
+            is_leaf=lambda s: isinstance(s, jax.sharding.Sharding))
+        return jax.jit(fn, **jit_kwargs).lower(*specs).compile()
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield run
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+def _campaign(lanes, megachunk=0, **backend_kwargs):
+    """The device-resident demo_tlv campaign chip_smoke.py runs, built on
+    the CPU at the real width (nothing is dispatched here)."""
+    from wtf_tpu.analysis.trace import build_tlv_campaign
+
+    return build_tlv_campaign(n_lanes=lanes, mutator="devmangle",
+                              limit=100_000, max_len=1024,
+                              megachunk=megachunk, device_decode=True,
+                              **backend_kwargs)
+
+
+@pytest.fixture(scope="module")
+def loop():
+    return _campaign(LANES)
+
+
+def test_chunk_program_compiles_for_v5e(loop, one_chip, compile_for):
+    """The step ladder's chunk program, as the chip traces it (float-
+    valued uops hosted: fp_host=True), and it fits the chip."""
+    from wtf_tpu.interp.step import make_run_chunk
+
+    runner = loop.backend.runner
+    compiled = compile_for(
+        make_run_chunk(runner.chunk_steps, donate=False, jit=False,
+                       fp_host=True),
+        runner.executor_operands(), one_chip)
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES, mem
+
+
+def test_step_source_has_no_float_to_int_bitcast():
+    """The one direction the TPU's 64-bit rewrite refuses stays out of
+    the step: no bitcast in step.py targets a 64-bit integer (f32 <-> u32
+    is a 32-bit move and accepted)."""
+    import inspect
+    import re
+
+    from wtf_tpu.interp import step
+
+    targets = re.findall(r"bitcast_convert_type\(\s*[^,]+,\s*jnp\.(\w+)",
+                         inspect.getsource(step))
+    assert targets and not {"uint64", "int64"} & set(targets), targets
+
+
+def test_decode_service_compiles_for_v5e(loop, one_chip, compile_for):
+    from wtf_tpu.analysis.rules import decode_service_program
+
+    fn, args = decode_service_program(loop.backend.runner)
+    compile_for(fn, args, one_chip)
+
+
+def test_devmut_generate_compiles_for_v5e(loop, one_chip, compile_for):
+    from wtf_tpu.devmut import hostref
+    from wtf_tpu.devmut.engine import generate
+
+    mut = loop.mutator
+    data, lens, cumw, _ = mut.corpus.arrays()
+    seeds = hostref.lane_seeds(mut.seed, 0, LANES)
+    compile_for(lambda *a: generate(*a, rounds=mut.rounds),
+                (data, lens, cumw, seeds), one_chip)
+
+
+def test_device_insert_compiles_for_v5e(loop, one_chip, compile_for):
+    from wtf_tpu.interp.runner import device_insert_impl
+
+    mut = loop.mutator
+    words = np.zeros((LANES, mut.max_len // 4), np.uint32)
+    compile_for(
+        device_insert_impl(len(mut.pfns), mut.spec.len_gpr,
+                           mut.spec.ptr_gpr),
+        (loop.backend.runner.machine, words, np.zeros(LANES, np.int32),
+         np.asarray(mut.pfns, np.int32), np.zeros(2, np.uint32)),
+        one_chip)
+
+
+def test_fused_kernel_is_refused_at_lowering(loop, one_chip, compile_for):
+    """Today's truth about the Pallas kernel (ROADMAP S2): the TPU
+    lowering refuses its rank-1 per-lane block specs before Mosaic proper
+    runs.  The PR that rewrites the kernel flips this test — and only
+    then may `--fused-step auto` select it again."""
+    from wtf_tpu.interp.pstep import fused_call_impl
+
+    runner = loop.backend.runner
+    with pytest.raises(Exception, match="rank 1 block shapes"):
+        compile_for(
+            lambda *a: fused_call_impl(*a, k_steps=runner.fused_k,
+                                       interpret=False),
+            runner.executor_operands(), one_chip)
+
+
+class _Captured(Exception):
+    pass
+
+
+def _window_dispatch(loop):
+    """(callable-seam arguments, dispatch operands) of the first
+    megachunk window `loop` would dispatch: the runner's callable seam is
+    spied on and the dispatch aborted once its operands are in hand."""
+    runner = loop.backend.runner
+    real, seen = runner.megachunk_callable, {}
+
+    def spy(*a, **k):
+        seen["fn"], seen["seam_args"] = real(*a, **k), a
+
+        def grab(*operands):
+            raise _Captured(operands)
+        return grab
+
+    runner.megachunk_callable = spy
+    with pytest.raises(_Captured) as caught:
+        loop.fuzz(runs=runner.n_lanes * 8)
+    return seen["fn"], seen["seam_args"], caught.value.args[0]
+
+
+@pytest.mark.slow
+def test_window_program_compiles_for_v5e(one_chip, compile_for):
+    """The megachunk window (XLA ladder inside, fused off) on the
+    operands the backend really dispatches."""
+    fn, _, operands = _window_dispatch(_campaign(LANES, megachunk=8))
+    mem = compile_for(fn, operands, one_chip).memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES, mem
+
+
+@pytest.mark.slow
+def test_mesh_window_compiles_for_four_v5e(topo, compile_for):
+    """The shard_map window on four described chips, 512 lanes each,
+    with each operand placed as the mesh backend places it on the
+    conftest's virtual CPU mesh.  PR 22 found a u64 psum here that the
+    TPU's 64-bit rewrite refuses (`Supported lowering only of Sum all
+    reduce`)."""
+    from wtf_tpu.fuzz.megachunk import make_mesh_megachunk
+    from wtf_tpu.meshrun.mesh import LANE_AXIS
+
+    loop = _campaign(4 * 512, megachunk=8, mesh_devices=4)
+    runner = loop.backend.runner
+    _, seam_args, operands = _window_dispatch(loop)
+    mesh = Mesh(np.array(topo.devices[:4]), (LANE_AXIS,))
+    placed = jax.tree.map(
+        lambda x: NamedSharding(mesh, x.sharding.spec if isinstance(
+            getattr(x, "sharding", None), NamedSharding) else P()),
+        operands)
+    fn = make_mesh_megachunk(*seam_args, deliver=runner.deliver_exceptions,
+                             mesh=mesh, devdec=True, interpret=False,
+                             donate=False)
+    compile_for(fn, operands, placed)
+
+
+@pytest.mark.slow
+def test_mesh_chunk_compiles_for_four_v5e(topo, compile_for):
+    """make_mesh_chunk on a Mesh of four described chips at the README's
+    512 lanes per chip: one all-reduce, and per-device argument bytes
+    that say the lane planes are split, not parked on the first chip."""
+    from wtf_tpu.meshrun.executor import IMAGE_SPEC, make_mesh_chunk
+    from wtf_tpu.meshrun.mesh import LANE_AXIS
+
+    lanes = 4 * 512
+    runner = _campaign(lanes).backend.runner
+    mesh = Mesh(np.array(topo.devices[:4]), (LANE_AXIS,))
+    rep, split = NamedSharding(mesh, P()), NamedSharding(mesh, P(LANE_AXIS))
+    tab, image, machine, limit = runner.executor_operands()
+    image_sh = jax.tree.map(lambda spec: NamedSharding(mesh, spec),
+                            IMAGE_SPEC, is_leaf=lambda x: isinstance(x, P))
+    compiled = compile_for(make_mesh_chunk(64, mesh, jit=False),
+                           (tab, image, machine, limit),
+                           (rep, image_sh, split, rep))
+    assert compiled.as_text().count("all-reduce(") + \
+        compiled.as_text().count("all-reduce-start(") == 1
+    whole = sum(x.nbytes for x in jax.tree.leaves(machine))
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    shared = sum(x.nbytes for x in jax.tree.leaves((tab, image)))
+    assert per_device < shared + whole / 4 * 1.1, (per_device, whole)

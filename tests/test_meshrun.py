@@ -326,11 +326,8 @@ def test_mesh_campaign_ramp_parity_slow():
     np.testing.assert_array_equal(np.asarray(a.backend._agg_edge),
                                   np.asarray(b.backend._agg_edge))
 
-    from wtf_tpu.interp.pstep import fused_available
-
-    if fused_available():
-        c = run(8, fused="on")
-        assert c.stats.testcases == 384
-        np.testing.assert_array_equal(np.asarray(a.backend._agg_cov),
-                                      np.asarray(c.backend._agg_cov))
-        assert c.registry.counter("device.fused_steps").value > 0
+    c = run(8, fused="on")
+    assert c.stats.testcases == 384
+    np.testing.assert_array_equal(np.asarray(a.backend._agg_cov),
+                                  np.asarray(c.backend._agg_cov))
+    assert c.registry.counter("device.fused_steps").value > 0

@@ -25,13 +25,6 @@ from wtf_tpu.interp.machine import (
 from wtf_tpu.interp.runner import Runner
 from wtf_tpu.snapshot.loader import Snapshot
 
-# skip-with-reason guard: some jax builds ship without pallas (or without
-# a working interpret mode); the suite must stay green there
-pstep = pytest.importorskip("wtf_tpu.interp.pstep")
-if not pstep.fused_available():
-    pytest.skip("this jax build cannot run pallas interpret kernels",
-                allow_module_level=True)
-
 RF_CMP = 0x8D5 | 0x400  # same modeled-flags mask as tests/test_step.py
 
 STATE_FIELDS = ("gpr", "rip", "rflags", "icount", "cov", "edge",
@@ -567,9 +560,9 @@ def test_fused_campaign_parity_demo_tlv():
 
 
 def test_fused_step_config_validation():
-    """Config surface: bad values raise; 'auto' on the CPU platform
-    resolves to the XLA ladder (the kernel-count win is a TPU property);
-    'on' forces the fused ladder."""
+    """Config surface: bad values raise; 'auto' resolves to the XLA
+    ladder (on every platform, until the TPU compiler accepts the kernel
+    — ROADMAP S2); 'on' forces the fused ladder."""
     from wtf_tpu.harness import demo_tlv
 
     snap = demo_tlv.build_snapshot()

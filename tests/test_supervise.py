@@ -19,6 +19,7 @@ import time
 import types
 from pathlib import Path
 
+import jax
 import numpy as np
 import pytest
 
@@ -96,9 +97,32 @@ def test_watchdog_abandons_hung_dispatch(monkeypatch):
     assert sup.registry.counter("supervise.watchdog_fires").value == 1
 
 
+@pytest.mark.parametrize("boom", [
+    ValueError("The Pallas TPU lowering currently requires that rank 1 "
+               "block shapes ..."),
+    jax.errors.JaxRuntimeError(
+        "UNIMPLEMENTED: While rewriting computation to not contain X64 "
+        "element types ..."),
+    jax.errors.JaxRuntimeError(
+        "INTERNAL: Mosaic failed to compile TPU kernel: ..."),
+], ids=["lowering", "x64-rewrite", "mosaic"])
+def test_dispatch_reraises_compile_time_refusal(boom):
+    """A refusal raised while lowering or compiling is deterministic: it
+    re-raises as it is, so no ladder rung can turn it into exit 0."""
+    sup = Supervisor(enabled=True)
+
+    def fn():
+        raise boom
+
+    with pytest.raises(type(boom)) as ei:
+        sup.dispatch("chunk", fn)
+    assert ei.value is boom
+    assert sup.registry.counter("supervise.device_errors").value == 0
+
+
 def test_dispatch_error_wraps_backend_exception():
     sup = Supervisor(enabled=True)
-    boom = ValueError("XlaRuntimeError stand-in")
+    boom = jax.errors.JaxRuntimeError("INTERNAL: device halted")
 
     def fn():
         raise boom

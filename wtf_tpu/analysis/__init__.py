@@ -92,19 +92,17 @@ def lint_main(families=None, budgets=None, rebaseline: bool = False,
     the `wtf-tpu lint` subcommand (which supplies telemetry wiring)."""
     out = out or sys.stdout
     # The lint's contracts are CPU-platform facts (HLO counts, donation
-    # policy), so the default pins the CPU backend.  WTF_LINT_PLATFORM
-    # overrides: "native" leaves the ambient jax platform untouched, any
-    # other value is passed to jax verbatim (e.g. "tpu").
+    # policy), so where JAX_PLATFORMS names nothing the default pins the
+    # CPU backend.  WTF_LINT_PLATFORM overrides: "native" leaves the
+    # ambient jax platform untouched, any other value is passed to jax
+    # verbatim (e.g. "tpu").
     import os
 
     platform = os.environ.get("WTF_LINT_PLATFORM", "cpu")
-    if platform != "native":
+    if platform != "native" and not os.environ.get("JAX_PLATFORMS"):
         import jax
 
-        try:
-            jax.config.update("jax_platforms", platform)
-        except Exception:  # noqa: BLE001 - backend already initialized
-            pass
+        jax.config.update("jax_platforms", platform)
     t0 = time.time()
     try:
         findings, info = run_lint(families=families, budgets_path=budgets,

@@ -407,11 +407,11 @@ def check_budget(counts: Dict[str, int], budget: Dict[str, int],
     return findings
 
 
-def decode_service_lowering(runner):
-    """Lower one devdec service round (compute_blocks + commit_blocks)
-    at the budget runner's shapes — the `decode_service` census
-    subject."""
-    import jax
+def decode_service_program(runner):
+    """(fn, args) of one devdec service round (compute_blocks +
+    commit_blocks) at `runner`'s shapes — the `decode_service` census
+    subject, also compiled for a described TPU by
+    tests/test_chip_compile.py."""
     import jax.numpy as jnp
 
     from wtf_tpu.interp import devdec
@@ -424,11 +424,19 @@ def decode_service_lowering(runner):
         return devdec.commit_blocks(tab, count, blocks, machine.status,
                                     capacity)
 
-    return jax.jit(service).lower(
+    return service, (
         runner.cache.device(),
         lane_image(runner.physmem.image, runner.n_lanes),
         runner.machine, jnp.int32(0),
         jnp.zeros(DECODE_BP_SLOTS, jnp.uint64), jnp.int32(0))
+
+
+def decode_service_lowering(runner):
+    """decode_service_program lowered for this process's backend."""
+    import jax
+
+    fn, args = decode_service_program(runner)
+    return jax.jit(fn).lower(*args)
 
 
 def run_decode_rules(runner, budgets_path: Optional[Path] = None,
@@ -452,7 +460,7 @@ def _iter_eqns(jxp):
     EXCEPT under pallas_call, which is atomic: on hardware it is ONE
     kernel dispatch, so its internal jaxpr must not leak into a
     kernel-count census."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def sub_jaxprs(v):
         if isinstance(v, ClosedJaxpr):
@@ -890,7 +898,7 @@ def weak_typed_leaves(args) -> List[Tuple[str, str]]:
     out = []
     flat = jax.tree_util.tree_flatten_with_path(args)[0]
     for path, leaf in flat:
-        aval = jax.core.get_aval(leaf)
+        aval = jax.typeof(leaf)
         if getattr(aval, "weak_type", False):
             out.append((jax.tree_util.keystr(path), str(aval.dtype)))
     return out

@@ -90,6 +90,7 @@ class EmuBackend(Backend):
                         skip_rip = None
                     continue
                 skip_rip = None
+                fresh = rip not in self._run_cov
                 self._run_cov.add(rip)
                 if writer is not None and not tenet:
                     writer.on_step(rip)
@@ -101,6 +102,10 @@ class EmuBackend(Backend):
                     result = Crash(f"crash-int-{e.rip:#x}")
                     break
                 except MemFault as e:
+                    if fresh and e.gva == rip and not e.write:
+                        # the FETCH faulted: nothing executed at rip, and
+                        # the device (no uop entry, no cov bit) agrees
+                        self._run_cov.discard(rip)
                     if self._deliver(VEC_PF, fault=e):
                         continue  # guest services the fault and retries
                     # execute-refinement: a fault on the fetch address is an

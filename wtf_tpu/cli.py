@@ -92,8 +92,10 @@ def _add_backend_tuning(p: argparse.ArgumentParser, mesh: bool = False
                    help="fused Pallas fast path (interp/pstep.py): one "
                         "kernel per chunk for the hot integer core, with "
                         "parked lanes resuming on the XLA step.  auto = "
-                        "on only where the per-kernel dispatch win exists "
-                        "(a real TPU backend)")
+                        "off on every platform until the TPU compiler "
+                        "accepts the kernel (ROADMAP S2); on = force it "
+                        "(pallas interpret mode on the CPU, the "
+                        "compiler's refusal on a TPU)")
     p.add_argument("--burst-any-tier", choices=("auto", "on", "off"),
                    default="auto",
                    help="the oracle burst's any-instruction tier for "

@@ -490,7 +490,7 @@ def make_mesh_megachunk(max_batches: int, n_pages: int, len_gpr: int,
     lane blocks and run ONE replicated sequential commit, so the table
     (and its slot/coverage-bit order) stays bit-identical on every shard
     AND to the single-device program — slots never partition by shard."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from wtf_tpu.meshrun.executor import IMAGE_SPEC
@@ -511,8 +511,17 @@ def make_mesh_megachunk(max_batches: int, n_pages: int, len_gpr: int,
         return lax.pmax(jnp.any(x).astype(jnp.int32), LANE_AXIS) > 0
 
     def sum_fn(ctr):
-        return lax.psum(jnp.sum(ctr.astype(jnp.uint64), axis=0),
-                        LANE_AXIS)
+        # the TPU compiler lowers only 32-bit sum all-reduces (a u64
+        # psum is UNIMPLEMENTED in its 64-bit rewrite): four 16-bit
+        # chunks ride ONE u32 psum and recombine with wrapping u64
+        # shifts — exact for any mesh up to 65536 shards
+        local = jnp.sum(ctr.astype(jnp.uint64), axis=0)
+        chunks = jnp.stack([(local >> jnp.uint64(16 * i))
+                            & jnp.uint64(0xFFFF) for i in range(4)])
+        s = lax.psum(chunks.astype(jnp.uint32),
+                     LANE_AXIS).astype(jnp.uint64)
+        return (s[0] + (s[1] << jnp.uint64(16)) + (s[2] << jnp.uint64(32))
+                + (s[3] << jnp.uint64(48)))
 
     def rsum_fn(r):
         return lax.psum(r, LANE_AXIS)
@@ -550,7 +559,7 @@ def make_mesh_megachunk(max_batches: int, n_pages: int, len_gpr: int,
             new_flags=P(None, LANE_AXIS), ctr_sums=P(), new_words=P(),
             prev=lane_snap, cur=lane_snap, tab=P(), count=P(),
             dd_stats=P(), engine_rounds=P()),
-        check_rep=False),
+        check_vma=False),
         donate_argnums=WINDOW_DONATE_ARGNUMS if donate else ())
     _MEGA_CACHE[key] = fn
     return fn

@@ -57,9 +57,12 @@ Authoring notes (TPU target, validated via interpret=True on CPU):
     carrying the register file as a value and the overlay living in
     in+out refs (copy-in at kernel start, RMW in place) so loads observe
     earlier in-kernel stores
-  * tier-1 runs the kernel under `interpret=True` on the CPU platform —
-    the Mosaic lowering is exercised only when a real TPU backend is
-    attached (`interpret=None` auto-detects)
+  * tier-1 runs the kernel under `interpret=True` on the CPU platform.
+    The TPU lowering REFUSES the kernel as written (every per-lane spec
+    is a rank-1 `(1,)` block on a `[n_lanes]` array; behind that wait the
+    whole-image VMEM blocks — ROADMAP S2, pinned by
+    tests/test_chip_compile.py), so on a TPU `interpret=None` surfaces
+    the compiler's own message and `--fused-step auto` stays off
 """
 
 from __future__ import annotations
@@ -124,32 +127,6 @@ def _u32(x) -> jnp.ndarray:
 
 def _pair(v: int):
     return (_u32(v & 0xFFFFFFFF), _u32((v >> 32) & 0xFFFFFFFF))
-
-
-def fused_available(interpret: bool = True) -> bool:
-    """Whether this jax build can run the fused kernel (tier-1's
-    skip-with-reason guard: some jax builds ship without pallas interpret
-    support).  Cached after the first probe."""
-    global _FUSED_OK
-    try:
-        return _FUSED_OK
-    except NameError:
-        pass
-    try:
-        from jax.experimental import pallas as pl
-
-        def probe(i_ref, o_ref):
-            o_ref[0] = i_ref[0] + jnp.uint32(1)
-
-        out = pl.pallas_call(
-            probe,
-            out_shape=jax.ShapeDtypeStruct((1,), jnp.uint32),
-            interpret=interpret,
-        )(jnp.zeros(1, jnp.uint32))
-        _FUSED_OK = int(out[0]) == 1
-    except Exception:  # noqa: BLE001 - any failure means "not available"
-        _FUSED_OK = False
-    return _FUSED_OK
 
 
 def _build_kernel(k_steps: int, n_fields: int, hash_size: int,

@@ -153,6 +153,46 @@ def _bitlen(x):
     return _popcnt(x)
 
 
+_EXP2_LADDER = tuple(1 << i for i in range(9, -1, -1))   # 512 .. 1
+
+
+def _f64_bits(f):
+    """IEEE-754 bit pattern of a float64 as uint64, WITHOUT the f64 -> int
+    bitcast: the TPU compiler's 64-bit rewrite refuses that one direction
+    (`bitcast-convert` u64 <- f64 is UNIMPLEMENTED; int -> float is fine).
+
+    Built from operations it accepts, every one exact on an IEEE double:
+    sign from a compare (1/x for a signed zero), exponent by power-of-two
+    scaling into [1, 2) (ten rounds down from 2**512, ten up), mantissa
+    by the f64 -> i64 convert of (m - 1) * 2**52.  Zero/Inf/NaN come from
+    bit constants: NaN reads as the positive quiet NaN (callers pick NaN
+    results from operand bits, never from the float) and a subnormal
+    reads as a signed zero, which is what a flushing platform hands the
+    old bitcast and what the FTZ divert below keys on."""
+    neg = (f < 0) | ((f == 0) & (1.0 / f < 0))
+    a = jnp.abs(f)
+    finite = a < jnp.inf
+    normal = finite & (a >= 2.0 ** -1022)
+    m = jnp.where(normal, a, 1.0)
+    e = jnp.zeros(f.shape, jnp.int32)
+    for k in _EXP2_LADDER:
+        big = m >= 2.0 ** k
+        m = jnp.where(big, m * 2.0 ** -k, m)
+        e = jnp.where(big, e + k, e)
+    for k in _EXP2_LADDER:
+        small = m < 2.0 ** (1 - k)
+        m = jnp.where(small, m * 2.0 ** k, m)
+        e = jnp.where(small, e - k, e)
+    frac = ((m - 1.0) * 2.0 ** 52).astype(jnp.int64).astype(jnp.uint64)
+    mag = ((e + 1023).astype(jnp.uint64) << _u(52)) | frac
+    mag = jnp.where(normal, mag,
+                    jnp.where(finite, _u(0),
+                              jnp.where(a == jnp.inf,
+                                        _u(0x7FF0000000000000),
+                                        _u(0x7FF8000000000000))))
+    return jnp.where(neg, _u(1 << 63), _u(0)) | mag
+
+
 def _mkflags(cf, pf, af, zf, sf, of):
     def bit(c, v):
         return jnp.where(c, _u(v), _u(0))
@@ -635,12 +675,29 @@ PORTED_LIMB_PATHS = {
 }
 
 
-def step_lane(tab: UopTable, image: MemImage, st: Machine, limit) -> Machine:
+def fp_on_host() -> bool:
+    """Whether float-valued uops must retire on the host oracle here.
+
+    The TPU has no IEEE double: its compiler carries an f64 as a pair of
+    f32s (measured on a v5e, PERF.md "On the chip": f32 range, ~48
+    mantissa bits — add/mul/div/sqrt/convert all differ from the oracle
+    in the low bits, and anything past 2**128 reads as Inf), and its f32
+    div/sqrt are not correctly rounded.  A snapshot fuzzer that disagrees
+    with its own oracle cannot reproduce its findings, so there the device
+    keeps only what it gets exactly (see `fp_hosted` in step_lane)."""
+    return jax.default_backend() == "tpu"
+
+
+def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
+              fp_host: bool = None) -> Machine:
     """Advance one lane by one instruction (vmapped over the batch).
 
     Lanes whose status != RUNNING are a no-op.  `limit` is the instruction
     budget (u64; 0 = unlimited) -> TIMEDOUT, the deterministic equivalent of
-    the reference's after_execution counter (bochscpu_backend.cc:458-469)."""
+    the reference's after_execution counter (bochscpu_backend.cc:458-469).
+    `fp_host` is trace-time static (None = this platform's fp_on_host())."""
+    if fp_host is None:
+        fp_host = fp_on_host()
     enabled = st.status == jnp.int32(int(StatusCode.RUNNING))
     # limb-packed hot state (ported paths) + free u64 bitcast views (cold
     # paths and the memory subsystem convert at this seam)
@@ -804,6 +861,28 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit) -> Machine:
         # that — surface loudly instead of executing with 64-bit regs
         | (is_string & (f[F_A32] != 0))
         | movcr_bad | div64_hard)
+    if fp_host:
+        # every uop whose result depends on a float VALUE the platform
+        # cannot compute like the oracle: all f64-element SSE forms, all
+        # converts, f32 div/sqrt, and the value-touching x87 subs (the
+        # same set x87_denorm guards, plus fild).  Bit movers (shuffles,
+        # unpacks, fld/fst m64, fxch, control words) and the f32
+        # add/sub/mul/min/max/compare forms stay on the device.
+        fp32_exact = (srcsize0 == 4) & (
+            (sub <= U.FP_MUL) | (sub == U.FP_MIN) | (sub == U.FP_MAX)
+            | (sub == U.FP_UCOMI) | (sub == U.FP_COMI) | (sub == U.FP_CMP))
+        fp_bits_only = (sub == U.FP_SHUF) | (sub == U.FP_UNPCKL) \
+            | (sub == U.FP_UNPCKH)
+        x87_valued = (
+            (sub == U.X87_ARITH_M) | (sub == U.X87_ARITH_ST)
+            | (((sub == U.X87_FLD_M) | (sub == U.X87_FST_M))
+               & (srcsize0 < 8))
+            | (sub == U.X87_FILD) | (sub == U.X87_FIST)
+            | (sub == U.X87_FIST_T) | (sub == U.X87_COM)
+            | (sub == U.X87_COMI))
+        fp_hosted = (is_ssefp & ~fp32_exact & ~fp_bits_only) \
+            | (is_x87 & x87_valued)
+        unsupported = unsupported | (pre_live & fp_hosted)
 
     # -- 4a. effective address (ported: u32 limbs end to end) -------------
     base_val_l = L.where64(breg == U.REG_RIP, next_rip_l,
@@ -1366,8 +1445,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit) -> Machine:
     def _b32(f):
         return lax.bitcast_convert_type(f, jnp.uint32)
 
-    def _b64(f):
-        return lax.bitcast_convert_type(f, jnp.uint64)
+    _b64 = _f64_bits
 
     nan_a32, nan_b32 = _nan32(fpa32), _nan32(fpb32)
     nan_a64, nan_b64 = _nan64(fpa64), _nan64(fpb64)
@@ -2264,7 +2342,8 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit) -> Machine:
 _CHUNK_CACHE: dict = {}
 
 
-def make_run_chunk(n_steps: int, donate: bool = None, jit: bool = True):
+def make_run_chunk(n_steps: int, donate: bool = None, jit: bool = True,
+                   fp_host: bool = None):
     """Build (or fetch) the jitted chunk executor: up to n_steps vmapped
     transitions with early exit when no lane is RUNNING.  The host runner
     (interp/runner.py) calls this in a loop, servicing lane statuses between
@@ -2295,16 +2374,22 @@ def make_run_chunk(n_steps: int, donate: bool = None, jit: bool = True):
     never memoized): the static analyzer's retrace-stability probe needs
     a genuinely fresh trace per lowering — jax's trace cache keys on
     function identity, so re-lowering the memoized jitted executor would
-    never re-trace and the probe would be vacuous."""
+    never re-trace and the probe would be vacuous.
+
+    fp_host (None = this platform's fp_on_host()) is step_lane's: float-
+    valued uops retire on the host oracle where the f64 is not IEEE."""
     if donate is None:
         donate = jax.default_backend() != "cpu"
-    key = (n_steps, donate)
+    if fp_host is None:
+        fp_host = fp_on_host()
+    key = (n_steps, donate, fp_host)
     if jit:
         cached = _CHUNK_CACHE.get(key)
         if cached is not None:
             return cached
 
-    step_v = jax.vmap(step_lane, in_axes=(None, IMAGE_IN_AXES, 0, None))
+    step_v = jax.vmap(partial(step_lane, fp_host=fp_host),
+                      in_axes=(None, IMAGE_IN_AXES, 0, None))
 
     def run_chunk(tab: UopTable, image: MemImage, machine: Machine, limit):
         # normalize in-body: the per-lane tenant selector is always

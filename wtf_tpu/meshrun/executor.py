@@ -26,7 +26,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from wtf_tpu.interp.step import make_run_chunk
@@ -81,7 +81,7 @@ def make_mesh_chunk(n_steps: int, mesh, donate: Optional[bool] = None,
         _chunk_with_coverage(body), mesh=mesh,
         in_specs=(P(), IMAGE_SPEC, P(LANE_AXIS), P()),
         out_specs=(P(LANE_AXIS), P(), P()),
-        check_rep=False)
+        check_vma=False)
     if not jit:
         return fn
     fn = jax.jit(fn, donate_argnums=(2,) if donate else ())
@@ -108,7 +108,7 @@ def make_mesh_fused(k_steps: int, mesh):
         mesh=mesh,
         in_specs=(P(), IMAGE_SPEC, P(LANE_AXIS), P()),
         out_specs=P(LANE_AXIS),
-        check_rep=False))
+        check_vma=False))
     _MESH_FUSED_CACHE[key] = fn
     return fn
 
@@ -133,6 +133,6 @@ def make_mesh_resume(n_steps: int, mesh, donate: Optional[bool] = None):
         _chunk_with_coverage(run_resume), mesh=mesh,
         in_specs=(P(), IMAGE_SPEC, P(LANE_AXIS), P()),
         out_specs=(P(LANE_AXIS), P(), P()),
-        check_rep=False), donate_argnums=(2,) if donate else ())
+        check_vma=False), donate_argnums=(2,) if donate else ())
     _MESH_CHUNK_CACHE[key] = fn
     return fn

@@ -56,13 +56,7 @@ def init_multihost(coordinator: Optional[str] = None,
         kwargs["num_processes"] = num_processes
     if process_id is not None:
         kwargs["process_id"] = process_id
-    try:  # jax >= 0.5 exposes is_initialized; older builds don't
-        already = jax.distributed.is_initialized()
-    except AttributeError:
-        from jax._src.distributed import global_state
-
-        already = global_state.client is not None
-    if not already:
+    if not jax.distributed.is_initialized():
         jax.distributed.initialize(**kwargs)  # raises on a bad coordinator
     return make_mesh()
 

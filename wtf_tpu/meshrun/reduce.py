@@ -30,7 +30,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from wtf_tpu.meshrun.mesh import LANE_AXIS
@@ -210,6 +210,6 @@ def make_mesh_merge(mesh):
         mesh_merge_local, mesh=mesh,
         in_specs=(P(), P(), P(LANE_AXIS), P(LANE_AXIS), P(LANE_AXIS)),
         out_specs=(P(), P(), P(LANE_AXIS), P()),
-        check_rep=False))
+        check_vma=False))
     _MESH_MERGE_CACHE[key] = fn
     return fn

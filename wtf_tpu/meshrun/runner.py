@@ -31,7 +31,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from wtf_tpu.interp.machine import CTR_INSTR
@@ -61,7 +61,7 @@ def _mesh_generate(rounds: int, mesh):
         partial(generate, rounds=rounds), mesh=mesh,
         in_specs=(P(), P(), P(), P(LANE_AXIS)),
         out_specs=(P(LANE_AXIS), P(LANE_AXIS)),
-        check_rep=False))
+        check_vma=False))
     _MESH_GEN_CACHE[key] = fn
     return fn
 

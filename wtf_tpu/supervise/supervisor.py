@@ -103,9 +103,27 @@ class DispatchHang(DispatchFailure):
 
 
 class DispatchError(DispatchFailure):
-    """The dispatch raised (XlaRuntimeError and friends, or injected)."""
+    """The device errored under a dispatch (the runtime's own error, or
+    an injected one)."""
 
     kind = "error"
+
+
+# what the compiler's refusals carry in the runtime's error type
+_REFUSAL_MARKS = ("UNIMPLEMENTED", "INVALID_ARGUMENT", "compile", "Compil")
+
+
+def is_device_failure(exc: Exception) -> bool:
+    """Whether the degradation ladder may step around `exc`: only the
+    runtime's own error (a chip that errored, went away, ran dry mid-run).
+    Anything raised while tracing, lowering or compiling is deterministic
+    — the program is refused the same way on every retry — so it
+    re-raises as it is instead of ending as a slower campaign that exits
+    0."""
+    import jax
+
+    return isinstance(exc, jax.errors.JaxRuntimeError) and not any(
+        mark in str(exc) for mark in _REFUSAL_MARKS)
 
 
 class LanePoisoned(DispatchFailure):
@@ -219,7 +237,7 @@ class Supervisor:
         except DispatchFailure:
             raise
         except Exception as exc:
-            if not self.enabled:
+            if not self.enabled or not is_device_failure(exc):
                 raise
             self._note_error(seam, index, repr(exc))
             raise DispatchError(seam, index, repr(exc)) from exc

@@ -140,13 +140,6 @@ def _resume_leg(seed: int) -> dict:
 def main(argv=None) -> int:
     seed = int((argv or sys.argv[1:] or [SEED])[0])
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-
-    # same persistent compile cache the test suite uses: the resume leg
-    # compiles the demo_tlv chunk executor, ~40s cold on a 1-core box
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.expanduser("~/.cache/wtf_tpu_xla"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
     print(f"chaos-smoke seed={seed:#x}")
     dist = _dist_leg(seed)
     print(f"dist leg OK: {dist}")

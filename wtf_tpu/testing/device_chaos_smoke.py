@@ -160,13 +160,6 @@ def _quarantine_leg(seed: int) -> dict:
 def main(argv=None) -> int:
     seed = int((argv or sys.argv[1:] or [SEED])[0])
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-
-    # same persistent compile cache the test suite uses — the legs
-    # compile the chunk + megachunk executors, slow cold on a 1-core box
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.expanduser("~/.cache/wtf_tpu_xla"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
     print(f"device-chaos-smoke seed={seed:#x}")
     err, ref_state = _error_leg(seed)
     print(f"error leg OK: {err}")
