@@ -20,7 +20,7 @@ import pytest
 from tests.emurunner import DATA_BASE, build_guest, run_emu
 from wtf_tpu.core.results import StatusCode
 from wtf_tpu.interp.machine import (
-    CTR_FUSED, CTR_INSTR, CTR_PARK_MEM, CTR_PARK_SUBSET,
+    CTR_FUSED, CTR_INSTR, CTR_PARK_MEM, CTR_PARK_SUBSET, CTR_SWEPT,
 )
 from wtf_tpu.interp.runner import Runner
 from wtf_tpu.snapshot.loader import Snapshot
@@ -62,9 +62,11 @@ def _assert_ladders_equal(r0, s0, r1, s1, check_mem=False):
         b = np.asarray(getattr(r1.machine, field))
         if field == "ctr":
             # the fused-only counters (kernel occupancy + park split)
-            # legitimately differ (that's the point); every other device
-            # counter must agree exactly
-            fused_only = [CTR_FUSED, CTR_PARK_SUBSET, CTR_PARK_MEM]
+            # legitimately differ (that's the point), and so do the
+            # lane-steps (the kernel passes every lane k times a
+            # dispatch); every other device counter must agree exactly
+            fused_only = [CTR_FUSED, CTR_PARK_SUBSET, CTR_PARK_MEM,
+                          CTR_SWEPT]
             a = np.delete(a, fused_only, axis=1)
             b = np.delete(b, fused_only, axis=1)
         assert np.array_equal(a, b), f"{field} diverged under fused ladder"

@@ -16,7 +16,7 @@ from wtf_tpu.core.results import Crash, Ok
 from wtf_tpu.dist.client import run_testcase_and_restore
 from wtf_tpu.harness import demo_tlv
 from wtf_tpu.interp.machine import (
-    CTR_DECODE_MISS, CTR_INSTR, CTR_MEM_FAULT, N_CTRS,
+    CTR_DECODE_MISS, CTR_INSTR, CTR_MEM_FAULT, CTR_SWEPT, N_CTRS,
 )
 from wtf_tpu.telemetry import (
     EventLog, NULL, Registry, StatsDict, get_registry, open_event_log,
@@ -357,6 +357,26 @@ def test_trace_collector_bounds_memory_by_dropping_oldest():
     assert {e["name"] for e in events} <= {f"p{i}" for i in range(15, 25)}
 
 
+def test_trace_collector_records_the_unix_time_of_its_zero(tmp_path):
+    """--trace-out rebases ts to its first event; otherData.epoch_unix_s
+    says when that was, so the timeline can be laid against heartbeat
+    `ts` and a profiler slice's start."""
+    import time
+
+    from wtf_tpu.telemetry import TraceCollector
+
+    reg = Registry()
+    reg.spans.collector = TraceCollector()
+    before = time.time()
+    with reg.spans.span("execute"):
+        pass
+    after = time.time()
+    reg.spans.collector.write(tmp_path / "trace.json")
+    doc = json.loads((tmp_path / "trace.json").read_text())
+    assert doc["traceEvents"][0]["ts"] == 0
+    assert before - 0.01 <= doc["otherData"]["epoch_unix_s"] <= after + 0.01
+
+
 # ---------------------------------------------------------------------------
 # device-side counters
 # ---------------------------------------------------------------------------
@@ -672,3 +692,381 @@ def test_telemetry_report_host_device_wall_breakdown(tmp_path):
     assert wb["by_phase"]["harvest"]["device_seconds"] == 0.0
     assert round(wb["host_busy_seconds"], 4) == 2.6
     assert round(wb["device_busy_seconds"], 4) == 9.9
+
+
+# ---------------------------------------------------------------------------
+# PR 25: the closed span tree, the in-graph sweep counter, the profiler's
+# clock, named programs
+# ---------------------------------------------------------------------------
+
+def _kernel_inputs(inputs: Path) -> None:
+    import struct
+
+    inputs.mkdir(parents=True)
+    (inputs / "a").write_bytes(b"\x03" + b"A" * 200)
+    (inputs / "b").write_bytes(
+        b"\x02" + struct.pack("<IQ", 0xDEADBEEF, 0x41) + b"pad")
+    (inputs / "c").write_bytes(b"\x04" + struct.pack("<Q", 0xDEAD0000) + b"x")
+    (inputs / "d").write_bytes(b"\x01\x10\x20")
+
+
+@pytest.fixture(scope="module")
+def kernel_campaign(tmp_path_factory):
+    """One seeded demo_kernel campaign on the batch loop (8 lanes, 8
+    batches, the pure-Python mangle engine so no native build matters):
+    its records, its last metrics dump and its finds."""
+    import contextlib
+    import io
+
+    from wtf_tpu.cli import main
+
+    target = tmp_path_factory.mktemp("kernel-campaign")
+    _kernel_inputs(target / "inputs")
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out):
+        rc = main(["campaign", "--name", "demo_kernel", "--target",
+                   str(target), "--runs", "64", "--lanes", "8", "--limit",
+                   "100000", "--max_len", "256", "--seed", "21",
+                   "--mutator", "mangle", "--telemetry-dir",
+                   str(target / "telem")])
+    assert rc in (0, 2)
+    records = list(read_events(target / "telem" / "events.jsonl"))
+    end = [r for r in records if r["type"] == "run-end"][-1]
+    return {"target": target, "records": records,
+            "metrics": end["metrics"], "stdout": out.getvalue()}
+
+
+def _calls(metrics, path):
+    return metrics["phase.calls"].get(path, 0)
+
+
+@pytest.mark.parametrize("path,counter", [
+    ("execute/device-step", "runner.chunks"),
+    ("execute/status-pull", "runner.chunks"),
+    ("execute/results", "backend.batches"),
+    ("harvest/crash-save", "backend.batches"),
+    ("harvest/account", "backend.batches"),
+    ("harvest/corpus-add", "backend.batches"),
+    ("harvest", "backend.batches"),
+    ("heartbeat", "backend.batches"),
+])
+def test_new_span_is_present_with_the_expected_calls(kernel_campaign,
+                                                     path, counter):
+    """One span per chunk or per batch, never one per lane (8 lanes a
+    batch here) nor one per crash: the crashes saved are
+    `campaign.crashes`, not `phase.calls`."""
+    metrics = kernel_campaign["metrics"]
+    assert metrics[counter] > 0
+    assert _calls(metrics, path) == metrics[counter], (
+        path, metrics["phase.calls"])
+    assert metrics["campaign.crashes"] > metrics["backend.batches"]
+
+
+def test_breakpoint_loop_gets_one_span_a_round_not_one_a_lane(
+        kernel_campaign):
+    metrics = kernel_campaign["metrics"]
+    calls = _calls(metrics, "execute/service-bp")
+    assert 0 < calls <= metrics["runner.chunks"]
+    assert metrics["runner.bp_dispatches"] > calls   # lanes share a span
+
+
+def test_exception_loop_gets_one_span_a_round_not_one_a_lane():
+    """demo_usermode has an IDT: #PF and #DE go back into the guest, lane
+    by lane, under ONE `service-exception` span per servicing round."""
+    from wtf_tpu.harness import demo_usermode as du
+
+    backend = create_backend("tpu", du.build_snapshot(), n_lanes=8,
+                             limit=100_000)
+    backend.initialize()
+    du.TARGET.init(backend)
+    backend.run_batch([b"\x02", b"\x03"] * 4, du.TARGET)  # wild read, #DE
+    metrics = backend.registry.dump()
+    calls = metrics["phase.calls"]["service-exception"]
+    assert 0 < calls <= metrics["runner.chunks"]
+    assert metrics["runner.exceptions_delivered"] > calls
+    assert metrics["phase.calls"]["status-pull"] == metrics["runner.chunks"]
+
+
+def test_result_pulls_count_three_transfers_per_crashing_lane(
+        kernel_campaign):
+    """Counted where the transfer is made: every `np.asarray` of a
+    machine field in `_map_result` goes through `_pull_field`."""
+    metrics = kernel_campaign["metrics"]
+    assert metrics["backend.result_pulls"] > 0
+    assert metrics["backend.result_pulls"] % 3 == 0
+    assert metrics["backend.result_pulls"] <= 3 * metrics["campaign.crashes"]
+    import inspect
+
+    from wtf_tpu.backend.tpu import TpuBackend
+    assert "np.asarray" not in inspect.getsource(TpuBackend._map_result)
+
+
+@pytest.mark.parametrize("parent", ["execute", "harvest"])
+def test_direct_children_cover_most_of_the_parent_span(kernel_campaign,
+                                                       parent):
+    seconds = kernel_campaign["metrics"]["phase.seconds"]
+    children = sum(v for k, v in seconds.items()
+                   if k.startswith(parent + "/") and k.count("/") == 1)
+    assert 0.8 * seconds[parent] <= children <= seconds[parent], seconds
+
+
+def test_top_level_spans_cover_the_wall_clock_between_heartbeats(
+        kernel_campaign):
+    """mutate + execute + harvest + restore + heartbeat (+ checkpoint)
+    tile the loop: between the first heartbeat and the run's end they
+    account for the host's clock."""
+    records = kernel_campaign["records"]
+    first = [r for r in records if r["type"] == "heartbeat"][0]
+    last = [r for r in records if r["type"] == "run-end"][-1]
+    top = ("mutate", "execute", "harvest", "restore", "heartbeat",
+           "checkpoint")
+    spanned = sum(last["metrics"]["phase.seconds"].get(k, 0.0)
+                  - first["metrics"]["phase.seconds"].get(k, 0.0)
+                  for k in top)
+    wall = last["ts"] - first["ts"]
+    assert wall > 0 and spanned >= 0.9 * wall, (spanned, wall)
+
+
+def test_sweeps_are_whole_and_bound_the_instructions_in_every_record(
+        kernel_campaign):
+    seen = 0
+    for record in kernel_campaign["records"]:
+        metrics = record.get("metrics")
+        if not metrics or "device.lane_steps" not in metrics:
+            continue
+        seen += 1
+        assert metrics["device.sweeps"] == int(metrics["device.sweeps"])
+        assert metrics["device.sweeps"] * 8 == metrics["device.lane_steps"]
+        assert metrics["device.instructions"] <= metrics["device.lane_steps"]
+        assert metrics["runner.fallbacks"] == 0   # no host-retired steps
+    assert seen >= 2      # the first heartbeat and the run's end
+
+
+# the same campaign on the parent commit (PR 24's tree), by hand: the
+# final line without its clock fields, and a digest over the sorted
+# (path, sha256) listing of outputs/ and crashes/
+_PARENT_LINE = "#64 cov: 51 corp: 17 crash: 30 timeout: 0 cr3: 0"
+_PARENT_FINDS = ("a4c6ca381f017e26b13e6a3ca283eacaec3a4c2645eba5f11daca15ec"
+                 "3cd7785")
+
+
+def test_equal_seed_campaign_writes_the_parents_line_and_finds(
+        kernel_campaign):
+    import hashlib
+    import re
+
+    line = kernel_campaign["stdout"].strip().splitlines()[-1]
+    line = re.sub(r"exec/s: [0-9.]+ ", "", line)
+    assert re.sub(r" uptime.*", "", line) == _PARENT_LINE
+    listing = hashlib.sha256()
+    for sub in ("outputs", "crashes"):
+        for path in sorted((kernel_campaign["target"] / sub).iterdir()):
+            digest = hashlib.sha256(path.read_bytes()).hexdigest()[:16]
+            listing.update(f"{sub}/{path.name} {digest}\n".encode())
+    assert listing.hexdigest() == _PARENT_FINDS
+
+
+def test_every_lane_carries_the_same_sweep_count_on_one_device():
+    from wtf_tpu.harness import demo_kernel as dk
+
+    backend = create_backend("tpu", dk.build_snapshot(), n_lanes=8,
+                             limit=100_000)
+    backend.initialize()
+    dk.TARGET.init(backend)
+    cases = [b"\x01\x01\x02\x03\x04", b"\x03" + b"A" * 200, b"",
+             b"\x02short", b"\x01" + bytes(range(250))]
+    backend.run_batch(cases, dk.TARGET)
+    ctr = backend.runner.device_counters()
+    swept = ctr[:, CTR_SWEPT]
+    assert swept[0] > 0 and (swept == swept[0]).all(), swept
+    assert (ctr[:, CTR_INSTR] <= swept).all()
+    reg = backend.registry
+    assert reg.counter("device.sweeps").value == int(swept[0])
+    assert reg.counter("device.lane_steps").value == 8 * int(swept[0])
+    dk.TARGET.restore()
+    backend.restore()
+    assert int(backend.runner.device_counters()[:, CTR_SWEPT].sum()) == 0
+
+
+def test_straight_line_testcase_sweeps_its_instructions_plus_one():
+    """Warm decode cache, one live lane: one sweep per retired
+    instruction, plus the ONE sweep that meets the finish breakpoint
+    (pre-execution: it retires nothing and leaves RUNNING, which ends
+    the loop).  A cold cache adds one sweep per decode miss."""
+    backend = _tpu_backend()
+    backend.run_batch([BENIGN], demo_tlv.TARGET)
+    cold = backend.runner.device_counters()
+    assert (int(cold[0, CTR_SWEPT]) == int(cold[0, CTR_INSTR]) + 1
+            + int(cold[0, CTR_DECODE_MISS]))
+    backend.restore()
+    assert int(backend.runner.device_counters()[:, CTR_SWEPT].sum()) == 0
+    backend.run_batch([BENIGN], demo_tlv.TARGET)
+    warm = backend.runner.device_counters()
+    assert int(warm[0, CTR_DECODE_MISS]) == 0
+    assert int(warm[0, CTR_SWEPT]) == int(warm[0, CTR_INSTR]) + 1
+    # the idle lane is swept all the same
+    assert int(warm[1, CTR_INSTR]) == 0
+    assert int(warm[1, CTR_SWEPT]) == int(warm[0, CTR_SWEPT])
+    backend.restore()
+
+
+# test_megachunk / test_meshrun shapes: compile-cache-shared
+_TLV_BUILD = dict(n_lanes=8, limit=20_000, chunk_steps=128, overlay_slots=16)
+_ENGINES = {"batch": {}, "window": {"megachunk": 3},
+            "mesh2": {"mesh_devices": 2}}
+
+
+@pytest.fixture(scope="module")
+def tlv_engines():
+    """One seed through the batch loop, the megachunk window and a
+    2-device mesh (devmangle, 3 batches each)."""
+    from wtf_tpu.analysis.trace import build_tlv_campaign
+
+    loops = {}
+    for name, kw in _ENGINES.items():
+        loop = build_tlv_campaign(mutator="devmangle", seed=0x5EED,
+                                  **_TLV_BUILD, **kw)
+        loop.fuzz(8 * 3)
+        loops[name] = loop
+    return loops
+
+
+@pytest.mark.parametrize("engine", sorted(_ENGINES))
+def test_sweep_invariants_hold_in_every_engine(tlv_engines, engine):
+    reg = tlv_engines[engine].registry
+    instr = reg.counter("device.instructions").value
+    lane_steps = reg.counter("device.lane_steps").value
+    sweeps = reg.counter("device.sweeps").value
+    assert 0 < instr <= lane_steps
+    assert sweeps * 8 == lane_steps
+    if engine != "mesh2":
+        assert sweeps == int(sweeps)     # one device: whole iterations
+
+
+def test_engines_retire_the_same_instructions_for_one_seed(tlv_engines):
+    dumps = {name: loop.registry.counter("device.instructions").value
+             for name, loop in tlv_engines.items()}
+    assert dumps["batch"] == dumps["window"] == dumps["mesh2"], dumps
+    steps = {name: loop.registry.counter("device.lane_steps").value
+             for name, loop in tlv_engines.items()}
+    # shards leave their chunk loops on their own: never more lane-steps
+    # than one device sweeping all lanes together
+    assert steps["mesh2"] <= steps["batch"], steps
+
+
+def _host_event_names(trace_dir: Path) -> set:
+    import jax
+
+    found = sorted(trace_dir.glob("plugins/profile/*/*.xplane.pb"))
+    assert found, list(trace_dir.rglob("*"))
+    names = set()
+    for plane in jax.profiler.ProfileData.from_file(str(found[-1])).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names.update(ev.name for ev in line.events)
+    return names
+
+
+def test_profiler_trace_carries_the_span_paths_on_its_host_plane(
+        tlv_engines, tmp_path):
+    """Every span is a TraceAnnotation: a jax.profiler trace of the
+    process holds the span paths beside whatever else it records."""
+    import jax
+
+    loop = tlv_engines["batch"]
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        loop.fuzz(loop.stats.testcases + 8)
+    finally:
+        jax.profiler.stop_trace()
+    names = _host_event_names(tmp_path)
+    assert {"execute/device-step", "harvest", "execute",
+            "execute/status-pull", "harvest/account"} <= names
+
+
+def test_xprof_dir_takes_one_bounded_trace_inside_a_batch(tmp_path):
+    """--xprof-dir: one short window opened by a thread inside the first
+    steady-state batch; the loop reports it as one `xprof` event."""
+    from wtf_tpu.analysis.trace import build_tlv_campaign
+
+    with EventLog(tmp_path / "events.jsonl") as events:
+        loop = build_tlv_campaign(mutator="devmangle", seed=0x5EED,
+                                  **_TLV_BUILD)
+        loop.events = events
+        from wtf_tpu.telemetry.xprof import ProfilerWindow
+
+        loop.xprof = ProfilerWindow(
+            tmp_path / "xprof", seconds=0.05, share=0.5,
+            open_path=loop.registry.spans.open_path)
+        loop.fuzz(8 * 5)
+    records = [r for r in read_events(tmp_path / "events.jsonl")
+               if r["type"] == "xprof"]
+    assert len(records) == 1, records
+    rec = records[0]
+    assert 0.04 <= rec["stop_ts"] - rec["start_ts"] < 5.0
+    assert rec["batch"] >= 3          # two warm batches come first
+    # a span that encloses the whole window begins no row inside it: the
+    # window names the path open at its two ends, in the record and in
+    # the trace
+    tops = ("mutate", "execute", "harvest", "restore", "heartbeat",
+            "(no span)")
+    assert rec["open_at_start"].split("/")[0] in tops
+    assert rec["open_at_stop"].split("/")[0] in tops
+    names = _host_event_names(tmp_path / "xprof")
+    assert f"open at start: {rec['open_at_start']}" in names
+    assert f"open at stop: {rec['open_at_stop']}" in names
+
+
+_STEP_SCOPES = ("decode-probe", "page-walk", "overlay-load",
+                "overlay-store", "ea", "alu", "unary", "shift", "mul",
+                "sse-fp", "x87", "coverage")
+
+
+def test_lowered_chunk_names_every_stage_of_the_step(tpu_backend):
+    from wtf_tpu.analysis.trace import step_executor_lowering
+
+    lowered = step_executor_lowering(tpu_backend.runner, n_steps=16,
+                                     donate=False)
+    text = lowered.as_text(debug_info=True)
+    missing = [s for s in _STEP_SCOPES if f"({s})" not in text]
+    assert not missing, missing
+
+
+@pytest.mark.parametrize("entry,total", [
+    ("xla_step", 108), ("tenant_chunk", 108),
+    ("megachunk_window_fused", 162), ("decode_service", 248),
+    ("mesh_chunk", 1),
+])
+def test_pinned_kernel_counts_did_not_move(entry, total):
+    """analysis/budgets.json as PR 24 left it (test_analysis holds the
+    measured programs to the file; this holds the file)."""
+    import wtf_tpu.analysis as analysis
+
+    budgets = json.loads(
+        (Path(analysis.__file__).parent / "budgets.json").read_text())
+    assert budgets[entry]["total"] == total
+
+
+def test_telemetry_report_prints_occupancy_and_sweeps_per_chunk(
+        kernel_campaign, capsys):
+    import telemetry_report
+
+    telem = kernel_campaign["target"] / "telem"
+    summary = telemetry_report.summarize(telem / "events.jsonl")
+    metrics = kernel_campaign["metrics"]
+    assert summary["device"]["lane_occupancy"] == round(
+        metrics["device.instructions"] / metrics["device.lane_steps"], 4)
+    assert summary["device"]["sweeps_per_chunk"] == round(
+        metrics["device.sweeps"] / metrics["runner.chunks"], 2)
+    telemetry_report._print_human(summary)
+    out = capsys.readouterr().out
+    assert "lane occupancy: " in out and "sweeps/chunk: " in out
+    # a stream from before the counter reads null, not 0
+    summary["device"]["lane_occupancy"] = None
+    summary["device"]["sweeps_per_chunk"] = None
+    telemetry_report._print_human(summary)
+    out = capsys.readouterr().out
+    assert "lane occupancy: null" in out and "sweeps/chunk: null" in out

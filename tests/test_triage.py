@@ -127,8 +127,7 @@ def test_fuzz_loop_dedups_by_bucket(backend, tmp_path):
                     Corpus(), crashes_dir=tmp_path / "crashes")
     batch = [c, d]
     results = backend.run_batch(batch, demo_tlv.TARGET)
-    for lane, (data, result) in enumerate(zip(batch, results)):
-        loop._harvest_lane(lane, data, result)
+    loop._harvest_batch(results, batch)
     demo_tlv.TARGET.restore()
     backend.restore()
     assert len(loop.crash_names) == 1          # filenames collide...

@@ -441,6 +441,19 @@ def summarize(path) -> dict:
                 if metrics.get("device.fused_window_rounds", 0) else None),
             "fused_window_bytes_saved": metrics.get(
                 "device.fused_window_bytes_saved", 0),
+            # in-graph sweep count (CTR_SWEPT): the share of lane-steps
+            # that retired an instruction, and how many iterations of
+            # its step loop a chunk dispatch really ran; null for
+            # streams from before the counter (or with no chunk at all)
+            "lane_occupancy": (
+                round(metrics.get("device.instructions", 0)
+                      / metrics["device.lane_steps"], 4)
+                if metrics.get("device.lane_steps") else None),
+            "sweeps_per_chunk": (
+                round(metrics["device.sweeps"]
+                      / metrics["runner.chunks"], 2)
+                if metrics.get("device.sweeps") is not None
+                and metrics.get("runner.chunks") else None),
         },
         "mesh": mesh,
         "triage": triage,
@@ -509,6 +522,14 @@ def _print_human(s: dict) -> None:
     print(f"device counters: instructions={dev['instructions']} "
           f"mem_faults={dev['mem_faults']} "
           f"decode_misses={dev['decode_misses']}{fused}")
+
+    def _or_null(value, fmt):
+        return "null" if value is None else fmt.format(value)
+
+    print("  lane occupancy: "
+          + _or_null(dev.get("lane_occupancy"), "{:.2%}")
+          + " of lane-steps retired an instruction")
+    print("  sweeps/chunk: " + _or_null(dev.get("sweeps_per_chunk"), "{}"))
     if dev.get("fused_window_share") is not None:
         total = (dev["fused_window_rounds"]
                  + dev["fused_window_xla_steps"])

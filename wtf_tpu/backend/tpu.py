@@ -99,7 +99,7 @@ class TpuBackend(Backend):
         # bochscpu_backend.h:17-45) — registry-backed dict facade
         self.stats = StatsDict(self.registry, "backend",
                                fields=("batches", "testcases",
-                                       "instructions"))
+                                       "instructions", "result_pulls"))
 
     # -- lifecycle ---------------------------------------------------------
     def initialize(self) -> None:
@@ -172,8 +172,7 @@ class TpuBackend(Backend):
             self._view = None
         statuses = runner.run(bp_handler=self._dispatch_bp)
         self._finish_batch(statuses, n_active)
-        return [self._map_result(lane, statuses[lane])
-                for lane in range(n_active)]
+        return self._map_results(statuses, n_active)
 
     def _finish_batch(self, statuses, n_active: int) -> None:
         """Post-run batch accounting shared by run_batch and
@@ -260,8 +259,7 @@ class TpuBackend(Backend):
                 sp.fence(runner.machine.status)
         statuses = runner.run(bp_handler=self._dispatch_bp)
         self._finish_batch(statuses, self.n_lanes)
-        return [self._map_result(lane, statuses[lane])
-                for lane in range(self.n_lanes)]
+        return self._map_results(statuses, self.n_lanes)
 
     def run_megachunk(self, mutator, target, max_batches: int,
                       n_batches: int):
@@ -354,17 +352,21 @@ class TpuBackend(Backend):
         published = 0
         if runner.device_decode:
             published = self._harvest_device_decode(out)
-        self._last_new_words = np.asarray(jax.device_get(out.new_words))
-        b_done = int(jax.device_get(out.batches))
-        incomplete = bool(jax.device_get(out.incomplete))
-        statuses = np.asarray(jax.device_get(out.statuses))
-        flags = np.asarray(jax.device_get(out.new_flags))
-        ctr_sums = np.asarray(jax.device_get(out.ctr_sums))
-        # engine-round census: [XLA step_v sweeps, Pallas dispatches]
-        # over the whole window.  Every Pallas dispatch with aliased
-        # overlay/machine planes is one avoided copy-through of those
-        # buffers — the donation win the status/telemetry rows surface.
-        er = np.asarray(jax.device_get(out.engine_rounds))
+        with spans.span("status-pull"):
+            # the window's one pull of its per-batch rows
+            self._last_new_words = np.asarray(
+                jax.device_get(out.new_words))
+            b_done = int(jax.device_get(out.batches))
+            incomplete = bool(jax.device_get(out.incomplete))
+            statuses = np.asarray(jax.device_get(out.statuses))
+            flags = np.asarray(jax.device_get(out.new_flags))
+            ctr_sums = np.asarray(jax.device_get(out.ctr_sums))
+            # engine-round census: [XLA step_v sweeps, Pallas dispatches]
+            # over the whole window.  Every Pallas dispatch with aliased
+            # overlay/machine planes is one avoided copy-through of those
+            # buffers — the donation win the status/telemetry rows
+            # surface.
+            er = np.asarray(jax.device_get(out.engine_rounds))
         self.registry.counter("device.fused_window_xla_steps").inc(
             int(er[0]))
         if int(er[1]):
@@ -410,24 +412,28 @@ class TpuBackend(Backend):
             row = statuses[b]
             frow = flags[b]
             runner.fold_counter_totals(ctr_sums[b])
-            if b == b_done - 1 and not incomplete:
-                # the live machine IS this batch's final state (the
-                # window stops on any non-clean terminal), so crash
-                # naming reads it exactly like run_batch's path
-                results = [self._map_result(lane, row[lane])
-                           for lane in range(self.n_lanes)]
-            else:
-                # interior batches are clean by the stop rule
-                results = [self._result_from_fields(
-                    StatusCode(int(row[lane])), 0, 0, 0, "")
-                    for lane in range(self.n_lanes)]
-            snap = out.cur if b == processed - 1 else out.prev
-            datas = {}
-            wanted = [lane for lane in range(self.n_lanes)
-                      if frow[lane] or isinstance(results[lane], Crash)]
-            if wanted:
-                mutator.set_current(snap.words, snap.lens)
-                datas = mutator.fetch(wanted)
+            # one `results` span per batch: the mapping and the fetch of
+            # the finds' bytes
+            with spans.span("results"):
+                if b == b_done - 1 and not incomplete:
+                    # the live machine IS this batch's final state (the
+                    # window stops on any non-clean terminal), so crash
+                    # naming reads it exactly like run_batch's path
+                    results = [self._map_result(lane, row[lane])
+                               for lane in range(self.n_lanes)]
+                else:
+                    # interior batches are clean by the stop rule
+                    results = [self._result_from_fields(
+                        StatusCode(int(row[lane])), 0, 0, 0, "")
+                        for lane in range(self.n_lanes)]
+                snap = out.cur if b == processed - 1 else out.prev
+                datas = {}
+                wanted = [lane for lane in range(self.n_lanes)
+                          if frow[lane]
+                          or isinstance(results[lane], Crash)]
+                if wanted:
+                    mutator.set_current(snap.words, snap.lens)
+                    datas = mutator.fetch(wanted)
             self._new_lane = frow
             self.stats["batches"] += 1
             self.stats["testcases"] += self.n_lanes
@@ -440,13 +446,15 @@ class TpuBackend(Backend):
             # host way — this IS the batch-at-a-time path
             statuses_fin = runner.run(bp_handler=self._dispatch_bp)
             self._finish_batch(statuses_fin, self.n_lanes)
-            results = [self._map_result(lane, statuses_fin[lane])
-                       for lane in range(self.n_lanes)]
-            frow = np.asarray(self._new_lane)
-            mutator.set_current(out.cur.words, out.cur.lens)
-            wanted = [lane for lane in range(self.n_lanes)
-                      if frow[lane] or isinstance(results[lane], Crash)]
-            datas = mutator.fetch(wanted) if wanted else {}
+            with spans.span("results"):
+                results = [self._map_result(lane, statuses_fin[lane])
+                           for lane in range(self.n_lanes)]
+                frow = np.asarray(self._new_lane)
+                mutator.set_current(out.cur.words, out.cur.lens)
+                wanted = [lane for lane in range(self.n_lanes)
+                          if frow[lane]
+                          or isinstance(results[lane], Crash)]
+                datas = mutator.fetch(wanted) if wanted else {}
             batches.append((results, frow, datas))
         return batches
 
@@ -640,6 +648,14 @@ class TpuBackend(Backend):
             return Crash(f"crash-{detail.split()[0]}")
         raise AssertionError(f"unmapped terminal status {status!r}")
 
+    def _map_results(self, statuses, n_active: int) -> List[TestcaseResult]:
+        """The batch's result list, under ONE `results` span: the per-lane
+        mapping pulls machine fields for every crashing lane, and that
+        host time belongs to no other span of `execute`."""
+        with self.registry.spans.span("results"):
+            return [self._map_result(lane, statuses[lane])
+                    for lane in range(n_active)]
+
     def _map_result(self, lane: int, status_val: int) -> TestcaseResult:
         if lane in self._lane_results:
             return self._lane_results[lane]
@@ -650,10 +666,17 @@ class TpuBackend(Backend):
         m = self.runner.machine
         return self._result_from_fields(
             status,
-            int(np.asarray(m.fault_gva)[lane]),
-            int(np.asarray(m.fault_write)[lane]),
-            int(np.asarray(m.rip)[lane]),
+            int(self._pull_field(m.fault_gva)[lane]),
+            int(self._pull_field(m.fault_write)[lane]),
+            int(self._pull_field(m.rip)[lane]),
             self.runner.lane_errors.get(lane, "hard-error"))
+
+    def _pull_field(self, field) -> np.ndarray:
+        """One device-to-host transfer of a machine field while mapping
+        results, counted (`backend.result_pulls`): the number a batched
+        pull brings down."""
+        self.stats["result_pulls"] += 1
+        return np.asarray(field)
 
     # -- Backend facade (single testcase == lane 0) ------------------------
     def run(self) -> TestcaseResult:

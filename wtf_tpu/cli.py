@@ -276,14 +276,16 @@ def build_parser() -> argparse.ArgumentParser:
                            "(wtf_tpu/fleet/store); outputs//crashes/ "
                            "become flat views of it")
     camp.add_argument("--xprof-dir", type=Path, default=None,
-                      help="capture ONE jax.profiler device trace over "
-                           "--xprof-batches steady-state batches (the "
-                           "first batches are compile/warmup and are "
-                           "skipped); open with xprof/tensorboard for "
-                           "kernel-level truth under the span timeline")
-    camp.add_argument("--xprof-batches", type=int, default=4,
-                      metavar="N",
-                      help="batches inside the --xprof-dir window")
+                      help="capture ONE short jax.profiler trace (Python "
+                           "tracer off) inside the first steady-state "
+                           "batch, at an offset drawn from --seed; its "
+                           "host plane carries the span paths beside the "
+                           "device's operations (open with xprof/"
+                           "tensorboard/Perfetto)")
+    camp.add_argument("--xprof-seconds", type=float, default=0.25,
+                      metavar="S",
+                      help="length of the --xprof-dir trace; writing one "
+                           "out costs tens of seconds per traced second")
     camp.add_argument("--coordinator", default=None,
                       help="jax.distributed coordinator address for a"
                            " multi-host launch (host:port)")
@@ -832,7 +834,8 @@ def cmd_campaign(args) -> int:
                         checkpoint_every=opts.checkpoint_every,
                         store=store, megachunk=opts.megachunk,
                         xprof_dir=args.xprof_dir,
-                        xprof_batches=args.xprof_batches)
+                        xprof_seconds=args.xprof_seconds,
+                        xprof_seed=opts.seed)
         if opts.resume:
             from wtf_tpu.resume import load_campaign, restore_campaign
 

@@ -74,6 +74,7 @@ def pack_words(b):
             | (b[..., 3::4] << jnp.uint32(24)))
 
 
+@jax.named_scope("devmut-generate")
 def generate(data, lens, cumw, seeds, *, rounds: int = 5
              ) -> Tuple[jax.Array, jax.Array]:
     """Generate one testcase per lane, entirely in-graph.

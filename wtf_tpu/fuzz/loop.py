@@ -14,8 +14,10 @@ Per batch (the batched RunTestcaseAndRestore, client.cc:88-180):
   4. target.restore + backend.restore
 
 Telemetry: every batch phase is a span (mutate / execute / harvest /
-restore — they tile run_one_batch, so their totals account for the
-campaign's wall-clock), counters live in the metrics registry behind
+restore tile run_one_batch; heartbeat and checkpoint the rest of the
+loop, so the top-level totals account for the campaign's wall-clock),
+harvest's children (account / crash-save / corpus-add, fetch on the
+device path) tile harvest, counters live in the metrics registry behind
 `CampaignStats`, and crash / new-coverage / timeout / heartbeat records
 land in the JSONL event log when one is wired.
 """
@@ -148,8 +150,8 @@ class FuzzLoop:
         store=None,
         megachunk: int = 0,
         xprof_dir: Optional[Path] = None,
-        xprof_batches: int = 4,
-        xprof_skip: int = 2,
+        xprof_seconds: float = 0.25,
+        xprof_seed: int = 0,
     ):
         self.backend = backend
         self.target = target
@@ -233,17 +235,20 @@ class FuzzLoop:
         # wrapper in run_one_batch; the ladder attaches lazily at the
         # first batch (the backend may not be initialized yet here)
         self.supervisor = getattr(backend, "supervisor", None)
-        # --xprof-dir: one jax.profiler.trace window over N STEADY-STATE
-        # batches (the first `xprof_skip` are compile/warmup noise — the
-        # profile must show the regime PERF.md's numbers describe, not
-        # tracing).  One window per campaign; device-level truth for
-        # what the span timeline (--trace-out) claims from the host side
-        self.xprof_dir = Path(xprof_dir) if xprof_dir else None
-        self.xprof_batches = int(xprof_batches)
-        self.xprof_skip = int(xprof_skip)
-        self._xprof_active = False
-        self._xprof_done = False
-        self._xprof_start_batch = 0
+        # --xprof-dir: ONE bounded jax.profiler window (telemetry/xprof.py)
+        # opened inside the first steady-state batch, at a share of the
+        # previous batch's length drawn from `xprof_seed` — device-level
+        # truth under the span paths, which every span annotates into it
+        self.xprof = None
+        if xprof_dir:
+            import random
+
+            from wtf_tpu.telemetry.xprof import ProfilerWindow
+
+            self.xprof = ProfilerWindow(
+                xprof_dir, xprof_seconds,
+                share=random.Random(xprof_seed).random(),
+                open_path=self.registry.spans.open_path)
         if self.checkpoint_every and not hasattr(backend, "coverage_state"):
             # fail at construction, not at the first cadence hit deep
             # into a campaign (the checkpoint needs the batched backend's
@@ -253,41 +258,66 @@ class FuzzLoop:
                 "(--backend=tpu); this backend has no coverage_state seam")
 
     def _account(self, data: bytes, result: TestcaseResult,
-                 requeue: bool = False, lane: Optional[int] = None) -> int:
-        """Per-result accounting shared by fuzz and minset (they used to
-        carry copy-pasted blocks of this): counters via CampaignStats,
-        crash saving + events, optional overlay-full requeue.  Returns 1
-        for a crash so batch loops can sum."""
-        if not self.stats.account(result):
-            if requeue and isinstance(result, OverlayFull):
-                digest = hex_digest(data)
-                if digest not in self._requeue_digests:
-                    self._requeue_digests.add(digest)
-                    self._requeue.append(data)
-            return 0
+                 requeue: bool = False) -> bool:
+        """Per-result accounting shared by fuzz and minset: counters via
+        CampaignStats, optional overlay-full requeue.  True for a crash
+        (the caller saves it: `_save_crash_lane`)."""
+        if self.stats.account(result):
+            return True
+        if requeue and isinstance(result, OverlayFull):
+            digest = hex_digest(data)
+            if digest not in self._requeue_digests:
+                self._requeue_digests.add(digest)
+                self._requeue.append(data)
+        return False
+
+    def _save_crash_lane(self, lane: Optional[int], data: bytes,
+                         result: Crash) -> None:
+        """Bucket + save one crash (an fsync'd file and an event)."""
         from wtf_tpu.triage.bucket import bucket_of
 
         self._save_crash(data, result, bucket_of(self.backend, lane, result))
-        return 1
 
-    def _harvest_lane(self, lane: int, data: bytes, result: TestcaseResult,
-                      requeue: bool = False, found_new=None) -> int:
-        """The ONE per-lane harvest body shared by the host, device and
-        megachunk batch paths: result accounting (+ optional
-        overlay-full requeue) and the new-coverage -> corpus/mutator/
-        event chain.  `found_new` overrides the backend's last-batch
-        flag for callers harvesting several batches at once (the
-        megachunk window's per-batch flag rows).  Returns 1 for a
-        crash."""
-        crashes = self._account(data, result, requeue=requeue, lane=lane)
-        if (self.backend.lane_found_new_coverage(lane)
-                if found_new is None else found_new):
-            self.stats.new_coverage += 1
-            if self.corpus.add(data):
-                self.mutator.on_new_coverage(data)
-                self.events.emit("new-coverage", digest=hex_digest(data),
-                                 size=len(data))
-        return crashes
+    def _harvest_batch(self, results, datas, requeue: bool = False,
+                       flags=None) -> int:
+        """The ONE harvest body shared by the host, device and megachunk
+        batch paths, in three passes so each is ONE child span of
+        `harvest` (never a span per lane): `account` (result accounting
+        + optional overlay-full requeue + the new-coverage count),
+        `crash-save` (bucket + save of every crashing lane),
+        `corpus-add` (the new-coverage -> corpus/mutator/event chain).
+        `datas` maps lane -> bytes (a list, or the device paths' dict of
+        fetched lanes);
+        `flags` overrides the backend's last-batch new-coverage flags
+        for callers harvesting several batches at once (the megachunk
+        window's per-batch rows).  Returns the crash count."""
+        spans = self.registry.spans
+        if isinstance(datas, dict):
+            def data_of(lane):
+                return datas.get(lane, b"")
+        else:
+            data_of = datas.__getitem__
+        crashed, fresh = [], []
+        with spans.span("account"):
+            for lane, result in enumerate(results):
+                if self._account(data_of(lane), result, requeue=requeue):
+                    crashed.append(lane)
+                if (self.backend.lane_found_new_coverage(lane)
+                        if flags is None else flags[lane]):
+                    self.stats.new_coverage += 1
+                    fresh.append(lane)
+        with spans.span("crash-save"):
+            for lane in crashed:
+                self._save_crash_lane(lane, data_of(lane), results[lane])
+        with spans.span("corpus-add"):
+            for lane in fresh:
+                data = data_of(lane)
+                if self.corpus.add(data):
+                    self.mutator.on_new_coverage(data)
+                    self.events.emit("new-coverage",
+                                     digest=hex_digest(data),
+                                     size=len(data))
+        return len(crashed)
 
     def _emit_timeouts(self, timeouts_before: int) -> None:
         """Aggregated: one record per batch, not one per timed-out lane."""
@@ -362,12 +392,9 @@ class FuzzLoop:
                     for _ in range(fresh)]
         with spans.span("execute"):
             results = self.backend.run_batch(testcases, self.target)
-        crashes = 0
         timeouts_before = self.stats.timeouts
         with spans.span("harvest"):
-            for lane, (data, result) in enumerate(zip(testcases, results)):
-                crashes += self._harvest_lane(lane, data, result,
-                                              requeue=True)
+            crashes = self._harvest_batch(results, testcases, requeue=True)
         self._emit_timeouts(timeouts_before)
         self._restore_batch()
         return crashes
@@ -391,18 +418,17 @@ class FuzzLoop:
         with spans.span("execute"):
             results = self.backend.run_batch_device(self.mutator,
                                                     self.target)
-        crashes = 0
         timeouts_before = self.stats.timeouts
         with spans.span("harvest"):
-            # double-buffer: batch N+1 generates while we harvest batch N
-            self.mutator.prelaunch()
-            wanted = [lane for lane, result in enumerate(results)
-                      if self.backend.lane_found_new_coverage(lane)
-                      or isinstance(result, Crash)]
-            datas = self.mutator.fetch(wanted)
-            for lane, result in enumerate(results):
-                crashes += self._harvest_lane(lane, datas.get(lane, b""),
-                                              result)
+            with spans.span("fetch"):
+                # double-buffer: batch N+1 generates while we harvest
+                # batch N
+                self.mutator.prelaunch()
+                wanted = [lane for lane, result in enumerate(results)
+                          if self.backend.lane_found_new_coverage(lane)
+                          or isinstance(result, Crash)]
+                datas = self.mutator.fetch(wanted)
+            crashes = self._harvest_batch(results, datas)
         self._emit_timeouts(timeouts_before)
         self._restore_batch()
         return crashes
@@ -448,12 +474,12 @@ class FuzzLoop:
                     # the final batch's finds enter the corpus — the
                     # legacy prelaunch samples batch k+1's slab at
                     # exactly this point of batch k's harvest, and the
-                    # bit-identical claim rides on reproducing it
-                    self.mutator.snapshot_entitled_slab()
-                for lane, result in enumerate(results):
-                    crashes += self._harvest_lane(
-                        lane, datas.get(lane, b""), result,
-                        found_new=bool(flags[lane]))
+                    # bit-identical claim rides on reproducing it.  (The
+                    # finds' bytes were fetched inside the window's
+                    # execute/results; this is a slab upload.)
+                    with spans.span("slab-sync"):
+                        self.mutator.snapshot_entitled_slab()
+                crashes += self._harvest_batch(results, datas, flags=flags)
         self._emit_timeouts(timeouts_before)
         with spans.span("restore"):
             # machine restore is in-graph (each batch's first phase);
@@ -548,18 +574,23 @@ class FuzzLoop:
         heartbeat carrying the full registry dump (per-phase span totals
         included) + an atomic status.json refresh next to the event log
         (what `wtf-tpu status` tails on a live local campaign)."""
-        fields = (self.supervisor.heartbeat_fields()
-                  if self.supervisor is not None
-                  and self.supervisor.enabled else {})
-        steady = self.steady_state_fields()
-        fields.update(steady)
-        line = self.stats.maybe_heartbeat(
-            self.events, self.registry,
-            lambda: self.stats.line(len(self.corpus), self._coverage())
-            + self._steady_line_suffix(steady),
-            every=self.stats_every, print_stats=print_stats, **fields)
-        if line is not None:
-            self._write_status(line)
+        # a top-level span: the coverage readback behind the line and the
+        # status.json write are wall clock between two batches.  The
+        # record's own dump is taken inside it, so a heartbeat's seconds
+        # show from the next record on.
+        with self.registry.spans.span("heartbeat"):
+            fields = (self.supervisor.heartbeat_fields()
+                      if self.supervisor is not None
+                      and self.supervisor.enabled else {})
+            steady = self.steady_state_fields()
+            fields.update(steady)
+            line = self.stats.maybe_heartbeat(
+                self.events, self.registry,
+                lambda: self.stats.line(len(self.corpus), self._coverage())
+                + self._steady_line_suffix(steady),
+                every=self.stats_every, print_stats=print_stats, **fields)
+            if line is not None:
+                self._write_status(line)
 
     def _write_status(self, line: str) -> None:
         """status.json beside events.jsonl, atomically replaced every
@@ -582,53 +613,17 @@ class FuzzLoop:
         except OSError:
             pass
 
-    def _maybe_xprof(self) -> None:
-        """Arm/disarm the one device-profiler window at batch
-        boundaries.  Best-effort: a platform without profiler support
-        logs once and the campaign proceeds unprofiled."""
-        if self.xprof_dir is None or self._xprof_done:
+    def _emit_xprof(self, record: Optional[dict]) -> None:
+        if record is None:
             return
-        if not self._xprof_active:
-            if self.batches_done < self.xprof_skip:
-                return
-            try:
-                import jax
+        if "error" in record:
+            import logging
 
-                jax.profiler.start_trace(str(self.xprof_dir))
-            except Exception as e:  # noqa: BLE001 - profiler is optional
-                import logging
-
-                logging.getLogger(__name__).warning(
-                    "xprof trace unavailable: %s", e)
-                self.events.emit("error", kind="xprof-start",
-                                 detail=str(e))
-                self._xprof_done = True
-                return
-            self._xprof_active = True
-            self._xprof_start_batch = self.batches_done
-            self.events.emit("xprof-start", batch=self.batches_done,
-                             dir=str(self.xprof_dir))
+            logging.getLogger(__name__).warning(
+                "xprof trace unavailable: %s", record["error"])
+            self.events.emit("error", kind="xprof", detail=record["error"])
             return
-        if (self.batches_done
-                >= self._xprof_start_batch + self.xprof_batches):
-            self._stop_xprof()
-
-    def _stop_xprof(self) -> None:
-        if not self._xprof_active:
-            return
-        self._xprof_active = False
-        self._xprof_done = True
-        try:
-            import jax
-
-            jax.profiler.stop_trace()
-        except Exception as e:  # noqa: BLE001
-            self.events.emit("error", kind="xprof-stop", detail=str(e))
-            return
-        self.events.emit("xprof-stop", batch=self.batches_done,
-                         batches=self.batches_done
-                         - self._xprof_start_batch,
-                         dir=str(self.xprof_dir))
+        self.events.emit("xprof", batch=self.batches_done, **record)
 
     def minset(self, outputs_dir, print_stats: bool = False) -> Corpus:
         """`--runs=0` mode: replay the seed corpus exactly once — no
@@ -653,7 +648,8 @@ class FuzzLoop:
 
         def harvest(start, batch, results):
             for lane, (data, result) in enumerate(zip(batch, results)):
-                self._account(data, result, lane=lane)
+                if self._account(data, result):
+                    self._save_crash_lane(lane, data, result)
                 if self.backend.lane_found_new_coverage(lane):
                     self.stats.new_coverage += 1
                     kept.add(data)
@@ -670,7 +666,9 @@ class FuzzLoop:
         self._runs_budget = runs
         try:
             while runs == 0 or self.stats.testcases < runs:
-                self._maybe_xprof()
+                if self.xprof is not None:
+                    self._emit_xprof(
+                        self.xprof.at_batch_boundary(self.batches_done))
                 found = self.run_one_batch()
                 self.batches_done += 1
                 self._maybe_checkpoint()
@@ -680,7 +678,8 @@ class FuzzLoop:
                 if stop_on_crash and found:
                     break
         finally:
-            self._stop_xprof()
+            if self.xprof is not None:
+                self._emit_xprof(self.xprof.finish())
         return self.stats
 
     def _maybe_reshard(self) -> bool:
@@ -705,7 +704,8 @@ class FuzzLoop:
         self.events.emit("reshard", batch=self.batches_done,
                          devices=self.reshard_to,
                          testcases=self.stats.testcases)
-        save_campaign(self, self.checkpoint_dir)
+        with self.registry.spans.span("checkpoint"):
+            save_campaign(self, self.checkpoint_dir)
         return True
 
     def _maybe_checkpoint(self) -> None:

@@ -191,6 +191,7 @@ def _make_body(max_batches: int, n_pages: int, len_gpr: int, ptr_gpr: int,
     if fused:
         from wtf_tpu.interp.pstep import fused_call_impl, fused_resume_impl
 
+        @jax.named_scope("run-quiesce")
         def run_quiesce(tab, image, m, limit):
             """The FUSED quiesce: the Pallas kernel is the step engine,
             the XLA ladder only the park-resume leg.  Terminates for the
@@ -215,6 +216,7 @@ def _make_body(max_batches: int, n_pages: int, len_gpr: int, ptr_gpr: int,
                 cond, qbody, (m, jnp.int32(0), jnp.int32(0)))
             return m, jnp.stack([xla_n, pl_n])
     else:
+        @jax.named_scope("run-quiesce")
         def run_quiesce(tab, image, m, limit):
             """The run-chunk ladder folded in: step until NO lane is
             RUNNING (decode misses, breakpoints and terminals all leave
@@ -262,6 +264,7 @@ def _make_body(max_batches: int, n_pages: int, len_gpr: int, ptr_gpr: int,
                     return a
                 return lax.dynamic_slice_in_dim(a, lane_base, n_lanes, 0)
 
+            @jax.named_scope("decode-service")
             def service(tabst, cnt, m, dstats, er):
                 """In-graph decode-miss service rounds around the
                 quiesce: compute per-lane blocks against the round-start

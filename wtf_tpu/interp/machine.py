@@ -67,7 +67,15 @@ CTR_PARK_MEM = 5     # fused-kernel park events for a MEMORY reason: a
                      # precise PAGE_FAULT/OVERLAY_FULL.  Distinct from
                      # CTR_PARK_SUBSET so occupancy loss is attributable
                      # (bench.py --fused-compare / telemetry_report)
-N_CTRS = 6
+CTR_SWEPT = 6        # lane-steps: every pass of the lane through step_lane
+                     # (or one iteration of the fused kernel's loop), live
+                     # or not — the denominator of lane occupancy
+                     # (CTR_INSTR / CTR_SWEPT).  On one device every lane
+                     # carries the same number (the chunk loop sweeps all
+                     # lanes together); mesh shards exit on their own.
+                     # Device passes only: instructions the host oracle
+                     # retires count in CTR_INSTR and not here
+N_CTRS = 7
 
 
 class Machine(NamedTuple):
@@ -232,6 +240,7 @@ def machine_init(
     )
 
 
+@jax.named_scope("restore")
 def _machine_restore_impl(machine: Machine,
                           snapshot_template: Machine) -> Machine:
     return snapshot_template._replace(

@@ -79,7 +79,7 @@ from wtf_tpu.interp import limbs as L
 from wtf_tpu.interp import step as S
 from wtf_tpu.interp.machine import (
     CTR_DECODE_MISS, CTR_FUSED, CTR_INSTR, CTR_PARK_MEM, CTR_PARK_SUBSET,
-    Machine, N_CTRS,
+    CTR_SWEPT, Machine, N_CTRS,
 )
 from wtf_tpu.interp.uoptable import (
     F_A32, F_BASE_REG, F_COND, F_DST_KIND, F_DST_REG, F_IDX_REG, F_LENGTH,
@@ -651,6 +651,8 @@ def _build_kernel(k_steps: int, n_fields: int, hash_size: int,
         delta = delta.at[CTR_FUSED].set(d_instr)
         delta = delta.at[CTR_PARK_SUBSET].set(d_ps)
         delta = delta.at[CTR_PARK_MEM].set(d_pm)
+        # the loop above passes every lane k_steps times, live or not
+        delta = delta.at[CTR_SWEPT].set(_u32(k_steps))
         ctr_out[0] = ctr_in[0] + delta
 
     return kernel

@@ -45,7 +45,7 @@ from wtf_tpu.cpu.interrupts import (
 from wtf_tpu.interp import limbs
 from wtf_tpu.interp.machine import (
     CTR_DECODE_MISS, CTR_FUSED, CTR_INSTR, CTR_MEM_FAULT, CTR_PARK_MEM,
-    CTR_PARK_SUBSET, Machine, machine_init, machine_restore,
+    CTR_PARK_SUBSET, CTR_SWEPT, Machine, machine_init, machine_restore,
 )
 from wtf_tpu.interp.step import fp_on_host, make_run_chunk
 from wtf_tpu.interp.uoptable import DecodeCache
@@ -67,6 +67,9 @@ _OPC_NAMES = {
     value: name[len("OPC_"):].lower()
     for name, value in vars(U).items() if name.startswith("OPC_")
 }
+
+# statuses the host delivers back into the guest through its IDT
+_FAULT_STATUSES = (int(StatusCode.PAGE_FAULT), int(StatusCode.DIVIDE_ERROR))
 
 PTE_P = 1
 PTE_W = 1 << 1
@@ -513,6 +516,7 @@ def device_insert_impl(n_pages: int, len_gpr: int, ptr_gpr: int,
     (wtf_tpu/fuzz/megachunk.py), so the two dispatch paths cannot drift.
     See `_make_device_insert` for the slot-claim contract."""
 
+    @jax.named_scope("device-insert")
     def impl(machine: Machine, words, lens, pfns, gva_l, *rest):
         n_words = words.shape[1]
         pad = n_pages * (PAGE_SIZE // 4) - n_words
@@ -1434,10 +1438,59 @@ class Runner:
                     steps=self.fused_resume_steps,
                     sync=lambda m: m.status)
                 sp.fence(self.machine.status)
-            # copy, not a view (donation note in run())
-            status = np.array(jax.device_get(self.machine.status))
+            with spans.span("status-pull"):
+                # copy, not a view (donation note in run())
+                status = np.array(jax.device_get(self.machine.status))
             if not (status == int(StatusCode.RUNNING)).any():
                 break
+
+    # -- per-chunk host work (each under one span in run()) ----------------
+    def _classify(self, status: np.ndarray,
+                  undeliverable: Set[int]) -> dict:
+        """Lanes needing host service after a chunk, by status.  Fault
+        lanes whose tenant has no IDT join `undeliverable` and stay
+        terminal."""
+        need = {
+            int(StatusCode.NEED_DECODE): [],
+            int(StatusCode.SMC): [],
+            int(StatusCode.UNSUPPORTED): [],
+            int(StatusCode.BREAKPOINT): [],
+        }
+        if self.deliver_exceptions:
+            need[int(StatusCode.PAGE_FAULT)] = []
+            need[int(StatusCode.DIVIDE_ERROR)] = []
+        for lane in np.nonzero(np.isin(status, list(need)))[0]:
+            if int(lane) in undeliverable:
+                continue  # delivery already failed: stays terminal
+            if (int(status[lane]) in _FAULT_STATUSES
+                    and not self._deliver_lane(int(lane))):
+                # heterogeneous batch: this lane's tenant has no IDT
+                # — its faults are terminal, exactly as they are in
+                # a solo campaign of that tenant
+                undeliverable.add(int(lane))
+                continue
+            need[int(status[lane])].append(int(lane))
+        return need
+
+    def _service_breakpoints(self, view: HostView, lanes: List[int],
+                             bp_handler) -> None:
+        for lane in lanes:
+            self.stats["bp_dispatches"] += 1
+            if bp_handler is None:
+                self.lane_errors[lane] = (
+                    f"breakpoint @ {view.get_rip(lane):#x} with no handler")
+                view.set_status(lane, StatusCode.CRASH)
+                continue
+            rip_before = view.get_rip(lane)
+            bp_handler(self, view, lane)
+            if view.get_status(lane) == StatusCode.BREAKPOINT:
+                # resume: suppress the bp for one step ONLY if the
+                # handler left rip in place; a redirected rip must hit
+                # any breakpoint armed at the new address (the emu
+                # backend's skip_rip-clearing semantics, emu.py:66-67)
+                if view.get_rip(lane) == rip_before:
+                    view.r["bp_skip"][lane] = np.int32(1)
+                view.set_status(lane, StatusCode.RUNNING)
 
     # -- run loop ----------------------------------------------------------
     def run(
@@ -1502,34 +1555,17 @@ class Runner:
                     # leaks into whichever later span synchronizes first
                     sp.fence(self.machine.status)
             self.stats["chunks"] += 1
-            # COPY, never a zero-copy view: the machine's buffers are
-            # donated into the next chunk call, and a live numpy view of
-            # a donated CPU buffer reads whatever XLA reuses the memory
-            # for (seen as garbage status/fpsw under multi-test processes)
-            status = np.array(jax.device_get(self.machine.status))
-            running = status == int(StatusCode.RUNNING)
-            need = {
-                int(StatusCode.NEED_DECODE): [],
-                int(StatusCode.SMC): [],
-                int(StatusCode.UNSUPPORTED): [],
-                int(StatusCode.BREAKPOINT): [],
-            }
-            if self.deliver_exceptions:
-                need[int(StatusCode.PAGE_FAULT)] = []
-                need[int(StatusCode.DIVIDE_ERROR)] = []
-            fault_statuses = (int(StatusCode.PAGE_FAULT),
-                              int(StatusCode.DIVIDE_ERROR))
-            for lane in np.nonzero(np.isin(status, list(need)))[0]:
-                if int(lane) in undeliverable:
-                    continue  # delivery already failed: stays terminal
-                if (int(status[lane]) in fault_statuses
-                        and not self._deliver_lane(int(lane))):
-                    # heterogeneous batch: this lane's tenant has no IDT
-                    # — its faults are terminal, exactly as they are in
-                    # a solo campaign of that tenant
-                    undeliverable.add(int(lane))
-                    continue
-                need[int(status[lane])].append(int(lane))
+            # one span per chunk: the pull and the per-lane classification
+            # are host time that scales with the lanes needing service
+            with spans.span("status-pull"):
+                # COPY, never a zero-copy view: the machine's buffers are
+                # donated into the next chunk call, and a live numpy view
+                # of a donated CPU buffer reads whatever XLA reuses the
+                # memory for (seen as garbage status/fpsw under
+                # multi-test processes)
+                status = np.array(jax.device_get(self.machine.status))
+                running = status == int(StatusCode.RUNNING)
+                need = self._classify(status, undeliverable)
             total = sum(len(v) for v in need.values())
             if total == 0:
                 if not running.any():
@@ -1572,27 +1608,18 @@ class Runner:
                 with spans.span("oracle-fallback"):
                     for lane in unsup_lanes:
                         self._fallback_burst(view, lane)
-            for lane in (need.get(int(StatusCode.PAGE_FAULT), [])
-                         + need.get(int(StatusCode.DIVIDE_ERROR), [])):
-                if not self._service_exception(view, lane):
-                    undeliverable.add(lane)
-            for lane in need[int(StatusCode.BREAKPOINT)]:
-                self.stats["bp_dispatches"] += 1
-                if bp_handler is None:
-                    self.lane_errors[lane] = (
-                        f"breakpoint @ {view.get_rip(lane):#x} with no handler")
-                    view.set_status(lane, StatusCode.CRASH)
-                    continue
-                rip_before = view.get_rip(lane)
-                bp_handler(self, view, lane)
-                if view.get_status(lane) == StatusCode.BREAKPOINT:
-                    # resume: suppress the bp for one step ONLY if the
-                    # handler left rip in place; a redirected rip must hit
-                    # any breakpoint armed at the new address (the emu
-                    # backend's skip_rip-clearing semantics, emu.py:66-67)
-                    if view.get_rip(lane) == rip_before:
-                        view.r["bp_skip"][lane] = np.int32(1)
-                    view.set_status(lane, StatusCode.RUNNING)
+            fault_lanes = (need.get(int(StatusCode.PAGE_FAULT), [])
+                           + need.get(int(StatusCode.DIVIDE_ERROR), []))
+            if fault_lanes:
+                # one span around the loop, never one per lane
+                with spans.span("service-exception"):
+                    for lane in fault_lanes:
+                        if not self._service_exception(view, lane):
+                            undeliverable.add(lane)
+            if need[int(StatusCode.BREAKPOINT)]:
+                with spans.span("service-bp"):
+                    self._service_breakpoints(
+                        view, need[int(StatusCode.BREAKPOINT)], bp_handler)
             with spans.span("service-push"):
                 self.push(view)
                 tab = self.device_tab()
@@ -1602,32 +1629,37 @@ class Runner:
         # runaway lane must not kill a campaign; TIMEDOUT lanes are
         # already excluded from the coverage merge by the backend's
         # include mask, so no partial-execution edges are credited.
-        status = np.array(jax.device_get(self.machine.status))
-        fault_statuses = (int(StatusCode.PAGE_FAULT),
-                          int(StatusCode.DIVIDE_ERROR))
-        nonterminal = [int(StatusCode.RUNNING), int(StatusCode.NEED_DECODE),
-                       int(StatusCode.SMC), int(StatusCode.UNSUPPORTED),
-                       int(StatusCode.BREAKPOINT), int(StatusCode.NEEDS_XLA)]
-        if self.deliver_exceptions:
-            # deliverable faults would have gone back to the guest too
-            nonterminal += list(fault_statuses)
-        stuck = [int(lane)
-                 for lane in np.nonzero(np.isin(status, nonterminal))[0]
-                 if int(lane) not in undeliverable
-                 and not (int(status[lane]) in fault_statuses
-                          and not self._deliver_lane(int(lane)))]
+        with spans.span("status-pull"):
+            status = np.array(jax.device_get(self.machine.status))
+            nonterminal = [int(StatusCode.RUNNING),
+                           int(StatusCode.NEED_DECODE),
+                           int(StatusCode.SMC), int(StatusCode.UNSUPPORTED),
+                           int(StatusCode.BREAKPOINT),
+                           int(StatusCode.NEEDS_XLA)]
+            if self.deliver_exceptions:
+                # deliverable faults would have gone back to the guest too
+                nonterminal += list(_FAULT_STATUSES)
+            stuck = [int(lane)
+                     for lane in np.nonzero(np.isin(status, nonterminal))[0]
+                     if int(lane) not in undeliverable
+                     and not (int(status[lane]) in _FAULT_STATUSES
+                              and not self._deliver_lane(int(lane)))]
         if stuck:
-            view = self.view()
+            with spans.span("service-pull"):
+                view = self.view()
             for lane in stuck:
                 self.lane_errors.setdefault(
                     lane, f"revoked: exceeded max_chunks={max_chunks}")
                 view.set_status(lane, StatusCode.TIMEDOUT)
-            self.push(view)
+            with spans.span("service-push"):
+                self.push(view)
             self.registry.counter("runner.max_chunks_timeouts").inc(
                 len(stuck))
             self.events.emit("timeout", kind="max-chunks", lanes=stuck,
                              chunks=max_chunks)
-        return np.array(jax.device_get(self.machine.status))
+            with spans.span("status-pull"):
+                status = np.array(jax.device_get(self.machine.status))
+        return status
 
     def restore(self) -> None:
         """Every lane back to the snapshot: O(1) overlay reset + register
@@ -1676,6 +1708,16 @@ class Runner:
         reg.counter("device.fused_park_subset").inc(
             int(totals[CTR_PARK_SUBSET]))
         reg.counter("device.fused_park_mem").inc(int(totals[CTR_PARK_MEM]))
+        # lane-steps (every lane's passes through the step, live or not)
+        # and sweeps = lane-steps per lane: the loop iterations of one
+        # device (whole there), the mean over shards on a mesh.  With
+        # device.instructions: lane occupancy; with runner.chunks and
+        # execute/device-step: sweeps per chunk and device time per sweep.
+        lane_steps = int(totals[CTR_SWEPT])
+        reg.counter("device.lane_steps").inc(lane_steps)
+        sweeps, rest = divmod(lane_steps, self.n_lanes)
+        reg.counter("device.sweeps").inc(
+            lane_steps / self.n_lanes if rest else sweeps)
 
     def fold_device_counters(self) -> np.ndarray:
         """Pull the counter block ONCE per burst and add the batch totals

@@ -341,6 +341,7 @@ def _gpr_write_l(gl, cond, idx, val, nbytes):
 # these standalone and fails if a 64-bit integer op appears in the HLO)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("alu")
 def alu_limb(sub, a, b, cf_in, opsize, rf_lo):
     """Integer ALU class on u32 limbs: add/adc/sub/sbb/cmp/and/or/xor/test
     plus the CF/PF/AF/ZF/SF/OF image — semantics mirror cpu/emu.py exactly
@@ -370,6 +371,7 @@ def alu_limb(sub, a, b, cf_in, opsize, rf_lo):
     return r, new_rf_lo, writes
 
 
+@jax.named_scope("unary")
 def unary_limb(sub, a, cf_in, opsize, rf_lo):
     """inc/dec/not/neg on u32 limbs (inc/dec preserve CF; neg CF = a != 0;
     not leaves rflags alone) — mirrors the deleted u64 UNARY block."""
@@ -411,6 +413,7 @@ def _scale_idx_l(v, scale):
     return jnp.where(keep, lo, _z32()), jnp.where(keep, hi, _z32())
 
 
+@jax.named_scope("ea")
 def ea_limb(disp, base, idx_scaled, seg, a32):
     """Effective address on u32 limbs: disp + base + scaled index, 67h
     truncation to 32 bits BEFORE the segment base (SDM address-size
@@ -421,6 +424,7 @@ def ea_limb(disp, base, idx_scaled, seg, a32):
     return L.add64((flat_lo, flat_hi), seg)
 
 
+@jax.named_scope("shift")
 def shift_limb(sub, sext_f, a, filler, cl_lo, src_lo, imm_lo, cf_in,
                opsize, rf_lo):
     """SHIFT/ROT class on u32 limbs: shl/shr/sar/rol/ror/rcl/rcr/shld/shrd
@@ -529,6 +533,7 @@ def shift_limb(sub, sext_f, a, filler, cl_lo, src_lo, imm_lo, cf_in,
     return r, new_rf_lo, cnz
 
 
+@jax.named_scope("mul")
 def mul_limb(sub, sext_f, a, b, rax, imm, opsize, rf_lo):
     """MUL class on u32 limbs: 2/3-operand imul plus the widening
     mul/imul forms (lo to rAX/dst, hi to rDX, 8-bit widening writes the
@@ -627,6 +632,7 @@ def _pack_pair(b16):
 # the transition function
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("decode-probe")
 def uop_lookup(tab: UopTable, rip_l):
     """Open-addressed probe (host inserter bounds chains to PROBES) ->
     entry index or -1 (NEED_DECODE).  All PROBES slots are fetched in ONE
@@ -673,6 +679,29 @@ PORTED_LIMB_PATHS = {
     "step.uop_lookup": uop_lookup,
     "step.gpr_write_l": _gpr_write_l,
 }
+
+
+@jax.named_scope("coverage")
+def _cover(cov, edge, reached, idxc, took_edge, rip, rip_exec):
+    """One lane's coverage and edge bitmaps after a step."""
+    # coverage: the instruction was reached (reference records RIP in
+    # before_execution even when the insn then faults, bochscpu:479-505)
+    wi = idxc >> 5
+    cov_bit = jnp.where(reached,
+                        jnp.uint32(1) << (idxc & 31).astype(jnp.uint32),
+                        jnp.uint32(0))
+    new_cov = cov.at[wi].set(cov[wi] | cov_bit)
+
+    # edges: taken AND not-taken control transfers (reference registers
+    # cnear_branch_taken/not_taken + ucnear hooks, bochscpu:235-257)
+    eh = _mix64(rip) ^ rip_exec
+    ebits = edge.shape[0] * 32
+    ei = (eh & _u(ebits - 1)).astype(jnp.int32)
+    edge_bit = jnp.where(took_edge,
+                         jnp.uint32(1) << (ei & 31).astype(jnp.uint32),
+                         jnp.uint32(0))
+    new_edge = edge.at[ei >> 5].set(edge[ei >> 5] | edge_bit)
+    return new_cov, new_edge
 
 
 def fp_on_host() -> bool:
@@ -1466,6 +1495,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
         return ((u & _u(0x7FF0000000000000)) == _u(0)) \
             & ((u & _u(0x7FFFFFFFFFFFFFFF)) != _u(0))
 
+    @jax.named_scope("sse-fp")
     def _fp_elementwise(fa, fb, ua, ub, nan_a, nan_b, bits, qbit, indef,
                         nanf, denf, magmask, expmask):
         """arith/minmax/sqrt/cmp over one lane-width's vector (f32[4]/f64[2]).
@@ -1581,6 +1611,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     # fp -> int (cvt/cvtt to gpr, and the packed PS2DQ/PD2DQ families):
     # rounding/range logic runs in f64 exactly like the oracle's to_int
     # (f32 widens losslessly first), indefinite = 1 << (bits-1)
+    @jax.named_scope("sse-fp")
     def _fp_to_int(v64, int_bits, truncate, src_nan):
         limit = jnp.float64(2.0) ** (int_bits - 1)
         r = jnp.where(truncate, jnp.trunc(v64),
@@ -1602,12 +1633,14 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
 
     # f32 <-> f64 converts: NaNs rebuilt at the bit level (payload shifted,
     # quiet bit forced) so the device never depends on platform NaN rules
+    @jax.named_scope("sse-fp")
     def _cvt_s2d(u32v, f32v, isnan):
         sign = (u32v.astype(jnp.uint64) >> _u(31)) << _u(63)
         frac = (u32v.astype(jnp.uint64) & _u(0x7FFFFF)) << _u(29)
         nan_bits = sign | _u(0x7FF0000000000000) | _QBIT64 | frac
         return jnp.where(isnan, nan_bits, _b64(f32v.astype(jnp.float64)))
 
+    @jax.named_scope("sse-fp")
     def _cvt_d2s(u64v, f64v, isnan):
         sign = (u64v >> _u(63)).astype(jnp.uint32) << jnp.uint32(31)
         frac = ((u64v >> _u(29)) & _u(0x3FFFFF)).astype(jnp.uint32)
@@ -1702,6 +1735,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     x_top = (fpsw_v >> _u(11)) & _u(7)
     x_i = imm & _u(7)
 
+    @jax.named_scope("x87")
     def _xphys(k):
         return ((x_top + k) & _u(7)).astype(jnp.int32)
 
@@ -1836,6 +1870,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
         jnp.where(x_is_push, (x_top - _u(1)) & _u(7),
                   (x_top + x_pops.astype(jnp.uint64)) & _u(7)))
 
+    @jax.named_scope("x87")
     def _tag_set(tw, phys_i32, val):
         sh = phys_i32.astype(jnp.uint64) * _u(2)
         return (tw & ~(_u(3) << sh)) | (_u(val) << sh)
@@ -2257,32 +2292,18 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     # per-step host sync this exists to avoid.  page_fault/miss already
     # imply `enabled`, commit implies `live`.  CTR_FUSED stays untouched
     # here: only the fused Pallas kernel (interp/pstep.py) retires into it.
+    # CTR_SWEPT counts the pass itself, whatever the lane's status.
     _f = jnp.bool_(False)
     new_ctr = st.ctr + jnp.stack(
-        [commit, page_fault, miss, _f, _f, _f]).astype(jnp.uint32)
+        [commit, page_fault, miss, _f, _f, _f,
+         jnp.bool_(True)]).astype(jnp.uint32)
     timed = commit & (limit > _u(0)) & (new_icount >= limit)
     new_rdrand = jnp.where(commit & is_(U.OPC_RDRAND), rdrand_next, st.rdrand)
     new_bp_skip = jnp.where(commit, jnp.int32(0), st.bp_skip)
 
-    # coverage: the instruction was reached (reference records RIP in
-    # before_execution even when the insn then faults, bochscpu:479-505)
-    cov_set = live
-    wi = idxc >> 5
-    cov_bit = jnp.where(cov_set,
-                        jnp.uint32(1) << (idxc & 31).astype(jnp.uint32),
-                        jnp.uint32(0))
-    new_cov = st.cov.at[wi].set(st.cov[wi] | cov_bit)
-
-    # edges: taken AND not-taken control transfers (reference registers
-    # cnear_branch_taken/not_taken + ucnear hooks, bochscpu:235-257)
     is_branch = is_(U.OPC_JMP) | is_(U.OPC_JCC) | is_call | is_ret
-    eh = _mix64(rip) ^ rip_exec
-    ebits = st.edge.shape[0] * 32
-    ei = (eh & _u(ebits - 1)).astype(jnp.int32)
-    edge_bit = jnp.where(commit & is_branch,
-                         jnp.uint32(1) << (ei & 31).astype(jnp.uint32),
-                         jnp.uint32(0))
-    new_edge = st.edge.at[ei >> 5].set(st.edge[ei >> 5] | edge_bit)
+    new_cov, new_edge = _cover(st.cov, st.edge, live, idxc,
+                               commit & is_branch, rip, rip_exec)
 
     # -- status ------------------------------------------------------------
     S = StatusCode

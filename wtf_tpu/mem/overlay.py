@@ -153,6 +153,7 @@ def pte_read_vec(
     return read_words_vec(image, overlay, slot, row, hit, off >> 3)
 
 
+@jax.named_scope("overlay-load")
 def load_windows3_vec(
     image: MemImage,
     overlay: DirtyOverlay,
@@ -254,6 +255,7 @@ def extract_pair(w0, w1, w2, gpa_first):
     return lo, hi
 
 
+@jax.named_scope("overlay-store")
 def store_window3(
     image: MemImage,
     overlay: DirtyOverlay,

@@ -67,6 +67,7 @@ def translate(
                        writable=t.writable[0], user=t.user[0])
 
 
+@jax.named_scope("page-walk")
 def translate_vec_l(
     image: MemImage, overlay: DirtyOverlay, cr3: jax.Array, gva_l: jax.Array
 ) -> Translation:

@@ -94,6 +94,7 @@ def _prefix_scan(x_in, prev):
     return cum, before
 
 
+@jax.named_scope("cov-merge")
 def _merge_core(agg_cov, agg_edge, cov_in, edge_in, prev_cov, prev_edge):
     """Prefix-credit merge of one contiguous lane block, given the OR of
     every EARLIER lane (`prev_*` — zeros for lane block 0 / the

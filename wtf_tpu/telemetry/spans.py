@@ -20,6 +20,13 @@ against wall-clock (paths without "/") and break a phase down.  Totals
 land in the owning registry as `phase.seconds{path}` / `phase.calls{path}`
 labeled counters — one metric namespace shared with everything else, one
 heartbeat dump carries it all.
+
+Every span also enters a `jax.profiler.TraceAnnotation(path)`: inert
+without a profiler session, and inside one (`--xprof-dir`, or any
+`jax.profiler` trace of the process) the span paths land on the host
+plane of the profiler's own trace, on the clock its device plane uses —
+which is what lets an idle stretch of the device be laid against the
+host span that covered it.
 """
 
 from __future__ import annotations
@@ -103,6 +110,9 @@ class TraceCollector:
         self._events: List[tuple] = []  # ("X", path, t0, dur) | ("i", ...)
         self._max = max_events
         self.dropped = 0
+        # one reading of both clocks: turns the rebased timeline's zero
+        # back into Unix time (otherData.epoch_unix_s)
+        self._unix_at = (time.time(), clock())
 
     def complete(self, path: str, t0: float, dur: float) -> None:
         self._append(("X", path, t0, dur))
@@ -149,9 +159,15 @@ class TraceCollector:
         from pathlib import Path
 
         events = self.trace_events()
+        other = {"producer": "wtf-tpu", "dropped_events": self.dropped}
+        if self._events:
+            # Unix time of ts 0 (the first event), so the timeline can be
+            # laid against heartbeat `ts` and a profiler slice's start
+            unix0, clock0 = self._unix_at
+            other["epoch_unix_s"] = unix0 + (
+                min(ev[2] for ev in self._events) - clock0)
         payload = {"traceEvents": events, "displayTimeUnit": "ms",
-                   "otherData": {"producer": "wtf-tpu",
-                                 "dropped_events": self.dropped}}
+                   "otherData": other}
         Path(path).parent.mkdir(parents=True, exist_ok=True)
         with open(path, "w", encoding="utf-8") as fh:
             json.dump(payload, fh)
@@ -169,11 +185,21 @@ class Spans:
         self._clock = clock
         self._stack: List[str] = []
         self.collector: Optional[TraceCollector] = None
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:  # pragma: no cover - jax is baked into this image
+            TraceAnnotation = None  # pure-host tools: spans still time
+        self._annotation = TraceAnnotation
 
     def span(self, name: str) -> "_SpanCtx":
         """Open a phase span (context manager; call sp.fence(value) inside
         the with-block on the device values the phase produced)."""
         return _SpanCtx(self, name)
+
+    def open_path(self) -> str:
+        """The path of the innermost open span ("" between spans).  Safe
+        to call from another thread: it copies the stack in one step."""
+        return "/".join(list(self._stack))
 
     def seconds(self, path: str) -> float:
         """Accumulated seconds recorded under `path` (0.0 if never hit)."""
@@ -195,17 +221,21 @@ class Spans:
 
 
 class _SpanCtx:
-    __slots__ = ("_spans", "_name", "_span")
+    __slots__ = ("_spans", "_name", "_span", "_anno")
 
     def __init__(self, spans: Spans, name: str):
         self._spans = spans
         self._name = name
         self._span: Optional[Span] = None
+        self._anno = None
 
     def __enter__(self) -> Span:
         spans = self._spans
         path = "/".join(spans._stack + [self._name])
         spans._stack.append(self._name)
+        if spans._annotation is not None:
+            self._anno = spans._annotation(path)
+            self._anno.__enter__()
         self._span = Span(spans, path)
         return self._span
 
@@ -214,6 +244,8 @@ class _SpanCtx:
         # is exactly what a post-mortem wants attributed
         spans = self._spans
         dt = self._span.elapsed
+        if self._anno is not None:
+            self._anno.__exit__(exc_type, exc, tb)
         if spans._stack and spans._stack[-1] == self._name:
             spans._stack.pop()
         spans._record(self._span.path, dt)
@@ -223,19 +255,27 @@ class _SpanCtx:
 
 
 class _TraceMarkCtx:
-    __slots__ = ("_spans", "_name", "_t0")
+    __slots__ = ("_spans", "_name", "_t0", "_anno")
 
     def __init__(self, spans: Spans, name: str):
         self._spans = spans
         self._name = name
         self._t0 = 0.0
+        self._anno = None
 
     def __enter__(self) -> "_TraceMarkCtx":
-        self._t0 = self._spans._clock()
+        spans = self._spans
+        if spans._annotation is not None:
+            self._anno = spans._annotation(
+                "/".join(spans._stack + [self._name]))
+            self._anno.__enter__()
+        self._t0 = spans._clock()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         spans = self._spans
+        if self._anno is not None:
+            self._anno.__exit__(exc_type, exc, tb)
         if spans.collector is not None:
             path = "/".join(spans._stack + [self._name])
             spans.collector.complete(path, self._t0,

@@ -214,8 +214,7 @@ class _TenancyMixin:
         statuses = runner.run(bp_handler=self._dispatch_bp)
         self._active_mask = active
         self._finish_batch(statuses, int(active.sum()))
-        return [self._map_result(lane, statuses[lane])
-                for lane in range(self.n_lanes)]
+        return self._map_results(statuses, self.n_lanes)
 
     # -- per-tenant checkpoint seams (wtf_tpu/tenancy/state.py) ------------
     def tenant_coverage_state(self, t: int):
