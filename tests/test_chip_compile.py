@@ -20,6 +20,7 @@ for a described chip is written to it but can never be read back.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -90,19 +91,82 @@ def loop():
     return _campaign(LANES)
 
 
+_RELAYOUT_OPCODES = ("copy", "reshape", "transpose", "select", "concatenate")
+_HLO_COMPUTATION = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
+_HLO_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(")
+_HLO_ARRAY = re.compile(r"\b[a-z]+\d+\[([\d,]*)\]")
+_HLO_CALLEE = re.compile(r"(?:body|condition|calls|to_apply)=%?([\w.\-]+)")
+
+
+def slab_relayouts(hlo_text, n_elems):
+    """Instructions inside the entry computation's while loops (every
+    computation a loop body reaches: conditions, nested loops, fusions and
+    so their roots) whose opcode is a relayout or a copy and whose result
+    holds `n_elems` elements or more.  The loop carry itself is a
+    parameter / get-tuple-element / tuple / bitcast and never listed."""
+    comps, entry, name = {}, None, None
+    for line in hlo_text.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            name = head.group(2)
+            comps[name] = []
+            entry = name if head.group(1) else entry
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    todo = [body for line in comps[entry] if " while(" in line
+            for body in re.findall(r"body=%?([\w.\-]+)", line)]
+    assert todo, "the entry computation starts no while loop"
+    seen, found = set(), []
+    while todo:
+        comp = todo.pop()
+        if comp in seen:
+            continue
+        seen.add(comp)
+        for line in comps[comp]:
+            todo += _HLO_CALLEE.findall(line)
+            ins = _HLO_INSTRUCTION.match(line)
+            if not ins or ins.group(2) not in _RELAYOUT_OPCODES:
+                continue
+            sizes = [int(np.prod([int(d) for d in dims.split(",") if d]))
+                     for dims in _HLO_ARRAY.findall(ins.group(1))]
+            if sizes and max(sizes) >= n_elems:
+                found.append(line.strip()[:200])
+    return found
+
+
 def test_chunk_program_compiles_for_v5e(loop, one_chip, compile_for):
-    """The step ladder's chunk program, as the chip traces it (float-
-    valued uops hosted: fp_host=True), and it fits the chip."""
+    """The step ladder's chunk program, as the chip traces and donates it
+    (float-valued uops hosted: fp_host=True; the machine donated), fits
+    the chip, and a sweep moves no overlay plane: inside the while loop
+    no copy / reshape / transpose / select / concatenate is as large as
+    one plane (ROADMAP S14: the parent relayouted the slab around the
+    store's scatter in every sweep), both overlay planes are aliased to
+    their outputs, and the program's temporaries stay under one data
+    plane (the parent held the relayouted planes and the u64 plane's two
+    u32 halves there)."""
     from wtf_tpu.interp.step import make_run_chunk
 
     runner = loop.backend.runner
+    operands = runner.executor_operands()
     compiled = compile_for(
         make_run_chunk(runner.chunk_steps, donate=False, jit=False,
                        fp_host=True),
-        runner.executor_operands(), one_chip)
+        operands, one_chip, donate_argnums=(2,))
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < HBM_BYTES, mem
+
+    ov = operands[2].overlay
+    plane_words = ov.valid.size            # lanes * slots * PAGE_WORDS
+    assert plane_words == LANES * ov.pfn.shape[1] * 512
+    found = slab_relayouts(compiled.as_text(), plane_words)
+    assert not found, "\n".join(found)
+    planes = ov.data.nbytes + ov.valid.nbytes
+    assert mem.alias_size_in_bytes > planes, mem
+    assert mem.temp_size_in_bytes < ov.data.nbytes, mem
 
 
 def test_step_source_has_no_float_to_int_bitcast():

@@ -24,7 +24,9 @@ from wtf_tpu.interp.uoptable import (
     M_BP, M_PFN0, M_PFN1, MU_DISP, MU_IMM, MU_RAW_HI, MU_RAW_LO,
     DecodeCache,
 )
-from wtf_tpu.mem.overlay import overlay_init
+from wtf_tpu.mem.overlay import (
+    LANE_AXES, lane as lane_view, lanes, overlay_init,
+)
 from wtf_tpu.mem.paging import translate, virt_read
 from wtf_tpu.mem.physmem import IMAGE_IN_AXES, PhysMem, lane_image
 from wtf_tpu.snapshot.synthetic import SyntheticSnapshotBuilder
@@ -130,7 +132,7 @@ class _HostService:
         for lane in range(upto):
             if st[lane] != NEED_DECODE:
                 continue
-            ov_lane = jax.tree.map(lambda x: x[lane], overlays)
+            ov_lane = lane_view(overlays, lane)
             rip = int(rips[lane])
             if not cache.has(rip):
                 if not self.decode_at(cache, ov_lane, rip):
@@ -147,8 +149,8 @@ class _HostService:
         cr3s = jnp.full((n,), self.cr3, jnp.uint64)
         blocks = jax.vmap(
             devdec.lane_block,
-            in_axes=(None, IMAGE_IN_AXES, 0, 0, 0, 0, None, None),
-        )(tab, image, overlays, cr3s, jnp.asarray(rips, jnp.uint64),
+            in_axes=(None, IMAGE_IN_AXES, LANE_AXES, 0, 0, 0, None, None),
+        )(tab, image, lanes(overlays), cr3s, jnp.asarray(rips, jnp.uint64),
           jnp.asarray(statuses, jnp.int32), jnp.zeros((2,), jnp.uint64),
           jnp.int32(0))
         out = devdec.commit_blocks(

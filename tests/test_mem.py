@@ -7,11 +7,17 @@ import pytest
 
 from wtf_tpu.core.gxa import PAGE_SIZE
 from wtf_tpu.mem.overlay import (
+    LANE_AXES,
+    commit_stores,
+    lane,
+    lanes,
     overlay_init,
     overlay_reset,
     phys_read,
     phys_read_u64,
     phys_write,
+    resident,
+    scatter_span,
 )
 from wtf_tpu.mem.paging import translate, virt_read, virt_read_u64, virt_write
 from wtf_tpu.mem.physmem import PhysMem
@@ -19,12 +25,19 @@ from wtf_tpu.snapshot.synthetic import SyntheticSnapshotBuilder
 
 
 def _lane(overlay, i=0):
-    """Extract lane i's overlay view (what vmap hands the per-lane fns)."""
-    return jax.tree.map(lambda x: x[i], overlay)
+    """Lane i's overlay view (what vmap hands the per-lane fns): its own
+    index state, the whole flat planes and its base into them."""
+    return lane(overlay, i)
 
 
 def _merge_lane(overlay, lane_overlay, i=0):
-    return jax.tree.map(lambda full, one: full.at[i].set(one), overlay, lane_overlay)
+    """Lane i's view back into the batched overlay (the view's planes
+    ARE the batch's planes)."""
+    return overlay._replace(
+        pfn=overlay.pfn.at[i].set(lane_overlay.pfn),
+        data=lane_overlay.data, valid=lane_overlay.valid,
+        count=overlay.count.at[i].set(lane_overlay.count),
+        overflow=overlay.overflow.at[i].set(lane_overlay.overflow))
 
 
 @pytest.fixture(scope="module")
@@ -99,7 +112,7 @@ def test_phys_write_crossing_and_reset(simple_mem):
     data = phys_read(simple_mem.image, ov, gpa, 2)
     assert list(np.asarray(data)) == [1, 2]
     # Restore: O(1) reset drops all dirty data.
-    ov = overlay_reset(ov)
+    ov = _lane(overlay_reset(_merge_lane(overlay_init(1, 4), ov)))
     assert int(ov.count) == 0
     data = phys_read(simple_mem.image, ov, gpa, 2)
     assert list(np.asarray(data)) == [0xAA, 0xBB]
@@ -127,16 +140,21 @@ def test_overlay_vmap_lanes(simple_mem):
     vals = jnp.arange(n, dtype=jnp.uint8)[:, None]
 
     def write_one(ov_lane, gpa, val):
-        new_ov, ok = phys_write(simple_mem.image, ov_lane, gpa, val, jnp.bool_(True))
-        return new_ov, ok
+        # under vmap the planes are unmapped: the lane's words come back
+        # as a commit, applied for every lane at once outside the vmap
+        return scatter_span(simple_mem.image, ov_lane, gpa, gpa, val,
+                            jnp.bool_(True))
 
-    ov2, oks = jax.vmap(write_one, in_axes=(0, 0, 0))(ov, gpas, vals)
+    view, oks, commit = jax.vmap(
+        write_one, in_axes=(LANE_AXES, 0, 0),
+        out_axes=(LANE_AXES, 0, 0))(lanes(ov), gpas, vals)
+    ov2 = commit_stores(resident(view), commit)
     assert bool(jnp.all(oks))
 
     def read_one(ov_lane, gpa):
         return phys_read(simple_mem.image, ov_lane, gpa, 1)
 
-    out = jax.vmap(read_one, in_axes=(0, 0))(ov2, gpas)
+    out = jax.vmap(read_one, in_axes=(LANE_AXES, 0))(lanes(ov2), gpas)
     assert list(np.asarray(out[:, 0])) == [0, 1, 2, 3]
 
 
@@ -347,3 +365,231 @@ def test_load_windows3_vec_matches_bytes(paged_guest):
         assert not bool(fault)
         got = int(lo[i]).to_bytes(8, "little") + int(hi[i]).to_bytes(8, "little")
         assert got == bytes(np.asarray(expect)), hex(start)
+
+
+# ---------------------------------------------------------------------------
+# the hot store: store_window3 (+ ensure_page), the twin of the load test
+# ---------------------------------------------------------------------------
+
+class _T:
+    """Translation-like: store_window3 reads only `.gpa`."""
+
+    def __init__(self, gpa):
+        self.gpa = gpa
+
+
+_PFN_A, _PFN_NEXT, _PFN_FAR, _PFN_B = 3, 4, 9, 11
+_PLACEMENTS = ("mid", "end", "far")     # in-page / contiguous / discontiguous
+_PROBES = (_PFN_A * PAGE_SIZE + 0x100 - 16,      # around the "mid" store
+           (_PFN_A + 1) * PAGE_SIZE - 48,        # the last bytes of page A
+           _PFN_NEXT * PAGE_SIZE,                # the first bytes of A + 1
+           _PFN_FAR * PAGE_SIZE)                 # ... and of the far frame
+_PROBE_LEN = 64
+
+
+@pytest.fixture(scope="module")
+def store_mem():
+    rng = np.random.default_rng(26)
+    return PhysMem.from_pages({
+        pfn: rng.integers(0, 256, PAGE_SIZE, dtype=np.uint8).tobytes()
+        for pfn in (_PFN_A, _PFN_NEXT, _PFN_FAR, _PFN_B)})
+
+
+def _span(placement, align, size):
+    """(gpa of the first byte, gpa of the last byte) of one store."""
+    if placement == "mid":
+        first = _PFN_A * PAGE_SIZE + 0x100 + align
+        return first, first + size - 1
+    first = (_PFN_A + 1) * PAGE_SIZE - 8 + align
+    over = align + size - 8            # bytes past the end of page A
+    if over <= 0 or placement == "end":
+        return first, first + size - 1
+    return first, _PFN_FAR * PAGE_SIZE + over - 1
+
+
+def _emu_store(emu, first, last, data):
+    """The oracle's memory takes the same store: bytes up to the end of
+    the first page at `first`, the rest ending at `last`."""
+    head = min(len(data), PAGE_SIZE - (first & (PAGE_SIZE - 1)))
+    emu.phys_write(first, data[:head])
+    if head < len(data):
+        emu.phys_write(last - (len(data) - head) + 1, data[head:])
+
+
+def _store_fns(image):
+    """(batched store, per-lane store, batched probe read, batched window
+    load) over THIS layout of the overlay — the only part of the test that
+    knows how lanes are held."""
+    from wtf_tpu.mem.overlay import (
+        extract_pair, load_windows3_vec, store_window3)
+
+    def store_one(ov, first, last, size, lo, hi, en):
+        return store_window3(image, ov, _T(first), _T(last), size, lo, hi,
+                             en)
+
+    @jax.jit
+    def store_lanes(ov, *args):
+        view, ok, commit = jax.vmap(
+            store_one, in_axes=(LANE_AXES,) + (0,) * 6,
+            out_axes=(LANE_AXES, 0, 0))(lanes(ov), *args)
+        return commit_stores(resident(view), commit), ok
+
+    @jax.jit
+    def store_jit(ov, *args):
+        one, ok, commit = store_one(ov, *args)
+        return commit_stores(one, commit), ok
+
+    def store_each(ov, *args):
+        oks = []
+        for i in range(ov.pfn.shape[0]):
+            one, ok = store_jit(_lane(ov, i), *(a[i] for a in args))
+            ov = _merge_lane(ov, one, i)
+            oks.append(ok)
+        return ov, jnp.stack(oks)
+
+    def probe_one(ov):
+        return jnp.stack([phys_read(image, ov, jnp.uint64(p), _PROBE_LEN)
+                          for p in _PROBES])
+
+    def load_one(ov, first, last):
+        w0, w1, w2 = load_windows3_vec(image, ov, first[None], last[None])
+        lo, hi = extract_pair(w0, w1, w2, first[None])
+        return lo[0], hi[0]
+
+    def probe(ov):
+        return jax.vmap(probe_one, in_axes=(LANE_AXES,))(lanes(ov))
+
+    def load(ov, first, last):
+        return jax.vmap(load_one, in_axes=(LANE_AXES, 0, 0))(
+            lanes(ov), first, last)
+
+    return store_lanes, store_each, jax.jit(probe), jax.jit(load)
+
+
+@pytest.fixture(scope="module")
+def store_fns(store_mem):
+    return _store_fns(store_mem.image)
+
+
+def _expect_probes(physmem, stores):
+    """What the oracle's memory reads at the probes after `stores`
+    ((first, last, bytes) in order)."""
+    from wtf_tpu.cpu.emu import EmuMem
+
+    emu = EmuMem(physmem)
+    for first, last, data in stores:
+        _emu_store(emu, first, last, data)
+    return [emu.phys_read(p, _PROBE_LEN) for p in _PROBES]
+
+
+def _args(spans, sizes, values, enabled):
+    return (jnp.asarray([s[0] for s in spans], jnp.uint64),
+            jnp.asarray([s[1] for s in spans], jnp.uint64),
+            jnp.asarray(sizes, jnp.int32),
+            jnp.asarray([int.from_bytes(v[:8], "little") for v in values],
+                        jnp.uint64),
+            jnp.asarray([int.from_bytes(v[8:], "little") for v in values],
+                        jnp.uint64),
+            jnp.asarray(enabled, bool))
+
+
+@pytest.mark.parametrize("case", list(range(1, 17)) + ["full", "reset"])
+def test_store_window3_matches_emu_memory(store_mem, store_fns, case):
+    """The hot store against the oracle's memory (cpu/emu.py EmuMem) and
+    against itself lane by lane: every size 1-16 at every alignment 0-7,
+    inside a page, across a contiguous and a discontiguous page boundary;
+    disabled lanes write nothing; `load_windows3_vec` reads back what was
+    stored; a full overlay sets `overflow` and ok=False and writes
+    nothing; a store after `overlay_reset` never reads stale `valid`."""
+    store_lanes, store_each, probe, load = store_fns
+    rng = np.random.default_rng(1000 + (case if isinstance(case, int) else 0))
+
+    if case == "full":
+        # one row per lane: lane 0 stores twice to page A (fits), lane 1
+        # to A then B (full), lane 2 across A | A+1 (second claim fails)
+        ov = overlay_init(3, 1)
+        val = bytes(range(1, 17))
+        a = _PFN_A * PAGE_SIZE + 0x100
+        first = _args([(a, a + 15)] * 2 + [_span("end", 4, 16)],
+                      [16] * 3, [val] * 3, [True] * 3)
+        ov, ok = store_lanes(ov, *first)
+        assert list(np.asarray(ok)) == [True, True, False]
+        b = _PFN_B * PAGE_SIZE + 8
+        second = _args([(a + 32, a + 47), (b, b + 15), (a, a + 15)],
+                       [16] * 3, [val] * 3, [True, True, False])
+        ov, ok = store_lanes(ov, *second)
+        assert list(np.asarray(ok)[:2]) == [True, False]
+        assert list(np.asarray(ov.overflow)) == [False, True, True]
+        assert list(np.asarray(ov.count)) == [1, 1, 1]
+        got = np.asarray(probe(ov))
+        want0 = _expect_probes(store_mem, [(a, a + 15, val),
+                                           (a + 32, a + 47, val)])
+        want1 = _expect_probes(store_mem, [(a, a + 15, val)])
+        want2 = _expect_probes(store_mem, [])
+        for lane_i, want in enumerate((want0, want1, want2)):
+            for j, w in enumerate(want):
+                assert bytes(got[lane_i, j]) == w, (lane_i, j)
+        return
+
+    if case == "reset":
+        ov = overlay_init(2, 2)
+        a = _PFN_A * PAGE_SIZE + 0x100
+        val = bytes(rng.integers(0, 256, 16, dtype=np.uint8))
+        ov, ok = store_lanes(ov, *_args([(a, a + 15)] * 2, [16] * 2,
+                                        [val] * 2, [True] * 2))
+        assert bool(jnp.all(ok))
+        ov = overlay_reset(ov)
+        got = np.asarray(probe(ov))
+        for j, w in enumerate(_expect_probes(store_mem, [])):
+            assert bytes(got[0, j]) == w
+        # the same row is claimed again for the same page: of the words
+        # stored before the reset only the one rewritten now is overlaid
+        ov, ok = store_lanes(ov, *_args([(a + 8, a + 8)] * 2, [1] * 2,
+                                        [b"\x5a" + bytes(15)] * 2,
+                                        [True, False]))
+        assert list(np.asarray(ov.count)) == [1, 0]
+        got = np.asarray(probe(ov))
+        want = (_expect_probes(store_mem, [(a + 8, a + 8, b"\x5a")]),
+                _expect_probes(store_mem, []))
+        for lane_i in range(2):
+            for j, w in enumerate(want[lane_i]):
+                assert bytes(got[lane_i, j]) == w, (lane_i, j)
+        return
+
+    size = case
+    lanes = [(p, al, en) for p in _PLACEMENTS for al in range(8)
+             for en in (True, False)]
+    spans = [_span(p, al, size) for p, al, _ in lanes]
+    values = [bytes(rng.integers(0, 256, 16, dtype=np.uint8))
+              for _ in lanes]
+    enabled = [en for _, _, en in lanes]
+    args = _args(spans, [size] * len(lanes),
+                 [v[:size] + bytes(16 - size) for v in values], enabled)
+
+    ov0 = overlay_init(len(lanes), 4)
+    ov, ok = store_lanes(ov0, *args)
+    ov_each, ok_each = store_each(ov0, *args)
+
+    en = np.asarray(enabled)
+    assert bool(np.all(np.asarray(ok)[en]))
+    # batched == lane by lane: index state, verdicts, and what memory reads
+    for name in ("pfn", "count", "overflow"):
+        assert np.array_equal(np.asarray(getattr(ov, name)),
+                              np.asarray(getattr(ov_each, name))), name
+    assert np.array_equal(np.asarray(ok), np.asarray(ok_each))
+    got = np.asarray(probe(ov))
+    assert np.array_equal(got, np.asarray(probe(ov_each)))
+    # disabled lanes claimed nothing
+    assert np.all(np.asarray(ov.pfn)[~en] == -1)
+    assert np.all(np.asarray(ov.count)[~en] == 0)
+    assert not np.any(np.asarray(ov.overflow))
+
+    lo, hi = load(ov, args[0], args[1])
+    for i, (span, val) in enumerate(zip(spans, values)):
+        stores = [(span[0], span[1], val[:size])] if enabled[i] else []
+        for j, w in enumerate(_expect_probes(store_mem, stores)):
+            assert bytes(got[i, j]) == w, (lanes[i], j)
+        if enabled[i]:
+            back = (int(lo[i]).to_bytes(8, "little")
+                    + int(hi[i]).to_bytes(8, "little"))
+            assert back[:size] == val[:size], lanes[i]

@@ -510,7 +510,7 @@ class TpuBackend(Backend):
         """Bytes of the 13 machine/overlay planes the fused kernel
         aliases in place (input_output_aliases) — the per-dispatch
         copy-through the donation leg eliminates, dominated by the
-        `[lanes, slots, words]` overlay data slab."""
+        overlay data slab (lanes * slots * PAGE_WORDS words)."""
         m = self.runner.machine
         ov = m.overlay
         leaves = (m.gpr_l, m.rip_l, m.rflags_l, m.status, m.icount,

@@ -69,9 +69,9 @@ from jax import lax
 from wtf_tpu.core.results import StatusCode
 from wtf_tpu.interp.machine import Machine, N_CTRS, _machine_restore_impl
 from wtf_tpu.interp.runner import device_insert_impl
-from wtf_tpu.interp.step import step_lane
+from wtf_tpu.interp.step import step_lanes
 from wtf_tpu.interp.uoptable import UopTable
-from wtf_tpu.mem.physmem import IMAGE_IN_AXES, MemImage, lane_image
+from wtf_tpu.mem.physmem import MemImage, lane_image
 from wtf_tpu.meshrun.reduce import merge_coverage, mesh_merge_local
 
 _RUNNING = int(StatusCode.RUNNING)
@@ -180,7 +180,6 @@ def _make_body(max_batches: int, n_pages: int, len_gpr: int, ptr_gpr: int,
     from wtf_tpu.interp.machine import CTR_MEM_FAULT
 
     insert = device_insert_impl(n_pages, len_gpr, ptr_gpr)
-    step_v = jax.vmap(step_lane, in_axes=(None, IMAGE_IN_AXES, 0, None))
     serviceable = SERVICEABLE_DELIVER if deliver else SERVICEABLE_BASE
     _ND = int(StatusCode.NEED_DECODE)
     B = max_batches
@@ -229,7 +228,7 @@ def _make_body(max_batches: int, n_pages: int, len_gpr: int, ptr_gpr: int,
 
             def qbody(c):
                 mm, n = c
-                return step_v(tab, image, mm, limit), n + jnp.int32(1)
+                return step_lanes(tab, image, mm, limit), n + jnp.int32(1)
 
             m, n = lax.while_loop(cond, qbody, (m, jnp.int32(0)))
             return m, jnp.stack([n, jnp.int32(0)])
@@ -281,12 +280,7 @@ def _make_body(max_batches: int, n_pages: int, len_gpr: int, ptr_gpr: int,
                     tl = tab._replace(
                         hash_tab=tabst[0], rip_l=tabst[1],
                         meta_i32=tabst[2], meta_u64=tabst[3])
-                    blocks = jax.vmap(
-                        DD.lane_block,
-                        in_axes=(None, IMAGE_IN_AXES, 0, 0, 0, 0, None,
-                                 None),
-                    )(tl, image, m.overlay, m.cr3, m.rip, m.status,
-                      bp_keys, n_bp)
+                    blocks = DD.compute_blocks(tl, image, m, bp_keys, n_bp)
                     out = DD.commit_blocks(tl, cnt, gather(blocks),
                                            gather(m.status), capacity)
                     fm = lane_slice(out.fault_mask)
@@ -449,8 +443,8 @@ def make_megachunk(max_batches: int, n_pages: int, len_gpr: int,
     policy (off on the XLA CPU backend where donation is unsound —
     step.make_run_chunk's caveat, the PR-2 finding; on elsewhere): with
     donation the machine and aggregate planes — including the kernel's
-    `[lanes, slots, words]` overlay slab, aliased through the Pallas
-    call itself — update in place across the whole window instead of
+    overlay slab (lanes * slots * PAGE_WORDS words), aliased through the
+    Pallas call itself — update in place across the whole window instead of
     copying through the executable."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"

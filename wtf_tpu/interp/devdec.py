@@ -59,6 +59,7 @@ from jax import lax
 
 from wtf_tpu.cpu import uops as U
 from wtf_tpu.cpu.uops import INT_FIELDS
+from wtf_tpu.mem import overlay as ovl
 from wtf_tpu.mem import paging
 from wtf_tpu.mem.physmem import MemImage
 
@@ -831,8 +832,8 @@ def compute_blocks(tab, image: MemImage, machine, bp_keys: jax.Array,
 
     return jax.vmap(
         lane_block,
-        in_axes=(None, IMAGE_IN_AXES, 0, 0, 0, 0, None, None),
-    )(tab, image, machine.overlay, machine.cr3, machine.rip,
+        in_axes=(None, IMAGE_IN_AXES, ovl.LANE_AXES, 0, 0, 0, None, None),
+    )(tab, image, ovl.lanes(machine.overlay), machine.cr3, machine.rip,
       machine.status, bp_keys, n_bp)
 
 
@@ -857,7 +858,7 @@ def gather_windows(image: MemImage, overlay, cr3: jax.Array,
     n_lanes = cr3.shape[0]
     img = lane_image(image, n_lanes)
     img_g = img._replace(tenant=img.tenant[idx])
-    ov_g = jax.tree.map(lambda x: x[idx], overlay)
+    ov_g = ovl.lane(overlay, idx)
 
     def one(image_l, overlay_l, cr3_l, rip):
         win, fault = paging.virt_read(image_l, overlay_l, cr3_l, rip,
@@ -870,7 +871,7 @@ def gather_windows(image: MemImage, overlay, cr3: jax.Array,
             t14.ok, (t14.gpa >> jnp.uint64(12)).astype(jnp.int32), pfn0)
         return win, fault, pfn0, pfn14
 
-    return jax.vmap(one, in_axes=(IMAGE_IN_AXES, 0, 0, 0))(
+    return jax.vmap(one, in_axes=(IMAGE_IN_AXES, ovl.LANE_AXES, 0, 0))(
         img_g, ov_g, cr3[idx], rips[idx])
 
 

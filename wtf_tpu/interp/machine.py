@@ -35,6 +35,7 @@ import numpy as np
 from wtf_tpu.core.cpustate import CpuState
 from wtf_tpu.core.results import StatusCode
 from wtf_tpu.interp.limbs import pack_u64, unpack_np
+from wtf_tpu.mem import overlay as ovl
 from wtf_tpu.mem.overlay import DirtyOverlay, overlay_init, overlay_reset
 
 # Device-side telemetry counter block: one u32 per lane per counter,
@@ -79,7 +80,8 @@ N_CTRS = 7
 
 
 class Machine(NamedTuple):
-    """All fields carry a leading lane axis."""
+    """All fields carry a leading lane axis, the overlay's two flat planes
+    included (lane-major: mem/overlay.py, "resident layout")."""
 
     # Architectural hot state, as little-endian u32 limbs (limbs.py)
     gpr_l: jax.Array      # uint32[L, 16, 2] (x86 encoding order)
@@ -131,7 +133,7 @@ class Machine(NamedTuple):
     edge: jax.Array       # uint32[L, EW] splitmix64 edge-hash bitmap
 
     # Guest memory writes (copy-on-write; reset = Restore)
-    overlay: DirtyOverlay  # fields carry the lane axis
+    overlay: DirtyOverlay  # a LaneOverlay inside a `lane_view`
 
     @property
     def n_lanes(self) -> int:
@@ -164,6 +166,19 @@ class Machine(NamedTuple):
     @property
     def gs_base(self) -> jax.Array:    # uint64[L]
         return pack_u64(self.gs_base_l)
+
+
+# vmap in_axes / out_axes of a `lane_view`: every field on the lane axis,
+# the overlay's planes unmapped
+MACHINE_LANE_AXES = Machine(**{f: 0 for f in Machine._fields})._replace(
+    overlay=ovl.LANE_AXES)
+
+
+def lane_view(machine: Machine) -> Machine:
+    """The machine as per-lane code takes it under
+    `jax.vmap(..., in_axes=MACHINE_LANE_AXES)`: the overlay as
+    `overlay.lanes()` (whole planes + each lane's base)."""
+    return machine._replace(overlay=ovl.lanes(machine.overlay))
 
 
 def _fpst_f64_bits(v: int) -> int:
@@ -253,15 +268,20 @@ def _machine_restore_impl(machine: Machine,
     )
 
 
+# keep_unused: the restored machine reads nothing of the live one but the
+# overlay's data plane, and jit would drop the other donated leaves before
+# pairing them with outputs: the zeroed planes (overlay.valid, cov, edge)
+# would then be NEW buffers beside the old ones instead of memsets in place
 _machine_restore_donated = partial(
-    jax.jit, donate_argnums=(0,))(_machine_restore_impl)
+    jax.jit, donate_argnums=(0,), keep_unused=True)(_machine_restore_impl)
 _machine_restore_plain = jax.jit(_machine_restore_impl)
 
 
 def machine_restore(machine: Machine, snapshot_template: Machine,
                     donate: bool = None) -> Machine:
-    """Restore(): every lane back to the snapshot.  O(1) in guest memory —
-    replaces the reference's dirty-page rewrite loops (SURVEY.md §5.4).
+    """Restore(): every lane back to the snapshot.  No guest page moves
+    (the overlay's validity plane is zeroed: overlay_reset) — replaces
+    the reference's dirty-page rewrite loops (SURVEY.md §5.4).
 
     `snapshot_template` is the pristine machine from machine_init.  Only its
     small per-lane register/bookkeeping arrays are used; the overlay STORAGE
@@ -271,7 +291,7 @@ def machine_restore(machine: Machine, snapshot_template: Machine,
 
     Donation (donate=True, the off-CPU hot path): `machine` is donated so
     the overlay storage is reset in place (no copy of the
-    [lanes, slots, 4096] buffer).  The template is NOT donated — XLA
+    lanes * slots * 4096-byte slab).  The template is NOT donated — XLA
     copies its leaves into the output, so the result never aliases the
     template and later run_chunk calls may donate the machine freely.
     On the CPU backend donation must stay OFF: XLA CPU's buffer reuse

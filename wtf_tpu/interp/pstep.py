@@ -86,9 +86,7 @@ from wtf_tpu.interp.uoptable import (
     F_OPC, F_OPSIZE, F_SCALE, F_SEG, F_SEXT, F_SRCSIZE, F_SRC_KIND,
     F_SRC_REG, F_SUB, M_BP, M_PFN0, M_PFN1, PROBES, UopTable,
 )
-from wtf_tpu.mem.physmem import (
-    IMAGE_IN_AXES, MemImage, PAGE_WORDS, lane_image,
-)
+from wtf_tpu.mem.physmem import MemImage, PAGE_WORDS, lane_image
 
 _RUNNING = int(StatusCode.RUNNING)
 _NEED_DECODE = int(StatusCode.NEED_DECODE)
@@ -668,12 +666,12 @@ def fused_call_impl(tab: UopTable, image: MemImage, machine: Machine,
 
     The kernel's machine-state operands (gpr..overlay, positions 11-23)
     are aliased 1:1 to its 13 outputs via `input_output_aliases`, so the
-    `[lanes, slots, words]` overlay slab — the largest HBM-resident
-    operand — updates in place instead of copying through the kernel per
-    dispatch.  XLA still inserts a defensive copy when an operand is a
-    non-donated entry parameter; pairing this with donation on the
-    enclosing executable (the window's donate_argnums) removes that last
-    copy too."""
+    overlay slab (lanes * slots * PAGE_WORDS words) — the largest
+    HBM-resident operand — updates in place instead of copying through
+    the kernel per dispatch.  XLA still inserts a defensive copy when an
+    operand is a non-donated entry parameter; pairing this with donation
+    on the enclosing executable (the window's donate_argnums) removes
+    that last copy too."""
     from jax.experimental import pallas as pl
 
     n_lanes = machine.status.shape[0]
@@ -690,7 +688,9 @@ def fused_call_impl(tab: UopTable, image: MemImage, machine: Machine,
     vwords = PAGE_WORDS // 4
 
     # u64 leaves cross the kernel boundary as free u32 bitcasts; the
-    # overlay's u8 valid plane packs 4 bytes per u32 the same way
+    # overlay's u8 valid plane packs 4 bytes per u32 the same way.  The
+    # overlay's data plane is u32 limbs already (mem/overlay.py); the
+    # kernel's per-lane blocks take both planes as [lanes, slots, words]
     tmu32 = lax.bitcast_convert_type(
         tab.meta_u64, jnp.uint32).reshape(capacity, 8)
     pages32 = lax.bitcast_convert_type(
@@ -701,8 +701,7 @@ def fused_call_impl(tab: UopTable, image: MemImage, machine: Machine,
         jnp.asarray(limit, jnp.uint64).reshape(1),
         jnp.uint32).reshape(2)
     ov = machine.overlay
-    ovdata32 = lax.bitcast_convert_type(
-        ov.data, jnp.uint32).reshape(n_lanes, slots, 2 * PAGE_WORDS)
+    ovdata32 = ov.data.reshape(n_lanes, slots, 2 * PAGE_WORDS)
     ovvalid32 = lax.bitcast_convert_type(
         ov.valid.reshape(n_lanes, slots, vwords, 4), jnp.uint32)
 
@@ -794,11 +793,8 @@ def fused_call_impl(tab: UopTable, image: MemImage, machine: Machine,
      ovpfn, ovdata, ovvalid, ovcount) = out
     overlay = ov._replace(
         pfn=ovpfn,
-        data=lax.bitcast_convert_type(
-            ovdata.reshape(n_lanes, slots, PAGE_WORDS, 2),
-            jnp.uint64),
-        valid=lax.bitcast_convert_type(
-            ovvalid, jnp.uint8).reshape(n_lanes, slots, PAGE_WORDS),
+        data=ovdata.reshape(-1),
+        valid=lax.bitcast_convert_type(ovvalid, jnp.uint8).reshape(-1),
         count=ovcount)
     return machine._replace(
         gpr_l=gpr_l, rip_l=rip_l, rflags_l=rf_l, status=status,
@@ -836,9 +832,8 @@ def fused_resume_impl(tab: UopTable, image: MemImage, machine: Machine,
     the fused window's ladder-engine round currency.  Shared by the
     jitted standalone leg (make_run_resume, which discards the count)
     and the fused megachunk window body."""
-    from wtf_tpu.interp.step import step_lane
+    from wtf_tpu.interp.step import step_lanes
 
-    step_v = jax.vmap(step_lane, in_axes=(None, IMAGE_IN_AXES, 0, None))
     running = jnp.int32(_RUNNING)
     parked = jnp.int32(_NEEDS_XLA)
 
@@ -853,7 +848,7 @@ def fused_resume_impl(tab: UopTable, image: MemImage, machine: Machine,
 
     def body(carry):
         i, m = carry
-        return i + 1, step_v(tab, image, m, limit)
+        return i + 1, step_lanes(tab, image, m, limit)
 
     iters, out = lax.while_loop(cond, body, (jnp.int32(0), machine))
     # release held lanes (step_lane never emits NEEDS_XLA itself, so

@@ -49,6 +49,7 @@ from wtf_tpu.interp.machine import (
 )
 from wtf_tpu.interp.step import fp_on_host, make_run_chunk
 from wtf_tpu.interp.uoptable import DecodeCache
+from wtf_tpu.mem import overlay as ovl
 from wtf_tpu.snapshot.loader import Snapshot
 from wtf_tpu.supervise import Supervisor
 from wtf_tpu.telemetry import NULL, Registry, StatsDict
@@ -191,10 +192,8 @@ class HostView:
         slots = np.nonzero(self._ov_pfn[lane] == pfn)[0]
         if len(slots) == 0:
             return None
-        slot = int(slots[0])
-        ov = self.runner.machine.overlay
-        data = np.asarray(ov.data[lane, slot])
-        valid = np.asarray(ov.valid[lane, slot])
+        data, valid = ovl.fetch_row(
+            self.runner.machine.overlay, lane, int(slots[0]))
         # delta row: only valid words come from the overlay, the rest
         # from the base image (little-endian words -> bytes on a LE host)
         base = np.frombuffer(self._base_page(lane, pfn), dtype=np.uint64)
@@ -477,20 +476,19 @@ def _apply_page_writes(machine: Machine, lanes, pfns, pages, ok_mask):
         can = overlay.count[lane] < capacity
         slot = jnp.where(hit, idx0, overlay.count[lane] % capacity)
         do = ok & (hit | can)
-        data = overlay.data.at[lane, slot].set(
-            jnp.where(do, page, overlay.data[lane, slot]))
+        old_data, old_valid = ovl.read_row(overlay, lane, slot)
         # a whole-page host write makes every word of the delta row valid
-        valid = overlay.valid.at[lane, slot].set(
-            jnp.where(do, jnp.ones_like(overlay.valid[lane, slot]),
-                      overlay.valid[lane, slot]))
+        overlay = ovl.write_row(
+            overlay, lane, slot, jnp.where(do, page, old_data),
+            jnp.where(do, jnp.ones_like(old_valid), old_valid))
         pfn_new = overlay.pfn.at[lane, slot].set(
             jnp.where(do, pfn, overlay.pfn[lane, slot]).astype(jnp.int32))
         count = overlay.count.at[lane].add(
             jnp.where(ok & ~hit & can, 1, 0).astype(jnp.int32))
         overflow = overlay.overflow.at[lane].set(
             overlay.overflow[lane] | (ok & ~hit & ~can))
-        return overlay._replace(pfn=pfn_new, data=data, valid=valid,
-                                count=count, overflow=overflow), None
+        return overlay._replace(pfn=pfn_new, count=count,
+                                overflow=overflow), None
 
     overlay, _ = lax.scan(body, machine.overlay, (lanes, pfns, pages, ok_mask))
     # A host write that exceeded the lane's slots was dropped — surface the
@@ -548,12 +546,9 @@ def device_insert_impl(n_pages: int, len_gpr: int, ptr_gpr: int,
                            jnp.int32(capacity - 1))
         sel = ok[:, None]
         full = ~can if active is None else (active & ~can)
-        overlay = ov._replace(
-            data=ov.data.at[li, ridx].set(
-                jnp.where(sel[..., None], rows, ov.data[li, ridx])),
-            valid=ov.valid.at[li, ridx].set(
-                jnp.where(sel[..., None], jnp.uint8(1),
-                          ov.valid[li, ridx])),
+        overlay = ovl.fill_rows(
+            ov, start, rows.reshape(n_lanes, n_pages * (PAGE_SIZE // 8)),
+            ok)._replace(
             pfn=pfn0.at[li, ridx].set(
                 jnp.where(sel, jnp.broadcast_to(pfns, (n_lanes, n_pages)),
                           pfn0[li, ridx])),

@@ -8,7 +8,7 @@ branchy C++ per instruction, every lane of the batch advances one
 divergence.  The host decodes (cpu/decoder.py), publishes uops to the device
 table (interp/uoptable.py), and this module consumes them.
 
-Structure of `step_lane` (single lane; `jax.vmap` adds the lane axis):
+Structure of `step_lane` (single lane; `step_lanes` adds the lane axis):
   1. hash-probe the uop table with rip          -> NEED_DECODE on miss
   2. breakpoint check (honoring bp_skip)        -> BREAKPOINT (pre-execution,
      like the reference's BeforeExecutionHook dispatch, bochscpu:545-547)
@@ -40,6 +40,7 @@ convert back at the pack_u64/unpack_u64 seam, which XLA lowers for free.
 from __future__ import annotations
 
 from functools import partial
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -52,7 +53,7 @@ from wtf_tpu.cpu import uops as U
 from wtf_tpu.cpu.emu import MSR_ATTR
 from wtf_tpu.cpu.cpuid import CPUID_TABLE, MAX_BASIC_LEAF
 from wtf_tpu.interp import limbs as L
-from wtf_tpu.interp.machine import Machine
+from wtf_tpu.interp.machine import MACHINE_LANE_AXES, Machine, lane_view
 from wtf_tpu.interp.uoptable import (
     F_A32,
     F_BASE_REG, F_COND, F_DST_KIND, F_DST_REG, F_IDX_REG, F_LENGTH, F_LOCK,
@@ -61,7 +62,8 @@ from wtf_tpu.interp.uoptable import (
     MU_RAW_LO, PROBES, UopTable,
 )
 from wtf_tpu.mem.overlay import (
-    extract_pair, load_windows3_vec, store_window3,
+    StoreCommit, commit_stores, extract_pair, load_windows3_vec, resident,
+    store_window3,
 )
 from wtf_tpu.mem.paging import Translation, translate_vec_l
 from wtf_tpu.mem.physmem import IMAGE_IN_AXES, MemImage, lane_image
@@ -718,8 +720,11 @@ def fp_on_host() -> bool:
 
 
 def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
-              fp_host: bool = None) -> Machine:
-    """Advance one lane by one instruction (vmapped over the batch).
+              fp_host: bool = None) -> Tuple[Machine, StoreCommit]:
+    """Advance one lane by one instruction (vmapped over the batch by
+    `step_lanes`, which hands it a `lane_view` and commits the store).
+    Returns (lane', store): the overlay's planes pass through untouched,
+    the words the instruction stores come back as a `StoreCommit`.
 
     Lanes whose status != RUNNING are a no-op.  `limit` is the instruction
     budget (u64; 0 = unlimited) -> TIMEDOUT, the deterministic equivalent of
@@ -2131,8 +2136,9 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     commit_pre = live & ~unsupported & ~is_crash & ~de & ~page_fault \
         & ~fp_oracle
 
-    overlay, store_ok = store_window3(image, overlay, ts0, ts1, st_size,
-                                      st_lo, st_hi, st_need & commit_pre)
+    overlay, store_ok, store = store_window3(
+        image, overlay, ts0, ts1, st_size, st_lo, st_hi,
+        st_need & commit_pre)
     ovf = st_need & commit_pre & ~store_ok
     commit = commit_pre & ~ovf
 
@@ -2353,12 +2359,28 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
         status=new_status, icount=new_icount, rdrand=new_rdrand,
         bp_skip=new_bp_skip, fault_gva=new_fault_gva,
         fault_write=new_fault_write, ctr=new_ctr, cov=new_cov,
-        edge=new_edge, overlay=overlay)
+        edge=new_edge, overlay=overlay), store
 
 
 # ---------------------------------------------------------------------------
 # chunked batch run
 # ---------------------------------------------------------------------------
+
+def step_lanes(tab: UopTable, image: MemImage, machine: Machine, limit,
+               fp_host: bool = None) -> Machine:
+    """ONE sweep: `step_lane` over every lane, then every lane's store in
+    one flat scatter per overlay plane.  The planes ride through the vmap
+    unmapped (each lane gathers from the resident buffer), so inside a
+    while loop the carry is updated in place and no operation reads or
+    writes a whole plane (mem/overlay.py, "resident layout").  `image` is
+    a `lane_image`."""
+    m, store = jax.vmap(
+        partial(step_lane, fp_host=fp_host),
+        in_axes=(None, IMAGE_IN_AXES, MACHINE_LANE_AXES, None),
+        out_axes=(MACHINE_LANE_AXES, 0),
+    )(tab, image, lane_view(machine), limit)
+    return m._replace(overlay=commit_stores(resident(m.overlay), store))
+
 
 _CHUNK_CACHE: dict = {}
 
@@ -2409,8 +2431,7 @@ def make_run_chunk(n_steps: int, donate: bool = None, jit: bool = True,
         if cached is not None:
             return cached
 
-    step_v = jax.vmap(partial(step_lane, fp_host=fp_host),
-                      in_axes=(None, IMAGE_IN_AXES, 0, None))
+    step_v = partial(step_lanes, fp_host=fp_host)
 
     def run_chunk(tab: UopTable, image: MemImage, machine: Machine, limit):
         # normalize in-body: the per-lane tenant selector is always

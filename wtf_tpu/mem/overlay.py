@@ -5,10 +5,10 @@ restore machinery (bochs write hooks bochscpu_backend.cc:550-593, KVM dirty
 bitmaps kvm_backend.cc:1568-1637, WHV R-X write-protection faults
 whv_backend.cc:1163-1189, and `Ram_t::Restore` ram.h:235-280).  Instead of
 mutating guest RAM and rolling dirty pages back after every testcase, each
-lane owns a small copy-on-write overlay: the first write to a page copies it
-from the shared HBM image into the lane's overlay slot, and every later
-read/write checks the overlay first.  `Restore()` is then a counter reset —
-no page data ever moves.
+lane owns a small copy-on-write overlay: the first write to a page claims
+one of the lane's overlay rows, and every later read/write checks the
+overlay first.  `Restore()` frees every row and zeroes the word-validity
+plane — no page data ever moves.
 
 Layout: page data is uint64 WORDS (little-endian), matching MemImage.  The
 hot primitives operate on aligned word windows — `pte_read` (1 word),
@@ -17,8 +17,37 @@ read-modify-write) — so a memory access costs a handful of word gathers
 instead of per-byte gathers.  Byte-granular `gather_bytes`/`scatter_span`
 remain for host-driven paths (testcase insertion, traces, tests).
 
-All functions here operate on a SINGLE lane's overlay and are `vmap`ped over
-the lane axis by the interpreter (MemImage broadcast, Overlay mapped).
+RESIDENT LAYOUT: the two big planes (`data`, `valid`) of the batched
+`DirtyOverlay` are FLAT and lane-major: word `w` of row `r` of lane `l`
+is word `(l * capacity + r) * PAGE_WORDS + w` of the planes
+(`row_start`).  `valid` holds one byte per word; `data` holds each u64
+word as its two little-endian u32 limbs side by side (the words' memory
+image, `uint32[2 * words]`), because the chip has no 64-bit integers:
+a u64 plane would be split into a low and a high u32 plane on the way into
+every dispatch and combined on the way out, with both planes as
+temporaries.  A flat plane is the layout the TPU compiler scatters into.
+Held as `[lanes, capacity, PAGE_WORDS]` (the layout until PR 26) the v5e
+compile of the chunk program served the per-lane store, inside the while
+body and on every sweep, as
+
+  %copy.1935 = u32[16384,4,8,128]{3,1,2,0:T(4,128)} copy(%bitcast...)
+  %reshape.4693 = u32[1024,128,512]{2,1,0:T(8,128)} reshape(%fusion.5376)
+
+i.e. it relayouted each whole plane (tile (8,128) -> (4,128)), bitcast it
+to `u32[67108864]{0:T(1024)}` for the scatter, and reshaped it back: 4 GB
+moved at 4096 lanes to store 48 KB.  A flat plane already IS
+`{0:T(1024)}`, so the scatter updates the loop carry in place.
+
+The per-lane functions below take a `LaneOverlay`: the lane's own index
+state plus the WHOLE planes and the lane's `base` offset into them.
+`jax.vmap` them over `lanes(overlay)` with `in_axes=LANE_AXES` (planes
+unmapped: every lane gathers from the one resident buffer).  Because the
+planes are unmapped, a vmapped store cannot return them: `store_window3`
+and `scatter_span` return a `StoreCommit` (plane indices + words), and
+the caller applies ONE flat scatter per plane for all lanes with
+`commit_stores` OUTSIDE the vmap.  The three sweeping engines
+(step.make_run_chunk, the megachunk ladder, the fused resume leg) do
+that through `step.step_lanes`.
 """
 
 from __future__ import annotations
@@ -27,8 +56,10 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from wtf_tpu.core.gxa import PAGE_SHIFT, PAGE_SIZE
+from wtf_tpu.interp.limbs import pack_u64, unpack_u64
 from wtf_tpu.mem.physmem import MemImage, PAGE_WORDS, frame_slot
 
 # pfn sentinel for "out of physical range" — never matches a stored pfn and
@@ -53,44 +84,188 @@ def _shr(x, s):
 
 
 class DirtyOverlay(NamedTuple):
-    """One lane's dirty pages (batched: leading lane axis on every field).
+    """Every lane's dirty pages, as the machine holds them between
+    dispatches (the RESIDENT layout; see the module docstring for why the
+    planes are flat).
 
-    Rows are DELTAS, not copies: `valid[row, w]` marks the words of `data`
-    that have been written; reads take the overlay word when its valid
-    byte is set and the base image word otherwise.  Allocating a slot
-    therefore never copies the 4 KiB base page — the former copy-on-write
-    fill was the hot path's dominant memory traffic (16 KiB/lane/step on
-    store-heavy code)."""
+    Rows are DELTAS, not copies: the `valid` byte of a word marks that it
+    has been written; reads take the overlay word when its valid byte is
+    set and the base image word otherwise.  Allocating a row therefore
+    never copies the 4 KiB base page.  INVARIANT: every word of a FREE
+    row has valid == 0 (`overlay_init`/`overlay_reset` zero the plane),
+    so claiming a row writes nothing but its pfn."""
 
-    pfn: jax.Array       # int32[capacity]; -1 = free slot
-    data: jax.Array      # uint64[capacity, PAGE_WORDS]
-    valid: jax.Array     # uint8[capacity, PAGE_WORDS]; 1 = word overlaid
-    count: jax.Array     # int32 scalar: allocated slots
-    overflow: jax.Array  # bool scalar: lane ran out of overlay slots
+    pfn: jax.Array       # int32[lanes, capacity]; -1 = free row
+    data: jax.Array      # uint32[2 * lanes * capacity * PAGE_WORDS]: limbs
+    valid: jax.Array     # uint8[lanes * capacity * PAGE_WORDS]; 1 = overlaid
+    count: jax.Array     # int32[lanes]: allocated rows
+    overflow: jax.Array  # bool[lanes]: lane ran out of overlay rows
+
+
+class LaneOverlay(NamedTuple):
+    """ONE lane's view of a `DirtyOverlay` — what the per-lane functions
+    of this module take.  `data`/`valid` are the WHOLE resident planes
+    (unmapped under vmap); `base` is the plane index of the lane's row 0."""
+
+    pfn: jax.Array       # int32[capacity]
+    data: jax.Array      # the whole uint32 limb plane
+    valid: jax.Array     # the whole uint8 plane
+    count: jax.Array     # int32 scalar
+    overflow: jax.Array  # bool scalar
+    base: jax.Array      # int32 scalar
+
+
+# vmap in_axes / out_axes for a `lanes(overlay)` view
+LANE_AXES = LaneOverlay(pfn=0, data=None, valid=None, count=0, overflow=0,
+                        base=0)
+
+
+class StoreCommit(NamedTuple):
+    """Words a lane's store writes, by plane index (distinct and in
+    bounds; a disabled store rewrites what was there)."""
+
+    idx: jax.Array       # int32[K] word index (the limbs' is twice that)
+    data: jax.Array      # uint64[K]
+    valid: jax.Array     # uint8[K]
 
 
 def overlay_init(n_lanes: int, capacity: int) -> DirtyOverlay:
     """Allocate the batched overlay store for `n_lanes` lanes."""
+    n_words = n_lanes * capacity * PAGE_WORDS
+    assert 2 * n_words < 1 << 31, "overlay plane indices are int32"
     return DirtyOverlay(
         pfn=jnp.full((n_lanes, capacity), -1, dtype=jnp.int32),
-        data=jnp.zeros((n_lanes, capacity, PAGE_WORDS), dtype=jnp.uint64),
-        valid=jnp.zeros((n_lanes, capacity, PAGE_WORDS), dtype=jnp.uint8),
+        data=jnp.zeros((2 * n_words,), dtype=jnp.uint32),
+        valid=jnp.zeros((n_words,), dtype=jnp.uint8),
         count=jnp.zeros((n_lanes,), dtype=jnp.int32),
         overflow=jnp.zeros((n_lanes,), dtype=bool),
     )
 
 
 def overlay_reset(overlay: DirtyOverlay) -> DirtyOverlay:
-    """Restore(): drop every dirty page, O(1) in page data.
+    """Restore(): drop every dirty page; no page data moves.
 
-    Replaces `Ram_t::Restore` + per-backend dirty loops (ram.h:235-280)."""
+    Replaces `Ram_t::Restore` + per-backend dirty loops (ram.h:235-280).
+    The validity plane is zeroed here (one byte per overlay word, written
+    in place under donation) so that no store has to clear a row when it
+    claims it: a restore happens once a testcase, a claim in every sweep
+    of every lane."""
     return DirtyOverlay(
         pfn=jnp.full_like(overlay.pfn, -1),
-        data=overlay.data,   # stale data is unreachable once pfn is -1
-        valid=overlay.valid,  # stale too: cleared when a slot reallocates
+        data=overlay.data,   # stale data is unreachable once valid is 0
+        valid=jnp.zeros_like(overlay.valid),
         count=jnp.zeros_like(overlay.count),
         overflow=jnp.zeros_like(overlay.overflow),
     )
+
+
+def lanes(overlay: DirtyOverlay) -> LaneOverlay:
+    """Every lane's `LaneOverlay`, batched on the lane axis except for the
+    planes: `jax.vmap(f, in_axes=LANE_AXES)(lanes(ov))`.  `base` is local
+    to the planes in hand, so a mesh shard's view indexes its own slab."""
+    n_lanes, capacity = overlay.pfn.shape
+    base = jnp.arange(n_lanes, dtype=jnp.int32) * jnp.int32(
+        capacity * PAGE_WORDS)
+    return LaneOverlay(overlay.pfn, overlay.data, overlay.valid,
+                       overlay.count, overlay.overflow, base)
+
+
+def lane(overlay: DirtyOverlay, i) -> LaneOverlay:
+    """Lane `i`'s view (unbatched callers: tests, host-side probes); an
+    index ARRAY gives the batched view of those lanes."""
+    v = lanes(overlay)
+    return LaneOverlay(v.pfn[i], v.data, v.valid, v.count[i],
+                       v.overflow[i], v.base[i])
+
+
+def resident(view: LaneOverlay) -> DirtyOverlay:
+    """Back from a (batched) `lanes()` view to the resident overlay."""
+    return DirtyOverlay(view.pfn, view.data, view.valid, view.count,
+                        view.overflow)
+
+
+def row_start(overlay: DirtyOverlay, lane_idx, row):
+    """Plane index of word 0 of (lane, row)."""
+    return (lane_idx * overlay.pfn.shape[-1] + row) * PAGE_WORDS
+
+
+def _limbs(words: jax.Array) -> jax.Array:
+    """uint64[..., n] -> uint32[..., 2 * n], limbs side by side."""
+    return unpack_u64(words).reshape(words.shape[:-1] + (-1,))
+
+
+def _words(limbs: jax.Array) -> jax.Array:
+    """uint32[..., 2 * n] -> uint64[..., n]."""
+    return pack_u64(limbs.reshape(limbs.shape[:-1] + (-1, 2)))
+
+
+def _limb_index(at: jax.Array) -> jax.Array:
+    """Word indices -> int32[..., 2] indices of their two limbs."""
+    return jnp.stack([2 * at, 2 * at + 1], axis=-1)
+
+
+def read_row(overlay: DirtyOverlay, lane_idx, row
+             ) -> Tuple[jax.Array, jax.Array]:
+    """(data u64[PAGE_WORDS], valid u8[PAGE_WORDS]) of one overlay row."""
+    start = row_start(overlay, lane_idx, row)
+    return (_words(lax.dynamic_slice(overlay.data, (2 * start,),
+                                     (2 * PAGE_WORDS,))),
+            lax.dynamic_slice(overlay.valid, (start,), (PAGE_WORDS,)))
+
+
+_read_row_jit = jax.jit(read_row)
+
+
+def fetch_row(overlay: DirtyOverlay, lane_idx: int, row: int):
+    """`read_row` for the host mirror: one dispatch and one transfer ->
+    (np.uint64[PAGE_WORDS], np.uint8[PAGE_WORDS])."""
+    return jax.device_get(_read_row_jit(overlay, lane_idx, row))
+
+
+def write_row(overlay: DirtyOverlay, lane_idx, row, data, valid
+              ) -> DirtyOverlay:
+    """Overwrite one whole overlay row (host page pushes)."""
+    start = row_start(overlay, lane_idx, row)
+    return overlay._replace(
+        data=lax.dynamic_update_slice(overlay.data, _limbs(data),
+                                      (2 * start,)),
+        valid=lax.dynamic_update_slice(overlay.valid, valid, (start,)))
+
+
+def fill_rows(overlay: DirtyOverlay, first_row, words,
+              enabled) -> DirtyOverlay:
+    """Each enabled lane overwrites its consecutive rows from
+    `first_row[lane]` with `words[lane]` (u64[lanes, n * PAGE_WORDS]:
+    whole pages, every word valid) — the batch's testcase insert."""
+    n_lanes = overlay.pfn.shape[0]
+    start = row_start(overlay, jnp.arange(n_lanes, dtype=jnp.int32),
+                      first_row)
+    # a window that starts past the plane is out of bounds and dropped
+    start = jnp.where(enabled, start, overlay.valid.shape[0])[:, None]
+    dnums = lax.ScatterDimensionNumbers(
+        update_window_dims=(1,), inserted_window_dims=(),
+        scatter_dims_to_operand_dims=(0,))
+
+    def put(plane, at, updates):
+        return lax.scatter(plane, at, updates, dnums, unique_indices=True,
+                           mode=lax.GatherScatterMode.FILL_OR_DROP)
+
+    return overlay._replace(
+        data=put(overlay.data, 2 * start, _limbs(words)),
+        valid=put(overlay.valid, start,
+                  jnp.ones(words.shape, jnp.uint8)))
+
+
+@jax.named_scope("overlay-store")
+def commit_stores(overlay, commit: StoreCommit):
+    """Apply a (possibly lane-batched) `StoreCommit` to the planes of a
+    `DirtyOverlay` or `LaneOverlay`: one flat scatter per plane."""
+    idx = commit.idx.reshape(-1)
+    return overlay._replace(
+        data=overlay.data.at[_limb_index(idx)].set(
+            unpack_u64(commit.data.reshape(-1)), unique_indices=True),
+        valid=overlay.valid.at[idx].set(commit.valid.reshape(-1),
+                                        unique_indices=True))
 
 
 def split_gpa(image: MemImage, gpa: jax.Array):
@@ -103,7 +278,7 @@ def split_gpa(image: MemImage, gpa: jax.Array):
     return pfn, off
 
 
-def lookup(overlay: DirtyOverlay, pfn: jax.Array) -> Tuple[jax.Array, jax.Array]:
+def lookup(overlay: LaneOverlay, pfn: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Find `pfn` in this lane's overlay -> (slot index, hit)."""
     eq = overlay.pfn == pfn
     idx = jnp.argmax(eq).astype(jnp.int32)
@@ -112,7 +287,7 @@ def lookup(overlay: DirtyOverlay, pfn: jax.Array) -> Tuple[jax.Array, jax.Array]
 
 
 def lookup_vec(
-    overlay: DirtyOverlay, pfn_vec: jax.Array
+    overlay: LaneOverlay, pfn_vec: jax.Array
 ) -> Tuple[jax.Array, jax.Array]:
     """Find K pfns in this lane's overlay at once -> (idx[K], hit[K]).
 
@@ -128,7 +303,7 @@ def lookup_vec(
 
 def read_words_vec(
     image: MemImage,
-    overlay: DirtyOverlay,
+    overlay: LaneOverlay,
     slot_vec: jax.Array,    # int32[K] image page slots
     row_vec: jax.Array,     # int32[K] overlay rows
     use_ov_vec: jax.Array,  # bool[K]: the page hit an overlay slot
@@ -137,13 +312,19 @@ def read_words_vec(
     """K overlay-aware aligned words in three gathers (image + overlay
     data + overlay word-validity)."""
     base = image.pages[slot_vec, widx_vec]
-    ov = overlay.data[row_vec, widx_vec]
-    ov_valid = overlay.valid[row_vec, widx_vec] != 0
-    return jnp.where(use_ov_vec & ov_valid, ov, base)
+    at = overlay.base + row_vec * PAGE_WORDS + widx_vec
+    ov_valid = overlay.valid[at] != 0
+    return jnp.where(use_ov_vec & ov_valid, read_data(overlay, at), base)
+
+
+def read_data(overlay: LaneOverlay, at: jax.Array) -> jax.Array:
+    """The u64 data words at word indices `at` (any shape): one gather of
+    their limb pairs."""
+    return pack_u64(overlay.data[_limb_index(at)])
 
 
 def pte_read_vec(
-    image: MemImage, overlay: DirtyOverlay, gpa_vec: jax.Array
+    image: MemImage, overlay: LaneOverlay, gpa_vec: jax.Array
 ) -> jax.Array:
     """K 8-aligned little-endian u64 reads (one page-walk level for every
     translation a step needs) -> u64[K]."""
@@ -156,7 +337,7 @@ def pte_read_vec(
 @jax.named_scope("overlay-load")
 def load_windows3_vec(
     image: MemImage,
-    overlay: DirtyOverlay,
+    overlay: LaneOverlay,
     gpa_first_vec: jax.Array,  # uint64[K] first-byte GPA per window
     gpa_last_vec: jax.Array,   # uint64[K] last-byte GPA per window
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -188,11 +369,14 @@ def load_windows3_vec(
 
 
 def ensure_page(
-    image: MemImage, overlay: DirtyOverlay, pfn: jax.Array, enabled: jax.Array
-) -> Tuple[DirtyOverlay, jax.Array, jax.Array]:
+    image: MemImage, overlay: LaneOverlay, pfn: jax.Array, enabled: jax.Array
+) -> Tuple[LaneOverlay, jax.Array, jax.Array]:
     """Claim an overlay slot for `pfn` when `enabled` (delta semantics: the
     row's data words are MEANINGLESS until their valid bytes are set by a
     store — always read through `read_words_vec`, never `data` directly).
+    Only the lane's index state changes: a free row's valid bytes are
+    already zero (the `DirtyOverlay` invariant), so the planes are not
+    touched.
 
     Returns (overlay', slot index, ok).  ok=False when the overlay is full
     (the run loop surfaces that lane as a hard error) or pfn is out of range.
@@ -205,11 +389,6 @@ def ensure_page(
     do_alloc = enabled & ~hit & can_alloc & in_range
     idx = jnp.where(hit, idx0, overlay.count % capacity).astype(jnp.int32)
 
-    # delta rows: allocation just claims the slot and clears its word
-    # validity (512 bytes) — no 4 KiB base-page copy
-    valid = overlay.valid.at[idx].set(
-        jnp.where(do_alloc, jnp.zeros(PAGE_WORDS, jnp.uint8),
-                  overlay.valid[idx]))
     pfns = overlay.pfn.at[idx].set(
         jnp.where(do_alloc, pfn, overlay.pfn[idx]).astype(jnp.int32)
     )
@@ -217,14 +396,14 @@ def ensure_page(
     overflow = overlay.overflow | (enabled & ~hit & ~can_alloc & in_range)
 
     ok = (hit | do_alloc) & in_range
-    return DirtyOverlay(pfns, overlay.data, valid, count, overflow), idx, ok
+    return overlay._replace(pfn=pfns, count=count, overflow=overflow), idx, ok
 
 
 # ---------------------------------------------------------------------------
 # hot word-window primitives (the interpreter's memory path)
 # ---------------------------------------------------------------------------
 
-def pte_read(image: MemImage, overlay: DirtyOverlay, gpa: jax.Array) -> jax.Array:
+def pte_read(image: MemImage, overlay: LaneOverlay, gpa: jax.Array) -> jax.Array:
     """Read an 8-aligned little-endian u64 (page-table entries).  K=1
     wrapper over `pte_read_vec` — one implementation of the overlay-aware
     word read."""
@@ -234,7 +413,7 @@ def pte_read(image: MemImage, overlay: DirtyOverlay, gpa: jax.Array) -> jax.Arra
 
 def load_window3(
     image: MemImage,
-    overlay: DirtyOverlay,
+    overlay: LaneOverlay,
     gpa_first: jax.Array,
     gpa_last: jax.Array,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -258,18 +437,19 @@ def extract_pair(w0, w1, w2, gpa_first):
 @jax.named_scope("overlay-store")
 def store_window3(
     image: MemImage,
-    overlay: DirtyOverlay,
+    overlay: LaneOverlay,
     t_first,               # Translation-like with .gpa of first byte
     t_last,                # Translation-like with .gpa of last byte
     size,                  # traced int32, 1..16
     lo: jax.Array,
     hi: jax.Array,
     enabled: jax.Array,
-) -> Tuple[DirtyOverlay, jax.Array]:
-    """Commit up to 16 bytes (value (lo, hi), little-endian) through the
-    lane overlay: copy-on-write the one or two touched pages, then a
+) -> Tuple[LaneOverlay, jax.Array, StoreCommit]:
+    """Store up to 16 bytes (value (lo, hi), little-endian) through the
+    lane overlay: claim the one or two touched pages' rows, then a
     3-word read-modify-write with per-word bitmasks.  Returns
-    (overlay', ok); !ok = overlay full."""
+    (overlay', ok, commit): overlay' carries the claimed rows, !ok =
+    overlay full, and `commit` is the three words for `commit_stores`."""
     pfn0, off0 = split_gpa(image, t_first.gpa)
     pfn1, _ = split_gpa(image, t_last.gpa)
     crosses = (off0 + size) > PAGE_SIZE
@@ -290,8 +470,7 @@ def store_window3(
     end_bit = sh + size.astype(jnp.uint64) * _u(8)
 
     w_start = off0 >> 3
-    rows = []
-    widxs = []
+    ats = []
     news = []
     vnews = []
     for j, vj in enumerate((v0, v1, v2)):
@@ -310,24 +489,25 @@ def store_window3(
         mask = _shl(_shl(_u(1), n_bits) - _u(1), off_in)
         # delta rows: a partial write to a not-yet-valid word merges with
         # the base image word; the stored word is then complete -> valid
-        was_valid = overlay.valid[row, widx] != 0
-        old = jnp.where(was_valid, overlay.data[row, widx],
+        at = overlay.base + row * PAGE_WORDS + widx
+        was_valid = overlay.valid[at] != 0
+        old = jnp.where(was_valid, read_data(overlay, at),
                         image.pages[slot, widx])
         touched = do & (mask != _u(0))
         new = jnp.where(touched, (old & ~mask) | (vj & mask), old)
-        rows.append(row)
-        widxs.append(widx)
+        ats.append(at)
         news.append(new)
         vnews.append(jnp.where(touched, jnp.uint8(1),
                                was_valid.astype(jnp.uint8)))
-    # ONE scatter for all three words (the (row, widx) pairs are distinct
-    # by construction: word indices strictly increase within a page and
-    # the straddle moves to another row) — sequential single-word
-    # scatters would each materialize an overlay copy on some backends
-    rows3, widxs3 = jnp.stack(rows), jnp.stack(widxs)
-    data = overlay.data.at[rows3, widxs3].set(jnp.stack(news))
-    valid = overlay.valid.at[rows3, widxs3].set(jnp.stack(vnews))
-    return overlay._replace(data=data, valid=valid), ok
+    # the three plane indices are distinct by construction (word indices
+    # strictly increase within a page and the straddle moves to another
+    # row), and lanes own disjoint spans of the planes: the caller commits
+    # every lane's words with ONE flat scatter per plane.  Scattered here,
+    # per lane under vmap, into [lanes, capacity, PAGE_WORDS] planes, the
+    # v5e compile relayouted both whole planes on the way in and out of
+    # the scatter in every sweep (module docstring)
+    return overlay, ok, StoreCommit(jnp.stack(ats), jnp.stack(news),
+                                    jnp.stack(vnews))
 
 
 # ---------------------------------------------------------------------------
@@ -336,7 +516,7 @@ def store_window3(
 
 def gather_bytes(
     image: MemImage,
-    overlay: DirtyOverlay,
+    overlay: LaneOverlay,
     gpa_vec: jax.Array,   # uint64[size]: per-byte physical address
     first_mask: jax.Array # bool[size]: byte belongs to page of gpa_vec[0]
 ) -> jax.Array:
@@ -363,17 +543,18 @@ def gather_bytes(
 
 def scatter_span(
     image: MemImage,
-    overlay: DirtyOverlay,
+    overlay: LaneOverlay,
     gpa_first: jax.Array,  # translated GPA of the span's first byte
     gpa_last: jax.Array,   # translated GPA of the span's last byte
     values: jax.Array,     # uint8[size], a virtually-contiguous span
     enabled: jax.Array,    # bool scalar
-) -> Tuple[DirtyOverlay, jax.Array]:
+) -> Tuple[LaneOverlay, jax.Array, StoreCommit]:
     """Overlay-aware write of a contiguous span over at most two physical
-    pages -> (overlay', ok).  Bytes are packed into aligned words and
-    committed with ONE collision-free word scatter.  Every guest-visible
-    write lands in the overlay and is therefore "dirty" by construction
-    (VirtWriteDirty, backend.cc:91-127)."""
+    pages -> (overlay', ok, commit), `store_window3`'s contract: the
+    bytes are packed into aligned words for ONE collision-free word
+    scatter (`commit_stores`).  Every guest-visible write lands in the
+    overlay and is therefore "dirty" by construction (VirtWriteDirty,
+    backend.cc:91-127)."""
     size = values.shape[0]
     pfn0, off0 = split_gpa(image, gpa_first)
     pfn1, _ = split_gpa(image, gpa_last)
@@ -391,7 +572,7 @@ def scatter_span(
     vals64 = values.astype(jnp.uint64)
     slot0 = frame_slot(image, pfn0)
     slot1 = frame_slot(image, pfn1)
-    rows, widxs, news, vnews = [], [], [], []
+    ats, news, vnews = [], [], []
     for j in range(n_words):
         # byte indices of this word: i such that head + i in [8j, 8j+8)
         i0 = 8 * j - head  # may be negative (traced)
@@ -410,22 +591,20 @@ def scatter_span(
         row = jnp.where(on_first, idx0, jnp.where(two_pages, idx1, idx0))
         slot = jnp.where(on_first, slot0, jnp.where(two_pages, slot1, slot0))
         # delta rows: merge partial words with the base image word
-        was_valid = overlay.valid[row, widx] != 0
-        old = jnp.where(was_valid, overlay.data[row, widx],
+        at = overlay.base + row * PAGE_WORDS + widx
+        was_valid = overlay.valid[at] != 0
+        old = jnp.where(was_valid, read_data(overlay, at),
                         image.pages[slot, widx])
         touched = do & (mask != 0)
-        rows.append(row)
-        widxs.append(widx)
+        ats.append(at)
         news.append(jnp.where(touched,
                               (old & ~mask) | (word_val & mask), old))
         vnews.append(jnp.where(touched, jnp.uint8(1),
                                was_valid.astype(jnp.uint8)))
-    # one scatter: (row, widx) pairs are distinct (word indices strictly
-    # increase within each page; the straddle changes row)
-    rws, wxs = jnp.stack(rows), jnp.stack(widxs)
-    data = overlay.data.at[rws, wxs].set(jnp.stack(news))
-    validmap = overlay.valid.at[rws, wxs].set(jnp.stack(vnews))
-    return overlay._replace(data=data, valid=validmap), ok
+    # plane indices are distinct (word indices strictly increase within
+    # each page; the straddle changes row)
+    return overlay, ok, StoreCommit(jnp.stack(ats), jnp.stack(news),
+                                    jnp.stack(vnews))
 
 
 def _contiguous_vec(gpa: jax.Array, size: int):
@@ -437,7 +616,7 @@ def _contiguous_vec(gpa: jax.Array, size: int):
 
 
 def phys_read(
-    image: MemImage, overlay: DirtyOverlay, gpa: jax.Array, size: int
+    image: MemImage, overlay: LaneOverlay, gpa: jax.Array, size: int
 ) -> jax.Array:
     """Contiguous overlay-aware physical read (size <= PAGE_SIZE)."""
     gpa_vec, first_mask = _contiguous_vec(gpa, size)
@@ -446,17 +625,21 @@ def phys_read(
 
 def phys_write(
     image: MemImage,
-    overlay: DirtyOverlay,
+    overlay: LaneOverlay,
     gpa: jax.Array,
     values: jax.Array,
     enabled: jax.Array,
-) -> Tuple[DirtyOverlay, jax.Array]:
-    """Contiguous overlay-aware physical write (size <= PAGE_SIZE)."""
+) -> Tuple[LaneOverlay, jax.Array]:
+    """Contiguous overlay-aware physical write (size <= PAGE_SIZE),
+    committed at once: for ONE lane's view, not under vmap (there the
+    planes are unmapped; take `scatter_span`'s commit out of it)."""
     last = gpa + jnp.uint64(values.shape[0] - 1)
-    return scatter_span(image, overlay, gpa, last, values, enabled)
+    overlay, ok, commit = scatter_span(image, overlay, gpa, last, values,
+                                       enabled)
+    return commit_stores(overlay, commit), ok
 
 
-def phys_read_u64(image: MemImage, overlay: DirtyOverlay, gpa: jax.Array) -> jax.Array:
+def phys_read_u64(image: MemImage, overlay: LaneOverlay, gpa: jax.Array) -> jax.Array:
     """Read a little-endian u64 (used for page-table entries; PTEs are
     8-aligned so this is a single word)."""
     return pte_read(image, overlay, gpa)

@@ -21,7 +21,8 @@ import jax.numpy as jnp
 
 from wtf_tpu.core.gxa import PAGE_SIZE
 from wtf_tpu.mem.overlay import (
-    DirtyOverlay,
+    LaneOverlay,
+    commit_stores,
     gather_bytes,
     phys_read_u64,
     pte_read_vec,
@@ -55,7 +56,7 @@ def is_canonical(gva: jax.Array) -> jax.Array:
 
 
 def translate(
-    image: MemImage, overlay: DirtyOverlay, cr3: jax.Array, gva: jax.Array
+    image: MemImage, overlay: LaneOverlay, cr3: jax.Array, gva: jax.Array
 ) -> Translation:
     """Walk PML4 -> PDPT -> PD -> PT for one GVA (single lane; vmapped).
 
@@ -69,7 +70,7 @@ def translate(
 
 @jax.named_scope("page-walk")
 def translate_vec_l(
-    image: MemImage, overlay: DirtyOverlay, cr3: jax.Array, gva_l: jax.Array
+    image: MemImage, overlay: LaneOverlay, cr3: jax.Array, gva_l: jax.Array
 ) -> Translation:
     """`translate_vec` over u32 limb-packed GVAs (uint32[K, 2], limb 0 low).
 
@@ -84,7 +85,7 @@ def translate_vec_l(
 
 
 def translate_vec(
-    image: MemImage, overlay: DirtyOverlay, cr3: jax.Array, gva_vec: jax.Array
+    image: MemImage, overlay: LaneOverlay, cr3: jax.Array, gva_vec: jax.Array
 ) -> Translation:
     """Walk K GVAs at once -> Translation with [K] fields.
 
@@ -136,7 +137,7 @@ def _virt_byte_addrs(gva: jax.Array, size: int, first: Translation, last: Transl
 
 def virt_read(
     image: MemImage,
-    overlay: DirtyOverlay,
+    overlay: LaneOverlay,
     cr3: jax.Array,
     gva: jax.Array,
     size: int,
@@ -156,14 +157,16 @@ def virt_read(
 
 def virt_write(
     image: MemImage,
-    overlay: DirtyOverlay,
+    overlay: LaneOverlay,
     cr3: jax.Array,
     gva: jax.Array,
     values: jax.Array,
     enabled: jax.Array,
     enforce_writable: bool = False,
-) -> Tuple[DirtyOverlay, jax.Array]:
-    """Write uint8[size] at a guest-virtual address -> (overlay', fault).
+) -> Tuple[LaneOverlay, jax.Array]:
+    """Write uint8[size] at a guest-virtual address -> (overlay', fault),
+    committed at once: for ONE lane's view, not under vmap (see
+    `overlay.phys_write`).
 
     `enforce_writable=True` is the guest-store path: writes to mappings whose
     walk lacks the W bit fault like a real CPU with CR0.WP would.  Host-side
@@ -176,15 +179,15 @@ def virt_write(
     fault = ~(first.ok & last.ok)
     if enforce_writable:
         fault = fault | ~(first.writable & last.writable)
-    overlay, ok = scatter_span(
+    overlay, ok, commit = scatter_span(
         image, overlay, first.gpa, last.gpa, values, enabled & ~fault
     )
-    return overlay, fault | (enabled & ~fault & ~ok)
+    return commit_stores(overlay, commit), fault | (enabled & ~fault & ~ok)
 
 
 def virt_read_u64(
     image: MemImage,
-    overlay: DirtyOverlay,
+    overlay: LaneOverlay,
     cr3: jax.Array,
     gva: jax.Array,
     size: int = 8,
