@@ -1071,3 +1071,23 @@ def test_telemetry_report_prints_occupancy_and_sweeps_per_chunk(
     telemetry_report._print_human(summary)
     out = capsys.readouterr().out
     assert "lane occupancy: null" in out and "sweeps/chunk: null" in out
+
+
+def test_telemetry_report_prints_lockstep_floor_share_beside_occupancy(
+        kernel_campaign, capsys):
+    import telemetry_report
+
+    telem = kernel_campaign["target"] / "telem"
+    summary = telemetry_report.summarize(telem / "events.jsonl")
+    metrics = kernel_campaign["metrics"]
+    floor = metrics["device.lockstep_floor_steps"]
+    assert metrics["device.instructions"] <= floor
+    assert summary["device"]["lockstep_floor_share"] == round(
+        floor / metrics["device.lane_steps"], 4)
+    telemetry_report._print_human(summary)
+    out = capsys.readouterr().out
+    assert out.index("lane occupancy: ") < out.index("lockstep floor: ")
+    # a stream without the counter (the megachunk window) prints no line
+    summary["device"]["lockstep_floor_share"] = None
+    telemetry_report._print_human(summary)
+    assert "lockstep floor" not in capsys.readouterr().out

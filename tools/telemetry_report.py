@@ -454,6 +454,15 @@ def summarize(path) -> dict:
                       / metrics["runner.chunks"], 2)
                 if metrics.get("device.sweeps") is not None
                 and metrics.get("runner.chunks") else None),
+            # lanes x the deepest lane's instructions, per batch, over
+            # the lane-steps swept: the rest is sweeps above the deepest
+            # lane; null without either counter (the megachunk window
+            # has no per-lane block to take a floor from)
+            "lockstep_floor_share": (
+                round(metrics["device.lockstep_floor_steps"]
+                      / metrics["device.lane_steps"], 4)
+                if metrics.get("device.lockstep_floor_steps") is not None
+                and metrics.get("device.lane_steps") else None),
         },
         "mesh": mesh,
         "triage": triage,
@@ -530,6 +539,9 @@ def _print_human(s: dict) -> None:
           + _or_null(dev.get("lane_occupancy"), "{:.2%}")
           + " of lane-steps retired an instruction")
     print("  sweeps/chunk: " + _or_null(dev.get("sweeps_per_chunk"), "{}"))
+    if dev.get("lockstep_floor_share") is not None:
+        print(f"  lockstep floor: {dev['lockstep_floor_share']:.2%} of "
+              "lane-steps lie at or under the batch's deepest lane")
     if dev.get("fused_window_share") is not None:
         total = (dev["fused_window_rounds"]
                  + dev["fused_window_xla_steps"])

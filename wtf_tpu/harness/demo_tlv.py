@@ -20,6 +20,15 @@ register+buffer insertion):
 
 Assembled with binutils at build time; bytes embedded so runtime needs no
 toolchain (source in _GUEST_ASM for auditability/regeneration).
+
+The benchmark runs this target as configuration `tlv-user`, cell
+`tlv-default` (BENCHMARK.json, benchmark/configs/tlv-user.json): 4096
+lanes on the batch loop with TlvStructureMutator (`--mutator auto`), no
+engine flag.  The frame is 16 bytes of buffer and 8 of saved rbp under
+the return address, so a type-3 record of 24+k bytes overwrites its k low
+bytes: the target's ordinary crash is a `ret` into wild code, most often
+into this image's own pages off an instruction boundary
+(tests/test_tlv_default.py holds those landings to the oracle).
 """
 
 from __future__ import annotations

@@ -1009,6 +1009,19 @@ class Runner:
             view.pending.clear()
 
     # -- servicing ---------------------------------------------------------
+    @staticmethod
+    def _fetch_window(view: HostView, lane: int, rip: int) -> bytes:
+        """The 15-byte decode window at `rip`, or, where it runs into an
+        unmapped page, the bytes up to that page: what the oracle's
+        EmuCpu._fetch_window gives.  An instruction that ends before the
+        boundary decodes and runs as on the oracle; only a `rip` that is
+        itself unmapped is a fetch fault (PERF.md section 6, fault (a))."""
+        try:
+            return view.virt_read(lane, rip, 15)
+        except HostFault:
+            return view.virt_read(lane, rip,
+                                  PAGE_SIZE - (rip & (PAGE_SIZE - 1)))
+
     def _decode_at(self, view: HostView, lane: int, rip: int,
                    prefetched=None) -> bool:
         """Decode the instruction at `rip` through `lane`'s memory view and
@@ -1021,8 +1034,8 @@ class Runner:
             window, faulted, pfn0, pfn14 = prefetched
         else:
             try:
-                window = view.virt_read(lane, rip, 15)
                 pfn0 = view.translate(lane, rip) >> PAGE_SHIFT
+                window = self._fetch_window(view, lane, rip)
                 faulted = False
             except HostFault:
                 faulted = True
@@ -1226,7 +1239,10 @@ class Runner:
             view.set_status(lane, StatusCode.DIVIDE_ERROR)
             return
         except UnsupportedInsn as e:
-            self.lane_errors[lane] = str(e)
+            # first word = the crash name's tail (TpuBackend.
+            # _result_from_fields): the oracle's `crash-unsupported-<rip>`
+            self.lane_errors[lane] = (
+                f"unsupported-{e.rip:#x} instruction {e.raw.hex()}")
             view.set_status(lane, StatusCode.HARD_ERROR)
             return
         _writeback_lane(view, lane, emu)
@@ -1721,6 +1737,13 @@ class Runner:
         returns the per-lane block for callers that want lane detail."""
         ctr = self.device_counters()
         self.fold_counter_totals(ctr.sum(axis=0, dtype=np.uint64))
+        # the batch's lockstep floor: every lane is swept as long as the
+        # deepest one runs.  With device.lane_steps it splits lost
+        # occupancy into depth skew (floor - instructions) and sweeps
+        # above the deepest lane (lane_steps - floor).  The megachunk
+        # window folds totals only, so it has no floor.
+        self.registry.counter("device.lockstep_floor_steps").inc(
+            self.n_lanes * int(ctr[:, CTR_INSTR].max()))
         return ctr
 
 
