@@ -169,6 +169,60 @@ def test_chunk_program_compiles_for_v5e(loop, one_chip, compile_for):
     assert mem.temp_size_in_bytes < ov.data.nbytes, mem
 
 
+def plane_sized_results(hlo_text, n_elems):
+    """Instructions of any computation whose result holds `n_elems`
+    elements or more, but for what hands an operand on by reference:
+    parameter, bitcast, tuple plumbing, and a while loop's carry (the
+    TPU compiler serves a gather of whole rows as a loop over them that
+    carries the plane it reads)."""
+    found = []
+    for line in hlo_text.splitlines():
+        ins = _HLO_INSTRUCTION.match(line)
+        if not ins or ins.group(2) in ("parameter", "bitcast", "tuple",
+                                       "get-tuple-element", "while"):
+            continue
+        sizes = [int(np.prod([int(d) for d in dims.split(",") if d]))
+                 for dims in _HLO_ARRAY.findall(ins.group(1))]
+        if sizes and max(sizes) >= n_elems:
+            found.append(line.strip()[:200])
+    return found
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_lane_row_reader_compiles_for_v5e_and_moves_no_plane(
+        loop, topo, compile_for, chips):
+    """`overlay.read_lane_rows` (HostView.prefetch's device half) at the
+    campaign's slab, on one chip and per shard on four: it gathers one
+    row per lane out of the resident planes and nothing else.  No
+    instruction's result is as large as a (shard's) plane, so no copy or
+    relayout of the slab; no collective, so no all-gather of lane-sharded
+    planes; temporaries stay within a few times what it returns."""
+    from wtf_tpu.mem import overlay as ovl
+    from wtf_tpu.meshrun.mesh import LANE_AXIS
+    from wtf_tpu.meshrun.runner import _mesh_read_lane_rows
+
+    ov = loop.backend.runner.machine.overlay
+    rows = np.zeros(LANES, np.int32)
+    if chips == 1:
+        fn, placed = ovl.read_lane_rows, SingleDeviceSharding(
+            topo.devices[0])
+    else:
+        mesh = Mesh(np.array(topo.devices[:chips]), (LANE_AXIS,))
+        fn, placed = _mesh_read_lane_rows(mesh), NamedSharding(
+            mesh, P(LANE_AXIS))
+    compiled = compile_for(fn, (ov, rows), placed)
+    text = compiled.as_text()
+    for collective in ("all-gather", "all-reduce", "all-to-all",
+                       "collective-permute"):
+        assert collective not in text, collective
+    found = plane_sized_results(text, ov.valid.size // chips)
+    assert not found, "\n".join(found)
+    mem = compiled.memory_analysis()
+    returned = LANES // chips * (4096 + 512)
+    assert mem.output_size_in_bytes >= returned, mem
+    assert mem.temp_size_in_bytes < 4 * returned, mem
+
+
 def test_step_source_has_no_float_to_int_bitcast():
     """The one direction the TPU's 64-bit rewrite refuses stays out of
     the step: no bitcast in step.py targets a 64-bit integer (f32 <-> u32

@@ -710,30 +710,45 @@ def _kernel_inputs(inputs: Path) -> None:
     (inputs / "d").write_bytes(b"\x01\x10\x20")
 
 
-@pytest.fixture(scope="module")
-def kernel_campaign(tmp_path_factory):
-    """One seeded demo_kernel campaign on the batch loop (8 lanes, 8
-    batches, the pure-Python mangle engine so no native build matters):
-    its records, its last metrics dump and its finds."""
+def _campaign(target: Path, name: str, *args: str) -> dict:
+    """One seeded campaign on the batch loop through the CLI: its
+    records, its last metrics dump and its finds."""
     import contextlib
     import io
 
     from wtf_tpu.cli import main
 
-    target = tmp_path_factory.mktemp("kernel-campaign")
-    _kernel_inputs(target / "inputs")
     out = io.StringIO()
     with contextlib.redirect_stdout(out):
-        rc = main(["campaign", "--name", "demo_kernel", "--target",
-                   str(target), "--runs", "64", "--lanes", "8", "--limit",
-                   "100000", "--max_len", "256", "--seed", "21",
-                   "--mutator", "mangle", "--telemetry-dir",
-                   str(target / "telem")])
+        rc = main(["campaign", "--name", name, "--target", str(target),
+                   "--lanes", "8", "--limit", "100000", "--max_len", "256",
+                   "--telemetry-dir", str(target / "telem"), *args])
     assert rc in (0, 2)
     records = list(read_events(target / "telem" / "events.jsonl"))
     end = [r for r in records if r["type"] == "run-end"][-1]
     return {"target": target, "records": records,
             "metrics": end["metrics"], "stdout": out.getvalue()}
+
+
+@pytest.fixture(scope="module")
+def kernel_campaign(tmp_path_factory):
+    """demo_kernel, 8 lanes, 8 batches, the pure-Python mangle engine so
+    no native build matters."""
+    target = tmp_path_factory.mktemp("kernel-campaign")
+    _kernel_inputs(target / "inputs")
+    return _campaign(target, "demo_kernel", "--runs", "64", "--seed", "21",
+                     "--mutator", "mangle")
+
+
+@pytest.fixture(scope="module")
+def tlv_campaign(tmp_path_factory):
+    """demo_tlv with its own structure mutator, 8 lanes, 12 batches: its
+    crashers smash the return address, so each one's stack page is in
+    its lane's overlay when it is bucketed."""
+    target = tmp_path_factory.mktemp("tlv-campaign")
+    (target / "inputs").mkdir()
+    return _campaign(target, "demo_tlv", "--backend", "tpu", "--runs", "96",
+                     "--seed", "29")
 
 
 def _calls(metrics, path):
@@ -787,18 +802,110 @@ def test_exception_loop_gets_one_span_a_round_not_one_a_lane():
     assert metrics["phase.calls"]["status-pull"] == metrics["runner.chunks"]
 
 
-def test_result_pulls_count_three_transfers_per_crashing_lane(
-        kernel_campaign):
-    """Counted where the transfer is made: every `np.asarray` of a
-    machine field in `_map_result` goes through `_pull_field`."""
+def _kernel_backend():
+    from wtf_tpu.harness import demo_kernel as dk
+
+    backend = create_backend("tpu", dk.build_snapshot(), n_lanes=8,
+                             limit=100_000)
+    backend.initialize()
+    dk.TARGET.init(backend)
+    return backend, dk
+
+
+def _jump_to(dk, gva: int, tail: bytes = b"") -> bytes:
+    import struct
+
+    return b"\x04" + struct.pack("<Q", gva) + tail
+
+
+def _result_pulls_at_most_one_a_batch(kernel_campaign):
+    """The mapping's one transfer is the batch's view, and only in a
+    batch with a lane whose name needs machine fields."""
     metrics = kernel_campaign["metrics"]
-    assert metrics["backend.result_pulls"] > 0
-    assert metrics["backend.result_pulls"] % 3 == 0
-    assert metrics["backend.result_pulls"] <= 3 * metrics["campaign.crashes"]
+    assert 0 < metrics["backend.result_pulls"] <= metrics["backend.batches"]
+    assert metrics["campaign.crashes"] > metrics["backend.batches"]
+
+
+def _result_pulls_none_without_a_crashing_lane(kernel_campaign):
+    """OK and TIMEDOUT lanes are named from their status alone: the
+    batch pulls nothing while mapping, and builds no view."""
+    backend, dk = _kernel_backend()
+    backend.limit = 40           # the byte-sum loop of lane 1 times out
+    results = backend.run_batch([b"\x01\x10\x20", b"\x01" + b"B" * 200],
+                                dk.TARGET)
+    assert [type(r).__name__ for r in results] == ["Ok", "Timedout"]
+    assert backend.stats["result_pulls"] == 0
+    assert backend._view is None
+
+
+def _result_mapping_holds_no_per_lane_device_read(kernel_campaign):
     import inspect
 
     from wtf_tpu.backend.tpu import TpuBackend
-    assert "np.asarray" not in inspect.getsource(TpuBackend._map_result)
+    source = (inspect.getsource(TpuBackend._map_results)
+              + inspect.getsource(TpuBackend._map_result))
+    for read in ("np.asarray", "device_get", ".machine", "jnp."):
+        assert read not in source, read
+    assert not hasattr(TpuBackend, "_pull_field")
+
+
+def _result_names_equal_a_per_lane_reference(kernel_campaign):
+    """Against the mapping as it was: three whole-plane pulls per
+    crashing lane, straight off the machine."""
+    from wtf_tpu.core.results import StatusCode
+
+    backend, dk = _kernel_backend()
+    cases = [
+        b"\x03" + b"A" * 200,                                # wild write
+        _jump_to(dk, 0xDEAD0000),                            # wild execute
+        _jump_to(dk, dk.USER_BUF + 9,
+                 bytes.fromhex("488b042500703301")),         # wild read
+        _jump_to(dk, dk.USER_BUF + 9, bytes.fromhex("31c9f7f1")),   # #DE
+        # hard error: a REX prefix alone before the unmapped page
+        _jump_to(dk, dk.USER_BUF + 0xFFF, b"\x48" * (0x1000 - 9)),
+        b"\x02" + b"\xef\xbe\xad\xde" + b"\x41" + b"\0" * 10,  # stop()
+        b"\x01\x10\x20",                                     # ok
+    ]
+    results = backend.run_batch(cases, dk.TARGET)
+    m = backend.runner.machine
+    status = np.asarray(m.status)
+    reference = []
+    for lane in range(len(cases)):
+        if lane in backend._lane_results:
+            reference.append(backend._lane_results[lane])
+            continue
+        reference.append(backend._result_from_fields(
+            StatusCode(int(status[lane])),
+            int(np.asarray(m.fault_gva)[lane]),
+            int(np.asarray(m.fault_write)[lane]),
+            int(np.asarray(m.rip)[lane]),
+            backend.runner.lane_errors.get(lane, "hard-error")))
+    assert [str(r) for r in results] == [str(r) for r in reference]
+    assert [str(r) for r in results] == [
+        "crash(crash-write-0xffff800000203000)",
+        "crash(crash-execute-0xdead0000)",
+        "crash(crash-read-0x1337000)",
+        "crash(crash-de-0x2000000b)",
+        "crash(crash-unsupported-0x20000fff)",
+        "crash(crash-bugcheck-0xdeadbeef-0x41)",
+        "ok"]
+    assert backend.stats["result_pulls"] == 1
+    # the harvest's bucket reads the view the mapping built
+    view = backend._view
+    assert view is not None and backend._ensure_view() is view
+
+
+@pytest.mark.parametrize("claim", [
+    _result_pulls_at_most_one_a_batch,
+    _result_pulls_none_without_a_crashing_lane,
+    _result_mapping_holds_no_per_lane_device_read,
+    _result_names_equal_a_per_lane_reference,
+], ids=lambda f: f.__name__.strip("_"))
+def test_result_pulls_are_one_view_pull_a_batch(kernel_campaign, claim):
+    """`backend.result_pulls` counts device-to-host transfers made while
+    mapping results: the batch's view, once, where it was three planes
+    per crashing lane."""
+    claim(kernel_campaign)
 
 
 @pytest.mark.parametrize("parent", ["execute", "harvest"])
@@ -850,20 +957,74 @@ _PARENT_FINDS = ("a4c6ca381f017e26b13e6a3ca283eacaec3a4c2645eba5f11daca15ec"
                  "3cd7785")
 
 
-def test_equal_seed_campaign_writes_the_parents_line_and_finds(
-        kernel_campaign):
-    import hashlib
+def _final_line(campaign) -> str:
     import re
 
-    line = kernel_campaign["stdout"].strip().splitlines()[-1]
+    line = campaign["stdout"].strip().splitlines()[-1]
     line = re.sub(r"exec/s: [0-9.]+ ", "", line)
-    assert re.sub(r" uptime.*", "", line) == _PARENT_LINE
+    return re.sub(r" uptime.*", "", line)
+
+
+def _finds_digest(campaign) -> str:
+    import hashlib
+
     listing = hashlib.sha256()
     for sub in ("outputs", "crashes"):
-        for path in sorted((kernel_campaign["target"] / sub).iterdir()):
+        for path in sorted((campaign["target"] / sub).iterdir()):
             digest = hashlib.sha256(path.read_bytes()).hexdigest()[:16]
             listing.update(f"{sub}/{path.name} {digest}\n".encode())
-    assert listing.hexdigest() == _PARENT_FINDS
+    return listing.hexdigest()
+
+
+def test_equal_seed_campaign_writes_the_parents_line_and_finds(
+        kernel_campaign):
+    assert _final_line(kernel_campaign) == _PARENT_LINE
+    assert _finds_digest(kernel_campaign) == _PARENT_FINDS
+
+
+# the same for demo_tlv, taken on the tree before the crashing lanes'
+# stack pages came down in one transfer a batch (PR 29's parent), with the
+# `crash` events in their order: name, new, bucket
+_TLV_PARENT_LINE = "#96 cov: 53 corp: 11 crash: 22 timeout: 0 cr3: 0"
+_TLV_PARENT_FINDS = ("2f69152f93856bbf711310104048e0b66f9f0a05be81715df6abe0"
+                     "2a677354ba")
+_TLV_PARENT_CRASH_EVENTS = ("b83c813bbf028284db97e6dc775cfa880694ea0b3b0975"
+                            "b7fed46502e6024529")
+
+
+def test_equal_seed_tlv_campaign_writes_the_parents_line_finds_and_buckets(
+        tlv_campaign):
+    import hashlib
+
+    assert _final_line(tlv_campaign) == _TLV_PARENT_LINE
+    assert _finds_digest(tlv_campaign) == _TLV_PARENT_FINDS
+    crashes = [r for r in tlv_campaign["records"] if r["type"] == "crash"]
+    assert len(crashes) == 22
+    # every bucket carries a stack hash: the page was read, not given up
+    assert all(r["bucket"].count(".") == 2
+               and not r["bucket"].endswith("nostack") for r in crashes)
+    events = hashlib.sha256("".join(
+        f"{r['name']} {r['new']} {r['bucket']}\n"
+        for r in crashes).encode()).hexdigest()
+    assert events == _TLV_PARENT_CRASH_EVENTS
+
+
+def test_tlv_campaign_fetches_stack_rows_once_a_batch(tlv_campaign):
+    """Every crashing lane's stack page is dirty: one row each, and one
+    round trip for all of a batch's."""
+    metrics = tlv_campaign["metrics"]
+    assert metrics["runner.view_rows"] == metrics["campaign.crashes"] == 22
+    assert 0 < metrics["runner.view_row_pulls"] <= metrics["backend.batches"]
+    assert metrics["runner.view_row_pulls"] == metrics["backend.result_pulls"]
+
+
+def test_kernel_campaign_fetches_no_stack_row(kernel_campaign):
+    """demo_kernel's crashers fault before they write their stack: no
+    row is dirty, and no row program is dispatched."""
+    metrics = kernel_campaign["metrics"]
+    assert metrics["campaign.crashes"] > 0
+    assert metrics["runner.view_row_pulls"] == 0
+    assert metrics["runner.view_rows"] == 0
 
 
 def test_every_lane_carries_the_same_sweep_count_on_one_device():

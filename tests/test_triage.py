@@ -135,6 +135,177 @@ def test_fuzz_loop_dedups_by_bucket(backend, tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# the crashing lanes' stack pages in one transfer (HostView.prefetch)
+# ---------------------------------------------------------------------------
+
+PAGE = 0x1000
+STACK_PAGE = demo_tlv.STACK_TOP - 2 * PAGE      # mapped, as is the next one
+
+
+def _smash(length: int, fill: int) -> bytes:
+    """A type-3 record of `length` bytes: 24 reach the return address,
+    the next 8 replace it (non-canonical: `ret` faults), the rest land
+    above it, where `rsp` points when the lane is bucketed."""
+    return bytes([3, length]) + bytes([fill]) * length
+
+
+def _insert_at(backend, case) -> None:
+    """(rsp or None, testcase): parse() entered with its return address
+    at `rsp` (the snapshot's is the first word of a page)."""
+    rsp, data = case
+    demo_tlv.TARGET.insert_testcase(backend, data)
+    if rsp is not None:
+        backend.set_reg(4, rsp)
+        backend.virt_write(rsp, demo_tlv.FINISH_GVA.to_bytes(8, "little"))
+
+
+_AT = type("Target", (), {"insert_testcase": staticmethod(_insert_at)})
+
+# after `ret`, rsp = the case's rsp + 8 and the bucket hashes 64 bytes there
+PLAIN = [(None, _smash(32 + 8 * i, 0x41 + i)) for i in range(5)]
+# rsp + 8 is 40 bytes under a page end and the copy runs 8 bytes into the
+# next page: both pages of the window are dirty
+CROSS_BOTH = (STACK_PAGE + PAGE - 48, _smash(80, 0x51))
+# the copy stops at the return address: the window's second page is clean
+CROSS_ONE = (STACK_PAGE + PAGE - 48, _smash(32, 0x52))
+# the return address fills a page's last word: the window is the next
+# page, which the lane never wrote
+CLEAN = (STACK_PAGE + PAGE - 8, _smash(32, 0x53))
+# the window runs off the mapped stack
+NOSTACK = (demo_tlv.STACK_TOP + PAGE - 48, _smash(32, 0x54))
+BENIGN_CASE = (None, b"\x01\x02XY")
+
+
+def _row_counters(backend):
+    stats = backend.runner.stats
+    return stats["view_row_pulls"], stats["view_rows"]
+
+
+def _buckets_lazy_then_prefetched(backend, cases):
+    """Run `cases`, bucket every crashing lane from a fresh view on the
+    lazy path, then again from a second fresh view after ONE
+    `prefetch_stacks`.  -> (results, lazy buckets, prefetched buckets,
+    (pulls, rows) of the lazy pass, of the prefetch, of the bucketing
+    behind it), with the prefetched pages checked against the lazy
+    view's, byte for byte."""
+    from wtf_tpu.triage.bucket import prefetch_stacks
+
+    results = backend.run_batch(cases, _AT)
+    crashed = [lane for lane, r in enumerate(results)
+               if isinstance(r, Crash)]
+    marks = [_row_counters(backend)]
+    backend._view = None
+    lazy_view = backend._ensure_view()
+    lazy = {lane: bucket_of(backend, lane, results[lane])
+            for lane in crashed}
+    marks.append(_row_counters(backend))
+    backend._view = None
+    view = backend._ensure_view()
+    assert view is not lazy_view
+    prefetch_stacks(backend, crashed)
+    marks.append(_row_counters(backend))
+    fetched = {lane: bucket_of(backend, lane, results[lane])
+               for lane in crashed}
+    marks.append(_row_counters(backend))
+    assert backend._view is view
+    for key, page in view._page_cache.items():
+        assert page == lazy_view.page(*key), key
+    demo_tlv.TARGET.restore()
+    backend.restore()
+    deltas = [(b[0] - a[0], b[1] - a[1]) for a, b in zip(marks, marks[1:])]
+    return results, lazy, fetched, deltas
+
+
+@pytest.fixture(params=["one-device", "mesh"])
+def either_backend(request, backend, mesh_backend):
+    return backend if request.param == "one-device" else mesh_backend
+
+
+def test_prefetched_buckets_equal_the_lazy_path(either_backend):
+    """N crashing lanes with a dirty stack page: N round trips on the
+    lazy path, ONE after a prefetch, the same rows and the same buckets,
+    and nothing left for `bucket_of` to fetch."""
+    cases = PLAIN[:3] + [BENIGN_CASE] + PLAIN[3:]
+    results, lazy, fetched, deltas = _buckets_lazy_then_prefetched(
+        either_backend, cases)
+    assert [isinstance(r, Crash) for r in results] == [
+        True, True, True, False, True, True]
+    assert fetched == lazy and len(set(lazy.values())) == 5
+    assert not any(b.endswith("nostack") for b in lazy.values())
+    assert deltas == [(5, 5), (1, 5), (0, 0)]
+
+
+def test_prefetch_near_a_page_end_clean_pages_and_an_unreadable_stack(
+        either_backend):
+    """A window that crosses a page end takes a second round trip only
+    if the lane dirtied the second page too; a clean page costs no row;
+    a window that leaves the mapped stack is `nostack` as before."""
+    cases = [PLAIN[0], CROSS_BOTH, CROSS_ONE, CLEAN, NOSTACK, BENIGN_CASE]
+    results, lazy, fetched, deltas = _buckets_lazy_then_prefetched(
+        either_backend, cases)
+    assert all(isinstance(r, Crash) for r in results[:5])
+    assert fetched == lazy and len(set(lazy.values())) == 5
+    assert [b.endswith("nostack") for b in lazy.values()] == [
+        False, False, False, False, True]
+    # rows: lanes 0, 2 and 4 one each (NOSTACK's first page is fetched
+    # before its second is found unmapped), CROSS_BOTH two, CLEAN none
+    assert deltas[0] == (5, 5)
+    assert deltas[1:] == [(2, 5), (0, 0)]
+
+
+def test_prefetch_of_clean_stacks_dispatches_nothing(backend, monkeypatch):
+    from wtf_tpu.triage.bucket import prefetch_stacks
+
+    def refuse(rows):
+        raise AssertionError("a row program for no dirty page")
+
+    monkeypatch.setattr(backend.runner, "fetch_overlay_rows", refuse)
+    results = backend.run_batch([CLEAN, CLEAN, BENIGN_CASE], _AT)
+    before = _row_counters(backend)
+    prefetch_stacks(backend, [0, 1])
+    prefetch_stacks(backend, [])
+    buckets = {bucket_of(backend, lane, results[lane]) for lane in (0, 1)}
+    assert _row_counters(backend) == before
+    assert len(buckets) == 1 and not buckets.pop().endswith("nostack")
+    demo_tlv.TARGET.restore()
+    backend.restore()
+
+
+def test_prefetch_with_a_quarantined_lane(either_backend):
+    """A quarantined lane is parked idle, is no crash and is fetched no
+    row; its neighbours' buckets are what they are without it."""
+    quarantined = either_backend.supervisor.quarantined
+    _, alone, _, _ = _buckets_lazy_then_prefetched(
+        either_backend, [PLAIN[0], PLAIN[2]])
+    quarantined.add(1)
+    try:
+        results, lazy, fetched, deltas = _buckets_lazy_then_prefetched(
+            either_backend, [PLAIN[0], PLAIN[1], PLAIN[2]])
+    finally:
+        quarantined.discard(1)
+    assert isinstance(results[1], Ok) and sorted(lazy) == [0, 2]
+    assert fetched == lazy
+    assert [lazy[0], lazy[2]] == [alone[0], alone[1]]
+    assert deltas == [(2, 2), (1, 2), (0, 0)]
+
+
+def test_harvest_prefetches_before_its_crash_save_loop(backend, tmp_path):
+    """`FuzzLoop._harvest_batch` hands the crashing lanes over once: one
+    round trip for the batch's rows, under `harvest/crash-save`."""
+    loop = FuzzLoop(backend, _AT, ByteMutator(random.Random(1), 128),
+                    Corpus(), crashes_dir=tmp_path / "crashes")
+    batch = PLAIN[:4] + [BENIGN_CASE]
+    results = backend.run_batch(batch, _AT)
+    before = _row_counters(backend)
+    assert loop._harvest_batch(results, [d for _, d in batch]) == 4
+    after = _row_counters(backend)
+    demo_tlv.TARGET.restore()
+    backend.restore()
+    assert (after[0] - before[0], after[1] - before[1]) == (1, 4)
+    assert len(loop.crash_buckets) == 4
+
+
+# ---------------------------------------------------------------------------
 # minimize
 # ---------------------------------------------------------------------------
 

@@ -649,9 +649,10 @@ class TpuBackend(Backend):
         raise AssertionError(f"unmapped terminal status {status!r}")
 
     def _map_results(self, statuses, n_active: int) -> List[TestcaseResult]:
-        """The batch's result list, under ONE `results` span: the per-lane
-        mapping pulls machine fields for every crashing lane, and that
-        host time belongs to no other span of `execute`."""
+        """The batch's result list, under ONE `results` span: the mapping
+        pulls the batch's view once if a lane's name needs machine
+        fields, and that host time belongs to no other span of
+        `execute`."""
         with self.registry.spans.span("results"):
             return [self._map_result(lane, statuses[lane])
                     for lane in range(n_active)]
@@ -663,20 +664,24 @@ class TpuBackend(Backend):
         if status in (StatusCode.OK, StatusCode.TIMEDOUT,
                       StatusCode.CR3_CHANGE):
             return self._result_from_fields(status, 0, 0, 0, "")
-        m = self.runner.machine
+        r = self._result_view().r
         return self._result_from_fields(
-            status,
-            int(self._pull_field(m.fault_gva)[lane]),
-            int(self._pull_field(m.fault_write)[lane]),
-            int(self._pull_field(m.rip)[lane]),
+            status, int(r["fault_gva"][lane]), int(r["fault_write"][lane]),
+            int(r["rip"][lane]),
             self.runner.lane_errors.get(lane, "hard-error"))
 
-    def _pull_field(self, field) -> np.ndarray:
-        """One device-to-host transfer of a machine field while mapping
-        results, counted (`backend.result_pulls`): the number a batched
-        pull brings down."""
-        self.stats["result_pulls"] += 1
-        return np.asarray(field)
+    def _result_view(self) -> HostView:
+        """The finished batch's view, for the fields a crash is named
+        from: ONE device-to-host transfer a batch (`backend.result_pulls`
+        counts it when the mapping is what builds the view), and the one
+        the harvest's `bucket_of` reads until `restore`.  Only a view
+        built after `Runner.run` returned holds the final machine."""
+        if self._view is None:
+            self.stats["result_pulls"] += 1
+        view = self._ensure_view()
+        assert view.fresh(), \
+            "result mapping on a view from before the batch's last dispatch"
+        return view
 
     # -- Backend facade (single testcase == lane 0) ------------------------
     def run(self) -> TestcaseResult:

@@ -284,13 +284,16 @@ class FuzzLoop:
         batch paths, in three passes so each is ONE child span of
         `harvest` (never a span per lane): `account` (result accounting
         + optional overlay-full requeue + the new-coverage count),
-        `crash-save` (bucket + save of every crashing lane),
+        `crash-save` (bucket + save of every crashing lane, behind ONE
+        `stack-prefetch` of the pages their buckets hash),
         `corpus-add` (the new-coverage -> corpus/mutator/event chain).
         `datas` maps lane -> bytes (a list, or the device paths' dict of
         fetched lanes);
         `flags` overrides the backend's last-batch new-coverage flags
         for callers harvesting several batches at once (the megachunk
         window's per-batch rows).  Returns the crash count."""
+        from wtf_tpu.triage.bucket import prefetch_stacks
+
         spans = self.registry.spans
         if isinstance(datas, dict):
             def data_of(lane):
@@ -307,6 +310,8 @@ class FuzzLoop:
                     self.stats.new_coverage += 1
                     fresh.append(lane)
         with spans.span("crash-save"):
+            with spans.span("stack-prefetch"):
+                prefetch_stacks(self.backend, crashed)
             for lane in crashed:
                 self._save_crash_lane(lane, data_of(lane), results[lane])
         with spans.span("corpus-add"):

@@ -156,10 +156,18 @@ class HostView:
                    if name in _LIMB_FIELDS else np.array(host[name]))
             for name in _MIRROR_FIELDS
         }
-        # overlay index pulled once; data rows fetched lazily per (lane, pfn)
+        # which machine this view mirrors, by its status leaf: every push,
+        # dispatch and restore gives the runner's machine another (`fresh`)
+        self._status = m.status
+        # overlay index pulled once; data rows fetched lazily per
+        # (lane, pfn), or for a list of them at once (`prefetch`)
         self._ov_pfn = host["__ov_pfn"]
         self._page_cache: Dict[Tuple[int, int], bytes] = {}
         self.pending: Dict[Tuple[int, int], bytearray] = {}
+
+    def fresh(self) -> bool:
+        """Does the runner's machine still stand as this view pulled it?"""
+        return self._status is self.runner.machine.status
 
     # -- registers -------------------------------------------------------
     def get_reg(self, lane: int, idx: int) -> int:
@@ -188,16 +196,52 @@ class HostView:
         return self.runner.lane_physmem(lane).host_read(
             pfn << PAGE_SHIFT, PAGE_SIZE)
 
-    def _device_overlay_page(self, lane: int, pfn: int) -> Optional[bytes]:
+    def _overlay_row(self, lane: int, pfn: int) -> Optional[int]:
         slots = np.nonzero(self._ov_pfn[lane] == pfn)[0]
-        if len(slots) == 0:
-            return None
-        data, valid = ovl.fetch_row(
-            self.runner.machine.overlay, lane, int(slots[0]))
+        return int(slots[0]) if len(slots) else None
+
+    def _merge_row(self, lane: int, pfn: int, data, valid) -> bytes:
         # delta row: only valid words come from the overlay, the rest
         # from the base image (little-endian words -> bytes on a LE host)
         base = np.frombuffer(self._base_page(lane, pfn), dtype=np.uint64)
         return np.where(valid != 0, data, base).tobytes()
+
+    def _count_row_pull(self, rows: int) -> None:
+        self.runner.stats["view_row_pulls"] += 1
+        self.runner.stats["view_rows"] += rows
+
+    def _device_overlay_page(self, lane: int, pfn: int) -> Optional[bytes]:
+        row = self._overlay_row(lane, pfn)
+        if row is None:
+            return None
+        self._count_row_pull(1)
+        data, valid = ovl.fetch_row(self.runner.machine.overlay, lane, row)
+        return self._merge_row(lane, pfn, data, valid)
+
+    def prefetch(self, pages) -> None:
+        """Bring the device-overlay pages among `pages` ((lane, pfn)
+        pairs) into the page cache, each exactly as `page` would read it
+        alone, in one dispatch and one transfer for all lanes: a second
+        one only where a lane wants a second page.  Pages that are
+        pending, cached or clean cost nothing, and a list of those alone
+        dispatches nothing."""
+        wanted: Dict[int, Dict[int, int]] = {}
+        for lane, pfn in pages:
+            if (lane, pfn) in self.pending or (lane, pfn) in self._page_cache:
+                continue
+            row = self._overlay_row(lane, pfn)
+            if row is not None:
+                wanted.setdefault(lane, {})[pfn] = row
+        while wanted:
+            taken = {lane: pfns.popitem() for lane, pfns in wanted.items()}
+            wanted = {lane: pfns for lane, pfns in wanted.items() if pfns}
+            rows = np.full(self._ov_pfn.shape[0], -1, dtype=np.int32)
+            rows[list(taken)] = [row for _, row in taken.values()]
+            self._count_row_pull(len(taken))
+            data, valid = self.runner.fetch_overlay_rows(rows)
+            for lane, (pfn, _) in taken.items():
+                self._page_cache[lane, pfn] = self._merge_row(
+                    lane, pfn, data[lane], valid[lane])
 
     def page(self, lane: int, pfn: int) -> bytes:
         """Current contents of a guest-physical page as this lane sees it."""
@@ -775,7 +819,8 @@ class Runner:
             fields=("chunks", "decodes", "decodes_prefetched",
                     "decode_windows_gathered", "fallbacks",
                     "fallback_burst_steps", "smc_updates",
-                    "bp_dispatches", "exceptions_delivered"),
+                    "bp_dispatches", "exceptions_delivered",
+                    "view_row_pulls", "view_rows"),
             gauges=("max_chunk_steps",),
             labeled=("fallbacks_by_opclass",))
         self.stats["max_chunk_steps"] = chunk_steps
@@ -920,6 +965,24 @@ class Runner:
     # -- host memory access ------------------------------------------------
     def view(self) -> HostView:
         return HostView(self)
+
+    def fetch_overlay_rows(self, rows):
+        """One overlay row per lane (`rows[lane]`, -1: none) in one
+        dispatch and one transfer: `HostView.prefetch`'s device seam.
+        One shape per runner, compiled by the first batch that wants a
+        row."""
+        overlay = self.machine.overlay
+        key = ("view-rows", overlay.pfn.shape, self.exec_sig)
+        if key not in _DISPATCHED_EXECUTORS:
+            _DISPATCHED_EXECUTORS.add(key)
+            self.events.emit("compile", kind="view-rows",
+                             lanes=self.n_lanes)
+        return ovl.fetch_lane_rows(overlay, rows, self._lane_rows_reader())
+
+    def _lane_rows_reader(self):
+        """The compiled `overlay.read_lane_rows` (the mesh runner's runs
+        per shard)."""
+        return ovl.read_lane_rows_jit
 
     # -- mutate-on-device insert seam (wtf_tpu/devmut) ---------------------
     def device_insert(self, words, lens, pfns, gva: int,

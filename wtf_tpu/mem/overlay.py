@@ -56,6 +56,7 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from wtf_tpu.core.gxa import PAGE_SHIFT, PAGE_SIZE
@@ -220,6 +221,44 @@ def fetch_row(overlay: DirtyOverlay, lane_idx: int, row: int):
     """`read_row` for the host mirror: one dispatch and one transfer ->
     (np.uint64[PAGE_WORDS], np.uint8[PAGE_WORDS])."""
     return jax.device_get(_read_row_jit(overlay, lane_idx, row))
+
+
+def read_lane_rows(overlay: DirtyOverlay, rows
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """Row `rows[lane]` of every lane at once (-1: none, reads as zeros)
+    -> (limbs u32[lanes, 2 * PAGE_WORDS], valid u8[lanes, PAGE_WORDS]).
+
+    Lane-local like `fill_rows`: a lane indexes its own rows, so a mesh
+    shard runs this on the planes in hand, and the shape hangs on the
+    lane count alone.  The words stay limbs on the device; the host reads
+    their memory image as u64 (`fetch_lane_rows`)."""
+    n_lanes = overlay.pfn.shape[0]
+    start = row_start(overlay, jnp.arange(n_lanes, dtype=jnp.int32), rows)
+    # a window that starts past the plane is out of bounds and filled
+    start = jnp.where(rows >= 0, start, overlay.valid.shape[0])[:, None]
+    dnums = lax.GatherDimensionNumbers(
+        offset_dims=(1,), collapsed_slice_dims=(), start_index_map=(0,))
+
+    def take(plane, at, size):
+        return lax.gather(plane, at, dnums, (size,),
+                          mode=lax.GatherScatterMode.FILL_OR_DROP,
+                          fill_value=0)
+
+    return (take(overlay.data, 2 * start, 2 * PAGE_WORDS),
+            take(overlay.valid, start, PAGE_WORDS))
+
+
+read_lane_rows_jit = jax.jit(read_lane_rows)
+
+
+def fetch_lane_rows(overlay: DirtyOverlay, rows, read):
+    """`read_lane_rows` for the host mirror: one dispatch and one transfer
+    for the whole batch -> (np.uint64[lanes, PAGE_WORDS],
+    np.uint8[lanes, PAGE_WORDS]).  `read` is the compiled reader:
+    `read_lane_rows_jit`, or the mesh's per-shard one."""
+    limbs, valid = jax.device_get(read(overlay, jnp.asarray(rows)))
+    # the two little-endian limbs of a word, side by side, are its bytes
+    return np.ascontiguousarray(limbs).view(np.uint64), valid
 
 
 def write_row(overlay: DirtyOverlay, lane_idx, row, data, valid

@@ -25,7 +25,7 @@ dispatch surface:
 
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 from typing import Optional, Tuple
 
 import jax
@@ -36,6 +36,7 @@ from jax.sharding import PartitionSpec as P
 
 from wtf_tpu.interp.machine import CTR_INSTR
 from wtf_tpu.interp.runner import Runner
+from wtf_tpu.mem import overlay as ovl
 from wtf_tpu.meshrun.executor import (
     make_mesh_chunk, make_mesh_fused, make_mesh_resume,
 )
@@ -64,6 +65,17 @@ def _mesh_generate(rounds: int, mesh):
         check_vma=False))
     _MESH_GEN_CACHE[key] = fn
     return fn
+
+
+@lru_cache(maxsize=None)
+def _mesh_read_lane_rows(mesh):
+    """`overlay.read_lane_rows` per shard: a lane reads its own rows from
+    its shard's part of the planes, so nothing crosses the interconnect
+    and no plane is gathered."""
+    return jax.jit(shard_map(
+        ovl.read_lane_rows, mesh=mesh,
+        in_specs=(P(LANE_AXIS), P(LANE_AXIS)),
+        out_specs=(P(LANE_AXIS), P(LANE_AXIS)), check_vma=False))
 
 
 class MeshRunner(Runner):
@@ -164,6 +176,9 @@ class MeshRunner(Runner):
                                jax.sharding.NamedSharding(
                                    self.mesh, P(None, LANE_AXIS)))
         return first, rest, seeds
+
+    def _lane_rows_reader(self):
+        return _mesh_read_lane_rows(self.mesh)
 
     # -- host write seams: keep the canonical sharding -----------------------
     def push(self, view) -> None:
