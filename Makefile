@@ -7,13 +7,11 @@
 PY ?= python
 
 .PHONY: verify test lint lint-rebaseline slow mesh-smoke chaos-smoke \
-	triage-smoke tenancy-smoke fleet-smoke fused-smoke \
-	fused-mega-smoke device-chaos-smoke decode-smoke obs-smoke \
-	bench-guard
+	triage-smoke tenancy-smoke fleet-smoke device-chaos-smoke \
+	decode-smoke obs-smoke
 
 verify: test lint chaos-smoke triage-smoke tenancy-smoke fleet-smoke \
-	fused-smoke fused-mega-smoke device-chaos-smoke decode-smoke \
-	obs-smoke bench-guard
+	device-chaos-smoke decode-smoke obs-smoke
 
 # tier-1 (the ROADMAP.md command without the driver's log plumbing)
 test:
@@ -73,21 +71,6 @@ tenancy-smoke:
 fleet-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m wtf_tpu.testing.fleet_smoke
 
-# fused-step + megachunk smoke (wtf_tpu/testing/fused_smoke): demo_tlv
-# occupancy >= 0.95 through the widened Pallas kernel (in-kernel page
-# walk + memory operands, interpret mode, small lanes) and a megachunk
-# window campaign bit-identical to the batch-at-a-time device loop
-fused-smoke:
-	JAX_PLATFORMS=cpu $(PY) -m wtf_tpu.testing.fused_smoke
-
-# fused-megachunk smoke (wtf_tpu/testing/fused_mega_smoke): the Pallas
-# kernel as the window's step engine must be bit-identical to the
-# XLA-ladder window at equal seeds, keep >=0.95 in-window occupancy,
-# and pass the donation lint (every donated/overlay leaf aliased in the
-# compiled window; jaxpr census on the megachunk_window_fused pin)
-fused-mega-smoke:
-	JAX_PLATFORMS=cpu $(PY) -m wtf_tpu.testing.fused_mega_smoke
-
 # deterministic fault-tolerance soak (wtf_tpu/testing/chaos_smoke):
 # seeded fault schedule over the real socket + checkpoint seams —
 # >=1 reconnect, >=1 reclaim, >=1 torn-checkpoint .prev fallback, zero
@@ -119,11 +102,3 @@ decode-smoke:
 # `wtf-tpu status` surface
 obs-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m wtf_tpu.testing.obs_smoke
-
-# perf-regression guard self-test (tools/bench_guard.py): extraction
-# over the checked-in BENCH_r06/r07 rounds must compare clean while a
-# synthetic 2x regression must be flagged.  To gate a fresh run:
-#   python bench.py > /tmp/bench.json && \
-#   python tools/bench_guard.py /tmp/bench.json
-bench-guard:
-	$(PY) tools/bench_guard.py --self-test

@@ -4,13 +4,13 @@ Two layers:
 
   * negative paths (ISSUE 5 satellite): each rule family gets a seeded
     violation — a u64 op in a "ported" path, a gather over budget, a
-    weak-typed operand / value captured in a trace, a pstep/step opclass
-    mismatch — and must fire its NAMED rule with actionable provenance
+    weak-typed operand / value captured in a trace, a donated window operand
+    left unaliased — and must fire its NAMED rule with actionable provenance
     (rule + entry point + primitive);
-  * clean paths: the cheap families (parity, donation policy, seam)
-    against the real tree; the full `run_lint` (which compiles the step
-    ladder, ~30s) runs in the slow tier — tier-1 covers the dtype family
-    through tests/test_limbs.py instead.
+  * clean paths: the cheap families (donation policy, seam) and the
+    megachunk window's pins against the real tree; the full `run_lint`
+    (which compiles the step ladder, ~30s) runs in the slow tier — tier-1
+    covers the dtype family through tests/test_limbs.py instead.
 """
 
 import json
@@ -21,14 +21,12 @@ import numpy as np
 import pytest
 
 from wtf_tpu.analysis.findings import Finding
-from wtf_tpu.analysis.parity import (
-    check_fused_parity, kernel_hot_opclasses, step_unsupported_opclasses,
-)
 from wtf_tpu.analysis.rules import (
     check_budget, check_donation_aliasing, check_no_u64,
     check_runner_donation_policy, check_seam_bitcast_only,
-    check_signature_stable, check_strong_inputs, count_data_dependent_ops,
-    load_budgets, run_dtype_family, run_lint,
+    check_signature_stable, check_strong_inputs,
+    check_window_donation_aliasing, count_data_dependent_ops,
+    load_budgets, run_dtype_family, run_lint, run_megachunk_rules,
 )
 from wtf_tpu.analysis.trace import compiled_hlo, lower_jit
 
@@ -243,66 +241,44 @@ def test_donation_aliasing_rule_fires_on_unaliased_leaf():
 
 
 # ---------------------------------------------------------------------------
-# parity family
+# the megachunk window's pins
 # ---------------------------------------------------------------------------
 
-def test_parity_clean_on_real_tree():
-    assert check_fused_parity() == []
+def test_window_pins_clean_on_the_ladder_window():
+    """The window the chip runs (tests/test_chip_compile.py compiles the
+    same program for a v5e) is what budgets.json pins: its jaxpr census
+    equals the `megachunk_window` entry, and lowered with donation every
+    donated machine/aggregate leaf is aliased in the compiled output."""
+    findings, info = run_megachunk_rules()
+    assert findings == [], [str(f) for f in findings]
+    pinned = dict(load_budgets()["megachunk_window"])
+    assert pinned.pop("entry") == info["entry"]
+    assert info["mega_counts"] == pinned
+    assert set(pinned) == {"gather", "dynamic-slice",
+                           "dynamic-update-slice", "scatter", "total"}
 
 
-def test_parity_extractors_see_real_sources():
-    assert "ALU" in kernel_hot_opclasses()
-    assert "SSECVT" in step_unsupported_opclasses()
+def test_window_donation_rule_fires_on_unaliased_leaf():
+    """A donated window operand no output can reuse (no result of its
+    shape) is invalidated without in-place reuse:
+    recompile.window-donation-unaliased names the operand; the donated
+    operands that ARE reused stay silent."""
+    # jit matches donors to results by shape: give the machine its own
+    args = tuple(jnp.zeros((8 if i == 2 else 4,), jnp.uint32)
+                 for i in range(14))
 
+    def window(*ops):
+        # machine (2): only a scalar survives; agg_cov/agg_edge (12, 13)
+        # update in place
+        return ops[2].sum(), ops[12] + 1, ops[13] + 1
 
-def test_parity_fires_on_kernel_claim_mismatch():
-    """Kernel hot_class grows an opclass the claim doesn't carry (or vice
-    versa): parity.claim-vs-kernel with the opclass named."""
-    pstep_src = "hot_class = ((opc == U.OPC_NOP) | (opc == U.OPC_PUSH))\n"
-    step_src = ("unsupported = pre_live & (is_(U.OPC_IRET))\n"
-                "x = is_(U.OPC_NOP)\n")
-    findings = check_fused_parity(claimed={"NOP"}, pstep_src=pstep_src,
-                                  step_src=step_src)
-    assert [ (f.rule, f.primitive) for f in findings ] == [
-        ("parity.claim-vs-kernel", "OPC_PUSH")]
-    assert "pstep" in findings[0].entry
-
-
-def test_parity_fires_on_unsupported_overlap():
-    """A claimed in-kernel opclass appearing in step.py's oracle-diverting
-    `unsupported` expression: the park/resume seam would diverge."""
-    pstep_src = "hot_class = (opc == U.OPC_JCC)\n"
-    step_src = "unsupported = pre_live & (is_(U.OPC_JCC))\n"
-    findings = check_fused_parity(claimed={"JCC"}, pstep_src=pstep_src,
-                                  step_src=step_src)
-    assert ("parity.fused-vs-unsupported", "OPC_JCC") in [
-        (f.rule, f.primitive) for f in findings]
-
-
-def test_parity_resolves_intermediate_bindings():
-    """The house style routes diverting predicates through locals
-    (`movcr_bad`, `x87_oracle`) and sometimes `|=` — the rule must see
-    through both, not just literal OPC names on the final RHS."""
-    step_src = ("jcc_bad = is_(U.OPC_JCC) & weird_mode\n"
-                "unsupported = pre_live & (is_(U.OPC_IRET) | jcc_bad)\n"
-                "unsupported |= is_(U.OPC_MSR)\n")
-    assert step_unsupported_opclasses(step_src) == {"JCC", "IRET", "MSR"}
-    findings = check_fused_parity(claimed={"JCC"},
-                                  pstep_src="hot_class = (opc == U.OPC_JCC)",
-                                  step_src=step_src)
-    assert ("parity.fused-vs-unsupported", "OPC_JCC") in [
-        (f.rule, f.primitive) for f in findings]
-    # the real tree resolves through its intermediates too
-    assert {"MOVCR", "DIV", "X87"} <= step_unsupported_opclasses()
-
-
-def test_parity_fires_on_missing_step_dispatch():
-    pstep_src = "hot_class = (opc == U.OPC_MOV)\n"
-    step_src = "unsupported = pre_live & (is_(U.OPC_IRET))\n"
-    findings = check_fused_parity(claimed={"MOV"}, pstep_src=pstep_src,
-                                  step_src=step_src)
-    assert [(f.rule, f.primitive) for f in findings] == [
-        ("parity.fused-vs-dispatch", "OPC_MOV")]
+    compiled = lower_jit(window, *args, donate_argnums=(2, 12, 13)).compile()
+    findings = check_window_donation_aliasing(
+        compiled, args, (2, 12, 13), entry="seeded.window")
+    assert [f.rule for f in findings] == [
+        "recompile.window-donation-unaliased"]
+    assert findings[0].primitive.startswith("machine")
+    assert findings[0].entry == "seeded.window"
 
 
 # ---------------------------------------------------------------------------
@@ -352,8 +328,8 @@ def test_report_no_churn_for_distinct_shapes(tmp_path):
         {"ts": 1.0, "seq": 0, "type": "run-start", "subcommand": "t"},
         {"ts": 1.1, "seq": 1, "type": "compile", "chunk_steps": 64,
          "donate": False},
-        {"ts": 1.2, "seq": 2, "type": "compile", "kind": "pallas-fused",
-         "k_steps": 32},
+        {"ts": 1.2, "seq": 2, "type": "compile", "kind": "megachunk",
+         "batches": 8, "lanes": 64},
         {"ts": 2.0, "seq": 3, "type": "run-end", "metrics": {}},
     ]
     path = tmp_path / "events.jsonl"
@@ -377,12 +353,12 @@ def test_finding_formats_provenance():
     assert "gather" in s and "90" in s and "81" in s
 
 
-def test_lint_cli_parity_only_with_telemetry(tmp_path, capsys):
+def test_lint_cli_one_cheap_family_with_telemetry(tmp_path, capsys):
     """The CLI path end to end on the cheap family: clean exit, CLEAN
     line, and a well-formed events.jsonl (run-start / run-end)."""
     from wtf_tpu.analysis import main
 
-    rc = main(["--families", "parity", "--telemetry-dir", str(tmp_path)])
+    rc = main(["--families", "supervise", "--telemetry-dir", str(tmp_path)])
     out = capsys.readouterr().out
     assert rc == 0
     assert "CLEAN" in out
@@ -402,7 +378,7 @@ def test_rebaseline_without_budget_family_rejected():
     """--rebaseline with a families filter that skips `budget` must fail
     loudly, not silently leave the pin stale."""
     with pytest.raises(ValueError, match="rebaseline"):
-        run_lint(families=["parity"], rebaseline=True)
+        run_lint(families=["supervise"], rebaseline=True)
 
 
 @pytest.mark.slow
@@ -410,7 +386,7 @@ def test_full_lint_clean_on_tree(tmp_path):
     """The acceptance gate: every family against the real tree —
     compiles the step ladder and the sharded mesh chunk (~minutes on
     the 1-core box), so slow tier; tier-1 covers dtype via test_limbs,
-    parity/negative paths above, mesh via test_meshrun, and the
+    the negative paths above, mesh via test_meshrun, and the
     contract families via test_flow."""
     from wtf_tpu.analysis.rules import FAMILIES
     from wtf_tpu.telemetry import Registry

@@ -3,7 +3,7 @@ no chip: the TPU compiler is installed here and compiles for a chip that
 is DESCRIBED, not attached (`topologies.get_topology_desc`).  A compile
 that passes is not a chip run and says nothing about results or times;
 what it guards is every later PR against the refusals PR 22 found (the
-f64 -> u64 bitcast in the step, the fused kernel's block specs).
+f64 -> u64 bitcast in the step, a u64 psum on the mesh).
 
 Shapes are chip_smoke.py's: demo_tlv, 1024 lanes, the campaign's default
 runner (256-step base chunk, 128 overlay slots).  Compile time is set by
@@ -268,21 +268,6 @@ def test_device_insert_compiles_for_v5e(loop, one_chip, compile_for):
         one_chip)
 
 
-def test_fused_kernel_is_refused_at_lowering(loop, one_chip, compile_for):
-    """Today's truth about the Pallas kernel (ROADMAP S2): the TPU
-    lowering refuses its rank-1 per-lane block specs before Mosaic proper
-    runs.  The PR that rewrites the kernel flips this test — and only
-    then may `--fused-step auto` select it again."""
-    from wtf_tpu.interp.pstep import fused_call_impl
-
-    runner = loop.backend.runner
-    with pytest.raises(Exception, match="rank 1 block shapes"):
-        compile_for(
-            lambda *a: fused_call_impl(*a, k_steps=runner.fused_k,
-                                       interpret=False),
-            runner.executor_operands(), one_chip)
-
-
 class _Captured(Exception):
     pass
 
@@ -309,8 +294,8 @@ def _window_dispatch(loop):
 
 @pytest.mark.slow
 def test_window_program_compiles_for_v5e(one_chip, compile_for):
-    """The megachunk window (XLA ladder inside, fused off) on the
-    operands the backend really dispatches."""
+    """The megachunk window on the operands the backend really
+    dispatches."""
     fn, _, operands = _window_dispatch(_campaign(LANES, megachunk=8))
     mem = compile_for(fn, operands, one_chip).memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes

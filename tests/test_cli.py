@@ -26,6 +26,17 @@ def test_parser_subcommands():
         parser.parse_args(["bogus"])
 
 
+def test_engine_option_is_rejected_by_the_parser(capsys):
+    """One device interpreter: `--fused-step` selected between two and is
+    rejected by the argument parser itself (exit 2), no shim."""
+    from wtf_tpu.cli import main
+
+    with pytest.raises(SystemExit) as ei:
+        main(["campaign", "--name", "demo_tlv", "--fused-step", "on"])
+    assert ei.value.code == 2
+    assert "--fused-step" in capsys.readouterr().err
+
+
 def test_target_paths_resolve(tmp_path):
     paths = TargetPaths(target=tmp_path / "t").resolve()
     assert paths.inputs == tmp_path / "t" / "inputs"

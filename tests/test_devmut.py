@@ -12,7 +12,7 @@ Three layers:
     measured under mutate/device (host share ~ dispatch only)
 
 The coverage-parity-vs-host-mangle comparison runs a real two-campaign
-A/B and lives in the slow tier (same policy as pstep's occupancy pair).
+A/B and lives in the slow tier.
 """
 
 import numpy as np

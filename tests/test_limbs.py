@@ -12,8 +12,8 @@ Two jobs:
    the limb library itself, the step's ALU/unary/addressing cores, the
    decode-cache hash probe — and assert the optimized HLO contains ZERO
    64-bit integer ops.  This is what keeps a future edit from silently
-   reintroducing u64 (XLA would lower it to a u32 pair on TPU and Pallas
-   would reject it outright) on the paths this PR ported.  Since ISSUE 5
+   reintroducing u64 (XLA would lower it to a u32 pair on TPU) on the
+   paths this PR ported.  Since ISSUE 5
    the contract lives in the analysis rule API (wtf_tpu/analysis/rules.py
    dtype family, enumerated from step.PORTED_LIMB_PATHS) — this file just
    runs the family, so the tests and `wtf-tpu lint` can never disagree.
@@ -278,11 +278,11 @@ def test_eval_cond_table():
 
 def test_hlo_ported_paths_are_u64_free():
     """The zero-u64/s64 (and float-free) HLO pin over EVERY enumerated
-    ported path — the limb library, the step ALU/unary/shift/mul/EA
-    cores, the decode-cache probe, the Pallas-bound register-file writer,
-    and the pack/unpack bitcast-only seam — via the analysis dtype rule
-    family (what `wtf-tpu lint` runs; step.PORTED_LIMB_PATHS is the
-    enumeration, so a newly ported path is covered by being exported)."""
+    ported path — the limb library, the step ALU/unary/shift/mul/EA cores,
+    the decode-cache probe, and the pack/unpack bitcast-only seam — via the
+    analysis dtype rule family (what `wtf-tpu lint` runs;
+    step.PORTED_LIMB_PATHS is the enumeration, so a newly ported path is
+    covered by being exported)."""
     from wtf_tpu.analysis.rules import run_dtype_family
 
     findings = run_dtype_family()
@@ -408,26 +408,3 @@ def test_const_shifts_and_small_add():
         s = jnp.full(len(CORNERS), small, dtype=jnp.uint32)
         assert _ints(L.add64_u32(a, s)) == [
             (x + small) & MASK64 for x in CORNERS], f"add64_u32 {small:#x}"
-
-
-def test_gpr_write_limb_matches_u64_reference():
-    """The Pallas-bound limb register-file writer against the u64 scatter
-    the step currently uses — same partial-write merge semantics."""
-    from wtf_tpu.cpu import uops as U
-
-    rng = np.random.default_rng(0x6B)
-    file64 = jnp.asarray(rng.integers(0, 1 << 64, 16, dtype=np.uint64))
-    gl = L.unpack_u64(file64)
-    val64 = jnp.uint64(0x1122334455667788)
-    val_l = L.pair(val64)
-    for idx in (0, 3, 15, U.REG_AH_BASE, U.REG_AH_BASE + 3):
-        for nbytes in (1, 2, 4, 8):
-            for cond in (False, True):
-                want = S._gpr_write(file64, jnp.bool_(cond), jnp.int32(idx),
-                                    val64, jnp.int32(nbytes))
-                got = S._gpr_write_l(gl, jnp.bool_(cond), jnp.int32(idx),
-                                     val_l, jnp.int32(nbytes))
-                assert (L.pack_u64(got) == want).all(), (
-                    f"idx={idx} nbytes={nbytes} cond={cond}")
-    # (the no-u64 HLO pin for _gpr_write_l rides the analysis dtype
-    # family — "step.gpr_write_l" in step.PORTED_LIMB_PATHS)

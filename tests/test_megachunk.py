@@ -177,73 +177,6 @@ def test_megachunk_checkpoint_killpoint_sweep(tmp_path):
         assert fp == ref_fp, f"kill at batch {kill_at}: state diverged"
 
 
-def test_fused_window_bit_identical_to_ladder_window():
-    """The PR-19 tentpole bar: the same equal-seed campaign through
-    megachunk windows whose quiesce body is the Pallas fused kernel +
-    bounded resume (fused_step=on) is byte-identical to the XLA-ladder
-    window campaign — aggregate coverage/edge BYTES, corpus digests in
-    order, crash names/buckets, every counter.  The engine split is
-    checked too: the fused campaign actually dispatched the kernel
-    (device.fused_window_rounds > 0), the ladder one never did."""
-    runs = BUILD["n_lanes"] * 12
-    ladder = _campaign(3, runs, fused_step="off")
-    fused = _campaign(3, runs, fused_step="on")
-    assert _fingerprint(fused) == _fingerprint(ladder)
-    assert _fingerprint(ladder)["cov_bits"] > 0
-    assert ladder.registry.counter(
-        "device.fused_window_rounds").value == 0
-    assert fused.registry.counter("device.fused_window_rounds").value > 0
-    # donation bookkeeping: bytes-saved scales exactly with dispatches
-    rounds = fused.registry.counter("device.fused_window_rounds").value
-    saved = fused.registry.counter(
-        "device.fused_window_bytes_saved").value
-    assert saved == rounds * fused.backend._fused_alias_bytes()
-
-
-def test_fused_window_mesh_parity():
-    """Fused windows on the forced 8-device mesh: the shard_map window
-    with the kernel inside — per-shard local trip counts folded by the
-    lockstep psum — is bit-identical to the single-device fused window
-    (and therefore to the ladder window)."""
-    if len(jax.devices()) < 8:
-        pytest.skip("needs XLA_FLAGS=--xla_force_host_platform_"
-                    "device_count=8 (make mesh-smoke environment)")
-    runs = BUILD["n_lanes"] * 3
-    fp_single = _fingerprint(_campaign(3, runs, fused_step="on"))
-    fp_mesh = _fingerprint(_campaign(3, runs, fused_step="on",
-                                     mesh_devices=8))
-    assert fp_mesh == fp_single
-
-
-@pytest.mark.slow
-def test_fused_window_checkpoint_killpoint_sweep(tmp_path):
-    """PR-8 crash-safety with the kernel inside the window: kill at
-    EVERY interior batch boundary of a fused-window campaign and resume
-    — final state bit-identical to the uninterrupted fused run (which
-    its own parity test pins equal to the ladder run)."""
-    batches = 4
-    runs = BUILD["n_lanes"] * batches
-    ref_fp = _fingerprint(_campaign(4, runs, fused_step="on"))
-    assert ref_fp["cov_bits"] > 0
-
-    for kill_at in range(1, batches):
-        ckpt = tmp_path / f"kill{kill_at}"
-        victim = build_tlv_campaign(mutator="devmangle", seed=0x5EED,
-                                    megachunk=4, fused_step="on", **BUILD)
-        victim.checkpoint_dir, victim.checkpoint_every = ckpt, 1
-        fuzz_until_killed(victim, runs, kill_at_batch=kill_at)
-
-        resumed = build_tlv_campaign(mutator="devmangle", seed=0x5EED,
-                                     megachunk=4, fused_step="on",
-                                     **BUILD)
-        state, fell_back = load_campaign(ckpt)
-        assert not fell_back
-        assert restore_campaign(resumed, state, ckpt) == kill_at
-        resumed.fuzz(runs)
-        fp = _fingerprint(resumed)
-        assert fp == ref_fp, f"kill at batch {kill_at}: state diverged"
-
-
 def test_megachunk_mesh_parity():
     """Windows on a forced 8-device mesh (conftest forces the virtual
     mesh for the whole suite): the shard_map megachunk — whose

@@ -302,16 +302,14 @@ def test_telemetry_report_mesh_section(tmp_path):
 def test_mesh_campaign_ramp_parity_slow():
     """The larger ramp: 64 lanes x 6 batches (384 execs) with crashes
     possible; mesh and single-device runs stay bit-identical on
-    coverage, crash names and corpus over the longer horizon, and the
-    fused Pallas ladder on the mesh agrees too."""
+    coverage, crash names and corpus over the longer horizon."""
     from wtf_tpu.analysis.trace import build_tlv_campaign
 
-    def run(mesh_devices, fused="off"):
+    def run(mesh_devices):
         loop = build_tlv_campaign(n_lanes=64, mutator="devmangle",
                                   limit=20_000, seed=0xAB, chunk_steps=128,
                                   overlay_slots=16,
-                                  mesh_devices=mesh_devices,
-                                  fused_step=fused)
+                                  mesh_devices=mesh_devices)
         for _ in range(6):
             loop.run_one_batch()
         return loop
@@ -325,9 +323,3 @@ def test_mesh_campaign_ramp_parity_slow():
                                   np.asarray(b.backend._agg_cov))
     np.testing.assert_array_equal(np.asarray(a.backend._agg_edge),
                                   np.asarray(b.backend._agg_edge))
-
-    c = run(8, fused="on")
-    assert c.stats.testcases == 384
-    np.testing.assert_array_equal(np.asarray(a.backend._agg_cov),
-                                  np.asarray(c.backend._agg_cov))
-    assert c.registry.counter("device.fused_steps").value > 0

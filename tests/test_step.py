@@ -544,10 +544,19 @@ def test_restore_roundtrip_batch():
     assert int(np.asarray(ov.count)[0]) == 0
 
 
-def test_page_fault_reported():
-    runner, status = run_tpu("mov rax, [0x1234]\nhlt", n_lanes=1)
+@pytest.mark.parametrize("asm,data,gva,retired", [
+    ("mov rax, [0x1234]\nhlt", None, 0x1234, 0),
+    # a non-present hole 2 MiB past a mapped page, after a load that hit
+    (f"mov rbx, {DATA_BASE}\nmov rax, [rbx]\n"
+     f"mov rcx, [rbx + 0x200000]\nint3",
+     {DATA_BASE: b"\x55" * 0x1000}, DATA_BASE + 0x200000, 2),
+], ids=["null-page", "hole-after-load"])
+def test_page_fault_reported(asm, data, gva, retired):
+    runner, status = run_tpu(asm, data=data, n_lanes=1)
     assert StatusCode(int(status[0])) == StatusCode.PAGE_FAULT
-    assert int(np.asarray(runner.machine.fault_gva)[0]) == 0x1234
+    assert int(np.asarray(runner.machine.fault_gva)[0]) == gva
+    assert int(np.asarray(runner.machine.fault_write)[0]) == 0
+    assert int(np.asarray(runner.machine.icount)[0]) == retired
 
 
 def test_divide_error_reported():

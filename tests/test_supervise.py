@@ -1,17 +1,16 @@
 """Self-healing device runtime tests (wtf_tpu/supervise).
 
-The acceptance contract (ISSUE 16): every device dispatch routes through
-the supervisor (lint-pinned seam enumeration); a hung dispatch is
-abandoned by the watchdog and the batch replays BIT-IDENTICALLY after a
-backend rebuild from live host-side state; repeated failures walk the
-degradation ladder (megachunk -> batch-at-a-time -> fused-off ->
-fixed-chunk) and hysteresis re-promotes after clean batches, every rung
-bit-identical at equal seeds; lanes failing the on-device integrity
-check are quarantined (masked idle, never harvested) while the campaign
-completes; the max_chunks satellite revokes stuck lanes as per-lane
-TIMEDOUT instead of aborting the batch; and the scripted device-fault
-chaos (hang/error/poison on the Nth dispatch) is operation-indexed,
-never wall-clock.
+The acceptance contract (ISSUE 16): every device dispatch routes through the
+supervisor (lint-pinned seam enumeration); a hung dispatch is abandoned by
+the watchdog and the batch replays BIT-IDENTICALLY after a backend rebuild
+from live host-side state; repeated failures walk the degradation ladder
+(megachunk -> batch-at-a-time -> fixed-chunk) and hysteresis re-promotes
+after clean batches, every rung bit-identical at equal seeds; lanes failing
+the on-device integrity check are quarantined (masked idle, never harvested)
+while the campaign completes; the max_chunks satellite revokes stuck lanes
+as per-lane TIMEDOUT instead of aborting the batch; and the scripted
+device-fault chaos (hang/error/poison on the Nth dispatch) is
+operation-indexed, never wall-clock.
 """
 
 import sys
@@ -168,18 +167,16 @@ def test_scripted_faults_are_operation_indexed(monkeypatch):
 # degradation ladder unit
 # ---------------------------------------------------------------------------
 
-def _stub_loop(megachunk=2, fused=True, adaptive=True):
-    runner = types.SimpleNamespace(fused_enabled=fused,
-                                   adaptive_chunks=adaptive)
+def _stub_loop(megachunk=2, adaptive=True):
+    runner = types.SimpleNamespace(adaptive_chunks=adaptive)
     return types.SimpleNamespace(
         backend=types.SimpleNamespace(runner=runner), megachunk=megachunk)
 
 
 def test_ladder_rungs_skip_inapplicable_features():
     full = DegradationLadder(_stub_loop())
-    assert full.rungs == ["full", "no-megachunk", "no-fused", "fixed-chunk"]
-    bare = DegradationLadder(_stub_loop(megachunk=0, fused=False,
-                                        adaptive=False))
+    assert full.rungs == ["full", "no-megachunk", "fixed-chunk"]
+    bare = DegradationLadder(_stub_loop(megachunk=0, adaptive=False))
     assert bare.rungs == ["full"]
     assert not bare.on_failure()       # nothing left to turn off
     assert bare.wants_reshard
@@ -192,22 +189,23 @@ def test_ladder_degrade_apply_and_hysteresis_promotion():
 
     assert ladder.on_failure() and ladder.rung_name == "no-megachunk"
     assert ladder.megachunk_off
-    assert ladder.on_failure() and ladder.rung_name == "no-fused"
     ladder.apply(loop)
-    assert loop.backend.runner.fused_enabled is False
     assert loop.backend.runner.adaptive_chunks is True
+    assert ladder.on_failure() and ladder.rung_name == "fixed-chunk"
+    ladder.apply(loop)
+    assert loop.backend.runner.adaptive_chunks is False
 
     # hysteresis: promote_after CONSECUTIVE cleans win one rung back
     assert not ladder.on_clean()
     assert ladder.on_clean() and ladder.rung_name == "no-megachunk"
     assert not ladder.on_clean()       # streak reset by the promotion
     ladder.on_failure()                # a failure resets the streak too
-    assert ladder.rung_name == "no-fused"
+    assert ladder.rung_name == "fixed-chunk"
     assert not ladder.on_clean()
     assert ladder.on_clean()
 
-    ladder.apply(loop)                 # back at no-megachunk: fused back on
-    assert loop.backend.runner.fused_enabled is True
+    ladder.apply(loop)                 # back at no-megachunk: growth back on
+    assert loop.backend.runner.adaptive_chunks is True
 
 
 def test_ladder_bottom_requests_reshard():
@@ -344,67 +342,42 @@ def test_megachunk_hang_degrades_and_repromotes(ref_state):
     assert reg.counter("supervise.promotions").value >= 1
 
 
-def test_fused_window_hang_recovery_replays_bit_identical(ref_state):
-    """DEVICE_HANG mid-FUSED-window (the PR-19 window body): the
-    watchdog abandons the in-flight fused window — whose machine/overlay
-    planes are donated into the dispatch on real hardware — the rebuild
-    reconstructs them from live host-side state, and the replayed
-    campaign is bit-identical to the fault-free reference.  Index 17 is
-    a steady-state fused window in the supervised dispatch schedule
-    (0 = cold window, 1-16 = cold-decode fused servicing)."""
-    plan = FaultPlan([], device_faults={17: DEVICE_HANG})
-    loop = build_tlv_campaign(megachunk=2, fused_step="on",
-                              supervise=True, dispatch_timeout=30.0,
-                              promote_after=1, **BUILD)
+def test_steady_window_hang_recovery_replays_bit_identical(ref_state):
+    """DEVICE_HANG on a STEADY-STATE window: the watchdog abandons the
+    in-flight window — whose machine/overlay planes are donated into the
+    dispatch on real hardware — the rebuild reconstructs them from live
+    host-side state, and the replayed campaign is bit-identical to the
+    fault-free reference.  Index 5 is the first steady-state window in
+    the supervised dispatch schedule (0 = cold window, 1-4 = cold-decode
+    chunk servicing)."""
+    plan = FaultPlan([], device_faults={5: DEVICE_HANG})
+    loop = build_tlv_campaign(megachunk=2, supervise=True,
+                              dispatch_timeout=30.0, promote_after=1,
+                              **BUILD)
     with chaos_device(plan):
         loop.fuzz(RUNS)
     assert _state_of(loop) == ref_state
-    assert [f[:2] for f in plan.fired] == [("device-hang", "megachunk")]
+    assert plan.fired[0][:3] == ("device-hang", "megachunk", 5)
+    assert len(plan.fired) == 1
     reg = loop.backend.supervisor.registry
     assert reg.counter("supervise.watchdog_fires").value == 1
     assert reg.counter("supervise.rebuilds").value >= 1
-    # the fault really interrupted the fused body, not a ladder window
-    assert loop.registry.counter("device.fused_window_rounds").value > 0
 
 
-def test_fused_window_error_recovery_replays_bit_identical(ref_state):
-    """DEVICE_ERROR on the COLD fused window (dispatch 0): the very
-    first window's donated operands are rebuilt from the pristine host
-    snapshot and the campaign replays bit-identically."""
+def test_cold_window_error_recovery_replays_bit_identical(ref_state):
+    """DEVICE_ERROR on the COLD window (dispatch 0): the very first
+    window's donated operands are rebuilt from the pristine host snapshot
+    and the campaign replays bit-identically."""
     plan = FaultPlan([], device_faults={0: DEVICE_ERROR})
-    loop = build_tlv_campaign(megachunk=2, fused_step="on",
-                              supervise=True, dispatch_timeout=30.0,
-                              promote_after=1, **BUILD)
+    loop = build_tlv_campaign(megachunk=2, supervise=True,
+                              dispatch_timeout=30.0, promote_after=1,
+                              **BUILD)
     with chaos_device(plan):
         loop.fuzz(RUNS)
     assert _state_of(loop) == ref_state
     assert [f[:2] for f in plan.fired] == [("device-error", "megachunk")]
     reg = loop.backend.supervisor.registry
     assert reg.counter("supervise.rebuilds").value >= 1
-    assert loop.registry.counter("device.fused_window_rounds").value > 0
-
-
-def test_no_fused_rung_disables_fused_window_body(ref_state):
-    """The no-fused rung's apply() clears runner.fused_enabled; the
-    megachunk WINDOW BODY must follow at the next dispatch (the flag is
-    read at call time and the compiled-window cache keys on it): pallas
-    dispatches stop, the XLA-ladder windows take over, and the campaign
-    stays bit-identical across the mid-campaign body swap."""
-    loop = build_tlv_campaign(megachunk=2, fused_step="on", **BUILD)
-    ladder = DegradationLadder(loop)
-    loop.fuzz(RUNS // 2)
-    reg = loop.registry
-    rounds_mid = reg.counter("device.fused_window_rounds").value
-    sweeps_mid = reg.counter("device.fused_window_xla_steps").value
-    assert rounds_mid > 0, "fused window body never ran"
-    while ladder.rung_name != "no-fused":
-        assert ladder.on_failure()
-    ladder.apply(loop)
-    assert loop.backend.runner.fused_enabled is False
-    loop.fuzz(RUNS)
-    assert reg.counter("device.fused_window_rounds").value == rounds_mid
-    assert reg.counter("device.fused_window_xla_steps").value > sweeps_mid
-    assert _state_of(loop) == ref_state
 
 
 @pytest.mark.slow
@@ -501,9 +474,8 @@ def test_lint_supervise_family_clean_on_real_tree():
     assert check_supervised_seams() == []
     assert check_seam_enumeration() == []
     # the enumeration covers every dispatch entry point the runtime has
-    assert set(SEAM_SITES) >= {"chunk", "fused", "fused-resume",
-                               "device-insert", "devmut-generate",
-                               "megachunk"}
+    assert set(SEAM_SITES) == {"chunk", "device-insert", "devmut-generate",
+                               "megachunk", "device-decode"}
 
 
 def test_lint_supervise_flags_unrouted_seam():

@@ -592,26 +592,30 @@ def test_trace_writers_context_manager_and_flush(tmp_path):
 # report tool smoke test
 # ---------------------------------------------------------------------------
 
-def test_telemetry_report_on_bench_output(tmp_path, capsys, monkeypatch):
-    """The CI/tooling satellite end-to-end: bench.py --telemetry writes a
-    registry-derived JSON + an events.jsonl, and telemetry_report
-    summarizes that bench output (per-phase share, testcases/s)."""
-    import bench
+def test_telemetry_report_on_device_campaign(tlv_campaign):
+    """telemetry_report over the stream of a campaign on the batched
+    device backend: testcases, per-phase shares and the in-graph device
+    counters all come from the registry dump the run-end record carries."""
     import telemetry_report
 
-    monkeypatch.setenv("BENCH_SECONDS", "1")
-    monkeypatch.setenv("BENCH_TELEM_LANES", "2")
-    monkeypatch.setenv("BENCH_TELEM_CHUNK", "128")
-    telem = tmp_path / "telem"
-    bench.telemetry_mode(str(telem))
-    report = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    # the bench JSON is DERIVED from the registry dump
-    assert report["metrics"]["campaign.testcases"] >= 2
-    assert "execute" in report["phases"]
-    summary = telemetry_report.summarize(telem)
-    assert summary["testcases"] == report["metrics"]["campaign.testcases"]
-    assert summary["phases"]  # per-phase time share present
-    assert summary["device"]["instructions"] > 0
+    metrics = tlv_campaign["metrics"]
+    summary = telemetry_report.summarize(tlv_campaign["target"] / "telem")
+    assert summary["testcases"] == metrics["campaign.testcases"] >= 96
+    assert "execute" in summary["phases"]
+    assert summary["device"]["instructions"] \
+        == metrics["device.instructions"] > 0
+    assert 0 < summary["device"]["lane_occupancy"] <= 1
+
+
+def test_heartbeats_carry_no_fused_engine_counters(tlv_campaign):
+    """One device interpreter: no heartbeat (and not the run-end dump) of
+    a campaign carries a `device.fused_*` counter any more."""
+    dumps = [r["metrics"] for r in tlv_campaign["records"]
+             if r["type"] in ("heartbeat", "run-end") and "metrics" in r]
+    assert len(dumps) >= 2
+    for dump in dumps:
+        assert "device.sweeps" in dump
+        assert not [k for k in dump if k.startswith("device.fused_")]
 
 
 def test_telemetry_report_segments_appended_runs(tmp_path):
@@ -1198,13 +1202,14 @@ def test_lowered_chunk_names_every_stage_of_the_step(tpu_backend):
 
 @pytest.mark.parametrize("entry,total", [
     ("xla_step", 104), ("tenant_chunk", 104),
-    ("megachunk_window_fused", 156), ("decode_service", 248),
+    ("megachunk_window", 155), ("decode_service", 248),
     ("mesh_chunk", 1),
 ])
 def test_pinned_kernel_counts_did_not_move(entry, total):
     """analysis/budgets.json as PR 26 re-pinned it with the overlay
-    store (test_analysis holds the measured programs to the file; this
-    holds the file)."""
+    store, and the ladder window's entry as PR 30 pinned it
+    (test_analysis holds the measured programs to the file; this holds
+    the file)."""
     import wtf_tpu.analysis as analysis
 
     budgets = json.loads(

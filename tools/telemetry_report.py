@@ -402,45 +402,6 @@ def summarize(path) -> dict:
             "instructions": metrics.get("device.instructions", 0),
             "mem_faults": metrics.get("device.mem_faults", 0),
             "decode_misses": metrics.get("device.decode_misses", 0),
-            "fused_steps": metrics.get("device.fused_steps", 0),
-            # fraction of retired instructions executed inside the fused
-            # Pallas kernel (interp/pstep.py); null when the fast path
-            # never ran, so 0% occupancy can't be confused with "off".
-            # "ran" is detected by the pallas-step span, not the counter:
-            # a fused campaign whose every lane parks each round must
-            # read as the actionable 0.0, not as null
-            "fused_occupancy": (
-                round(metrics.get("device.fused_steps", 0)
-                      / metrics["device.instructions"], 4)
-                if metrics.get("device.instructions")
-                and (metrics.get("device.fused_steps", 0) > 0
-                     or any(path.split("/")[-1] == "pallas-step"
-                            for path in (metrics.get("phase.seconds")
-                                         or {})))
-                else None),
-            # WHY lanes left the kernel (interp/pstep.py park split):
-            # subset = cold opclass / armed bp / SMC-risk code window,
-            # mem = failing/unwritable walk or overlay-slot exhaustion
-            # mid-window — one opaque number used to hide the reason
-            "fused_park_subset": metrics.get("device.fused_park_subset",
-                                             0),
-            "fused_park_mem": metrics.get("device.fused_park_mem", 0),
-            # fused megachunk windows (fuzz/megachunk.py fused=True):
-            # in-window quiesce dispatch split — Pallas kernel rounds vs
-            # XLA ladder/resume sweeps — and the machine/overlay bytes
-            # donation kept from copying through the kernel per dispatch
-            "fused_window_rounds": metrics.get(
-                "device.fused_window_rounds", 0),
-            "fused_window_xla_steps": metrics.get(
-                "device.fused_window_xla_steps", 0),
-            "fused_window_share": (
-                round(metrics.get("device.fused_window_rounds", 0)
-                      / (metrics.get("device.fused_window_rounds", 0)
-                         + metrics.get("device.fused_window_xla_steps",
-                                       0)), 4)
-                if metrics.get("device.fused_window_rounds", 0) else None),
-            "fused_window_bytes_saved": metrics.get(
-                "device.fused_window_bytes_saved", 0),
             # in-graph sweep count (CTR_SWEPT): the share of lane-steps
             # that retired an instruction, and how many iterations of
             # its step loop a chunk dispatch really ran; null for
@@ -523,14 +484,9 @@ def _print_human(s: dict) -> None:
         for opclass, rate in s["fallback_rate_per_opclass"].items():
             print(f"  {opclass:<12} {rate}")
     dev = s["device"]
-    fused = (f" fused_steps={dev['fused_steps']}"
-             f" (occupancy {dev['fused_occupancy'] * 100:.1f}%; parks "
-             f"subset={dev.get('fused_park_subset', 0)} "
-             f"mem={dev.get('fused_park_mem', 0)})"
-             if dev.get("fused_occupancy") is not None else "")
     print(f"device counters: instructions={dev['instructions']} "
           f"mem_faults={dev['mem_faults']} "
-          f"decode_misses={dev['decode_misses']}{fused}")
+          f"decode_misses={dev['decode_misses']}")
 
     def _or_null(value, fmt):
         return "null" if value is None else fmt.format(value)
@@ -542,19 +498,6 @@ def _print_human(s: dict) -> None:
     if dev.get("lockstep_floor_share") is not None:
         print(f"  lockstep floor: {dev['lockstep_floor_share']:.2%} of "
               "lane-steps lie at or under the batch's deepest lane")
-    if dev.get("fused_window_share") is not None:
-        total = (dev["fused_window_rounds"]
-                 + dev["fused_window_xla_steps"])
-        print(f"  fused windows: {dev['fused_window_share'] * 100:.1f}% "
-              f"of {total} quiesce dispatches in-kernel "
-              f"({dev['fused_window_rounds']} pallas, "
-              f"{dev['fused_window_xla_steps']} ladder sweeps)")
-        saved = dev.get("fused_window_bytes_saved", 0)
-        if saved:
-            print(f"  donation: {saved / (1 << 20):.1f} MiB "
-                  f"copy-through saved "
-                  f"({saved // max(dev['fused_window_rounds'], 1)} "
-                  f"B/dispatch)")
     mesh = s.get("mesh")
     if mesh:
         print(f"mesh: {mesh['devices']} devices x "

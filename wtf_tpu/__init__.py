@@ -35,8 +35,8 @@ import jax
 
 # The guest is an x86-64 machine: 64-bit GPRs, 64-bit linear addresses.
 # Enable x64 so uint64 is a real dtype everywhere (XLA lowers 64-bit integer
-# ops to 32-bit pairs on TPU; correctness first, the Pallas hot path works on
-# packed 32-bit lanes).
+# ops to 32-bit pairs on TPU; correctness first, the hot path works on
+# packed 32-bit limbs, interp/limbs.py).
 jax.config.update("jax_enable_x64", True)
 
 

@@ -3,7 +3,7 @@
 The snapshot→execute→restore loop's fast path is defined by graph-shape
 invariants (zero u64 in the ported integer core, a pinned count of
 data-dependent gather kernels per step, no recompile hazards, donation
-aliasing, fused-subset parity between pstep and step).  This package
+aliasing).  This package
 traces the real entry points into jaxpr/StableHLO/optimized HLO on the
 CPU backend — statically, no chip — and walks them with a rule engine,
 so a regression shows up as a named lint failure with provenance.
@@ -14,7 +14,7 @@ Entry points:
     wtf-tpu lint ...                    (installed console script)
 
 Rule families (wtf_tpu/analysis/rules.py): dtype, budget, recompile,
-parity, mesh, supervise, telemetry, plus the dataflow contract families
+mesh, supervise, telemetry, plus the dataflow contract families
 (wtf_tpu/analysis/contracts.py on the shared engine in flow.py): state,
 transfer, thread, contracts.  Kernel/collective/transfer budgets live in
 wtf_tpu/analysis/budgets.json and the state/transfer/thread allowlists
@@ -35,7 +35,6 @@ import time
 from typing import List, Optional
 
 from wtf_tpu.analysis.findings import Finding  # noqa: F401
-from wtf_tpu.analysis.parity import check_fused_parity  # noqa: F401
 from wtf_tpu.analysis.rules import (  # noqa: F401
     FAMILIES, apply_rebaseline, check_budget, check_mesh_collectives,
     check_no_u64,
@@ -73,7 +72,7 @@ def _build_parser() -> argparse.ArgumentParser:
     p.add_argument("--deep", action="store_true",
                    help="run the transfer family's jaxpr host-transfer "
                         "census even without the budget family (whose "
-                        "fused-window trace it would otherwise reuse)")
+                        "window trace it would otherwise reuse)")
     p.add_argument("--sarif", default=None, metavar="OUT.json",
                    help="also write the findings as a SARIF 2.1.0 "
                         "document (file:line provenance mapped to "

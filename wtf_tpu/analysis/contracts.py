@@ -156,7 +156,7 @@ TRANSFER_ENTRY = "host_transfer"
 TRANSFER_CENSUS_ENTRY = ("jaxpr host-transfer census (callback/infeed/"
                          "outfeed/device_put) over steady-state programs"
                          " / demo_tlv / n_lanes=4")
-TRANSFER_PROGRAMS = ("megachunk_window_fused", "devmut_generate",
+TRANSFER_PROGRAMS = ("megachunk_window", "devmut_generate",
                      "device_insert", "decode_service")
 
 
@@ -397,8 +397,8 @@ def check_transfer_seams(contracts: Optional[Dict] = None,
 
 
 def count_host_transfers(jaxpr) -> int:
-    """Host-callback-class primitives in a jaxpr (sub-jaxprs included,
-    pallas_call atomic): pure/io/debug callbacks, infeed/outfeed, and
+    """Host-callback-class primitives in a jaxpr (sub-jaxprs
+    included): pure/io/debug callbacks, infeed/outfeed, and
     explicit device_put — everything that moves data across the
     host/device boundary inside a steady-state program."""
     from wtf_tpu.analysis.rules import _iter_eqns
@@ -415,7 +415,7 @@ def count_host_transfers(jaxpr) -> int:
 
 def measure_transfer_census(runner=None, mega_jaxpr=None) -> Dict[str, int]:
     """The device->host transfer census of the steady-state programs.
-    `mega_jaxpr` reuses the budget family's fused-window trace when both
+    `mega_jaxpr` reuses the budget family's window trace when both
     families run; `runner` reuses its demo_tlv runner."""
     import jax
     import jax.numpy as jnp
@@ -429,9 +429,9 @@ def measure_transfer_census(runner=None, mega_jaxpr=None) -> Dict[str, int]:
         cfg = MEGA_CONFIG
         lowered, args, fn = trace.megachunk_window_lowering(
             max_batches=cfg["max_batches"], n_lanes=cfg["n_lanes"],
-            fused=True, donate=True, limit=cfg["limit"])
+            donate=True, limit=cfg["limit"])
         mega_jaxpr = jax.make_jaxpr(fn)(*args)
-    counts["megachunk_window_fused"] = count_host_transfers(mega_jaxpr)
+    counts["megachunk_window"] = count_host_transfers(mega_jaxpr)
 
     from wtf_tpu.devmut import engine as DM
 
@@ -445,8 +445,7 @@ def measure_transfer_census(runner=None, mega_jaxpr=None) -> Dict[str, int]:
     counts["devmut_generate"] = count_host_transfers(gen_jaxpr)
 
     if runner is None:
-        runner = trace.build_tlv_runner(n_lanes=4, chunk_steps=16,
-                                        payload=None)
+        runner = trace.build_tlv_runner(n_lanes=4, chunk_steps=16)
 
     from wtf_tpu.interp.runner import _make_device_insert
 
@@ -486,8 +485,8 @@ def check_transfer_census(measured: Dict[str, int],
                           budgets_file: str = "budgets.json"
                           ) -> List[Finding]:
     """`transfer.census-drift`: a steady-state program's host-callback
-    count exceeds the pin.  The pin is EXACT downward too via
-    --rebaseline + bench_guard; lint only fails on growth."""
+    count exceeds the pin.  A decrease is re-pinned with
+    --rebaseline; lint only fails on growth."""
     findings: List[Finding] = []
     for prog in list(TRANSFER_PROGRAMS) + ["total"]:
         if prog not in measured:

@@ -9,9 +9,6 @@ bespoke importlib+regex paths:
   * per-function dataflow — attribute-assignment/read extraction over a
     base name (`self`, or a named parameter like `loop`), the raw
     material of the state and thread families;
-  * transitive name resolution — the PR-5 parity resolver's
-    worklist-over-local-bindings algorithm, generalized so parity.py and
-    any future value-set rule share one implementation;
   * call classification — supervisor.dispatch routing, telemetry
     serialization, and host-coercion (`.item()` / `float()` / `bool()` /
     `np.asarray` / `jax.device_get`) call sites with line numbers;
@@ -29,7 +26,7 @@ import ast
 import importlib
 import inspect
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
+from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 # ---------------------------------------------------------------------------
 # module walking
@@ -222,48 +219,6 @@ def function_param_accesses(info: FunctionInfo, param: str
     accessed = {a for a, _ in attribute_writes(info.node, param)}
     accessed |= {a for a, _ in attribute_reads(info.node, param)}
     return accessed
-
-
-# ---------------------------------------------------------------------------
-# transitive name resolution (the PR-5 parity resolver, generalized)
-# ---------------------------------------------------------------------------
-
-def name_bindings(tree: ast.AST) -> Dict[str, List[ast.AST]]:
-    """Name -> [RHS value nodes] over every Assign/AugAssign in a tree
-    (the house style routes predicate sets through locals and builds
-    with `|=`; a literal-only walk of one RHS would be blind to both)."""
-    defs: Dict[str, List[ast.AST]] = {}
-    for node in ast.walk(tree):
-        if isinstance(node, ast.Assign):
-            for t in node.targets:
-                if isinstance(t, ast.Name):
-                    defs.setdefault(t.id, []).append(node.value)
-        elif isinstance(node, ast.AugAssign):
-            if isinstance(node.target, ast.Name):
-                defs.setdefault(node.target.id, []).append(node.value)
-    return defs
-
-
-def resolve_transitive(src: str, target: str,
-                       extract: Callable[[ast.AST], Set[str]]) -> Set[str]:
-    """Values `extract` finds under every assignment reachable from
-    `target`, resolving intermediate Name bindings transitively.
-    Raises ValueError when `target` is never assigned."""
-    defs = name_bindings(ast.parse(src))
-    if target not in defs:
-        raise ValueError(f"no `{target} = ...` assignment found in source")
-    names: Set[str] = set()
-    seen = {target}
-    work = [target]
-    while work:
-        for rhs in defs[work.pop()]:
-            names |= extract(rhs)
-            for sub in ast.walk(rhs):
-                if (isinstance(sub, ast.Name) and sub.id in defs
-                        and sub.id not in seen):
-                    seen.add(sub.id)
-                    work.append(sub.id)
-    return names
 
 
 # ---------------------------------------------------------------------------

@@ -1,4 +1,4 @@
-"""The graph-invariant rule engine: four families of static checks.
+"""The graph-invariant rule engine: families of static checks.
 
 Each rule traces a *real* entry point (the chunked XLA step ladder, the
 ported u32-limb hot paths, the pack/unpack seams, overlay restore) into
@@ -28,8 +28,6 @@ the result:
             donated machine leaf actually aliased in the compiled
             output, and the Runner's CPU-donation gate intact — the
             PR-2 corruption class caught statically)
-  parity    the fused-subset contract between pstep.py and step.py
-            (wtf_tpu/analysis/parity.py)
   mesh      the sharded chunk executor (wtf_tpu/meshrun) on a forced
             multi-device CPU mesh: cross-device collectives pinned to
             exactly the coverage all-reduce (no accidental resharding
@@ -77,7 +75,6 @@ from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from wtf_tpu.analysis.findings import Finding
-from wtf_tpu.analysis.parity import check_fused_parity
 from wtf_tpu.analysis.trace import (
     build_tenant_runner, build_tlv_runner, compiled_hlo,
     step_executor_lowering, tenant_executor_lowering,
@@ -123,16 +120,13 @@ MESH_DEVICES = 8
 MESH_CONFIG = dict(n_steps=16, lanes_per_shard=2,
                    uop_capacity=1 << 10, overlay_slots=8, edge_bits=12)
 
-# canonical fused-megachunk window configuration (fuzz/megachunk.py with
-# the Pallas step engine): the whole window program's data-dependent
-# JAXPR census — the Pallas dispatch counted ATOMICALLY as one
-# "pallas-call" (on hardware it IS one kernel; in interpret mode the
-# lowering would inline it and pollute an HLO census) — pinned as the
-# `megachunk_window_fused` entry, plus the two donation rules: every
-# pallas_call output aliased to its operand, and every machine/aggregate
-# leaf of the donate-lowered window executable aliased in the compiled
-# output (zero copy-through end to end).
-MEGA_ENTRY = "megachunk_window_fused"
+# canonical megachunk window configuration (fuzz/megachunk.py, the
+# program tests/test_chip_compile.py compiles for a v5e): the whole
+# window program's data-dependent JAXPR census pinned as the
+# `megachunk_window` entry, plus the donation rule: every
+# machine/aggregate leaf of the donate-lowered window executable aliased
+# in the compiled output (zero copy-through end to end).
+MEGA_ENTRY = "megachunk_window"
 MEGA_CONFIG = dict(n_lanes=4, max_batches=2, limit=10_000)
 
 # canonical device-decode service configuration (wtf_tpu/interp/devdec):
@@ -144,7 +138,7 @@ MEGA_CONFIG = dict(n_lanes=4, max_batches=2, limit=10_000)
 DECODE_ENTRY = "decode_service"
 DECODE_BP_SLOTS = 8
 
-FAMILIES = ("dtype", "budget", "recompile", "parity", "mesh", "supervise",
+FAMILIES = ("dtype", "budget", "recompile", "mesh", "supervise",
             "telemetry", "state", "transfer", "thread", "contracts")
 
 # families that (re)write budgets.json vs analysis/contracts.json on
@@ -180,7 +174,7 @@ def check_no_u64(fn, *args, entry: str) -> List[Finding]:
                 count=n,
                 message=("64-bit/float op reintroduced in a ported "
                          "u32-limb path (XLA lowers it to a carry-chained "
-                         "u32 pair on TPU; Pallas cannot hold it at all)"))
+                         "u32 pair on TPU)"))
         for dtype, n in sorted(found.items())
     ]
 
@@ -234,7 +228,6 @@ def _dtype_arg_recipes() -> Dict[str, Tuple]:
         meta_u64=jnp.zeros((cap, 4), jnp.uint32),
         hash_tab=jnp.full((cap * 4, 3), -1, jnp.int32),
     )
-    gl = jnp.zeros((16, 2), jnp.uint32)
     recipes: Dict[str, Tuple] = {
         # limb library (interp/limbs.py public helpers)
         "limbs.adc64": (L.adc64, (p, q, cin)),
@@ -268,8 +261,6 @@ def _dtype_arg_recipes() -> Dict[str, Tuple]:
         "step.scale_idx_l": (S._scale_idx_l, (p, n4)),
         "step.uop_lookup": (S.uop_lookup,
                             (tab, (jnp.uint32(0x1000), jnp.uint32(0x14)))),
-        "step.gpr_write_l": (S._gpr_write_l,
-                             (gl, jnp.bool_(True), jnp.int32(3), p, n4)),
     }
     # devmut engine paths (devmut.PORTED_LIMB_PATHS): tiny shapes — the
     # pin is about dtypes, not scale
@@ -383,14 +374,12 @@ def count_data_dependent_ops(hlo_text: str) -> Dict[str, int]:
 
 
 def check_budget(counts: Dict[str, int], budget: Dict[str, int],
-                 entry: str, ops: Optional[Sequence[str]] = None
-                 ) -> List[Finding]:
+                 entry: str) -> List[Finding]:
     """Exact pin: any drift (up OR down) is a finding — an improvement
     must be re-baselined consciously (see PERF.md round 9), a regression
-    must be explained or fixed.  `ops` extends the censused op set
-    (the fused-window entry adds "pallas-call")."""
+    must be explained or fixed."""
     findings = []
-    for name in list(ops if ops is not None else DATA_DEP_OPS) + ["total"]:
+    for name in list(DATA_DEP_OPS) + ["total"]:
         got = counts.get(name, 0)
         want = budget.get(name)
         if want is None or got == want:
@@ -456,10 +445,7 @@ def run_decode_rules(runner, budgets_path: Optional[Path] = None,
 
 def _iter_eqns(jxp):
     """Depth-first over a jaxpr's equations, descending into every
-    sub-jaxpr carried in params (while/cond/scan/pjit/custom calls) —
-    EXCEPT under pallas_call, which is atomic: on hardware it is ONE
-    kernel dispatch, so its internal jaxpr must not leak into a
-    kernel-count census."""
+    sub-jaxpr carried in params (while/cond/scan/pjit/custom calls)."""
     from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def sub_jaxprs(v):
@@ -473,28 +459,20 @@ def _iter_eqns(jxp):
 
     for eqn in jxp.eqns:
         yield eqn
-        if eqn.primitive.name == "pallas_call":
-            continue
         for v in eqn.params.values():
             for sub in sub_jaxprs(v):
                 yield from _iter_eqns(sub)
 
 
 def count_data_dependent_eqns(jaxpr) -> Dict[str, int]:
-    """JAXPR-level analogue of count_data_dependent_ops for programs
-    that embed a Pallas kernel: gather-class primitives counted across
-    every sub-jaxpr, each pallas_call counted as ONE "pallas-call"
-    (the fused window's per-round dispatch cost on hardware).  The HLO
-    census can't serve here — interpret-mode lowering inlines the
-    kernel body, which only exists on the CPU stand-in."""
+    """JAXPR-level analogue of count_data_dependent_ops: gather-class
+    primitives counted across every sub-jaxpr (the megachunk window's
+    census — static code sites, before XLA's pipeline)."""
     jxp = jaxpr.jaxpr if hasattr(jaxpr, "jaxpr") else jaxpr
     counts = {name: 0 for name in DATA_DEP_OPS}
-    counts["pallas-call"] = 0
     for eqn in _iter_eqns(jxp):
         name = eqn.primitive.name
-        if name == "pallas_call":
-            counts["pallas-call"] += 1
-        elif name == "gather":
+        if name == "gather":
             counts["gather"] += 1
         elif name == "dynamic_slice":
             counts["dynamic-slice"] += 1
@@ -504,42 +482,6 @@ def count_data_dependent_eqns(jaxpr) -> Dict[str, int]:
             counts["scatter"] += 1
     counts["total"] = sum(counts.values())
     return counts
-
-
-def check_pallas_aliasing(jaxpr, entry: str) -> List[Finding]:
-    """Every pallas_call in the fused window program must alias EVERY
-    output to an input operand (input_output_aliases) — an unaliased
-    output means the machine/overlay plane copies through the kernel on
-    each dispatch, the exact copy-through the fused-megachunk donation
-    leg eliminates.  A window with NO pallas_call is also a finding: the
-    census subject isn't actually running the kernel."""
-    jxp = jaxpr.jaxpr if hasattr(jaxpr, "jaxpr") else jaxpr
-    findings: List[Finding] = []
-    n_calls = 0
-    for eqn in _iter_eqns(jxp):
-        if eqn.primitive.name != "pallas_call":
-            continue
-        n_calls += 1
-        ioa = eqn.params.get("input_output_aliases") or ()
-        covered = {int(o) for (_i, o) in ioa}
-        missing = sorted(set(range(len(eqn.outvars))) - covered)
-        if missing:
-            findings.append(Finding(
-                rule="recompile.pallas-unaliased", entry=entry,
-                primitive=f"pallas_call outputs {missing}",
-                count=len(missing),
-                message=("fused-kernel output not aliased to its "
-                         "operand — the plane copies through the kernel "
-                         "every dispatch; extend input_output_aliases "
-                         "in pstep.fused_call_impl")))
-    if n_calls == 0:
-        findings.append(Finding(
-            rule="recompile.pallas-unaliased", entry=entry,
-            primitive="pallas_call",
-            message=("no pallas_call in the fused window program — the "
-                     "fused megachunk is not running the Pallas step "
-                     "engine; the pin's census subject is wrong")))
-    return findings
 
 
 def check_window_donation_aliasing(compiled, args,
@@ -601,12 +543,11 @@ def check_window_donation_aliasing(compiled, args,
 def run_megachunk_rules(budgets_path: Optional[Path] = None,
                         rebaseline: bool = False
                         ) -> Tuple[List[Finding], Dict]:
-    """The fused-window pins, one trace for all three:
+    """The megachunk-window pins, one trace for both:
 
-      1. the `megachunk_window_fused` kernel census (jaxpr-level,
-         pallas_call atomic) against budgets.json;
-      2. every pallas_call aliases all its machine-state outputs;
-      3. the window executable, LOWERED with donation (safe on CPU —
+      1. the `megachunk_window` kernel census (jaxpr-level) against
+         budgets.json;
+      2. the window executable, LOWERED with donation (safe on CPU —
          only execution is unsound there), aliases every donated
          machine/aggregate leaf in its compiled output.
 
@@ -618,19 +559,17 @@ def run_megachunk_rules(budgets_path: Optional[Path] = None,
     from wtf_tpu.fuzz.megachunk import WINDOW_DONATE_ARGNUMS
 
     cfg = MEGA_CONFIG
-    entry = (f"megachunk(max_batches={cfg['max_batches']}, fused=True, "
-             f"donate=True) / demo_tlv / n_lanes={cfg['n_lanes']}")
+    entry = (f"megachunk(max_batches={cfg['max_batches']}, donate=True) "
+             f"/ demo_tlv / n_lanes={cfg['n_lanes']}")
     lowered, args, fn = megachunk_window_lowering(
         max_batches=cfg["max_batches"], n_lanes=cfg["n_lanes"],
-        fused=True, donate=True, limit=cfg["limit"])
+        donate=True, limit=cfg["limit"])
     jaxpr = jax.make_jaxpr(fn)(*args)
     counts = count_data_dependent_eqns(jaxpr)
     findings: List[Finding] = []
     if not rebaseline:
         budget = load_budgets(budgets_path).get(MEGA_ENTRY, {})
-        findings = check_budget(counts, budget, entry=entry,
-                                ops=list(DATA_DEP_OPS) + ["pallas-call"])
-    findings.extend(check_pallas_aliasing(jaxpr, entry=entry))
+        findings = check_budget(counts, budget, entry=entry)
     findings.extend(check_window_donation_aliasing(
         lowered.compile(), args, WINDOW_DONATE_ARGNUMS, entry=entry))
     # the raw jaxpr rides along (popped before JSON) so the transfer
@@ -687,7 +626,7 @@ def check_supervised_seams(sites: Optional[Dict[str, str]] = None
     mechanism): a new dispatch seam must be listed there AND its listed
     site must contain a `supervisor.dispatch("<seam>"...)` routing
     call.  AST-level on the shared dataflow engine (analysis/flow.py) —
-    the seams include paths (mesh, fused) a CPU lint run never
+    the seams include paths (mesh) a CPU lint run never
     executes, and the AST walk cannot false-hit on comments or
     docstrings the way the retired regex could.  `sites` parameterizes
     the enumeration for rule tests."""
@@ -773,8 +712,8 @@ def check_seam_enumeration() -> List[Finding]:
     from wtf_tpu.supervise import SEAM_SITES
 
     claimed = set(SEAM_SITES)
-    required = {"chunk", "fused", "fused-resume", "device-insert",
-                "devmut-generate", "megachunk", "device-decode"}
+    required = {"chunk", "device-insert", "devmut-generate", "megachunk",
+                "device-decode"}
     missing = sorted(required - claimed)
     return [Finding(
         rule="supervise.seam-enumeration", entry="supervise.SEAM_SITES",
@@ -1091,7 +1030,7 @@ def _mesh_chunk_compiled(n_shards: int) -> str:
     cfg = MESH_CONFIG
     runner = build_tlv_runner(
         n_lanes=cfg["lanes_per_shard"] * n_shards,
-        chunk_steps=cfg["n_steps"], payload=None,
+        chunk_steps=cfg["n_steps"],
         uop_capacity=cfg["uop_capacity"],
         overlay_slots=cfg["overlay_slots"], edge_bits=cfg["edge_bits"])
     mesh = make_mesh(n_shards)
@@ -1197,7 +1136,7 @@ def run_lint(families: Optional[Sequence[str]] = None,
     `lint-finding` event per finding when an event sink is given.
 
     `deep` forces the transfer family's jaxpr host-callback census even
-    when the budget family (whose fused-window trace it would reuse) is
+    when the budget family (whose window trace it would reuse) is
     not co-selected; without either, the transfer family runs its cheap
     AST rule only.
 
@@ -1238,7 +1177,7 @@ def run_lint(families: Optional[Sequence[str]] = None,
         t0 = time.time()
         runner = build_tlv_runner(
             n_lanes=BUDGET_CONFIG["n_lanes"],
-            chunk_steps=BUDGET_CONFIG["chunk_steps"], payload=None)
+            chunk_steps=BUDGET_CONFIG["chunk_steps"])
         lowered = step_executor_lowering(
             runner, n_steps=BUDGET_CONFIG["n_steps"],
             donate=BUDGET_CONFIG["donate"])
@@ -1302,9 +1241,8 @@ def run_lint(families: Optional[Sequence[str]] = None,
         for name, value in counts_d.items():
             registry.gauge("analysis.decode_kernel_count").labels(
                 name).set(value)
-        # fused megachunk window (fuzz/megachunk.py fused=True): jaxpr
-        # census with pallas_call atomic, plus the two donation rules —
-        # kernel output aliasing and window-executable donation aliasing
+        # megachunk window (fuzz/megachunk.py): jaxpr census plus the
+        # window-executable donation aliasing rule
         mega_findings, mega_info = run_megachunk_rules(
             budgets_path=budgets_path, rebaseline=rebaseline)
         findings.extend(mega_findings)
@@ -1368,12 +1306,6 @@ def run_lint(families: Optional[Sequence[str]] = None,
             compiled_text, runner.machine, n_prefix, entry=entry,
             dropped_args=dropped))
         info["seconds"]["recompile"] = round(time.time() - t0, 1)
-
-    if "parity" in families:
-        t0 = time.time()
-        findings.extend(check_fused_parity())
-        info["seconds"]["parity"] = round(time.time() - t0, 1)
-        info["entries"].append("pstep.hot_class vs step.unsupported")
 
     if "mesh" in families:
         t0 = time.time()

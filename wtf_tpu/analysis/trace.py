@@ -2,85 +2,29 @@
 
 Two jobs in one module:
 
-  * the warm-runner + chunk-timing recipe `ablate.py` and `bench.py`
-    both used to hand-roll (build a demo_tlv Runner, warm the decode
-    cache through the oracle, write the payload into every lane, time a
-    cold and a warm chunk dispatch) — extracted here so the benches and
-    the linter share one trace-capture path;
+  * the shape-only builders the linter, the tests and the smokes share
+    (a demo_tlv Runner, a two-tenant Runner, a demo_tlv FuzzLoop);
   * HLO/StableHLO text capture for the rule engine
     (wtf_tpu/analysis/rules.py): lower a jitted entry point, compile it,
     hand the text to the rules.
 
 Heavy imports (jax, the interpreter stack) stay inside functions so
-importing this module never initializes a backend — the benches pick
-their platform after import, exactly like before.
+importing this module never initializes a backend.
 """
 
 from __future__ import annotations
 
-import time
-from typing import Optional
-
 import numpy as np
 
-DEFAULT_PAYLOAD = b"\x01\x08AAAAAAAA" * 200
 
-
-def insert_payload(runner, payload: bytes) -> None:
-    """Write `payload` (demo_tlv calling convention: bytes at INPUT_GVA,
-    length in rdx) into every lane and push."""
+def build_tlv_runner(n_lanes: int, chunk_steps: int, **runner_kwargs):
+    """A demo_tlv Runner in the linter's shape-only configuration: no
+    decode warmup, no payload, nothing dispatched."""
     from wtf_tpu.harness import demo_tlv
+    from wtf_tpu.interp.runner import Runner
 
-    view = runner.view()
-    for lane in range(runner.n_lanes):
-        view.virt_write(lane, demo_tlv.INPUT_GVA, payload)
-        view.r["gpr"][lane, 2] = np.uint64(len(payload))
-    runner.push(view)
-
-
-def build_tlv_runner(n_lanes: int = 1024, chunk_steps: int = 512,
-                     payload: Optional[bytes] = DEFAULT_PAYLOAD,
-                     snapshot=None, warm: bool = True, limit: int = 0,
-                     **runner_kwargs):
-    """A demo_tlv Runner ready to dispatch: decode cache warmed through
-    the host oracle (no device compile), payload inserted in every lane.
-    `payload=None` (the linter's shape-only path) skips both."""
-    from wtf_tpu.harness import demo_tlv
-    from wtf_tpu.interp.runner import Runner, warm_decode_cache
-
-    if snapshot is None:
-        snapshot = demo_tlv.build_snapshot()
-    runner = Runner(snapshot, n_lanes=n_lanes, chunk_steps=chunk_steps,
-                    **runner_kwargs)
-    runner.limit = limit
-    if payload is not None:
-        if warm:
-            warm_decode_cache(runner, demo_tlv.TARGET, payload)
-        insert_payload(runner, payload)
-    return runner
-
-
-def timed_chunk(runner, limit: int = 1 << 40) -> dict:
-    """Dispatch the runner's chunk executor cold then warm; returns
-    {"compile_s", "warm_wall_s", "instr"}.  Leaves runner.machine at the
-    post-dispatch state (donation-safe: icount is copied, never viewed)."""
-    import jax.numpy as jnp
-
-    tab = runner.cache.device()
-    run_chunk = runner.chunk_executor()
-    image = runner.physmem.image
-    t0 = time.time()
-    m = run_chunk(tab, image, runner.machine, jnp.uint64(limit))
-    m.status.block_until_ready()
-    compile_s = time.time() - t0
-    ic0 = np.asarray(m.icount).copy()  # m is donated into the next call
-    t0 = time.time()
-    m2 = run_chunk(tab, image, m, jnp.uint64(limit))
-    m2.status.block_until_ready()
-    warm_s = time.time() - t0
-    runner.machine = m2
-    return {"compile_s": compile_s, "warm_wall_s": warm_s,
-            "instr": int((np.asarray(m2.icount) - ic0).sum())}
+    return Runner(demo_tlv.build_snapshot(), n_lanes=n_lanes,
+                  chunk_steps=chunk_steps, **runner_kwargs)
 
 
 def build_tenant_runner(quotas=(2, 2), order=("demo_tlv", "demo_kernel"),
@@ -115,8 +59,7 @@ def build_tlv_campaign(n_lanes: int = 64, mutator: str = "mangle",
     """A demo_tlv FuzzLoop ready to run_one_batch(): tpu backend built
     and initialized, target init, one TLV seed in the corpus, and the
     mutation engine picked by name ("mangle" = best host engine;
-    "devmangle" = the device-resident engine, wtf_tpu/devmut) — the
-    A/B harness `ablate.py devmut` and the devmut tests share."""
+    "devmangle" = the device-resident engine, wtf_tpu/devmut)."""
     import random
 
     from wtf_tpu.backend import create_backend
@@ -210,11 +153,10 @@ def tenant_executor_lowering(runner, n_steps: int = 16,
 
 
 def megachunk_window_lowering(max_batches: int = 2, n_lanes: int = 4,
-                              fused: bool = True, donate: bool = True,
-                              limit: int = 10_000):
+                              donate: bool = True, limit: int = 10_000):
     """Lower (without executing) ONE megachunk window program at the
     canonical budget shapes: a demo_tlv devmangle campaign's window with
-    the requested step engine and donation policy.  Returns
+    the requested donation policy.  Returns
     (lowered, args, fn): the jax .lower() handle of the window
     executable, the operand tuple it was lowered against (the donation
     rules index its pytree structure), and the window callable itself
@@ -230,8 +172,7 @@ def megachunk_window_lowering(max_batches: int = 2, n_lanes: int = 4,
     from wtf_tpu.fuzz.megachunk import NO_FINISH, make_megachunk
 
     loop = build_tlv_campaign(n_lanes=n_lanes, mutator="devmangle",
-                              limit=limit, megachunk=max_batches,
-                              fused_step="on" if fused else "off")
+                              limit=limit, megachunk=max_batches)
     backend = loop.backend
     runner = backend.runner
     mutator = loop.mutator
@@ -240,10 +181,7 @@ def megachunk_window_lowering(max_batches: int = 2, n_lanes: int = 4,
     fn = make_megachunk(max_batches, n_pages, spec.len_gpr,
                         spec.ptr_gpr, mutator.rounds,
                         deliver=runner.deliver_exceptions,
-                        devdec=runner.device_decode, fused=fused,
-                        fused_k=runner.fused_k,
-                        fused_resume_steps=runner.fused_resume_steps,
-                        donate=donate)
+                        devdec=runner.device_decode, donate=donate)
     finish = spec.finish_gva if spec.finish_gva is not None else NO_FINISH
     slab_first, slab_rest = mutator.window_slabs()
     seeds = mutator.window_seeds(max_batches)

@@ -296,8 +296,7 @@ class TpuBackend(Backend):
                                        spec.len_gpr, spec.ptr_gpr,
                                        mutator.rounds)
         key = ("megachunk", max_batches, n_pages, self.n_lanes,
-               mutator.rounds, runner.exec_sig,
-               bool(runner.fused_enabled), runner._donate)
+               mutator.rounds, runner.exec_sig, runner._donate)
         from wtf_tpu.interp.runner import _DISPATCHED_EXECUTORS
 
         if key not in _DISPATCHED_EXECUTORS:
@@ -361,19 +360,6 @@ class TpuBackend(Backend):
             statuses = np.asarray(jax.device_get(out.statuses))
             flags = np.asarray(jax.device_get(out.new_flags))
             ctr_sums = np.asarray(jax.device_get(out.ctr_sums))
-            # engine-round census: [XLA step_v sweeps, Pallas dispatches]
-            # over the whole window.  Every Pallas dispatch with aliased
-            # overlay/machine planes is one avoided copy-through of those
-            # buffers — the donation win the status/telemetry rows
-            # surface.
-            er = np.asarray(jax.device_get(out.engine_rounds))
-        self.registry.counter("device.fused_window_xla_steps").inc(
-            int(er[0]))
-        if int(er[1]):
-            self.registry.counter("device.fused_window_rounds").inc(
-                int(er[1]))
-            self.registry.counter("device.fused_window_bytes_saved").inc(
-                int(er[1]) * self._fused_alias_bytes())
         processed = b_done + (1 if incomplete else 0)
         mutator.consume_window(processed)
         if runner.device_decode and not incomplete:
@@ -497,26 +483,10 @@ class TpuBackend(Backend):
         """Everything a speculative window's operands were derived from:
         a prelaunched window is adopted only when the next call's
         signature is identical (same window size, same stream cursor,
-        same decode cache, same breakpoint set, same limit, same step
-        engine — a degradation-ladder rung flip mid-campaign must drop
-        the speculative window, not adopt one built by the other
-        engine)."""
+        same decode cache, same breakpoint set, same limit)."""
         cache = self.runner.cache
         return (max_batches, n_batches, n_pages, mutator._batch,
-                self.limit, cache.count, frozenset(cache.pending_bps),
-                bool(self.runner.fused_enabled))
-
-    def _fused_alias_bytes(self) -> int:
-        """Bytes of the 13 machine/overlay planes the fused kernel
-        aliases in place (input_output_aliases) — the per-dispatch
-        copy-through the donation leg eliminates, dominated by the
-        overlay data slab (lanes * slots * PAGE_WORDS words)."""
-        m = self.runner.machine
-        ov = m.overlay
-        leaves = (m.gpr_l, m.rip_l, m.rflags_l, m.status, m.icount,
-                  m.bp_skip, m.ctr, m.cov, m.edge, ov.pfn, ov.data,
-                  ov.valid, ov.count)
-        return int(sum(x.size * x.dtype.itemsize for x in leaves))
+                self.limit, cache.count, frozenset(cache.pending_bps))
 
     def _harvest_device_decode(self, out) -> int:
         """Adopt the window's device-published decode entries into the
@@ -854,20 +824,6 @@ class TpuBackend(Backend):
               f"chunks={s['chunks']} decodes={s['decodes']} "
               f"fallbacks={s['fallbacks']} "
               f"smc={s['smc_updates']} bp_dispatches={s['bp_dispatches']}")
-        # fused-step occupancy: what fraction of retired instructions ran
-        # inside the Pallas kernel.  Printed whenever the fast path is
-        # enabled, so 0% occupancy (every lane parks — the hot subset
-        # misses this target) stays distinguishable from "off"
-        fused = self.registry.counter("device.fused_steps").value
-        if fused or getattr(self.runner, "fused_enabled", False):
-            instr = max(self.registry.counter("device.instructions").value, 1)
-            # the park split answers WHY lanes left the kernel: a cold
-            # opclass (subset) vs a memory fault / overlay exhaustion
-            ps = self.registry.counter("device.fused_park_subset").value
-            pm = self.registry.counter("device.fused_park_mem").value
-            print(f"[tpu] fused steps: {h(fused)} "
-                  f"({fused / instr:.1%} of instructions in-kernel; "
-                  f"parks: subset={h(ps)} mem={h(pm)})")
         by_class = s.get("fallbacks_by_opclass", {})
         if by_class:
             # attribution for the fallback total (VERDICT r5 item 3):

@@ -87,15 +87,6 @@ def _add_backend_tuning(p: argparse.ArgumentParser, mesh: bool = False
                             "count (lanes/N per chip) and must divide "
                             "by N; coverage OR-reduces on-chip, so the "
                             "fuzz loop sees one logical backend")
-    p.add_argument("--fused-step", choices=("off", "auto", "on"),
-                   default="off",
-                   help="fused Pallas fast path (interp/pstep.py): one "
-                        "kernel per chunk for the hot integer core, with "
-                        "parked lanes resuming on the XLA step.  auto = "
-                        "off on every platform until the TPU compiler "
-                        "accepts the kernel (ROADMAP S2); on = force it "
-                        "(pallas interpret mode on the CPU, the "
-                        "compiler's refusal on a TPU)")
     p.add_argument("--burst-any-tier", choices=("auto", "on", "off"),
                    default="auto",
                    help="the oracle burst's any-instruction tier for "
@@ -123,7 +114,7 @@ def _add_backend_tuning(p: argparse.ArgumentParser, mesh: bool = False
 
 
 def _backend_tuning_kwargs(args) -> dict:
-    kwargs = {"fused_step": getattr(args, "fused_step", "off")}
+    kwargs = {}
     tier = getattr(args, "burst_any_tier", "auto")
     if tier != "auto":
         kwargs["burst_any_tier"] = tier == "on"
@@ -477,7 +468,7 @@ def build_parser() -> argparse.ArgumentParser:
     lint.add_argument("--json", action="store_true",
                       help="machine-readable output (one JSON object)")
     lint.add_argument("--families", default=None,
-                      help="comma list: dtype,budget,recompile,parity,"
+                      help="comma list: dtype,budget,recompile,"
                            "mesh,supervise,telemetry,state,transfer,"
                            "thread,contracts (default: all)")
     lint.add_argument("--budgets", type=Path, default=None,
@@ -580,7 +571,7 @@ def _build_backend(target, backend_name: str, paths: TargetPaths,
             raise SystemExit(
                 f"target {target.name!r} has no snapshot factory and no "
                 f"--state dir was given")
-    # engine tuning (--fused-step/--burst-any-tier) applies to the batched
+    # engine tuning (--burst-any-tier etc.) applies to the batched
     # tpu backend only; the oracle backend has no runner underneath
     kwargs = ({"n_lanes": lanes, **(tuning or {})}
               if backend_name == "tpu" else {})
@@ -1195,29 +1186,11 @@ def _derived_status_rows(metrics: dict) -> List[str]:
         v = metrics.get(name, default)
         return v if isinstance(v, (int, float)) else default
 
-    instr = val("device.instructions")
-    fused = val("device.fused_steps")
-    if fused and instr:
-        rows.append(f"fused occupancy: {fused / instr:.1%}")
     windows = val("megachunk.windows")
     if windows:
         zh = val("devdec.zero_host_windows")
         rows.append(f"zero-host windows: {zh}/{windows} "
                     f"({zh / windows:.0%})")
-        # fused-window share: what fraction of in-window quiesce
-        # dispatches were Pallas kernel rounds vs XLA ladder sweeps
-        rounds = val("device.fused_window_rounds")
-        sweeps = val("device.fused_window_xla_steps")
-        if rounds:
-            rows.append(f"fused windows: "
-                        f"{rounds / (rounds + sweeps):.1%} of "
-                        f"{rounds + sweeps} quiesce dispatches "
-                        f"in-kernel")
-            saved = val("device.fused_window_bytes_saved")
-            if saved:
-                rows.append(f"donation: {saved / (1 << 20):.1f} MiB "
-                            f"copy-through saved "
-                            f"({saved // max(rounds, 1)} B/dispatch)")
         prelaunched = val("megachunk.prelaunched")
         if prelaunched:
             rows.append(f"prelaunch: "

@@ -38,12 +38,6 @@ class StatusCode(enum.IntEnum):
     HARD_ERROR = 12   # terminal: instruction unsupported even by the host
                       # oracle, or other unrecoverable servicing failure
                       # (details in Runner.lane_errors)
-    NEEDS_XLA = 13    # fused Pallas fast path parked the lane BEFORE
-                      # executing (instruction outside the hot integer
-                      # subset, armed breakpoint, or dirty/diverged code
-                      # bytes); state is untouched and the runner resumes
-                      # it on the XLA chunk path — never escapes the
-                      # runner's fused ladder (interp/pstep.py)
 
 
 # Statuses the device can set that the host run loop must service before the

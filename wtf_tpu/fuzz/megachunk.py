@@ -125,12 +125,6 @@ class MegaOut(NamedTuple):
     tab: UopTable
     count: jax.Array
     dd_stats: jax.Array
-    # step-engine round census, int32[2] = [XLA step_v sweeps, Pallas
-    # kernel dispatches] summed over the window (psum'd across shards on
-    # a mesh).  The window's data-dependent kernel count derives from
-    # this: sweeps x the per-step census (budgets.json `xla_step` total)
-    # + one kernel per Pallas dispatch — the ablate fused-mega currency.
-    engine_rounds: jax.Array
 
 
 def _snap(words, lens) -> MegaSnap:
@@ -140,26 +134,11 @@ def _snap(words, lens) -> MegaSnap:
 def _make_body(max_batches: int, n_pages: int, len_gpr: int, ptr_gpr: int,
                rounds: int, deliver: bool, merge_fn, any_fn, sum_fn,
                devdec_on: bool = False, gather_fn=None,
-               lane_base_fn=None, fused: bool = False, fused_k: int = 32,
-               fused_resume_steps: int = 1, interpret: bool = True,
-               rsum_fn=None):
+               lane_base_fn=None):
     """The window body shared by the single-device and mesh programs.
     `merge_fn` is the batch coverage merge, `any_fn` a (possibly
     cross-shard) boolean any, `sum_fn` a (possibly psum'd) per-batch
-    counter total, `rsum_fn` the (possibly psum'd) engine-round total.
-
-    With `fused` the quiesce runs the Pallas kernel (interp/pstep) as
-    the window's step engine: each round is ONE kernel dispatch
-    advancing every lane up to `fused_k` hot instructions, then the XLA
-    resume leg retires the one instruction each parked lane stopped on
-    (`fused_resume_steps` sweeps, statuses swapped/held exactly like
-    Runner._fused_dispatch).  The ladder quiesce — one step_v sweep per
-    data-dependent kernel census — remains the park-resume leg only, so
-    a steady-state window pays ~1 kernel per `fused_k` instructions
-    instead of the full per-step census.  Every instruction still
-    retires bit-exact through exactly one engine (the pstep parity
-    contract), so fused-window campaigns are byte-identical to
-    ladder-window ones.
+    counter total (its CTR_SWEPT column is the window's sweep count).
 
     With `devdec_on` the window grows three operands — the live decode
     cache count, the padded pending-breakpoint key vector, and its live
@@ -183,55 +162,20 @@ def _make_body(max_batches: int, n_pages: int, len_gpr: int, ptr_gpr: int,
     serviceable = SERVICEABLE_DELIVER if deliver else SERVICEABLE_BASE
     _ND = int(StatusCode.NEED_DECODE)
     B = max_batches
-    if rsum_fn is None:
-        def rsum_fn(r):
-            return r
+    @jax.named_scope("run-quiesce")
+    def run_quiesce(tab, image, m, limit):
+        """The run-chunk ladder folded in: step until NO lane is
+        RUNNING (decode misses, breakpoints and terminals all leave
+        RUNNING, and a nonzero instruction budget bounds the rest —
+        the driver enforces limit > 0 before building a megachunk)."""
 
-    if fused:
-        from wtf_tpu.interp.pstep import fused_call_impl, fused_resume_impl
+        def cond(mm):
+            return jnp.any(mm.status == jnp.int32(_RUNNING))
 
-        @jax.named_scope("run-quiesce")
-        def run_quiesce(tab, image, m, limit):
-            """The FUSED quiesce: the Pallas kernel is the step engine,
-            the XLA ladder only the park-resume leg.  Terminates for the
-            same reason the ladder does — every round retires >= 1
-            instruction per still-RUNNING lane (in-kernel, or precisely
-            via the resume sweep for an immediately-parking lane), and a
-            nonzero instruction budget bounds the rest.  Returns
-            (machine, int32[2] = [xla sweeps, pallas dispatches])."""
+        def qbody(mm):
+            return step_lanes(tab, image, mm, limit)
 
-            def cond(c):
-                return jnp.any(c[0].status == jnp.int32(_RUNNING))
-
-            def qbody(c):
-                mm, xla_n, pl_n = c
-                mm = fused_call_impl(tab, image, mm, limit,
-                                     k_steps=fused_k, interpret=interpret)
-                mm, iters = fused_resume_impl(
-                    tab, image, mm, limit, n_steps=fused_resume_steps)
-                return mm, xla_n + iters, pl_n + jnp.int32(1)
-
-            m, xla_n, pl_n = lax.while_loop(
-                cond, qbody, (m, jnp.int32(0), jnp.int32(0)))
-            return m, jnp.stack([xla_n, pl_n])
-    else:
-        @jax.named_scope("run-quiesce")
-        def run_quiesce(tab, image, m, limit):
-            """The run-chunk ladder folded in: step until NO lane is
-            RUNNING (decode misses, breakpoints and terminals all leave
-            RUNNING, and a nonzero instruction budget bounds the rest —
-            the driver enforces limit > 0 before building a megachunk).
-            Returns (machine, int32[2] = [xla sweeps, 0])."""
-
-            def cond(c):
-                return jnp.any(c[0].status == jnp.int32(_RUNNING))
-
-            def qbody(c):
-                mm, n = c
-                return step_lanes(tab, image, mm, limit), n + jnp.int32(1)
-
-            m, n = lax.while_loop(cond, qbody, (m, jnp.int32(0)))
-            return m, jnp.stack([n, jnp.int32(0)])
+        return lax.while_loop(cond, qbody, m)
 
     def _window(tab: UopTable, image: MemImage, machine: Machine,
                 template: Machine, slab_first: Tuple, slab_rest: Tuple,
@@ -264,7 +208,7 @@ def _make_body(max_batches: int, n_pages: int, len_gpr: int, ptr_gpr: int,
                 return lax.dynamic_slice_in_dim(a, lane_base, n_lanes, 0)
 
             @jax.named_scope("decode-service")
-            def service(tabst, cnt, m, dstats, er):
+            def service(tabst, cnt, m, dstats):
                 """In-graph decode-miss service rounds around the
                 quiesce: compute per-lane blocks against the round-start
                 table, commit them in global lane order (replicated on a
@@ -272,11 +216,11 @@ def _make_body(max_batches: int, n_pages: int, len_gpr: int, ptr_gpr: int,
                 Stops when no un-parked lane is NEED_DECODE."""
 
                 def scond(c):
-                    _tabst, _cnt, m, _dstats, _er, parked = c
+                    _tabst, _cnt, m, _dstats, parked = c
                     return any_fn((m.status == jnp.int32(_ND)) & ~parked)
 
                 def sbody(c):
-                    tabst, cnt, m, dstats, er, parked = c
+                    tabst, cnt, m, dstats, parked = c
                     tl = tab._replace(
                         hash_tab=tabst[0], rip_l=tabst[1],
                         meta_i32=tabst[2], meta_u64=tabst[3])
@@ -294,16 +238,16 @@ def _make_body(max_batches: int, n_pages: int, len_gpr: int, ptr_gpr: int,
                             lane_slice(out.mem_fault_inc)))
                     dstats2 = dstats + jnp.concatenate(
                         [out.stats, jnp.ones((1,), jnp.int32)])
-                    m3, dr = run_quiesce(out.tab, image, m2, limit)
+                    m3 = run_quiesce(out.tab, image, m2, limit)
                     return ((out.tab.hash_tab, out.tab.rip_l,
                              out.tab.meta_i32, out.tab.meta_u64),
-                            out.count, m3, dstats2, er + dr,
+                            out.count, m3, dstats2,
                             parked | lane_slice(out.parked))
 
                 parked0 = jnp.zeros((n_lanes,), bool)
-                tabst, cnt, m, dstats, er, _parked = lax.while_loop(
-                    scond, sbody, (tabst, cnt, m, dstats, er, parked0))
-                return tabst, cnt, m, dstats, er
+                tabst, cnt, m, dstats, _parked = lax.while_loop(
+                    scond, sbody, (tabst, cnt, m, dstats, parked0))
+                return tabst, cnt, m, dstats
 
         def cond(carry):
             b, stop = carry[0], carry[1]
@@ -311,7 +255,7 @@ def _make_body(max_batches: int, n_pages: int, len_gpr: int, ptr_gpr: int,
 
         def body(carry):
             (b, _stop, incomplete, find_b, m, agg_c, agg_e, sts, flags,
-             ctrs, nw, prev, cur, tabst, cnt, dstats, er) = carry
+             ctrs, nw, prev, cur, tabst, cnt, dstats) = carry
             tab_b = (tab._replace(hash_tab=tabst[0], rip_l=tabst[1],
                                   meta_i32=tabst[2], meta_u64=tabst[3])
                      if devdec_on else tab)
@@ -323,16 +267,12 @@ def _make_body(max_batches: int, n_pages: int, len_gpr: int, ptr_gpr: int,
             words, lens = generate(data, lens_s, cumw, seeds[b],
                                    rounds=rounds)
             m = insert(m, words, lens, pfns, gva_l)
-            m, dr = run_quiesce(tab_b, image, m, limit)
+            m = run_quiesce(tab_b, image, m, limit)
             if devdec_on:
-                tabst, cnt, m, dstats, dr = service(tabst, cnt, m,
-                                                    dstats, dr)
+                tabst, cnt, m, dstats = service(tabst, cnt, m, dstats)
                 tab_b = tab._replace(
                     hash_tab=tabst[0], rip_l=tabst[1], meta_i32=tabst[2],
                     meta_u64=tabst[3])
-            # the quiesce trip counts are per-shard local (no collectives
-            # inside); fold them here, in the lockstep outer body
-            er = er + rsum_fn(dr)
             # declarative stop: BREAKPOINT at the finish rip == the
             # host handler's stop(Ok()) — pre-execution, so no icount /
             # coverage for the breakpointed instruction, like the host
@@ -375,7 +315,7 @@ def _make_body(max_batches: int, n_pages: int, len_gpr: int, ptr_gpr: int,
                 | (complete & (b + 1 > find_b2 + 1))
             return (b2, stop2, incomplete | need_service, find_b2, m,
                     agg_c3, agg_e3, sts2, flags2, ctrs2, nw2, prev2,
-                    cur2, tabst, cnt, dstats, er)
+                    cur2, tabst, cnt, dstats)
 
         if devdec_on:
             tabst0 = (tab.hash_tab, tab.rip_l, tab.meta_i32, tab.meta_u64)
@@ -385,13 +325,11 @@ def _make_body(max_batches: int, n_pages: int, len_gpr: int, ptr_gpr: int,
             tabst0 = ()
             cnt0 = jnp.int32(-1)
         dstats0 = jnp.zeros((4,), jnp.int32)
-        er0 = jnp.zeros((2,), jnp.int32)
         init = (jnp.int32(0), jnp.bool_(False), jnp.bool_(False),
                 jnp.int32(B), machine, agg_cov, agg_edge, statuses0,
-                flags0, ctrs0, nw0, snap0, snap0, tabst0, cnt0, dstats0,
-                er0)
+                flags0, ctrs0, nw0, snap0, snap0, tabst0, cnt0, dstats0)
         (b, _stop, incomplete, _fb, m, agg_c, agg_e, sts, flags, ctrs,
-         nw, prev, cur, tabst, cnt, dstats, er) = lax.while_loop(
+         nw, prev, cur, tabst, cnt, dstats) = lax.while_loop(
             cond, body, init)
         tab_out = (tab._replace(hash_tab=tabst[0], rip_l=tabst[1],
                                 meta_i32=tabst[2], meta_u64=tabst[3])
@@ -400,7 +338,7 @@ def _make_body(max_batches: int, n_pages: int, len_gpr: int, ptr_gpr: int,
                        batches=b, incomplete=incomplete, statuses=sts,
                        new_flags=flags, ctr_sums=ctrs, new_words=nw,
                        prev=prev, cur=cur, tab=tab_out, count=cnt,
-                       dd_stats=dstats, engine_rounds=er)
+                       dd_stats=dstats)
 
     if devdec_on:
         def window(tab, image, machine, template, slab_first, slab_rest,
@@ -429,30 +367,21 @@ WINDOW_DONATE_ARGNUMS = (2, 12, 13)
 
 def make_megachunk(max_batches: int, n_pages: int, len_gpr: int,
                    ptr_gpr: int, rounds: int, deliver: bool,
-                   devdec: bool = False, fused: bool = False,
-                   fused_k: int = 32, fused_resume_steps: int = 1,
-                   interpret: bool = None, donate: bool = None):
+                   devdec: bool = False, donate: bool = None):
     """Build (or fetch) the jitted single-device megachunk window:
     (tab, image, machine, template, slab_first, slab_rest, seeds[B,L,2],
     pfns, gva_l, finish, limit, n_batches, agg_cov, agg_edge
     [, count, bp_keys, n_bp when devdec]) -> MegaOut.
 
-    `fused` swaps the quiesce's step engine for the Pallas kernel (see
-    _make_body); `interpret=None` auto-selects the kernel mode like
-    pstep.make_run_fused.  `donate=None` follows the repo donation
-    policy (off on the XLA CPU backend where donation is unsound —
-    step.make_run_chunk's caveat, the PR-2 finding; on elsewhere): with
-    donation the machine and aggregate planes — including the kernel's
-    overlay slab (lanes * slots * PAGE_WORDS words), aliased through the
-    Pallas call itself — update in place across the whole window instead of
+    `donate=None` follows the repo donation policy (off on the XLA CPU
+    backend where donation is unsound — step.make_run_chunk's caveat,
+    the PR-2 finding; on elsewhere): with donation the machine and
+    aggregate planes update in place across the whole window instead of
     copying through the executable."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if donate is None:
         donate = jax.default_backend() != "cpu"
     key = ("1dev", max_batches, n_pages, len_gpr, ptr_gpr, rounds,
-           deliver, devdec, fused, fused_k, fused_resume_steps,
-           interpret, donate)
+           deliver, devdec, donate)
     cached = _MEGA_CACHE.get(key)
     if cached is not None:
         return cached
@@ -462,10 +391,7 @@ def make_megachunk(max_batches: int, n_pages: int, len_gpr: int,
 
     body = _make_body(max_batches, n_pages, len_gpr, ptr_gpr, rounds,
                       deliver, merge_fn=merge_coverage, any_fn=jnp.any,
-                      sum_fn=sum_fn, devdec_on=devdec, fused=fused,
-                      fused_k=fused_k,
-                      fused_resume_steps=fused_resume_steps,
-                      interpret=interpret)
+                      sum_fn=sum_fn, devdec_on=devdec)
     fn = jax.jit(body, donate_argnums=WINDOW_DONATE_ARGNUMS if donate
                  else ())
     _MEGA_CACHE[key] = fn
@@ -474,9 +400,7 @@ def make_megachunk(max_batches: int, n_pages: int, len_gpr: int,
 
 def make_mesh_megachunk(max_batches: int, n_pages: int, len_gpr: int,
                         ptr_gpr: int, rounds: int, deliver: bool, mesh,
-                        devdec: bool = False, fused: bool = False,
-                        fused_k: int = 32, fused_resume_steps: int = 1,
-                        interpret: bool = None, donate: bool = None):
+                        devdec: bool = False, donate: bool = None):
     """The megachunk window per shard under shard_map: machine/template/
     seed-stream/snapshots lane-sharded, slabs + uop table + aggregates
     replicated, the per-batch merge the shard-aware prefix-credit core,
@@ -493,13 +417,10 @@ def make_mesh_megachunk(max_batches: int, n_pages: int, len_gpr: int,
     from wtf_tpu.meshrun.executor import IMAGE_SPEC
     from wtf_tpu.meshrun.mesh import LANE_AXIS
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if donate is None:
         donate = jax.default_backend() != "cpu"
     key = ("mesh", max_batches, n_pages, len_gpr, ptr_gpr, rounds,
-           deliver, mesh, devdec, fused, fused_k, fused_resume_steps,
-           interpret, donate)
+           deliver, mesh, devdec, donate)
     cached = _MEGA_CACHE.get(key)
     if cached is not None:
         return cached
@@ -520,9 +441,6 @@ def make_mesh_megachunk(max_batches: int, n_pages: int, len_gpr: int,
         return (s[0] + (s[1] << jnp.uint64(16)) + (s[2] << jnp.uint64(32))
                 + (s[3] << jnp.uint64(48)))
 
-    def rsum_fn(r):
-        return lax.psum(r, LANE_AXIS)
-
     def merge_fn(agg_cov, agg_edge, cov, edge, include):
         return mesh_merge_local(agg_cov, agg_edge, cov, edge, include,
                                 LANE_AXIS)
@@ -537,10 +455,7 @@ def make_mesh_megachunk(max_batches: int, n_pages: int, len_gpr: int,
                       deliver, merge_fn=merge_fn, any_fn=any_fn,
                       sum_fn=sum_fn, devdec_on=devdec,
                       gather_fn=gather_fn if devdec else None,
-                      lane_base_fn=lane_base_fn if devdec else None,
-                      fused=fused, fused_k=fused_k,
-                      fused_resume_steps=fused_resume_steps,
-                      interpret=interpret, rsum_fn=rsum_fn)
+                      lane_base_fn=lane_base_fn if devdec else None)
     lane_snap = MegaSnap(words=P(LANE_AXIS), lens=P(LANE_AXIS))
     in_specs = (P(), IMAGE_SPEC, P(LANE_AXIS), P(LANE_AXIS),
                 (P(), P(), P()), (P(), P(), P()), P(None, LANE_AXIS),
@@ -555,7 +470,7 @@ def make_mesh_megachunk(max_batches: int, n_pages: int, len_gpr: int,
             batches=P(), incomplete=P(), statuses=P(None, LANE_AXIS),
             new_flags=P(None, LANE_AXIS), ctr_sums=P(), new_words=P(),
             prev=lane_snap, cur=lane_snap, tab=P(), count=P(),
-            dd_stats=P(), engine_rounds=P()),
+            dd_stats=P()),
         check_vma=False),
         donate_argnums=WINDOW_DONATE_ARGNUMS if donate else ())
     _MEGA_CACHE[key] = fn

@@ -11,11 +11,6 @@ byte order of the snapshot image), and every helper here is built from
 decode-cache hash probe) run on these helpers; cold paths convert at the
 ``pack_u64``/``unpack_u64`` seam, which XLA lowers to a free bitcast.
 
-This is also the prerequisite representation for the fused Pallas step
-kernel (PERF.md open lever 3): Pallas TPU kernels cannot hold 64-bit
-integers at all, so everything a future kernel needs must already exist
-here in u32 form.
-
 Conventions:
   * a "pair" is a tuple ``(lo, hi)`` of uint32 arrays (scalars under vmap)
   * byte-count operands (``nbytes``) are int32 like the uop table fields;
@@ -306,7 +301,7 @@ def mul32_wide(a32, b32):
     """Widening 32x32 -> 64 multiply from 16-bit partial products.
 
     Every operand of every multiply stays < 2^32, so XLA never sees a
-    64-bit multiplier — this is the primitive the Pallas kernel will use.
+    64-bit multiplier.
     """
     m16 = _u32(0xFFFF)
     a0, a1 = a32 & m16, a32 >> 16
@@ -360,10 +355,9 @@ def smulhi64(a, b):
 # splitmix64 (decode-cache hash probe; must match utils.hashing bit-for-bit)
 # ---------------------------------------------------------------------------
 
-# plain-int limb pairs, NOT jnp arrays: a device array created at import
-# time would be a captured constant inside a Pallas kernel trace
-# (interp/pstep.py), which pallas_call rejects; python ints weak-type
-# against the u32 operands and lower to u32 literals either way
+# plain-int limb pairs, NOT jnp arrays: importing wtf_tpu must not touch
+# the device; python ints weak-type against the u32 operands and lower to
+# u32 literals
 _GOLDEN = (0x9E3779B97F4A7C15 & U32_MASK, 0x9E3779B97F4A7C15 >> 32)
 _MIX1 = (0xBF58476D1CE4E5B9 & U32_MASK, 0xBF58476D1CE4E5B9 >> 32)
 _MIX2 = (0x94D049BB133111EB & U32_MASK, 0x94D049BB133111EB >> 32)

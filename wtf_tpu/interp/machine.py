@@ -17,8 +17,8 @@ Hot-state representation: the fields the transition function touches every
 step (GPRs, rip, rflags, the XMM file, fs/gs bases) are stored as explicit
 little-endian u32 limb arrays (`*_l` fields, trailing axis = limb) because
 the TPU has no native 64-bit integers — XLA would otherwise lower every
-u64 op into a u32 pair with carry plumbing the semantics rarely need, and
-the future Pallas step kernel cannot hold u64 at all (interp/limbs.py).
+u64 op into a u32 pair with carry plumbing the semantics rarely need
+(interp/limbs.py).
 The u64-named accessors (`machine.gpr`, `.rip`, ...) are free bitcast
 views for host mirrors, tests, and cold device paths.
 """
@@ -53,30 +53,14 @@ CTR_INSTR = 0        # instructions retired (commit) — the oracle fallback
 CTR_MEM_FAULT = 1    # translation faults observed (device page walks +
                      # oracle MemFaults), counted once per fault event
 CTR_DECODE_MISS = 2  # decode-cache misses (NEED_DECODE transitions)
-CTR_FUSED = 3        # instructions retired INSIDE the fused Pallas step
-                     # kernel (interp/pstep.py); a subset of CTR_INSTR, so
-                     # fused occupancy = CTR_FUSED / CTR_INSTR.  Stays 0
-                     # on the plain XLA chunk path
-CTR_PARK_SUBSET = 4  # fused-kernel park events for a SUBSET reason: a
-                     # non-hot opclass/operand form, an armed breakpoint,
-                     # or an SMC-risk code window (one count per park,
-                     # not per held step).  Stays 0 on the XLA path
-CTR_PARK_MEM = 5     # fused-kernel park events for a MEMORY reason: a
-                     # non-present/non-writable walk, an out-of-range
-                     # store frame, or overlay-slot exhaustion — the lane
-                     # leaves the kernel so the XLA leg can raise the
-                     # precise PAGE_FAULT/OVERLAY_FULL.  Distinct from
-                     # CTR_PARK_SUBSET so occupancy loss is attributable
-                     # (bench.py --fused-compare / telemetry_report)
-CTR_SWEPT = 6        # lane-steps: every pass of the lane through step_lane
-                     # (or one iteration of the fused kernel's loop), live
-                     # or not — the denominator of lane occupancy
+CTR_SWEPT = 3        # lane-steps: every pass of the lane through step_lane,
+                     # live or not — the denominator of lane occupancy
                      # (CTR_INSTR / CTR_SWEPT).  On one device every lane
                      # carries the same number (the chunk loop sweeps all
                      # lanes together); mesh shards exit on their own.
                      # Device passes only: instructions the host oracle
                      # retires count in CTR_INSTR and not here
-N_CTRS = 7
+N_CTRS = 4
 
 
 class Machine(NamedTuple):

@@ -44,8 +44,8 @@ from wtf_tpu.cpu.interrupts import (
 )
 from wtf_tpu.interp import limbs
 from wtf_tpu.interp.machine import (
-    CTR_DECODE_MISS, CTR_FUSED, CTR_INSTR, CTR_MEM_FAULT, CTR_PARK_MEM,
-    CTR_PARK_SUBSET, CTR_SWEPT, Machine, machine_init, machine_restore,
+    CTR_DECODE_MISS, CTR_INSTR, CTR_MEM_FAULT, CTR_SWEPT, Machine,
+    machine_init, machine_restore,
 )
 from wtf_tpu.interp.step import fp_on_host, make_run_chunk
 from wtf_tpu.interp.uoptable import DecodeCache
@@ -670,10 +670,6 @@ class Runner:
         deliver_exceptions: Optional[bool] = None,
         registry: Optional[Registry] = None,
         events=None,
-        fused_step: str = "off",
-        fused_k: int = 32,
-        fused_rounds: int = 8,
-        fused_resume_steps: int = 1,
         burst_any_tier: Optional[bool] = None,
         tenants=None,
         supervisor: Optional[Supervisor] = None,
@@ -743,28 +739,10 @@ class Runner:
         # this graph (see make_run_chunk's caveat) and donation buys
         # nothing on a host backend anyway.
         self._donate = jax.default_backend() != "cpu"
-        # Fused Pallas fast path (interp/pstep.py): per chunk the runner
-        # dispatches the fused kernel first, then a SHORT XLA chunk that
-        # resumes lanes the kernel parked (NEEDS_XLA) — the park-and-
-        # resume ladder.  The TPU compiler refuses the kernel as written
-        # (ROADMAP S2), so until it is rewritten "auto" resolves to the
-        # XLA ladder on every platform; "on" forces the kernel (pallas
-        # interpret mode on the CPU, the compiler's own refusal on a TPU).
-        if fused_step not in ("off", "auto", "on"):
-            raise ValueError(
-                f"fused_step must be off|auto|on, got {fused_step!r}")
-        self.fused_step = fused_step
-        self.fused_enabled = fused_step == "on"
-        if fused_step == "auto" and jax.default_backend() == "tpu":
-            _log.info("fused-step auto: the XLA ladder (the TPU compiler "
-                      "refuses the fused kernel as written, ROADMAP S2)")
         if fp_on_host():
             _log.info("float-valued SSE/x87 uops retire on the host "
                       "oracle: f64 on %s is not an IEEE double",
                       jax.default_backend())
-        self.fused_k = fused_k
-        self.fused_rounds = fused_rounds
-        self.fused_resume_steps = fused_resume_steps
         # Device-resident x86 decode (interp/devdec): megachunk windows
         # service decode misses in-graph and host servicing rounds pull
         # only the missing lanes' code windows instead of full page
@@ -855,31 +833,17 @@ class Runner:
         shard_map executor, meshrun/executor.py)."""
         return make_run_chunk(n_steps, donate=self._donate)
 
-    def _fused_callables(self):
-        """(fused kernel, resume leg) pair for _fused_dispatch."""
-        from wtf_tpu.interp.pstep import make_run_fused, make_run_resume
-
-        return (make_run_fused(self.fused_k),
-                make_run_resume(self.fused_resume_steps,
-                                donate=self._donate))
-
     def megachunk_callable(self, max_batches: int, n_pages: int,
                            len_gpr: int, ptr_gpr: int, rounds: int):
         """The one-dispatch multi-batch window program (wtf_tpu/fuzz/
         megachunk.py) — the seam the megachunk driver dispatches, so
         mesh runners can swap in the shard_map variant with the same
-        signature.  `fused_enabled` is read HERE, at call time, so the
-        degradation ladder's no-fused rung (supervise.DegradationLadder
-        toggling runner.fused_enabled) also swaps the window's step
-        engine back to the XLA ladder."""
+        signature."""
         from wtf_tpu.fuzz.megachunk import make_megachunk
 
         return make_megachunk(max_batches, n_pages, len_gpr, ptr_gpr,
                               rounds, deliver=self.deliver_exceptions,
                               devdec=self.device_decode,
-                              fused=bool(self.fused_enabled),
-                              fused_k=self.fused_k,
-                              fused_resume_steps=self.fused_resume_steps,
                               donate=self._donate)
 
     def devdec_operands(self) -> Tuple:
@@ -945,11 +909,11 @@ class Runner:
             self._smc_updates = {(int(t), int(r)): int(n)
                                  for t, r, n in smc}
 
-    # -- trace-capture hooks (ablate.py / bench.py / wtf_tpu.analysis) -----
+    # -- trace-capture hooks (wtf_tpu.analysis, tests/test_chip_compile.py) -
     def executor_operands(self) -> Tuple:
         """(tab, image, machine, limit) — the chunk executor's positional
-        operands, exactly as run() dispatches them.  The export hook for
-        benches and the static analyzer; no private-state reach-in."""
+        operands, exactly as run() dispatches them.  The export hook for the
+        static analyzer and the compile tests; no private-state reach-in."""
         return (self.device_tab(), self.image, self.machine,
                 jnp.uint64(self.limit))
 
@@ -1475,49 +1439,6 @@ class Runner:
         view.set_status(lane, StatusCode.RUNNING)
         return True
 
-    # -- fused Pallas fast path (interp/pstep.py) --------------------------
-    def _fused_dispatch(self, tab, limit, shape_sig, spans) -> None:
-        """One fused 'chunk': `fused_rounds` pairs of (Pallas kernel for up
-        to fused_k hot steps) -> (unpark + fused_resume_steps XLA steps for
-        parked lanes).  With resume_steps=1 every XLA-retired instruction
-        is exactly one park event, so fused occupancy equals the hot
-        fraction of the instruction stream.  Rounds stop early once no
-        lane is RUNNING (everything needs host servicing or finished)."""
-        run_fused, run_resume = self._fused_callables()
-        fkey = ("fused", self.fused_k, self.n_lanes, shape_sig,
-                self.exec_sig)
-        if fkey not in _DISPATCHED_EXECUTORS:
-            _DISPATCHED_EXECUTORS.add(fkey)
-            self.events.emit("compile", kind="pallas-fused",
-                             k_steps=self.fused_k)
-        rkey = ("resume", self.fused_resume_steps, self._donate,
-                self.n_lanes, shape_sig, self.exec_sig)
-        if rkey not in _DISPATCHED_EXECUTORS:
-            _DISPATCHED_EXECUTORS.add(rkey)
-            self.events.emit("compile",
-                             chunk_steps=self.fused_resume_steps,
-                             donate=self._donate, kind="fused-resume")
-        for _ in range(max(self.fused_rounds, 1)):
-            with spans.span("pallas-step") as sp:
-                self.machine = self.supervisor.dispatch(
-                    "fused", run_fused, tab, self.image,
-                    self.machine, limit,
-                    steps=self.fused_k, sync=lambda m: m.status)
-                sp.fence(self.machine.status)
-            with spans.span("device-step") as sp:
-                # resumes parked lanes; ends with NO lane in NEEDS_XLA
-                self.machine = self.supervisor.dispatch(
-                    "fused-resume", run_resume, tab, self.image,
-                    self.machine, limit,
-                    steps=self.fused_resume_steps,
-                    sync=lambda m: m.status)
-                sp.fence(self.machine.status)
-            with spans.span("status-pull"):
-                # copy, not a view (donation note in run())
-                status = np.array(jax.device_get(self.machine.status))
-            if not (status == int(StatusCode.RUNNING)).any():
-                break
-
     # -- per-chunk host work (each under one span in run()) ----------------
     def _classify(self, status: np.ndarray,
                   undeliverable: Set[int]) -> dict:
@@ -1591,43 +1512,40 @@ class Runner:
         spans = self.registry.spans
         undeliverable: Set[int] = set()  # lanes whose IDT delivery failed
         for _ in range(max_chunks):
-            if self.fused_enabled:
-                self._fused_dispatch(tab, limit, shape_sig, spans)
-            else:
-                size = (self._chunk_sizes[self._chunk_level]
-                        if self.adaptive_chunks else self.chunk_steps)
-                self.stats["max_chunk_steps"] = max(
-                    self.stats["max_chunk_steps"], size)
-                run_chunk = self._chunk_callable(size)
-                compile_key = (size, self._donate, self.n_lanes, shape_sig,
-                               self.exec_sig)
-                if compile_key not in _DISPATCHED_EXECUTORS:
-                    # the first dispatch of this executor shape pays the
-                    # XLA compile (jit compiles on call, not on
-                    # make_run_chunk); its wall shows up inside the next
-                    # device-step span.  Process-global like the jit cache
-                    # itself — a second Runner at the same (size, donate,
-                    # lanes) dispatches warm and must not re-report a
-                    # compile.
-                    _DISPATCHED_EXECUTORS.add(compile_key)
-                    # the image tag keeps scheduler placements with
-                    # different stacked-image shapes (wtf_tpu/tenancy)
-                    # from reading as shape-churn in telemetry_report
-                    self.events.emit("compile", chunk_steps=size,
-                                     donate=self._donate,
-                                     lanes=self.n_lanes,
-                                     image="x".join(
-                                         str(d) for d in
-                                         self.image.frame_table.shape))
-                with spans.span("device-step") as sp:
-                    self.machine = self.supervisor.dispatch(
-                        "chunk", run_chunk,
-                        tab, self.image, self.machine, limit,
-                        steps=size, sync=lambda m: m.status)
-                    # explicit fence: JAX dispatch is async; without it
-                    # this span times Python dispatch and the device time
-                    # leaks into whichever later span synchronizes first
-                    sp.fence(self.machine.status)
+            size = (self._chunk_sizes[self._chunk_level]
+                    if self.adaptive_chunks else self.chunk_steps)
+            self.stats["max_chunk_steps"] = max(
+                self.stats["max_chunk_steps"], size)
+            run_chunk = self._chunk_callable(size)
+            compile_key = (size, self._donate, self.n_lanes, shape_sig,
+                           self.exec_sig)
+            if compile_key not in _DISPATCHED_EXECUTORS:
+                # the first dispatch of this executor shape pays the
+                # XLA compile (jit compiles on call, not on
+                # make_run_chunk); its wall shows up inside the next
+                # device-step span.  Process-global like the jit cache
+                # itself — a second Runner at the same (size, donate,
+                # lanes) dispatches warm and must not re-report a
+                # compile.
+                _DISPATCHED_EXECUTORS.add(compile_key)
+                # the image tag keeps scheduler placements with
+                # different stacked-image shapes (wtf_tpu/tenancy)
+                # from reading as shape-churn in telemetry_report
+                self.events.emit("compile", chunk_steps=size,
+                                 donate=self._donate,
+                                 lanes=self.n_lanes,
+                                 image="x".join(
+                                     str(d) for d in
+                                     self.image.frame_table.shape))
+            with spans.span("device-step") as sp:
+                self.machine = self.supervisor.dispatch(
+                    "chunk", run_chunk,
+                    tab, self.image, self.machine, limit,
+                    steps=size, sync=lambda m: m.status)
+                # explicit fence: JAX dispatch is async; without it
+                # this span times Python dispatch and the device time
+                # leaks into whichever later span synchronizes first
+                sp.fence(self.machine.status)
             self.stats["chunks"] += 1
             # one span per chunk: the pull and the per-lane classification
             # are host time that scales with the lanes needing service
@@ -1708,8 +1626,7 @@ class Runner:
             nonterminal = [int(StatusCode.RUNNING),
                            int(StatusCode.NEED_DECODE),
                            int(StatusCode.SMC), int(StatusCode.UNSUPPORTED),
-                           int(StatusCode.BREAKPOINT),
-                           int(StatusCode.NEEDS_XLA)]
+                           int(StatusCode.BREAKPOINT)]
             if self.deliver_exceptions:
                 # deliverable faults would have gone back to the guest too
                 nonterminal += list(_FAULT_STATUSES)
@@ -1771,17 +1688,6 @@ class Runner:
         reg.counter("device.instructions").inc(int(totals[CTR_INSTR]))
         reg.counter("device.mem_faults").inc(int(totals[CTR_MEM_FAULT]))
         reg.counter("device.decode_misses").inc(int(totals[CTR_DECODE_MISS]))
-        # instructions retired inside the fused Pallas kernel (a subset of
-        # device.instructions; their ratio is the fused-step occupancy)
-        reg.counter("device.fused_steps").inc(int(totals[CTR_FUSED]))
-        # park-reason split (interp/pstep.py): SUBSET = non-hot opclass /
-        # armed bp / SMC-risk code; MEM = a lane the kernel WOULD have
-        # run that the memory path diverted (failing walk, unwritable
-        # store, overlay exhaustion).  One number used to hide why lanes
-        # leave the kernel; these two make occupancy loss attributable.
-        reg.counter("device.fused_park_subset").inc(
-            int(totals[CTR_PARK_SUBSET]))
-        reg.counter("device.fused_park_mem").inc(int(totals[CTR_PARK_MEM]))
         # lane-steps (every lane's passes through the step, live or not)
         # and sweeps = lane-steps per lane: the loop iterations of one
         # device (whole there), the mean over shards on a mesh.  With

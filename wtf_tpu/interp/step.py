@@ -27,14 +27,14 @@ the same "precise slow path backs a fast path" split the reference gets from
 bochscpu vs KVM, collapsed into one machine.
 
 Representation: the hot machine state is u32 limb pairs (interp/limbs.py;
-TPU has no native 64-bit integers, and the future Pallas kernel cannot hold
-them at all).  The ported paths — decode-cache hash probe, integer ALU and
-unary ops, flag images, effective addressing, condition evaluation, and the
-fallthrough/Jcc rip updates — run entirely on u32 limbs (`alu_limb`,
-`unary_limb`, `ea_limb` below are compiled standalone by tests/test_limbs.py
-to pin the absence of 64-bit ops).  Cold classes (shifts, mul/div, strings,
-SSE/x87, syscalls, the memory/paging subsystem) read u64 bitcast views and
-convert back at the pack_u64/unpack_u64 seam, which XLA lowers for free.
+the TPU has no native 64-bit integers).  The ported paths — decode-cache
+hash probe, integer ALU and unary ops, flag images, effective addressing,
+condition evaluation, and the fallthrough/Jcc rip updates — run entirely on
+u32 limbs (`alu_limb`, `unary_limb`, `ea_limb` below are compiled standalone
+by tests/test_limbs.py to pin the absence of 64-bit ops).  Cold classes
+(shifts, mul/div, strings, SSE/x87, syscalls, the memory/paging subsystem)
+read u64 bitcast views and convert back at the pack_u64/unpack_u64 seam,
+which XLA lowers for free.
 """
 
 from __future__ import annotations
@@ -311,31 +311,6 @@ def _read_reg_l(gl, idx, nbytes):
     lo, hi = L.zext((row[0], row[1]), nbytes)
     ah = (row[0] >> 8) & jnp.uint32(0xFF)
     return jnp.where(high, ah, lo), jnp.where(high, _z32(), hi)
-
-
-def _gpr_write_l(gl, cond, idx, val, nbytes):
-    """_gpr_write on the limb file: 32-bit writes zero the high limb,
-    8/16-bit writes merge into the low limb, AH-views hit bits 15:8.
-
-    Not called by step_lane (the one shared u64 scatter is cheaper while
-    the file lives behind a free bitcast) — this is the register-file
-    writer for the Pallas fused-step kernel, where no u64 file can exist;
-    tests/test_limbs.py pins it against _gpr_write."""
-    high = idx >= U.REG_AH_BASE
-    base = jnp.clip(jnp.where(high, idx - U.REG_AH_BASE, idx), 0, 15)
-    old_lo, old_hi = gl[base, 0], gl[base, 1]
-    mlo, _mhi = L.size_mask(nbytes)
-    ah_merged = ((old_lo & jnp.uint32(0xFFFF00FF))
-                 | ((val[0] & jnp.uint32(0xFF)) << 8))
-    lo = jnp.where(high, ah_merged,
-                   jnp.where(nbytes >= 4, val[0],
-                             (old_lo & ~mlo) | (val[0] & mlo)))
-    hi = jnp.where(high, old_hi,
-                   jnp.where(nbytes >= 8, val[1],
-                             jnp.where(nbytes == 4, _z32(), old_hi)))
-    lo = jnp.where(cond, lo, old_lo)
-    hi = jnp.where(cond, hi, old_hi)
-    return gl.at[base].set(jnp.stack([lo, hi]))
 
 
 # ---------------------------------------------------------------------------
@@ -679,7 +654,6 @@ PORTED_LIMB_PATHS = {
     "step.ea_limb": ea_limb,
     "step.scale_idx_l": _scale_idx_l,
     "step.uop_lookup": uop_lookup,
-    "step.gpr_write_l": _gpr_write_l,
 }
 
 
@@ -2296,13 +2270,10 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     # device-side telemetry block (machine.CTR_* order): accumulated
     # in-graph every step, folded into host metrics once per burst — the
     # per-step host sync this exists to avoid.  page_fault/miss already
-    # imply `enabled`, commit implies `live`.  CTR_FUSED stays untouched
-    # here: only the fused Pallas kernel (interp/pstep.py) retires into it.
-    # CTR_SWEPT counts the pass itself, whatever the lane's status.
-    _f = jnp.bool_(False)
+    # imply `enabled`, commit implies `live`.  CTR_SWEPT counts the pass
+    # itself, whatever the lane's status.
     new_ctr = st.ctr + jnp.stack(
-        [commit, page_fault, miss, _f, _f, _f,
-         jnp.bool_(True)]).astype(jnp.uint32)
+        [commit, page_fault, miss, jnp.bool_(True)]).astype(jnp.uint32)
     timed = commit & (limit > _u(0)) & (new_icount >= limit)
     new_rdrand = jnp.where(commit & is_(U.OPC_RDRAND), rdrand_next, st.rdrand)
     new_bp_skip = jnp.where(commit, jnp.int32(0), st.bp_skip)

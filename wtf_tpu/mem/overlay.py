@@ -45,9 +45,9 @@ unmapped: every lane gathers from the one resident buffer).  Because the
 planes are unmapped, a vmapped store cannot return them: `store_window3`
 and `scatter_span` return a `StoreCommit` (plane indices + words), and
 the caller applies ONE flat scatter per plane for all lanes with
-`commit_stores` OUTSIDE the vmap.  The three sweeping engines
-(step.make_run_chunk, the megachunk ladder, the fused resume leg) do
-that through `step.step_lanes`.
+`commit_stores` OUTSIDE the vmap.  The two sweeping engines
+(step.make_run_chunk, the megachunk ladder) do that through
+`step.step_lanes`.
 """
 
 from __future__ import annotations

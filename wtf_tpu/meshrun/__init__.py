@@ -10,10 +10,10 @@ parallelism:
                image/uop-table replicated, multi-host init)
   reduce.py    the ONE shard-aware coverage OR-reduce family (chunk
                bitmaps, batch merge with reference set-union credit)
-  executor.py  shard_map chunk / fused-step / resume executors — the
-               compiled chunk carries exactly one cross-device
-               collective, the coverage all-reduce (pinned statically
-               by `wtf-tpu lint`'s mesh family)
+  executor.py  the shard_map chunk executor — the compiled chunk
+               carries exactly one cross-device collective, the
+               coverage all-reduce (pinned statically by `wtf-tpu
+               lint`'s mesh family)
   runner.py    MeshRunner: the host servicing loop over a sharded batch
   backend.py   MeshBackend: the one-logical-backend seam the fuzz loop,
                dist clients and CLI drive (`campaign --mesh-devices N`)
@@ -37,8 +37,6 @@ _EXPORTS = {
     "merge_coverage": "reduce",
     "make_mesh_merge": "reduce",
     "make_mesh_chunk": "executor",
-    "make_mesh_fused": "executor",
-    "make_mesh_resume": "executor",
     "MeshRunner": "runner",
     "MeshBackend": "backend",
 }

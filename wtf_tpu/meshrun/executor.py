@@ -14,10 +14,6 @@ SAME chunk body runs per shard under `shard_map`, so
     boolean all-reduce of the cov/edge bitmaps, so the host reads back
     ONE merged bitmap per chunk instead of gathering [lanes, words]
     planes — the only cross-chip traffic of the hot loop.
-
-The fused Pallas kernel (interp/pstep.py) wraps the same way: the kernel
-grid runs over the shard's local lanes, and its XLA resume leg doubles
-as the merged-coverage producer.
 """
 
 from __future__ import annotations
@@ -40,7 +36,6 @@ from wtf_tpu.meshrun.reduce import bitplane_or
 IMAGE_SPEC = MemImage(pages=P(), frame_table=P(), tenant=P(LANE_AXIS))
 
 _MESH_CHUNK_CACHE: dict = {}
-_MESH_FUSED_CACHE: dict = {}
 
 
 def _chunk_with_coverage(body):
@@ -85,54 +80,5 @@ def make_mesh_chunk(n_steps: int, mesh, donate: Optional[bool] = None,
     if not jit:
         return fn
     fn = jax.jit(fn, donate_argnums=(2,) if donate else ())
-    _MESH_CHUNK_CACHE[key] = fn
-    return fn
-
-
-def make_mesh_fused(k_steps: int, mesh):
-    """The fused Pallas kernel (interp/pstep.py) per shard: the pallas
-    grid spans the shard's LOCAL lanes (the kernel reads its lane count
-    from the block it is handed), machine stays lane-sharded, and no
-    collective is emitted — parked lanes are resumed by the mesh resume
-    leg, which also carries the merged-coverage all-reduce."""
-    key = (k_steps, mesh)
-    cached = _MESH_FUSED_CACHE.get(key)
-    if cached is not None:
-        return cached
-    from wtf_tpu.interp.pstep import make_run_fused
-
-    run_fused = make_run_fused(k_steps)
-    fn = jax.jit(shard_map(
-        lambda tab, image, machine, limit: run_fused(
-            tab, image, machine, limit),
-        mesh=mesh,
-        in_specs=(P(), IMAGE_SPEC, P(LANE_AXIS), P()),
-        out_specs=P(LANE_AXIS),
-        check_vma=False))
-    _MESH_FUSED_CACHE[key] = fn
-    return fn
-
-
-def make_mesh_resume(n_steps: int, mesh, donate: Optional[bool] = None):
-    """The fused ladder's XLA resume leg per shard (see pstep.
-    make_run_resume for the park/hold/release contract), extended like
-    make_mesh_chunk to emit the merged cov/edge bitmaps — the fused
-    mesh round's one collective rides here."""
-    if donate is None:
-        donate = jax.default_backend() != "cpu"
-    key = ("resume", n_steps, mesh, donate)
-    cached = _MESH_CHUNK_CACHE.get(key)
-    if cached is not None:
-        return cached
-    from wtf_tpu.interp.pstep import make_run_resume
-
-    # the memoized single-device executor is jitted; tracing through it
-    # inside shard_map inlines the program, donation stays on the outer
-    run_resume = make_run_resume(n_steps, donate=False)
-    fn = jax.jit(shard_map(
-        _chunk_with_coverage(run_resume), mesh=mesh,
-        in_specs=(P(), IMAGE_SPEC, P(LANE_AXIS), P()),
-        out_specs=(P(LANE_AXIS), P(), P()),
-        check_vma=False), donate_argnums=(2,) if donate else ())
     _MESH_CHUNK_CACHE[key] = fn
     return fn

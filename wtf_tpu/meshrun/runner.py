@@ -37,9 +37,7 @@ from jax.sharding import PartitionSpec as P
 from wtf_tpu.interp.machine import CTR_INSTR
 from wtf_tpu.interp.runner import Runner
 from wtf_tpu.mem import overlay as ovl
-from wtf_tpu.meshrun.executor import (
-    make_mesh_chunk, make_mesh_fused, make_mesh_resume,
-)
+from wtf_tpu.meshrun.executor import make_mesh_chunk
 from wtf_tpu.meshrun.mesh import (
     LANE_AXIS, lane_sharding, make_mesh, replicate, replicated_sharding,
     shard_machine,
@@ -131,18 +129,6 @@ class MeshRunner(Runner):
 
         return dispatch
 
-    def _fused_callables(self):
-        fused = make_mesh_fused(self.fused_k, self.mesh)
-        resume = make_mesh_resume(self.fused_resume_steps, self.mesh,
-                                  donate=self._donate)
-
-        def dispatch_resume(tab, image, machine, limit):
-            machine, self._merged_cov, self._merged_edge = resume(
-                tab, image, machine, limit)
-            return machine
-
-        return fused, dispatch_resume
-
     def megachunk_callable(self, max_batches: int, n_pages: int,
                            len_gpr: int, ptr_gpr: int, rounds: int):
         """The megachunk window per shard (fuzz/megachunk.py mesh
@@ -155,10 +141,6 @@ class MeshRunner(Runner):
                                    deliver=self.deliver_exceptions,
                                    mesh=self.mesh,
                                    devdec=self.device_decode,
-                                   fused=bool(self.fused_enabled),
-                                   fused_k=self.fused_k,
-                                   fused_resume_steps=(
-                                       self.fused_resume_steps),
                                    donate=self._donate)
 
     def megachunk_place(self, slab_first, slab_rest, seeds):

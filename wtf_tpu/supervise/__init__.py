@@ -4,7 +4,7 @@
                          batch-boundary snapshot + rebuild/replay
                          recovery, per-batch integrity + quarantine
   ladder.DegradationLadder
-                         megachunk -> batch -> fused-off -> fixed-chunk
+                         megachunk -> batch -> fixed-chunk
                          step-down with hysteresis re-promotion
   integrity              the jitted invariant/digest fold and the
                          poison/mask write-side helpers

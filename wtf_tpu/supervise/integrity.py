@@ -8,13 +8,11 @@ jitted function over the live machine pytree (lane-parallel elementwise
 work plus two tiny reductions — noise next to a chunk dispatch, and it
 pipelines behind the batch's own async dispatch):
 
-  status    in [RUNNING .. NEEDS_XLA] — every value StatusCode can map
+  status    in [RUNNING .. HARD_ERROR] — every value StatusCode can map
   rip       canonical: the u32 hi limb's bits 63..47 all-zero or all-one
             (the u64 rip is stored as two u32 limbs; no u64 on device)
   overlay   0 <= count <= capacity AND count == #allocated slots
             (pfn >= 0) — a corrupt count would tear COW restore
-  ctr       fused-retired <= total-retired (CTR_FUSED counts a subset of
-            CTR_INSTR by construction)
 
 The digest is a lane-mixed wraparound-sum fold over the same planes — a cheap
 whole-state fingerprint for the poisoned-lane event (two occurrences of
@@ -34,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from wtf_tpu.core.results import StatusCode
-from wtf_tpu.interp.machine import CTR_FUSED, CTR_INSTR, Machine
+from wtf_tpu.interp.machine import CTR_INSTR, Machine
 
 _STATUS_MAX = max(int(s) for s in StatusCode)
 
@@ -56,7 +54,6 @@ def _check(machine: Machine) -> Tuple[jax.Array, jax.Array]:
     capacity = ov.pfn.shape[1]
     allocated = jnp.sum((ov.pfn >= 0).astype(jnp.int32), axis=1)
     ok &= (ov.count >= 0) & (ov.count <= capacity) & (allocated == ov.count)
-    ok &= machine.ctr[:, CTR_FUSED] <= machine.ctr[:, CTR_INSTR]
     # lane-mixed fingerprint folded with wraparound add (order-free, and
     # unlike a custom XOR lax.reduce it lowers to the stock add-reduction
     # every backend — including sharded host CPU — implements)

@@ -2,20 +2,19 @@
 
 Every rung below "full" turns OFF one throughput feature whose output is
 already pinned bit-identical to the plain path at equal seeds (megachunk
-parity, fused-step parity, and the adaptive-chunk schedule are all
-tier-1 parity tests) — so stepping down after repeated device failures
-changes how fast the campaign runs, never what it finds:
+parity and the adaptive-chunk schedule are tier-1 parity tests) — so
+stepping down after repeated device failures changes how fast the
+campaign runs, never what it finds:
 
-  level 0  full            megachunk windows, fused step, adaptive chunks
+  level 0  full            megachunk windows, adaptive chunks
   level 1  no-megachunk    batch-at-a-time dispatch (smallest abandonable
                            unit shrinks from a window to one batch)
-  level 2  no-fused        fused mutate->execute off; plain chunk executor
-  level 3  fixed-chunk     adaptive chunk growth off; base chunk_steps
+  level 2  fixed-chunk     adaptive chunk growth off; base chunk_steps
                            only (the minimal XLA surface: one executor)
 
-Rungs that don't apply to the campaign (no megachunk configured, fused
-step already off) are skipped at construction, so `level` always indexes
-a real change.  Hysteresis: one failure steps down one rung immediately;
+A rung that doesn't apply to the campaign (no megachunk configured) is
+skipped at construction, so `level` always indexes a real change.
+Hysteresis: one failure steps down one rung immediately;
 `promote_after` CONSECUTIVE clean batches step back up one rung — a
 flapping device ratchets down and stays down.
 
@@ -29,20 +28,16 @@ from __future__ import annotations
 
 FULL = "full"
 NO_MEGACHUNK = "no-megachunk"
-NO_FUSED = "no-fused"
 FIXED_CHUNK = "fixed-chunk"
 
 
 class DegradationLadder:
     def __init__(self, loop, promote_after: int = 8):
         runner = loop.backend.runner
-        self._orig_fused = bool(getattr(runner, "fused_enabled", False))
         self._orig_adaptive = bool(getattr(runner, "adaptive_chunks", True))
         self.rungs = [FULL]
         if getattr(loop, "megachunk", 0):
             self.rungs.append(NO_MEGACHUNK)
-        if self._orig_fused:
-            self.rungs.append(NO_FUSED)
         if self._orig_adaptive:
             self.rungs.append(FIXED_CHUNK)
         self.level = 0
@@ -92,6 +87,5 @@ class DegradationLadder:
         every rung change and after every rebuild (the fresh Runner comes
         up with its construction-time defaults, not the rung's)."""
         runner = loop.backend.runner
-        runner.fused_enabled = self._orig_fused and not self._active(NO_FUSED)
         runner.adaptive_chunks = (self._orig_adaptive
                                   and not self._active(FIXED_CHUNK))

@@ -4,7 +4,7 @@ The device plane was the last part of the fuzzer that trusted its
 substrate completely: a hung XLA dispatch, an `XlaRuntimeError`, or a
 silently corrupted lane either aborted the campaign or — worse —
 credited poisoned coverage.  The Supervisor closes that gap by owning
-every device dispatch seam (Runner chunk/fused/insert, the megachunk
+every device dispatch seam (Runner chunk/insert, the megachunk
 window, devmut generation — enumerated in `SEAM_SITES`, pinned by the
 lint `supervise` family) with four capabilities:
 
@@ -20,10 +20,10 @@ lint `supervise` family) with four capabilities:
                replays bit-identically: the failed attempt only ever
                decoded a prefix of the same deterministic stream.
   degradation  repeated failures step down the `DegradationLadder`
-               (megachunk -> batch-at-a-time -> fused off -> fixed
-               chunks); N clean batches re-promote.  Every rung is
-               pinned bit-identical at equal seeds elsewhere in the
-               tree, so rungs trade wall-clock, never results.
+               (megachunk -> batch-at-a-time -> fixed chunks); N clean
+               batches re-promote.  Every rung is pinned bit-identical
+               at equal seeds elsewhere in the tree, so rungs trade
+               wall-clock, never results.
   quarantine   a cheap jitted integrity fold over the machine planes
                runs once per batch (supervise/integrity.py); violating
                lanes raise LanePoisoned (the batch replays from restore
@@ -57,8 +57,6 @@ from wtf_tpu.telemetry import NULL, Registry
 # supervisor is a lint failure, not a silent recovery hole.
 SEAM_SITES: Dict[str, str] = {
     "chunk": "wtf_tpu.interp.runner:Runner.run",
-    "fused": "wtf_tpu.interp.runner:Runner._fused_dispatch",
-    "fused-resume": "wtf_tpu.interp.runner:Runner._fused_dispatch",
     "device-insert": "wtf_tpu.interp.runner:Runner.device_insert",
     "devmut-generate": "wtf_tpu.devmut.mutator:DevMangleMutator.generate",
     "megachunk": "wtf_tpu.backend.tpu:TpuBackend._dispatch_window",
@@ -69,8 +67,7 @@ SUPERVISED_SEAMS = tuple(sorted(SEAM_SITES))
 # seams whose dispatch output carries machine state — the only ones a
 # scripted poison fault can corrupt (faultinject slides poison scheduled
 # on other seams to the next dispatch index)
-MACHINE_SEAMS = frozenset(
-    ("chunk", "fused", "fused-resume", "device-insert", "megachunk"))
+MACHINE_SEAMS = frozenset(("chunk", "device-insert", "megachunk"))
 
 # scripted device-fault kinds (testing/faultinject.FaultPlan.device_faults)
 DEVICE_HANG = "device-hang"

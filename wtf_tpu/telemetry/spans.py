@@ -40,17 +40,15 @@ SECONDS = "phase.seconds"
 CALLS = "phase.calls"
 
 # Span leaves that measure DEVICE work (each is fenced with
-# jax.block_until_ready before its span closes): the device-step/
-# pallas-step executors, the fused devmut generation / insert /
-# megachunk-window waits ("device" under mutate/insert/execute), the
-# overlay restore, and the coverage readback.  Everything else inside a
-# top-level phase is host time.  The ONE list the host/device wall
-# breakdown rides on — tools/telemetry_report.py and ablate.py's
-# host-share A/B both consume it, so the split cannot drift between
-# the report and the benchmark.
+# jax.block_until_ready before its span closes): the device-step
+# executor, the devmut generation / insert / megachunk-window waits
+# ("device" under mutate/insert/execute), the overlay restore, and the
+# coverage readback.  Everything else inside a top-level phase is host
+# time.  The ONE list the host/device wall breakdown rides on —
+# tools/telemetry_report.py and `wtf-tpu status` both consume it, so
+# the split cannot drift between them.
 DEVICE_SPAN_LEAVES = frozenset((
-    "device", "device-step", "pallas-step", "overlay-restore",
-    "cov-readback",
+    "device", "device-step", "overlay-restore", "cov-readback",
 ))
 
 
