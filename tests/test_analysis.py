@@ -396,4 +396,6 @@ def test_full_lint_clean_on_tree(tmp_path):
     assert findings == [], [str(f) for f in findings]
     assert info["kernel_counts"]["total"] == \
         load_budgets()["xla_step"]["total"]
+    assert info["tail_kernel_counts"]["total"] == \
+        load_budgets()["tail_chunk"]["total"]
     assert registry.dump().get("analysis.families_run") == len(FAMILIES)

@@ -99,12 +99,9 @@ _HLO_ARRAY = re.compile(r"\b[a-z]+\d+\[([\d,]*)\]")
 _HLO_CALLEE = re.compile(r"(?:body|condition|calls|to_apply)=%?([\w.\-]+)")
 
 
-def slab_relayouts(hlo_text, n_elems):
-    """Instructions inside the entry computation's while loops (every
-    computation a loop body reaches: conditions, nested loops, fusions and
-    so their roots) whose opcode is a relayout or a copy and whose result
-    holds `n_elems` elements or more.  The loop carry itself is a
-    parameter / get-tuple-element / tuple / bitcast and never listed."""
+def _computations(hlo_text):
+    """{name: lines} of every computation of an HLO module, and the entry
+    computation's name."""
     comps, entry, name = {}, None, None
     for line in hlo_text.splitlines():
         head = _HLO_COMPUTATION.match(line)
@@ -116,10 +113,13 @@ def slab_relayouts(hlo_text, n_elems):
             name = None
         elif name is not None:
             comps[name].append(line)
-    todo = [body for line in comps[entry] if " while(" in line
-            for body in re.findall(r"body=%?([\w.\-]+)", line)]
-    assert todo, "the entry computation starts no while loop"
-    seen, found = set(), []
+    return comps, entry
+
+
+def _reached(comps, root):
+    """Lines of every computation `root` reaches (conditions, nested
+    loops, fusions and so their roots), itself included."""
+    todo, seen = [root], set()
     while todo:
         comp = todo.pop()
         if comp in seen:
@@ -127,6 +127,28 @@ def slab_relayouts(hlo_text, n_elems):
         seen.add(comp)
         for line in comps[comp]:
             todo += _HLO_CALLEE.findall(line)
+            yield line
+
+
+def entry_loop_bodies(hlo_text):
+    """(computations, the bodies of the entry computation's while loops)."""
+    comps, entry = _computations(hlo_text)
+    bodies = [body for line in comps[entry] if " while(" in line
+              for body in re.findall(r"body=%?([\w.\-]+)", line)]
+    assert bodies, "the entry computation starts no while loop"
+    return comps, bodies
+
+
+def slab_relayouts(hlo_text, n_elems):
+    """Instructions inside the entry computation's while loops (every
+    computation a loop body reaches) whose opcode is a relayout or a copy
+    and whose result holds `n_elems` elements or more.  The loop carry
+    itself is a parameter / get-tuple-element / tuple / bitcast and never
+    listed."""
+    comps, bodies = entry_loop_bodies(hlo_text)
+    found = []
+    for body in bodies:
+        for line in _reached(comps, body):
             ins = _HLO_INSTRUCTION.match(line)
             if not ins or ins.group(2) not in _RELAYOUT_OPCODES:
                 continue
@@ -137,16 +159,29 @@ def slab_relayouts(hlo_text, n_elems):
     return found
 
 
+def sweeping_loops(hlo_text):
+    """The entry computation's while loops that sweep: their body reaches
+    the overlay's commit scatter (named scope `overlay-store`)."""
+    comps, bodies = entry_loop_bodies(hlo_text)
+    return [body for body in bodies
+            if any("overlay-store" in line for line in _reached(comps, body))]
+
+
 def test_chunk_program_compiles_for_v5e(loop, one_chip, compile_for):
     """The step ladder's chunk program, as the chip traces and donates it
-    (float-valued uops hosted: fp_host=True; the machine donated), fits
-    the chip, and a sweep moves no overlay plane: inside the while loop
+    (float-valued uops hosted: fp_host=True; the machine donated; at
+    1024 lanes the sweeps go over groups of 64 lanes, sixteen down to one),
+    fits the chip, holds the step ONCE (one loop nest, one body at the
+    group's width), and a sweep moves no overlay plane: inside the loops
     no copy / reshape / transpose / select / concatenate is as large as
-    one plane (ROADMAP S14: the parent relayouted the slab around the
-    store's scatter in every sweep), both overlay planes are aliased to
-    their outputs, and the program's temporaries stay under one data
-    plane (the parent held the relayouted planes and the u64 plane's two
-    u32 halves there)."""
+    one plane (ROADMAP S14: the parent of PR 26 relayouted the slab
+    around the store's scatter in every sweep; the planes ride through
+    the levels, the sweeps and the groups as in-place carries), every
+    donated machine leaf is aliased to its output through the sort of the
+    rows and their scatter back, and the program's temporaries stay under
+    one data plane (PR 26's parent held the relayouted planes and the u64
+    plane's two u32 halves there)."""
+    from wtf_tpu.interp import step
     from wtf_tpu.interp.step import make_run_chunk
 
     runner = loop.backend.runner
@@ -162,10 +197,19 @@ def test_chunk_program_compiles_for_v5e(loop, one_chip, compile_for):
     ov = operands[2].overlay
     plane_words = ov.valid.size            # lanes * slots * PAGE_WORDS
     assert plane_words == LANES * ov.pfn.shape[1] * 512
-    found = slab_relayouts(compiled.as_text(), plane_words)
+    text = compiled.as_text()
+    width = step.tail_width(LANES)
+    assert width == LANES // step.TAIL_SHARE == 64
+    assert len(sweeping_loops(text)) == 1, "one loop nest"
+    # the step is held once: every operation of the commit scatter sits
+    # under one path of loops, and works on one group of lanes
+    sites = set(re.findall(r'op_name="([^"]*?)/vmap\(overlay-store\)', text))
+    assert len(sites) == 1, sites
+    assert f"u32[{width},{operands[2].edge.shape[1]}]" in text
+    found = slab_relayouts(text, plane_words)
     assert not found, "\n".join(found)
-    planes = ov.data.nbytes + ov.valid.nbytes
-    assert mem.alias_size_in_bytes > planes, mem
+    machine_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(operands[2]))
+    assert mem.alias_size_in_bytes >= machine_bytes, mem
     assert mem.temp_size_in_bytes < ov.data.nbytes, mem
 
 

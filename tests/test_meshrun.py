@@ -82,6 +82,51 @@ def test_mesh_chunk_bit_parity_and_merged_bitmaps(mesh):
     assert cov_host.sum() > 0  # something actually executed
 
 
+def test_mesh_chunk_through_the_tail_equals_head_only(monkeypatch):
+    """Each shard narrows its sweeps at the width of ITS lanes (4 shards
+    x 4 lanes, groups of 1; the floor lowered for the test): every
+    machine leaf but the two sweep counters equals the single-device
+    full-width chunk."""
+    from wtf_tpu.interp import step
+    from wtf_tpu.interp.machine import CTR_SWEPT, CTR_TAIL
+
+    r1 = _runner()
+    m_single = make_run_chunk(160, donate=False, tail=0)(
+        r1.cache.device(), r1.physmem.image, r1.machine, jnp.uint64(500))
+
+    monkeypatch.setattr(step, "TAIL_MIN_LANES", 4)
+    monkeypatch.setattr(step, "TAIL_SHARE", 4)
+    mesh4 = make_mesh(4)
+    r2 = _runner()
+    m_mesh, cov, edge = jax.jit(
+        make_mesh_chunk(160, mesh4, donate=False, jit=False))(
+        replicate(r2.cache.device(), mesh4),
+        replicate(r2.physmem.image, mesh4),
+        shard_machine(r2.machine, mesh4), jnp.uint64(500))
+
+    for (path, la), lb in zip(jax.tree_util.tree_leaves_with_path(m_single),
+                              jax.tree.leaves(m_mesh)):
+        la, lb = np.asarray(la), np.asarray(lb)
+        if jax.tree_util.keystr(path) == ".ctr":
+            keep = [c for c in range(la.shape[1])
+                    if c not in (CTR_SWEPT, CTR_TAIL)]
+            la, lb = la[:, keep], lb[:, keep]
+        np.testing.assert_array_equal(
+            la, lb, err_msg=f"machine leaf {path} diverges in the tail")
+    np.testing.assert_array_equal(
+        np.asarray(cov),
+        np.bitwise_or.reduce(np.asarray(m_single.cov), axis=0))
+    ctr = np.asarray(m_mesh.ctr).reshape(4, 4, -1).astype(np.int64)
+    swept, tail = ctr[:, :, CTR_SWEPT], ctr[:, :, CTR_TAIL]
+    assert tail.sum() > 0, "no shard narrowed"
+    # a shard's lanes all take its full-width sweeps, and the narrow ones
+    # are counted lane by lane; the shards narrow and exit on their own
+    np.testing.assert_array_equal(
+        tail, swept - swept.min(axis=1, keepdims=True))
+    assert len({tuple(sorted(row)) for row in swept.tolist()}) > 1
+    assert swept.sum() < np.asarray(m_single.ctr)[:, CTR_SWEPT].sum()
+
+
 def test_mesh_runner_full_run_parity(mesh):
     """MeshRunner.run() (host servicing, decode misses, breakpoints
     included) matches Runner.run() bit-for-bit, per-shard counters sum

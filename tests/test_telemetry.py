@@ -1203,13 +1203,16 @@ def test_lowered_chunk_names_every_stage_of_the_step(tpu_backend):
 @pytest.mark.parametrize("entry,total", [
     ("xla_step", 104), ("tenant_chunk", 104),
     ("megachunk_window", 155), ("decode_service", 248),
-    ("mesh_chunk", 1),
+    ("mesh_chunk", 1), ("tail_chunk", 472),
 ])
 def test_pinned_kernel_counts_did_not_move(entry, total):
     """analysis/budgets.json as PR 26 re-pinned it with the overlay
     store, and the ladder window's entry as PR 30 pinned it
     (test_analysis holds the measured programs to the file; this holds
-    the file)."""
+    the file).  `tail_chunk` (PR 31) is `xla_step` with groups of 2
+    lanes asked for: one body of the step, the slices that take a group's
+    rows out and put them back, the sort of the rows a level and their
+    scatter back."""
     import wtf_tpu.analysis as analysis
 
     budgets = json.loads(

@@ -216,6 +216,32 @@ def test_mixed_batch_isolation_bit_parity():
         assert cache.entry_index(rip, 0) != cache.entry_index(rip, 1)
 
 
+def test_mixed_batch_through_the_tail_equals_head_only(monkeypatch):
+    """The chunk executor's narrow sweeps (8 lanes in groups of 2; the
+    floor and the group count lowered for the test) move each lane's
+    tenant selector with its row: a two-image batch leaves what the
+    full-width executor leaves."""
+    from wtf_tpu.interp import step
+
+    cfg = [("alice", "demo_tlv", 4), ("bob", "demo_kernel", 4)]
+    plain_backend, _r1, plain = _campaign(cfg, batches=2)
+    monkeypatch.setattr(step, "TAIL_MIN_LANES", 8)
+    monkeypatch.setattr(step, "TAIL_SHARE", 4)
+    monkeypatch.setattr(step, "_CHUNK_CACHE", {})
+    backend, _r2, tailed = _campaign(cfg, batches=2)
+    assert tailed == plain
+    counter = backend.registry.counter
+    for key in ("device.instructions", "device.decode_misses",
+                "device.lockstep_floor_steps", "runner.chunks"):
+        assert counter(key).value == plain_backend.registry.counter(
+            key).value, key
+    assert 0 < counter("device.tail_lane_steps").value \
+        < counter("device.lane_steps").value \
+        < plain_backend.registry.counter("device.lane_steps").value
+    assert plain_backend.registry.counter(
+        "device.tail_lane_steps").value == 0
+
+
 def test_devmangle_tenant_stream_bit_parity():
     muts = dict(MUTS, alice=("devmangle", 42, SEED_TLV))
     cap_solo: dict = {}

@@ -208,6 +208,12 @@ def test_lockstep_floor_is_lanes_times_the_deepest_lane(tpu, emu, seed):
     assert len(set(icounts)) > 4, "a mixed batch"
     assert counters["device.instructions"] == sum(icounts)
     assert counters["device.lockstep_floor_steps"] == LANES * max(icounts)
+    # floor <= lane_steps holds because these 32 lanes take no tail
+    # (step.TAIL_MIN_LANES): the chunk loop sweeps every lane as long as
+    # the deepest one runs.  With the tail on, lane_steps falls BELOW the
+    # floor (the tail sweeps a quarter of the lanes where lockstep swept
+    # all; tests/test_chunk_tail.py holds that direction), and
+    # instructions <= floor is what stays true at any width.
     assert counters["device.instructions"] \
         <= counters["device.lockstep_floor_steps"] \
         <= counters["device.lane_steps"]

@@ -424,6 +424,13 @@ def summarize(path) -> dict:
                       / metrics["device.lane_steps"], 4)
                 if metrics.get("device.lockstep_floor_steps") is not None
                 and metrics.get("device.lane_steps") else None),
+            # the lane-steps taken in the chunk loop's narrow tail
+            # (CTR_TAIL); null for streams from before the counter
+            "tail_share": (
+                round(metrics["device.tail_lane_steps"]
+                      / metrics["device.lane_steps"], 4)
+                if metrics.get("device.tail_lane_steps") is not None
+                and metrics.get("device.lane_steps") else None),
         },
         "mesh": mesh,
         "triage": triage,
@@ -496,8 +503,12 @@ def _print_human(s: dict) -> None:
           + " of lane-steps retired an instruction")
     print("  sweeps/chunk: " + _or_null(dev.get("sweeps_per_chunk"), "{}"))
     if dev.get("lockstep_floor_share") is not None:
-        print(f"  lockstep floor: {dev['lockstep_floor_share']:.2%} of "
-              "lane-steps lie at or under the batch's deepest lane")
+        print(f"  lockstep floor: lanes x the batch's deepest lane is "
+              f"{dev['lockstep_floor_share']:.2%} of the lane-steps swept"
+              " (over 100%: the narrow tail swept fewer)")
+    if dev.get("tail_share"):
+        print(f"  tail: {dev['tail_share']:.2%} of lane-steps were taken "
+              "in the chunk loop's narrow tail")
     mesh = s.get("mesh")
     if mesh:
         print(f"mesh: {mesh['devices']} devices x "

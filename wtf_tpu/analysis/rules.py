@@ -93,6 +93,15 @@ DATA_DEP_OPS = ("gather", "dynamic-slice", "dynamic-update-slice", "scatter")
 BUDGET_ENTRY = "xla_step"
 BUDGET_CONFIG = dict(n_lanes=4, chunk_steps=64, n_steps=64, donate=True)
 
+# the chunk executor's narrow sweeps (step.make_run_chunk): the budget
+# runner's 4 lanes take none, so the budget family lowers the same
+# runner's chunk once more with groups of TAIL_WIDTH lanes asked for and
+# holds the loop nest, the sort of the rows and their scatter back to the
+# `tail_chunk` entry; the recompile family holds its donated machine
+# leaves to alias through that scatter
+TAIL_ENTRY = "tail_chunk"
+TAIL_WIDTH = 2
+
 # canonical heterogeneous-batch configuration (wtf_tpu/tenancy): the
 # budget family lowers the SAME step ladder over a two-tenant stacked
 # image table, counts its gather-class kernels against the
@@ -785,6 +794,53 @@ def run_tenant_rules(budgets_path: Optional[Path] = None,
     return findings, {"tenant_counts": counts, "entry": entry}
 
 
+def check_chunk_donation(compiled, compiled_text: str, runner,
+                         entry: str) -> List[Finding]:
+    """`check_donation_aliasing` for a chunk executor compiled on
+    `runner`'s operands (tab, image, machine, limit)."""
+    import jax
+
+    n_prefix = len(jax.tree_util.tree_leaves(runner.cache.device())) \
+        + len(jax.tree_util.tree_leaves(runner.physmem.image))
+    n_machine = len(jax.tree_util.tree_leaves(runner.machine))
+    # jit DCEs unused flat args (e.g. tab.rip_l once uop_lookup's
+    # probe verifies against the hash rows' own key limbs), shifting
+    # the compiled param numbering the alias map indexes
+    kept = getattr(getattr(compiled, "_executable", None),
+                   "_kept_var_idx", None)
+    dropped = (frozenset(i for i in range(n_prefix + n_machine)
+                         if i not in kept)
+               if kept is not None else frozenset())
+    return check_donation_aliasing(compiled_text, runner.machine, n_prefix,
+                                   entry=entry, dropped_args=dropped)
+
+
+def run_tail_rules(runner, families, budgets_path: Optional[Path] = None,
+                   rebaseline: bool = False) -> Tuple[List[Finding], Dict]:
+    """The chunk executor with its narrow sweeps on, at the budget
+    runner's shapes (ONE compile for both families): the kernel census
+    against the `tail_chunk` entry (budget) and the alias coverage of the
+    donated machine through the rows' scatter back (recompile)."""
+    entry = (f"make_run_chunk({BUDGET_CONFIG['n_steps']}, "
+             f"donate={BUDGET_CONFIG['donate']}, tail={TAIL_WIDTH}) / "
+             f"demo_tlv / n_lanes={BUDGET_CONFIG['n_lanes']}")
+    compiled = step_executor_lowering(
+        runner, n_steps=BUDGET_CONFIG["n_steps"],
+        donate=BUDGET_CONFIG["donate"], tail=TAIL_WIDTH).compile()
+    text = compiled.as_text()
+    findings: List[Finding] = []
+    info: Dict = {"entry": entry}
+    if "budget" in families:
+        info["tail_counts"] = count_data_dependent_ops(text)
+        if not rebaseline:
+            budget = load_budgets(budgets_path).get(TAIL_ENTRY, {})
+            findings.extend(check_budget(info["tail_counts"], budget,
+                                         entry=entry))
+    if "recompile" in families:
+        findings.extend(check_chunk_donation(compiled, text, runner, entry))
+    return findings, info
+
+
 def apply_rebaseline(budgets: Dict, measured: Dict,
                      allow_regression: bool = False) -> Dict:
     """Merge freshly measured budget entries over the checked-in ones —
@@ -1289,23 +1345,24 @@ def run_lint(families: Optional[Sequence[str]] = None,
         if compiled_text is None:
             compiled = lowered.compile()
             compiled_text = compiled.as_text()
-        import jax
-
-        n_prefix = len(jax.tree_util.tree_leaves(runner.cache.device())) \
-            + len(jax.tree_util.tree_leaves(runner.physmem.image))
-        n_machine = len(jax.tree_util.tree_leaves(runner.machine))
-        # jit DCEs unused flat args (e.g. tab.rip_l once uop_lookup's
-        # probe verifies against the hash rows' own key limbs), shifting
-        # the compiled param numbering the alias map indexes
-        kept = getattr(getattr(compiled, "_executable", None),
-                       "_kept_var_idx", None)
-        dropped = (frozenset(i for i in range(n_prefix + n_machine)
-                             if i not in kept)
-                   if kept is not None else frozenset())
-        findings.extend(check_donation_aliasing(
-            compiled_text, runner.machine, n_prefix, entry=entry,
-            dropped_args=dropped))
+        findings.extend(check_chunk_donation(
+            compiled, compiled_text, runner, entry))
         info["seconds"]["recompile"] = round(time.time() - t0, 1)
+
+    if needs_trace:
+        t0 = time.time()
+        tail_findings, tail_info = run_tail_rules(
+            runner, families, budgets_path=budgets_path,
+            rebaseline=rebaseline)
+        findings.extend(tail_findings)
+        info["entries"].append(tail_info["entry"])
+        counts_tail = tail_info.get("tail_counts")
+        if counts_tail:
+            info["tail_kernel_counts"] = counts_tail
+            if rebaseline:
+                measured_budgets[TAIL_ENTRY] = {
+                    "entry": tail_info["entry"], **counts_tail}
+        info["seconds"]["tail"] = round(time.time() - t0, 1)
 
     if "mesh" in families:
         t0 = time.time()

@@ -105,12 +105,13 @@ def compiled_hlo(fn, *args, donate_argnums=()):
 
 
 def step_executor_lowering(runner, n_steps: int = 64, donate: bool = True,
-                           perturb: bool = False):
+                           perturb: bool = False, tail: int = None):
     """Lowered handle of the chunked XLA step ladder on this runner's
     operands.  `perturb=True` re-traces under perturbed-but-same-shape
     inputs (register values bumped, a different limit) — the
     signature-stability probe: both lowerings must produce identical
     StableHLO or something value-dependent leaked into the trace.
+    `tail` is make_run_chunk's (None: the width this lane count takes).
 
     Each call traces FRESH (make_run_chunk(jit=False) + a new jit
     wrapper): jax's trace cache keys on function identity, so lowering
@@ -129,7 +130,7 @@ def step_executor_lowering(runner, n_steps: int = 64, donate: bool = True,
             gpr_l=machine.gpr_l + np.uint32(1),
             icount=machine.icount + np.uint64(7))
         limit = jnp.uint64(12345)
-    run_chunk = make_run_chunk(n_steps, donate=donate, jit=False)
+    run_chunk = make_run_chunk(n_steps, donate=donate, jit=False, tail=tail)
     jitted = jax.jit(run_chunk, donate_argnums=(2,) if donate else ())
     return jitted.lower(tab, runner.physmem.image, machine, limit)
 

@@ -60,7 +60,12 @@ CTR_SWEPT = 3        # lane-steps: every pass of the lane through step_lane,
                      # lanes together); mesh shards exit on their own.
                      # Device passes only: instructions the host oracle
                      # retires count in CTR_INSTR and not here
-N_CTRS = 4
+CTR_TAIL = 4         # the lane-steps of CTR_SWEPT taken in NARROW sweeps
+                     # of the chunk executor (step.make_run_chunk): sweeps
+                     # over fewer than all groups of lanes.  Full-width
+                     # sweeps = (CTR_SWEPT - CTR_TAIL) of any lane.  Added
+                     # once a level, not by step_lane
+N_CTRS = 5
 
 
 class Machine(NamedTuple):
@@ -163,6 +168,34 @@ def lane_view(machine: Machine) -> Machine:
     `jax.vmap(..., in_axes=MACHINE_LANE_AXES)`: the overlay as
     `overlay.lanes()` (whole planes + each lane's base)."""
     return machine._replace(overlay=ovl.lanes(machine.overlay))
+
+
+def _rows(m: Machine) -> Machine:
+    """A machine or a `lane_view` without the overlay's two planes: its
+    lane-axis leaves alone, as `jax.tree.map` sees them."""
+    return m._replace(overlay=m.overlay._replace(data=None, valid=None))
+
+
+def map_rows(fn, view: Machine, *more: Machine) -> Machine:
+    """`fn` over every lane-axis leaf of a `lane_view` (each lane's `base`
+    among them) and the same leaf of `more`; the overlay's two planes pass
+    through from `view` untouched."""
+    out = jax.tree.map(fn, _rows(view), *map(_rows, more))
+    return out._replace(overlay=out.overlay._replace(
+        data=view.overlay.data, valid=view.overlay.valid))
+
+
+def put_lanes(machine: Machine, lanes, view: Machine) -> Machine:
+    """Back from a `lane_view` whose row `k` is lane `lanes[k]` (distinct):
+    the rows scattered to their lanes of `machine`, and the planes as the
+    view holds them."""
+    ov = ovl.resident(view.overlay)
+    out = jax.tree.map(
+        lambda full, rows: full.at[lanes].set(
+            rows, mode="promise_in_bounds", unique_indices=True),
+        _rows(machine), _rows(view._replace(overlay=ov)))
+    return out._replace(
+        overlay=out.overlay._replace(data=ov.data, valid=ov.valid))
 
 
 def _fpst_f64_bits(v: int) -> int:

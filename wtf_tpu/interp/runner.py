@@ -44,7 +44,7 @@ from wtf_tpu.cpu.interrupts import (
 )
 from wtf_tpu.interp import limbs
 from wtf_tpu.interp.machine import (
-    CTR_DECODE_MISS, CTR_INSTR, CTR_MEM_FAULT, CTR_SWEPT, Machine,
+    CTR_DECODE_MISS, CTR_INSTR, CTR_MEM_FAULT, CTR_SWEPT, CTR_TAIL, Machine,
     machine_init, machine_restore,
 )
 from wtf_tpu.interp.step import fp_on_host, make_run_chunk
@@ -1689,12 +1689,16 @@ class Runner:
         reg.counter("device.mem_faults").inc(int(totals[CTR_MEM_FAULT]))
         reg.counter("device.decode_misses").inc(int(totals[CTR_DECODE_MISS]))
         # lane-steps (every lane's passes through the step, live or not)
-        # and sweeps = lane-steps per lane: the loop iterations of one
-        # device (whole there), the mean over shards on a mesh.  With
+        # and sweeps = lane-steps per lane: FULL-WIDTH EQUIVALENTS.  The
+        # chunk executor (step.make_run_chunk) sweeps fewer groups of
+        # lanes as fewer run, and tail_lane_steps are the lane-steps of
+        # those narrow sweeps; full-width loop iterations =
+        # (lane_steps - tail_lane_steps) / lanes (per shard on a mesh).  With
         # device.instructions: lane occupancy; with runner.chunks and
         # execute/device-step: sweeps per chunk and device time per sweep.
         lane_steps = int(totals[CTR_SWEPT])
         reg.counter("device.lane_steps").inc(lane_steps)
+        reg.counter("device.tail_lane_steps").inc(int(totals[CTR_TAIL]))
         sweeps, rest = divmod(lane_steps, self.n_lanes)
         reg.counter("device.sweeps").inc(
             lane_steps / self.n_lanes if rest else sweeps)

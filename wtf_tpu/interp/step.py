@@ -53,7 +53,9 @@ from wtf_tpu.cpu import uops as U
 from wtf_tpu.cpu.emu import MSR_ATTR
 from wtf_tpu.cpu.cpuid import CPUID_TABLE, MAX_BASIC_LEAF
 from wtf_tpu.interp import limbs as L
-from wtf_tpu.interp.machine import MACHINE_LANE_AXES, Machine, lane_view
+from wtf_tpu.interp.machine import (
+    CTR_TAIL, MACHINE_LANE_AXES, Machine, lane_view, map_rows, put_lanes,
+)
 from wtf_tpu.interp.uoptable import (
     F_A32,
     F_BASE_REG, F_COND, F_DST_KIND, F_DST_REG, F_IDX_REG, F_LENGTH, F_LOCK,
@@ -2271,9 +2273,10 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     # in-graph every step, folded into host metrics once per burst — the
     # per-step host sync this exists to avoid.  page_fault/miss already
     # imply `enabled`, commit implies `live`.  CTR_SWEPT counts the pass
-    # itself, whatever the lane's status.
+    # itself, whatever the lane's status; CTR_TAIL is the chunk loop's.
     new_ctr = st.ctr + jnp.stack(
-        [commit, page_fault, miss, jnp.bool_(True)]).astype(jnp.uint32)
+        [commit, page_fault, miss, jnp.bool_(True),
+         jnp.bool_(False)]).astype(jnp.uint32)
     timed = commit & (limit > _u(0)) & (new_icount >= limit)
     new_rdrand = jnp.where(commit & is_(U.OPC_RDRAND), rdrand_next, st.rdrand)
     new_bp_skip = jnp.where(commit, jnp.int32(0), st.bp_skip)
@@ -2337,36 +2340,79 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
 # chunked batch run
 # ---------------------------------------------------------------------------
 
-def step_lanes(tab: UopTable, image: MemImage, machine: Machine, limit,
-               fp_host: bool = None) -> Machine:
-    """ONE sweep: `step_lane` over every lane, then every lane's store in
-    one flat scatter per overlay plane.  The planes ride through the vmap
-    unmapped (each lane gathers from the resident buffer), so inside a
-    while loop the carry is updated in place and no operation reads or
-    writes a whole plane (mem/overlay.py, "resident layout").  `image` is
-    a `lane_image`."""
+def _sweep(tab: UopTable, image: MemImage, view: Machine, limit,
+           fp_host: bool = None) -> Machine:
+    """ONE sweep over the lanes of a `lane_view` (all of them, or one
+    group of its rows): `step_lane` over every lane in hand, then every
+    lane's store in one flat scatter per overlay plane.  The planes ride
+    through the vmap unmapped (each lane gathers from the resident buffer
+    at its own `base`), so inside a while loop the carry is updated in
+    place and no operation reads or writes a whole plane (mem/overlay.py,
+    "resident layout").  `image` is a `lane_image` of the same lanes."""
     m, store = jax.vmap(
         partial(step_lane, fp_host=fp_host),
         in_axes=(None, IMAGE_IN_AXES, MACHINE_LANE_AXES, None),
         out_axes=(MACHINE_LANE_AXES, 0),
-    )(tab, image, lane_view(machine), limit)
-    return m._replace(overlay=commit_stores(resident(m.overlay), store))
+    )(tab, image, view, limit)
+    return m._replace(overlay=commit_stores(m.overlay, store))
+
+
+def step_lanes(tab: UopTable, image: MemImage, machine: Machine, limit,
+               fp_host: bool = None) -> Machine:
+    """ONE sweep of every lane of a resident machine (`_sweep`)."""
+    m = _sweep(tab, image, lane_view(machine), limit, fp_host)
+    return m._replace(overlay=resident(m.overlay))
+
+
+# The chunk executor's narrow sweeps (make_run_chunk): a chunk sweeps its
+# lanes in TAIL_SHARE groups and drops a group whenever the lanes still
+# RUNNING fit in one fewer.  What a sweep costs is linear in the lanes it
+# takes (22.4 ms at 4096, 5.4 at 1024, 1.6 at 256: PERF.md section 5), so
+# narrower groups track the RUNNING lanes closer, at 6.4 us a lane where
+# groups of 1024 pay 5.3 and at one sort of the rows per level: sixteen
+# groups beat four on both of the benchmark's cells (PERF.md section 6,
+# PR 31, call c4); below TAIL_MIN_LANES a sweep is too cheap to split.
+TAIL_MIN_LANES = 1024
+TAIL_SHARE = 16
+
+
+def tail_width(n_lanes: int) -> int:
+    """Lanes in one group of the chunk executor's sweeps at `n_lanes` (a
+    device's, or a mesh shard's); 0: no groups, every sweep full width."""
+    return n_lanes // TAIL_SHARE if n_lanes >= TAIL_MIN_LANES else 0
 
 
 _CHUNK_CACHE: dict = {}
 
 
 def make_run_chunk(n_steps: int, donate: bool = None, jit: bool = True,
-                   fp_host: bool = None):
+                   fp_host: bool = None, tail: int = None):
     """Build (or fetch) the jitted chunk executor: up to n_steps vmapped
     transitions with early exit when no lane is RUNNING.  The host runner
     (interp/runner.py) calls this in a loop, servicing lane statuses between
     chunks — the batched analog of the reference's vmexit servicing
     (kvm_backend.cc:1371-1566).
 
-    Memoized per (n_steps, donate) so every Runner with the same chunk size
-    shares one jit cache entry (XLA recompiles only on new array *shapes*,
-    not per Runner instance).
+    From TAIL_MIN_LANES lanes up the sweeps NARROW with the lanes still
+    running: the rows are held as `groups` groups of `tail_width(lanes)`
+    lanes, one sweep is `_sweep` over the first `n_active` groups in turn
+    (ONE body of the step, at the group's width: a sweep is priced by the
+    lane, PERF.md section 5), and a level sweeps `n_active` groups while
+    more lanes are RUNNING than `n_active - 1` groups hold.  Entering a
+    level sorts the rows, RUNNING lanes to the front (a stable argsort,
+    each row keeping its lane's `base` into the overlay's planes, which
+    are neither gathered nor moved); nothing on the device sets a lane
+    RUNNING, so the rows behind can go unswept, and at the end the rows
+    are scattered back to their lanes.  `step_lane` is per lane and passes
+    a parked lane through, so every leaf comes out as full-width sweeps
+    leave it but `ctr[:, CTR_SWEPT]` (fewer passes) and `CTR_TAIL` (the
+    passes a lane took in sweeps of fewer than all groups).  `tail`
+    overrides the width (tests; 0: every sweep at full width, which is
+    the whole program below TAIL_MIN_LANES).
+
+    Memoized per (n_steps, donate, fp_host, tail) so every Runner with the
+    same chunk size shares one jit cache entry (XLA recompiles only on
+    new array *shapes*, not per Runner instance).
 
     donate=True (the runner's hot path): the machine argument is donated so
     the dominant buffers (overlay data, cov/edge bitmaps) update in place
@@ -2396,31 +2442,88 @@ def make_run_chunk(n_steps: int, donate: bool = None, jit: bool = True,
         donate = jax.default_backend() != "cpu"
     if fp_host is None:
         fp_host = fp_on_host()
-    key = (n_steps, donate, fp_host)
+    key = (n_steps, donate, fp_host, tail)
     if jit:
         cached = _CHUNK_CACHE.get(key)
         if cached is not None:
             return cached
 
-    step_v = partial(step_lanes, fp_host=fp_host)
-
     def run_chunk(tab: UopTable, image: MemImage, machine: Machine, limit):
+        n_lanes = machine.status.shape[0]
+        width = tail_width(n_lanes) if tail is None else tail
+        running = jnp.int32(int(StatusCode.RUNNING))
         # normalize in-body: the per-lane tenant selector is always
         # populated past this point (zeros for single-image callers), so
         # one vmap structure serves both dispatch shapes
-        image = lane_image(image, machine.status.shape[0])
+        image = lane_image(image, n_lanes)
 
-        def cond(carry):
-            i, m = carry
-            return (i < n_steps) & jnp.any(
-                m.status == jnp.int32(int(StatusCode.RUNNING)))
+        if not width:
+            def cond(carry):
+                i, m = carry
+                return (i < n_steps) & jnp.any(m.status == running)
 
-        def body(carry):
-            i, m = carry
-            return i + 1, step_v(tab, image, m, limit)
+            def body(carry):
+                i, m = carry
+                return i + 1, step_lanes(tab, image, m, limit, fp_host)
 
-        _, out = lax.while_loop(cond, body, (jnp.int32(0), machine))
-        return out
+            _, out = lax.while_loop(cond, body, (jnp.int32(0), machine))
+            return out
+
+        assert n_lanes % width == 0, (n_lanes, width)
+        groups = n_lanes // width
+
+        def sweep_groups(n_active, tenant, view):
+            """One sweep of the first `n_active` groups of `width` rows."""
+            def one(g, view):
+                at = g * width
+                rows = map_rows(
+                    lambda x: lax.dynamic_slice_in_dim(x, at, width), view)
+                rows = _sweep(
+                    tab, image._replace(
+                        tenant=lax.dynamic_slice_in_dim(tenant, at, width)),
+                    rows, limit, fp_host)
+                return map_rows(
+                    lambda part, full: lax.dynamic_update_slice_in_dim(
+                        full, part, at, 0), rows, view)
+
+            return lax.fori_loop(0, n_active, one, view)
+
+        def level(e, carry):
+            """Sweeps at `groups - e` groups, while more lanes run than
+            one group fewer would hold.  The rows are sorted first,
+            RUNNING lanes to the front (nothing on the device sets a lane
+            RUNNING, so those are the only ones that will run), which is
+            what lets the rows behind go unswept: step_lane passes a
+            parked lane through."""
+            i, lanes, tenant, view = carry
+            order = jnp.argsort(view.status != running,
+                                stable=True).astype(jnp.int32)
+            lanes, tenant = lanes[order], tenant[order]
+            view = map_rows(
+                lambda x: x.at[order].get(mode="promise_in_bounds",
+                                          unique_indices=True), view)
+            n_active = groups - e
+            floor = (n_active - 1) * width
+
+            def cond(c):
+                j, v = c
+                return (j < n_steps) & (jnp.sum(v.status == running) > floor)
+
+            def body(c):
+                j, v = c
+                return j + 1, sweep_groups(n_active, tenant, v)
+
+            j, view = lax.while_loop(cond, body, (i, view))
+            narrow = (jnp.arange(n_lanes) < n_active * width) & (e > 0)
+            view = view._replace(ctr=view.ctr.at[:, CTR_TAIL].add(
+                jnp.where(narrow, j - i, 0).astype(jnp.uint32)))
+            return j, lanes, tenant, view
+
+        _, lanes, _, view = lax.fori_loop(
+            0, groups, level,
+            (jnp.int32(0), jnp.arange(n_lanes, dtype=jnp.int32),
+             image.tenant, lane_view(machine)))
+        return put_lanes(machine, lanes, view)
 
     if not jit:
         return run_chunk
