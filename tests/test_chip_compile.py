@@ -6,7 +6,10 @@ what it guards is every later PR against the refusals PR 22 found (the
 f64 -> u64 bitcast in the step, a u64 psum on the mesh).
 
 Shapes are chip_smoke.py's: demo_tlv, 1024 lanes, the campaign's default
-runner (256-step base chunk, 128 overlay slots).  Compile time is set by
+runner (256-step base chunk, 128 overlay slots); the chunk program takes
+its image operand at the shapes of a 4-GiB full dump (demo_tlv_dump, the
+`big-image` cell's: 1,052,672 page rows), so that what is pinned is the
+program a deployment runs.  Compile time is set by
 the step body (~75-90 s per program that contains it, whatever its
 shape), so ONE tier-1 case contains `step_lane`; the window, the
 four-chip mesh chunk and the four-chip mesh window carry the `slow` mark
@@ -49,11 +52,12 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture
+@pytest.fixture(scope="module")
 def compile_for():
     """compile_for(fn, args, shardings, **jit_kwargs): `fn` jitted and
-    compiled on the shapes of `args` placed by `shardings` (one sharding
-    for every leaf, or a pytree prefix of `args`), cache off around it."""
+    compiled on the shapes of `args` (arrays, or shapes alone) placed by
+    `shardings` (one sharding for every leaf, or a pytree prefix of
+    `args`), cache off around this file's tests."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     def run(fn, args, shardings, **jit_kwargs):
@@ -167,7 +171,41 @@ def sweeping_loops(hlo_text):
             if any("overlay-store" in line for line in _reached(comps, body))]
 
 
-def test_chunk_program_compiles_for_v5e(loop, one_chip, compile_for):
+def dump_image_shapes():
+    """The image operand of a campaign on demo_tlv_dump at the
+    configuration's 4 GiB, as shapes: nothing of that size is built."""
+    from wtf_tpu.harness import demo_tlv_dump
+    from wtf_tpu.mem import physmem
+    from wtf_tpu.snapshot.synthetic import RamLayout
+
+    layout = RamLayout.of_bytes(demo_tlv_dump.RAM_BYTES)
+    return physmem.MemImage(
+        pages=jax.ShapeDtypeStruct(
+            (physmem.padded(layout.frames + 1, physmem.ROW_GRANULE),
+             2 * physmem.PAGE_WORDS), jnp.uint32),
+        frame_table=jax.ShapeDtypeStruct(
+            (1, physmem.padded(layout.span, physmem.SPAN_GRANULE)),
+            jnp.int32))
+
+
+@pytest.fixture(scope="module")
+def chunk_program(loop, one_chip, compile_for):
+    """(compiled, operands) of the step ladder's chunk program, as the
+    chip traces and donates it, over a 4-GiB dump's image: ONE compile of
+    the step for the tests that pin it."""
+    from wtf_tpu.interp.step import make_run_chunk
+
+    runner = loop.backend.runner
+    tab, _, machine, limit = runner.executor_operands()
+    operands = (tab, dump_image_shapes(), machine, limit)
+    compiled = compile_for(
+        make_run_chunk(runner.chunk_steps, donate=False, jit=False,
+                       fp_host=True),
+        operands, one_chip, donate_argnums=(2,))
+    return compiled, operands
+
+
+def test_chunk_program_compiles_for_v5e(chunk_program):
     """The step ladder's chunk program, as the chip traces and donates it
     (float-valued uops hosted: fp_host=True; the machine donated; at
     1024 lanes the sweeps go over groups of 64 lanes, sixteen down to one),
@@ -182,14 +220,8 @@ def test_chunk_program_compiles_for_v5e(loop, one_chip, compile_for):
     one data plane (PR 26's parent held the relayouted planes and the u64
     plane's two u32 halves there)."""
     from wtf_tpu.interp import step
-    from wtf_tpu.interp.step import make_run_chunk
 
-    runner = loop.backend.runner
-    operands = runner.executor_operands()
-    compiled = compile_for(
-        make_run_chunk(runner.chunk_steps, donate=False, jit=False,
-                       fp_host=True),
-        operands, one_chip, donate_argnums=(2,))
+    compiled, operands = chunk_program
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < HBM_BYTES, mem
@@ -211,6 +243,27 @@ def test_chunk_program_compiles_for_v5e(loop, one_chip, compile_for):
     machine_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(operands[2]))
     assert mem.alias_size_in_bytes >= machine_bytes, mem
     assert mem.temp_size_in_bytes < ov.data.nbytes, mem
+
+
+def test_chunk_program_makes_nothing_as_large_as_the_image(chunk_program):
+    """The image is gathered from and never copied: over a 4-GiB dump's
+    image (4.3 GB of page rows) no instruction of the chunk program, inside
+    its loops or before them, has a result as large as the page rows (a
+    copy, a convert or a relayout of them would be one; PR 34's parent
+    split the uint64 rows into two uint32 planes at the head of every
+    dispatch, 4.4 GB of temporaries), the argument is counted once, and
+    the program's temporaries stay under a twentieth of it."""
+    compiled, operands = chunk_program
+    pages = operands[1].pages
+    image_bytes = int(np.prod(pages.shape)) * 4
+    assert image_bytes > 4 * 2 ** 30
+    text = compiled.as_text()
+    assert "image_pages" in text        # the operand is there by name
+    found = plane_sized_results(text, int(np.prod(pages.shape)) // 2)
+    assert not found, "\n".join(found)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < image_bytes // 20, mem
+    assert mem.argument_size_in_bytes < image_bytes + HBM_BYTES // 8, mem
 
 
 def plane_sized_results(hlo_text, n_elems):

@@ -432,6 +432,16 @@ def summarize(path) -> dict:
                 if metrics.get("device.tail_lane_steps") is not None
                 and metrics.get("device.lane_steps") else None),
         },
+        # the snapshot's packed image (gauges set once at snapshot-load;
+        # its child spans are under nested_phases) and the host reads
+        # that had to fetch image bytes from the device: 0 by design
+        "image": ({
+            "bytes": metrics["image.bytes"],
+            "frames_present": metrics.get("image.frames_present"),
+            "span_frames": metrics.get("image.span_frames"),
+            "rows": metrics.get("image.rows"),
+            "device_pulls": metrics.get("physmem.device_pulls", 0),
+        } if metrics.get("image.bytes") is not None else None),
         "mesh": mesh,
         "triage": triage,
         "tenants": tenants,
@@ -509,6 +519,12 @@ def _print_human(s: dict) -> None:
     if dev.get("tail_share"):
         print(f"  tail: {dev['tail_share']:.2%} of lane-steps were taken "
               "in the chunk loop's narrow tail")
+    image = s.get("image")
+    if image:
+        print(f"image: {image['bytes']} bytes, {image['frames_present']} "
+              f"frames present under a span of {image['span_frames']}, "
+              f"{image['rows']} page rows; device pulls: "
+              f"{image['device_pulls']}")
     mesh = s.get("mesh")
     if mesh:
         print(f"mesh: {mesh['devices']} devices x "

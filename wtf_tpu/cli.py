@@ -562,15 +562,31 @@ def _build_backend(target, backend_name: str, paths: TargetPaths,
     from wtf_tpu.snapshot.loader import load_snapshot
 
     registry = registry if registry is not None else Registry()
-    with registry.spans.span("snapshot-load"):
+    spans = registry.spans
+    with spans.span("snapshot-load"):
         if paths.state and Path(paths.state).exists():
-            snapshot = load_snapshot(paths.state)
+            snapshot = load_snapshot(paths.state, spans=spans)
         elif target.snapshot is not None:
-            snapshot = target.snapshot()
+            # a target's own factory fills the packed image in place:
+            # there is no `pack` beside it
+            with spans.span("synthesize"):
+                snapshot = target.snapshot()
         else:
             raise SystemExit(
                 f"target {target.name!r} has no snapshot factory and no "
                 f"--state dir was given")
+        physmem = snapshot.physmem
+        physmem.pull_counter = registry.counter("physmem.device_pulls")
+        if backend_name == "tpu":
+            # the oracle backend reads the host arrays and never builds
+            # the device image
+            with spans.span("upload") as sp:
+                sp.fence(physmem.image)
+        for name, value in (("bytes", physmem.nbytes),
+                            ("frames_present", physmem.frames_present),
+                            ("span_frames", len(physmem.table)),
+                            ("rows", len(physmem.rows))):
+            registry.gauge(f"image.{name}").set(value)
     # engine tuning (--burst-any-tier etc.) applies to the batched
     # tpu backend only; the oracle backend has no runner underneath
     kwargs = ({"n_lanes": lanes, **(tuning or {})}
@@ -1291,8 +1307,6 @@ def cmd_snapshot(args) -> int:
     external scripts.  npz <-> Windows crash dump both ways."""
     import json
 
-    import numpy as np
-
     from wtf_tpu.snapshot.kdmp import write_kdmp
     from wtf_tpu.snapshot.loader import dump_cpu_state_json, load_snapshot
 
@@ -1301,17 +1315,13 @@ def cmd_snapshot(args) -> int:
     if args.format == "npz":
         snap.save_raw(args.out)
     else:
-        table = np.asarray(snap.physmem.image.frame_table)[0]
-        page_data = np.asarray(snap.physmem.image.pages).view(np.uint8)
-        pages = {int(pfn): page_data[int(table[pfn])].tobytes()
-                 for pfn in np.nonzero(table)[0]}
-        write_kdmp(args.out / "mem.dmp", pages,
+        write_kdmp(args.out / "mem.dmp", snap.pages_dict(),
                    dump_type="bmp" if args.format == "dmp-bmp" else "full",
                    dtb=snap.cpu.cr3, cpu=snap.cpu)
         (args.out / "regs.json").write_text(dump_cpu_state_json(snap.cpu))
         (args.out / "symbol-store.json").write_text(json.dumps(
             {k: hex(v) for k, v in snap.symbols.items()}, indent=1))
-    n_pages = int((np.asarray(snap.physmem.image.frame_table) != 0).sum())
+    n_pages = snap.physmem.frames_present
     print(f"wrote {args.format} snapshot ({n_pages} pages) to {args.out}")
     return 0
 

@@ -83,10 +83,17 @@ _GUEST_CODE = bytes.fromhex(
 )
 
 
-def build_snapshot() -> Snapshot:
-    """Synthesize the snapshot: parser entered as if just called, return
-    address pointing at FINISH_GVA (so `ret` = end of testcase)."""
-    b = SyntheticSnapshotBuilder()
+SYMBOLS = {
+    "tlv!parse": CODE_GVA,
+    "tlv!finish": FINISH_GVA,
+}
+
+
+def lay_out(b: SyntheticSnapshotBuilder):
+    """The guest in `b`'s frames -> (pages, cpu): parser entered as if just
+    called, return address pointing at FINISH_GVA (so `ret` = end of
+    testcase).  Shared with demo_tlv_dump, which places the same frames
+    inside a full dump."""
     b.write(CODE_GVA, _GUEST_CODE)
     b.write(FINISH_GVA, b"\x90\xf4")          # nop; hlt (never reached: bp)
     b.map(INPUT_GVA, MAX_INPUT)
@@ -98,11 +105,12 @@ def build_snapshot() -> Snapshot:
     cpu.rsi = INPUT_GVA
     cpu.rdx = 0
     cpu.r15 = SCRATCH_GVA
-    return Snapshot.from_pages(
-        pages, cpu, symbols={
-            "tlv!parse": CODE_GVA,
-            "tlv!finish": FINISH_GVA,
-        })
+    return pages, cpu
+
+
+def build_snapshot() -> Snapshot:
+    pages, cpu = lay_out(SyntheticSnapshotBuilder())
+    return Snapshot.from_pages(pages, cpu, symbols=dict(SYMBOLS))
 
 
 def _init(backend) -> bool:

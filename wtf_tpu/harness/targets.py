@@ -112,5 +112,5 @@ def load_builtin_targets() -> None:
     equivalent is importing the harness modules)."""
     from wtf_tpu.harness import (  # noqa: F401
         demo_fs, demo_ioctl, demo_kernel, demo_maze, demo_pe, demo_spin,
-        demo_tlv, demo_usermode,
+        demo_tlv, demo_tlv_dump, demo_usermode,
     )

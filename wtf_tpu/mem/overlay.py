@@ -350,10 +350,17 @@ def read_words_vec(
 ) -> jax.Array:
     """K overlay-aware aligned words in three gathers (image + overlay
     data + overlay word-validity)."""
-    base = image.pages[slot_vec, widx_vec]
+    base = image_words(image, slot_vec, widx_vec)
     at = overlay.base + row_vec * PAGE_WORDS + widx_vec
     ov_valid = overlay.valid[at] != 0
     return jnp.where(use_ov_vec & ov_valid, read_data(overlay, at), base)
+
+
+def image_words(image: MemImage, slot: jax.Array, widx: jax.Array
+                ) -> jax.Array:
+    """The u64 words `widx` of the base image's page rows `slot` (any
+    shape): one gather of their limb pairs."""
+    return pack_u64(image.pages[slot[..., None], _limb_index(widx)])
 
 
 def read_data(overlay: LaneOverlay, at: jax.Array) -> jax.Array:
@@ -531,7 +538,7 @@ def store_window3(
         at = overlay.base + row * PAGE_WORDS + widx
         was_valid = overlay.valid[at] != 0
         old = jnp.where(was_valid, read_data(overlay, at),
-                        image.pages[slot, widx])
+                        image_words(image, slot, widx))
         touched = do & (mask != _u(0))
         new = jnp.where(touched, (old & ~mask) | (vj & mask), old)
         ats.append(at)
@@ -633,7 +640,7 @@ def scatter_span(
         at = overlay.base + row * PAGE_WORDS + widx
         was_valid = overlay.valid[at] != 0
         old = jnp.where(was_valid, read_data(overlay, at),
-                        image.pages[slot, widx])
+                        image_words(image, slot, widx))
         touched = do & (mask != 0)
         ats.append(at)
         news.append(jnp.where(touched,

@@ -31,10 +31,11 @@ from __future__ import annotations
 
 import ctypes
 import dataclasses
-import mmap
 import struct
 from pathlib import Path
 from typing import Dict, Optional
+
+import numpy as np
 
 PAGE_SIZE = 0x1000
 
@@ -157,7 +158,9 @@ def _parse_native(path: Path):
             n_pages=n,
             context_raw=bytes(ctx),
         )
-        index = [(int(pfns[i]), int(offsets[i])) for i in range(n)]
+        index = np.stack([np.ctypeslib.as_array(pfns),
+                          np.ctypeslib.as_array(offsets)],
+                         axis=1).astype(np.int64)
         return info, index
     finally:
         lib.wtf_kdmp_close(handle)
@@ -188,20 +191,18 @@ def _parse_python_inner(data) -> tuple:
     if u32(0) != SIG_PAGE or u32(4) != SIG_DU64:
         raise KdmpError("bad signature (not a 64-bit kernel crash dump)")
     dump_type = u32(_OFF_DUMPTYPE)
-    index = []
     if dump_type == FULL_DUMP:
         nruns = u32(_OFF_PHYSMEM_DESC)
         if nruns == SIG_PAGE or nruns > 4096:
             raise KdmpError("invalid physmem descriptor")
-        file_off = _OFF_DATA
-        for i in range(nruns):
-            base = u64(_OFF_PHYSMEM_RUNS + i * 16)
-            count = u64(_OFF_PHYSMEM_RUNS + i * 16 + 8)
-            for p in range(count):
-                if file_off + PAGE_SIZE > len(data):
-                    raise KdmpError("truncated full dump")
-                index.append((base + p, file_off))
-                file_off += PAGE_SIZE
+        runs = [(u64(_OFF_PHYSMEM_RUNS + i * 16),
+                 u64(_OFF_PHYSMEM_RUNS + i * 16 + 8)) for i in range(nruns)]
+        if _OFF_DATA + sum(c for _, c in runs) * PAGE_SIZE > len(data):
+            raise KdmpError("truncated full dump")
+        pfns = np.concatenate(
+            [np.arange(base, base + count, dtype=np.int64)
+             for base, count in runs] or [np.zeros(0, np.int64)])
+        first_page = _OFF_DATA
     elif dump_type == BMP_DUMP_TYPE:
         sig = u32(_OFF_DATA)
         if sig not in (BMP_SDMP, BMP_FDMP) or u32(_OFF_DATA + 4) != BMP_DUMP:
@@ -212,21 +213,15 @@ def _parse_python_inner(data) -> tuple:
         bitmap_off = _OFF_DATA + 0x38
         if bitmap_off + bitmap_pages // 8 > len(data):
             raise KdmpError("bitmap extends past end of file")
-        file_off = first_page
-        for byte_idx in range(bitmap_pages // 8):
-            byte = data[bitmap_off + byte_idx]
-            if not byte:
-                continue
-            for bit in range(8):
-                if not (byte >> bit) & 1:
-                    continue
-                if file_off + PAGE_SIZE > len(data):
-                    raise KdmpError("truncated BMP dump")
-                index.append((byte_idx * 8 + bit, file_off))
-                file_off += PAGE_SIZE
-        if len(index) != total_present:
+        bitmap = np.frombuffer(data, dtype=np.uint8,
+                               count=bitmap_pages // 8, offset=bitmap_off)
+        pfns = np.nonzero(np.unpackbits(bitmap, bitorder="little"))[0] \
+            .astype(np.int64)
+        if first_page + len(pfns) * PAGE_SIZE > len(data):
+            raise KdmpError("truncated BMP dump")
+        if len(pfns) != total_present:
             raise KdmpError(
-                f"bitmap/total mismatch ({len(index)} != {total_present})")
+                f"bitmap/total mismatch ({len(pfns)} != {total_present})")
     elif dump_type == KERNEL_DUMP:
         raise KdmpError("partial kernel dumps are not supported "
                         "(use full or active/BMP dumps, as the reference)")
@@ -236,10 +231,12 @@ def _parse_python_inner(data) -> tuple:
         dump_type=dump_type,
         dtb=u64(_OFF_DTB),
         bugcheck_code=u32(_OFF_BUGCHECK),
-        n_pages=len(index),
+        n_pages=len(pfns),
         context_raw=bytes(data[_OFF_CONTEXT:_OFF_CONTEXT + _CTX_SIZE]),
     )
-    return info, index
+    # both formats pack the pages back to back from their first page
+    offsets = first_page + PAGE_SIZE * np.arange(len(pfns), dtype=np.int64)
+    return info, np.stack([pfns, offsets], axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -251,28 +248,42 @@ def parse_kdmp_info(path) -> KdmpInfo:
     return _parse(Path(path))[0]
 
 
-def parse_kdmp(path) -> Dict[int, bytes]:
-    """Parse a dump into {pfn: 4KiB page bytes} (the shape
-    snapshot.loader/PhysMem.from_pages consume).  One mmap serves both the
-    (fallback) header parse and the page slicing."""
+def _map(path: Path) -> np.ndarray:
+    """The file as a read-only uint8 array over an mmap that lives as long
+    as any view of it."""
+    try:
+        return np.memmap(path, dtype=np.uint8, mode="r")
+    except ValueError as e:     # an empty file cannot be mapped
+        raise KdmpError(f"file too small for a 64-bit dump header: {e}") \
+            from e
+
+
+def parse_kdmp_arrays(path):
+    """The dump's pages as arrays, `(pfns int64[n], pages uint8[n, 4096])`,
+    for `PhysMem.from_arrays`: full and BMP dumps pack their pages back to
+    back, so `pages` is ONE view over the mapped file and no page is a
+    Python object."""
     path = Path(path)
-    native = _parse_native(path)
-    pages: Dict[int, bytes] = {}
-    with open(path, "rb") as f:
-        with mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ) as m:
-            _, index = native if native is not None else _parse_python(m)
-            for pfn, off in index:
-                pages[pfn] = bytes(m[off:off + PAGE_SIZE])
-    return pages
+    data = _map(path)
+    _, index = _parse_native(path) or _parse_python(data)
+    pfns, offsets = index[:, 0], index[:, 1]
+    n = len(pfns)
+    first = int(offsets[0]) if n else _OFF_DATA
+    if np.any(offsets != first + PAGE_SIZE * np.arange(n)) \
+            or first + n * PAGE_SIZE > len(data):
+        raise KdmpError("page data is not packed back to back")
+    return pfns, data[first:first + n * PAGE_SIZE].reshape(n, PAGE_SIZE)
+
+
+def parse_kdmp(path) -> Dict[int, bytes]:
+    """Parse a dump into {pfn: 4KiB page bytes}: for tests and tools that
+    want a page a Python object (the loader takes `parse_kdmp_arrays`)."""
+    pfns, pages = parse_kdmp_arrays(path)
+    return {int(pfn): page.tobytes() for pfn, page in zip(pfns, pages)}
 
 
 def _parse(path: Path):
-    native = _parse_native(path)
-    if native is not None:
-        return native
-    with open(path, "rb") as f:
-        with mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ) as m:
-            return _parse_python(m)
+    return _parse_native(path) or _parse_python(_map(path))
 
 
 # ---------------------------------------------------------------------------

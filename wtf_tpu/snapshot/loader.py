@@ -14,6 +14,7 @@ into a `Snapshot{PhysMem, CpuState, symbols}` ready for device upload.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 from pathlib import Path
@@ -38,19 +39,23 @@ class Snapshot:
     ) -> "Snapshot":
         return cls(physmem=PhysMem.from_pages(pages), cpu=cpu, symbols=symbols or {})
 
+    def pages_dict(self) -> Dict[int, bytes]:
+        """{pfn: 4KiB page bytes} of the present frames: for writers and
+        tests of small images (a page a Python object)."""
+        flat = self.physmem.rows.view(np.uint8)
+        table = self.physmem.table
+        return {int(pfn): flat[table[pfn]].tobytes()
+                for pfn in self.physmem.present_pfns()}
+
     def save_raw(self, state_dir) -> None:
         """Persist in the raw format (mem.npz + regs.json + symbol-store.json)."""
         state_dir = Path(state_dir)
         state_dir.mkdir(parents=True, exist_ok=True)
-        image = self.physmem
-        pages_np = np.asarray(image.image.pages)
-        table_np = np.asarray(image.image.frame_table)[0]
-        pfns = np.nonzero(table_np)[0]
-        slots = table_np[pfns]
+        pfns = self.physmem.present_pfns()
         np.savez_compressed(
             state_dir / "mem.npz",
             pfns=pfns.astype(np.int64),
-            pages=pages_np[slots],
+            pages=self.physmem.rows[self.physmem.table[pfns]],
         )
         (state_dir / "regs.json").write_text(dump_cpu_state_json(self.cpu))
         (state_dir / "symbol-store.json").write_text(
@@ -58,9 +63,14 @@ class Snapshot:
         )
 
 
-def load_snapshot(state_dir, sanitize: bool = True) -> Snapshot:
+def load_snapshot(state_dir, sanitize: bool = True, spans=None) -> Snapshot:
     """Load a snapshot directory (reference startup path wtf.cc:378-465:
-    LoadCpuStateFromJSON -> backend init -> SanitizeCpuState)."""
+    LoadCpuStateFromJSON -> backend init -> SanitizeCpuState).  The pages
+    travel as arrays from the file to the packed image; `spans` (a
+    telemetry `Spans`) times reading them (`parse`) and packing them
+    (`pack`) as children of the caller's open span."""
+    span = spans.span if spans is not None else (
+        lambda name: contextlib.nullcontext())
     state_dir = Path(state_dir)
     cpu = load_cpu_state_json(state_dir / "regs.json")
     if sanitize and not sanitize_cpu_state(cpu):
@@ -74,20 +84,21 @@ def load_snapshot(state_dir, sanitize: bool = True) -> Snapshot:
 
     npz_path = state_dir / "mem.npz"
     dmp_path = state_dir / "mem.dmp"
-    if npz_path.exists():
-        data = np.load(npz_path)
-        pages = {
-            int(pfn): bytes(page.tobytes())
-            for pfn, page in zip(data["pfns"], data["pages"])
-        }
-    elif dmp_path.exists():
-        from wtf_tpu.snapshot.kdmp import parse_kdmp
+    with span("parse"):
+        if npz_path.exists():
+            data = np.load(npz_path)
+            pfns, pages = data["pfns"], data["pages"]
+        elif dmp_path.exists():
+            from wtf_tpu.snapshot.kdmp import parse_kdmp_arrays
 
-        pages = parse_kdmp(dmp_path)
-    else:
-        raise FileNotFoundError(f"no mem.npz or mem.dmp under {state_dir}")
-
-    return Snapshot(physmem=PhysMem.from_pages(pages), cpu=cpu, symbols=symbols)
+            # the pages: one view over the mapped file
+            pfns, pages = parse_kdmp_arrays(dmp_path)
+        else:
+            raise FileNotFoundError(
+                f"no mem.npz or mem.dmp under {state_dir}")
+    with span("pack"):
+        physmem = PhysMem.from_arrays(pfns, pages)
+    return Snapshot(physmem=physmem, cpu=cpu, symbols=symbols)
 
 
 def dump_cpu_state_json(cpu: CpuState) -> str:

@@ -1,4 +1,4 @@
-"""Synthetic snapshot builder: tiny long-mode guests for tests and benches.
+"""Synthetic snapshot builder: long-mode guests for tests and benches.
 
 The reference's snapshots are Windows kernel crash-dumps taken with bdump.js
 (reference README.md:168-240); none ship with the tree (targets/ is empty).
@@ -7,11 +7,24 @@ architecturally real snapshots: 4-level page tables, long-mode CpuState, code
 and data mapped at arbitrary GVAs.  The result loads through the same
 `Snapshot` path as a parsed crash-dump, so everything downstream is exercised
 identically.
+
+Two sizes.  `SyntheticSnapshotBuilder` alone makes a guest of tens of
+frames as a dict of pages.  With `RamLayout`, `fill_noise` and
+`direct_map_tables` a target lays such a guest into a Windows-shaped full
+dump (harness/demo_tlv_dump.py): every frame of a PC-style RAM present, the
+guest's frames where a seeded scatter puts them, a supervisor-only direct
+map of RAM in the kernel half, everything else seeded noise, all of it as
+arrays filled in place (no page is a Python object).
 """
 
 from __future__ import annotations
 
-from typing import Dict, Optional
+import dataclasses
+import os
+from concurrent.futures import ThreadPoolExecutor
+from typing import Callable, Dict, Optional
+
+import numpy as np
 
 from wtf_tpu.core.cpustate import (
     CR0_PE,
@@ -27,16 +40,26 @@ from wtf_tpu.core.gxa import PAGE_SHIFT, PAGE_SIZE
 _PTE_P = 1
 _PTE_W = 1 << 1
 _PTE_U = 1 << 2
+_PTE_PS = 1 << 7
+
+KERNEL_BASE = 0xFFFF_8000_0000_0000   # PML4 slot 256: the direct map
+_LARGE = 1 << 21                      # a PDE's reach: 2 MiB, 512 frames
+_MASK64 = (1 << 64) - 1
 
 
 class SyntheticSnapshotBuilder:
     """Builds {pfn: page bytes} + a long-mode CpuState with real page tables.
 
-    Guest-physical layout: page tables from `table_base`, mapped data pages
-    allocated by a bump allocator above them.
+    Guest-physical layout: page tables from `table_base` (an arena of
+    0x100 frames), mapped data pages allocated by a bump allocator above
+    them; or, with `place`, every frame (data and page table alike) where
+    the caller says: `place()` returns the next pfn, and the tables take
+    as many as the address space needs.
     """
 
-    def __init__(self, table_base: int = 0x10000):
+    def __init__(self, table_base: int = 0x10000,
+                 place: Optional[Callable[[], int]] = None):
+        self._place = place
         self._phys: Dict[int, bytearray] = {}
         self._mappings: Dict[int, int] = {}  # gva pfn -> gpa pfn
         self._writable: Dict[int, bool] = {}
@@ -53,8 +76,11 @@ class SyntheticSnapshotBuilder:
         return page
 
     def alloc_phys(self) -> int:
-        pfn = self._next_pfn
-        self._next_pfn += 1
+        if self._place is not None:
+            pfn = self._place()
+        else:
+            pfn = self._next_pfn
+            self._next_pfn += 1
         self._phys_page(pfn)
         return pfn
 
@@ -97,6 +123,8 @@ class SyntheticSnapshotBuilder:
         next_table = [self._table_base >> PAGE_SHIFT]
 
         def alloc_table() -> int:
+            if self._place is not None:
+                return self.alloc_phys()
             pfn = next_table[0]
             next_table[0] += 1
             if pfn >= (self._table_base >> PAGE_SHIFT) + 0x100:
@@ -179,3 +207,120 @@ class SyntheticSnapshotBuilder:
         cpu.ss = Seg(present=True, selector=0x2B, base=0, limit=0xFFFFFFFF, attr=0xCFF3)
         pages = {pfn: bytes(page) for pfn, page in self._phys.items()}
         return pages, cpu
+
+
+# ---------------------------------------------------------------------------
+# a full dump's worth of RAM, as arrays
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class RamLayout:
+    """PC-style guest RAM: `frames` frames, all present, under a pfn span
+    with a hole below the top of low memory (reference ram.h:41: 4 GiB of
+    RAM end at 0x1'08'00'00'00).  RAM [0, lo), hole [lo, frames), RAM
+    [frames, span)."""
+
+    frames: int
+    hole: int
+
+    @classmethod
+    def of_bytes(cls, ram_bytes: int) -> "RamLayout":
+        """The layout of `ram_bytes` of RAM; the hole is 1/32 of it (128
+        MiB of 4 GiB), kept a multiple of 2 MiB."""
+        if ram_bytes <= 0 or ram_bytes % (32 * _LARGE):
+            raise ValueError(f"RAM of {ram_bytes:#x} bytes is not a "
+                             "multiple of 64 MiB")
+        frames = ram_bytes // PAGE_SIZE
+        return cls(frames=frames, hole=frames // 32)
+
+    @property
+    def lo(self) -> int:
+        return self.frames - self.hole
+
+    @property
+    def span(self) -> int:
+        return self.frames + self.hole
+
+    def present_pfns(self) -> np.ndarray:
+        return np.concatenate([np.arange(self.lo, dtype=np.int64),
+                               np.arange(self.frames, self.span,
+                                         dtype=np.int64)])
+
+    def index_of(self, pfns):
+        """Position of present frame(s) among `present_pfns()`."""
+        pfns = np.asarray(pfns, dtype=np.int64)
+        return np.where(pfns < self.lo, pfns, pfns - self.hole)
+
+    def scatter(self, rng: np.random.Generator, count: int) -> np.ndarray:
+        """`count` distinct present frames, none of them frame 0, in the
+        order drawn."""
+        picks = 1 + rng.choice(self.frames - 1, size=count, replace=False)
+        return np.where(picks < self.lo, picks, picks + self.hole)
+
+
+def _mix64(x: int) -> int:
+    x = (x + 0x9E3779B97F4A7C15) & _MASK64
+    x = ((x ^ (x >> 30)) * 0xBF58476D1CE4E5B9) & _MASK64
+    x = ((x ^ (x >> 27)) * 0x94D049BB133111EB) & _MASK64
+    return x ^ (x >> 31)
+
+
+def fill_noise(words: np.ndarray, seed: int, block: int = 1 << 17) -> None:
+    """Seeded content for the cold frames of a dump, written in place
+    into `words` (uint64, contiguous): one block of mixed counters, xored
+    with a key of its own for every block of 1 MiB.  No page is zero and
+    no two are equal, so no packer can elide one; one pass over the
+    memory.  What it costs is the first touch of that memory (a page
+    fault every 4 KiB where the kernel has no huge pages: 11-16 s for 4
+    GiB on the chip machine's host from one thread, PR 34), so a few
+    threads share the blocks; numpy releases the interpreter lock inside
+    the xor, and the result does not depend on who wrote which block."""
+    flat = words.reshape(-1)
+    base = np.arange(block, dtype=np.uint64) * np.uint64(0x9E3779B97F4A7C15)
+    base ^= base >> np.uint64(29)
+    base *= np.uint64(0xBF58476D1CE4E5B9)
+    base ^= base >> np.uint64(32)
+    base |= np.uint64(1)
+
+    def fill(i: int) -> None:
+        out = flat[i * block:(i + 1) * block]
+        np.bitwise_xor(base[:out.size],
+                       np.uint64(_mix64(seed + i) & ~1), out=out)
+
+    n_blocks = -(-flat.size // block)
+    with ThreadPoolExecutor(max_workers=min(8, os.cpu_count() or 1)) as pool:
+        list(pool.map(fill, range(n_blocks), chunksize=64))
+
+
+def direct_map_tables(layout: RamLayout, place: Callable[[], int],
+                      large_share: int = 4):
+    """The kernel half of a dump's address space: RAM mapped 1:1 at
+    KERNEL_BASE + gpa, supervisor-only, the first 1/`large_share` of the
+    2-MiB regions by large pages and the rest through page tables whose
+    frames `place()` gives; the hole is not mapped.  Returns (the PML4
+    entry for slot 256, table pfns int64[k], their pages uint64[k, 512]).
+    """
+    flags = np.uint64(_PTE_P | _PTE_W)
+    n_regions = -(-layout.span * PAGE_SIZE // _LARGE)
+    n_large = n_regions // large_share
+    n_pds = -(-n_regions // 512)
+    pdpt_pfn = place()
+    pd_pfns = [place() for _ in range(n_pds)]
+    pdpt = np.zeros(512, dtype=np.uint64)
+    pdpt[:n_pds] = (np.array(pd_pfns, dtype=np.uint64) << np.uint64(12)) \
+        | flags
+    pds = np.zeros(n_pds * 512, dtype=np.uint64)
+    regions = np.arange(n_regions, dtype=np.int64)
+    first = regions * 512                       # a region's first frame
+    mapped = (first + 512 <= layout.lo) | (first >= layout.frames)
+    large = mapped & (regions < n_large)
+    pds[:n_regions][large] = (first[large].astype(np.uint64)
+                              << np.uint64(12)) | flags | np.uint64(_PTE_PS)
+    small = np.nonzero(mapped & ~large)[0]
+    pt_pfns = np.array([place() for _ in small], dtype=np.int64)
+    pds[small] = (pt_pfns.astype(np.uint64) << np.uint64(12)) | flags
+    pts = ((first[small, None] + np.arange(512)).astype(np.uint64)
+           << np.uint64(12)) | flags
+    pfns = np.concatenate([[pdpt_pfn], pd_pfns, pt_pfns]).astype(np.int64)
+    pages = np.concatenate([pdpt[None, :], pds.reshape(n_pds, 512), pts])
+    return (pdpt_pfn << PAGE_SHIFT) | _PTE_P | _PTE_W, pfns, pages

@@ -48,14 +48,13 @@ def stack_images(physmems: Sequence) -> MemImage:
     if len(physmems) > MAX_TENANTS:
         raise ValueError(f"{len(physmems)} tenants exceed the "
                          f"{MAX_TENANTS} tag-key limit")
-    span = max(pm.image.frame_table.shape[-1] for pm in physmems)
+    span = max(len(pm.table) for pm in physmems)
     tables = np.zeros((len(physmems), span), dtype=np.int32)
     bodies: List[np.ndarray] = []
     cur = 1  # slot 0 stays the shared zero page
     for t, pm in enumerate(physmems):
-        pages_np = np.asarray(pm.image.pages)          # [slots_t, PW]
-        body = pages_np[1:]                            # drop its zero page
-        tbl = np.asarray(pm.image.frame_table)[0]      # [span_t]
+        body = pm.rows[1:]          # [slots_t - 1, PW]: drop its zero page
+        tbl = pm.table              # [span_t]
         tables[t, :tbl.shape[0]] = np.where(tbl != 0, tbl + (cur - 1), 0)
         bodies.append(body)
         cur += body.shape[0]
@@ -65,7 +64,7 @@ def stack_images(physmems: Sequence) -> MemImage:
     for body in bodies:
         stacked[pos:pos + body.shape[0]] = body
         pos += body.shape[0]
-    return MemImage(pages=jnp.asarray(stacked),
+    return MemImage(pages=jnp.asarray(stacked.view(np.uint32)),
                     frame_table=jnp.asarray(tables))
 
 
