@@ -605,6 +605,9 @@ def test_telemetry_report_on_device_campaign(tlv_campaign):
     assert summary["device"]["instructions"] \
         == metrics["device.instructions"] > 0
     assert 0 < summary["device"]["lane_occupancy"] <= 1
+    # the campaign section: a crash's file work is done once a name
+    assert summary["crash_files_written"] == 6
+    assert summary["crash_saves_skipped"] == summary["crashes"] - 6 == 16
 
 
 def test_heartbeats_carry_no_fused_engine_counters(tlv_campaign):
@@ -957,8 +960,15 @@ def test_sweeps_are_whole_and_bound_the_instructions_in_every_record(
 # final line without its clock fields, and a digest over the sorted
 # (path, sha256) listing of outputs/ and crashes/
 _PARENT_LINE = "#64 cov: 51 corp: 17 crash: 30 timeout: 0 cr3: 0"
-_PARENT_FINDS = ("a4c6ca381f017e26b13e6a3ca283eacaec3a4c2645eba5f11daca15ec"
-                 "3cd7785")
+# re-taken on purpose in PR 35: crashes/<name> now holds the FIRST
+# testcase seen under the name where the last writer won (2 of the 19
+# names differ in bytes here, 3 of 6 in the TLV campaign).  What did
+# not move is pinned beside it, taken on PR 35's parent: the names under
+# crashes/ and every (name, sha256) under outputs/.
+_PARENT_FINDS = ("2ed2245a5a3388cef94c21f4ffe3ee1c68bba6c592c50fb150eb7e3b5"
+                 "d0f883d")
+_PARENT_NAMES = ("f96e3e620234cfdd6da397a1e3d4a390734e15bb8c0730af9a766bc64"
+                 "3f3462d")
 
 
 def _final_line(campaign) -> str:
@@ -969,14 +979,19 @@ def _final_line(campaign) -> str:
     return re.sub(r" uptime.*", "", line)
 
 
-def _finds_digest(campaign) -> str:
+def _finds_digest(campaign, crash_bytes: bool = True) -> str:
+    """Over the sorted listing of outputs/ and crashes/: (path, sha256)
+    lines, or with `crash_bytes` off the bare path for crashes/."""
     import hashlib
 
     listing = hashlib.sha256()
     for sub in ("outputs", "crashes"):
         for path in sorted((campaign["target"] / sub).iterdir()):
-            digest = hashlib.sha256(path.read_bytes()).hexdigest()[:16]
-            listing.update(f"{sub}/{path.name} {digest}\n".encode())
+            line = f"{sub}/{path.name}"
+            if crash_bytes or sub == "outputs":
+                digest = hashlib.sha256(path.read_bytes()).hexdigest()[:16]
+                line += f" {digest}"
+            listing.update(f"{line}\n".encode())
     return listing.hexdigest()
 
 
@@ -990,8 +1005,10 @@ def test_equal_seed_campaign_writes_the_parents_line_and_finds(
 # stack pages came down in one transfer a batch (PR 29's parent), with the
 # `crash` events in their order: name, new, bucket
 _TLV_PARENT_LINE = "#96 cov: 53 corp: 11 crash: 22 timeout: 0 cr3: 0"
-_TLV_PARENT_FINDS = ("2f69152f93856bbf711310104048e0b66f9f0a05be81715df6abe0"
-                     "2a677354ba")
+_TLV_PARENT_FINDS = ("0087f605995d190a736f108cedfcce48fefb76c843f761f5508c37"
+                     "320c4c904d")     # re-taken in PR 35, as _PARENT_FINDS
+_TLV_PARENT_NAMES = ("bd47d34feec9021ca444e47d96d137ae8ae3270cfd0bbcee24abb2"
+                     "5622dfc7c3")
 _TLV_PARENT_CRASH_EVENTS = ("b83c813bbf028284db97e6dc775cfa880694ea0b3b0975"
                             "b7fed46502e6024529")
 
@@ -1011,6 +1028,30 @@ def test_equal_seed_tlv_campaign_writes_the_parents_line_finds_and_buckets(
         f"{r['name']} {r['new']} {r['bucket']}\n"
         for r in crashes).encode()).hexdigest()
     assert events == _TLV_PARENT_CRASH_EVENTS
+
+
+@pytest.mark.parametrize("campaign,names,files", [
+    ("kernel_campaign", _PARENT_NAMES, 19),
+    ("tlv_campaign", _TLV_PARENT_NAMES, 6),
+])
+def test_a_crash_names_file_is_written_once_and_nothing_else_moved(
+        campaign, names, files, request):
+    """Writing a crash name's file once changed bytes under repeated
+    names only: the listing of names and all of outputs/ are as they
+    were before it, and every crash wrote its name's file or found it
+    held."""
+    campaign = request.getfixturevalue(campaign)
+    assert _finds_digest(campaign, crash_bytes=False) == names
+    metrics = campaign["metrics"]
+    assert metrics["campaign.crash_files_written"] == files == len(
+        list((campaign["target"] / "crashes").iterdir()))
+    assert (metrics["campaign.crash_files_written"]
+            + metrics["campaign.crash_saves_skipped"]
+            == metrics["campaign.crashes"])
+    # the pair is in every heartbeat, from the first on
+    beats = [r for r in campaign["records"] if r["type"] == "heartbeat"]
+    assert beats and all(
+        "campaign.crash_saves_skipped" in r["metrics"] for r in beats)
 
 
 def test_tlv_campaign_fetches_stack_rows_once_a_batch(tlv_campaign):

@@ -393,6 +393,10 @@ def summarize(path) -> dict:
                      "by_shape": dict(sorted(compiles_by_shape.items()))},
         "compile_shape_churn": dict(sorted(compile_shape_churn.items())),
         "crashes": metrics.get("campaign.crashes", 0),
+        # the file work of a crash is done once a name: null for streams
+        # from before the pair (or from a loop without a crashes dir)
+        "crash_files_written": metrics.get("campaign.crash_files_written"),
+        "crash_saves_skipped": metrics.get("campaign.crash_saves_skipped"),
         "crash_names": crashes,
         "new_coverage": metrics.get("campaign.new_coverage", 0),
         "fallbacks": metrics.get("runner.fallbacks", 0),
@@ -489,6 +493,10 @@ def _print_human(s: dict) -> None:
             print(f"  warning: shape-churn — {shape} compiled {n}x "
                   f"(expected 1 per executor shape)")
     print(f"crashes: {s['crashes']} new-coverage: {s['new_coverage']}")
+    if s.get("crash_files_written") is not None:
+        print(f"  crash files written: {s['crash_files_written']}, saves "
+              f"skipped under a name already held: "
+              f"{s['crash_saves_skipped']}")
     if s["crash_names"]:
         for name, n in sorted(s["crash_names"].items()):
             print(f"  {name} x{n}")

@@ -24,6 +24,8 @@ land in the JSONL event log when one is wired.
 
 from __future__ import annotations
 
+import functools
+import os
 import time
 from pathlib import Path
 from typing import Optional
@@ -61,6 +63,12 @@ class CampaignStats:
 
     testcases = _campaign_counter("testcases")
     crashes = _campaign_counter("crashes")
+    # crashes/<name> is made once a name: each crash either wrote it or
+    # found the name held (the two sum to `crashes` where a crashes_dir is
+    # set, less the saves that failed and, under a store, the blobs it
+    # dropped for a known bucket: `error` events, fleet.bucket_dedup)
+    crash_files_written = _campaign_counter("crash_files_written")
+    crash_saves_skipped = _campaign_counter("crash_saves_skipped")
     timeouts = _campaign_counter("timeouts")
     cr3s = _campaign_counter("cr3s")
     overlay_fulls = _campaign_counter("overlay_fulls")
@@ -197,6 +205,10 @@ class FuzzLoop:
         self.stats = CampaignStats(self.registry)
         self.stats_every = stats_every
         self.crash_names = set()
+        if self.crashes_dir:
+            # registered here so that every heartbeat carries the pair
+            self.registry.counter("campaign.crash_files_written")
+            self.registry.counter("campaign.crash_saves_skipped")
         # triage-grade crash dedup (wtf_tpu/triage/bucket.py): found
         # crashes bucket by (kind, faulting RIP, top-of-stack hash), not
         # by output filename — two bugs faulting on the same wild
@@ -505,33 +517,63 @@ class FuzzLoop:
         self.crash_buckets.add(bucket)
         self.crash_names.add(name)
         if self.crashes_dir:
-            from wtf_tpu.utils.atomicio import atomic_write_bytes
-
-            try:
-                # atomic (tmp+fsync+rename): a kill mid-save must not
-                # leave a torn repro, and a full disk must not abort the
-                # campaign from inside the harvest loop (same contract
-                # as the dist master's crash save).  With a store the
-                # blob is journaled content-addressed (bucket-deduped)
-                # and crashes/<name> becomes a view of it — names stay
-                # reference-shaped for the single-process driver.
-                if self.store is not None:
-                    digest, _ = self.store.put(data, kind="crash",
-                                               name=name, bucket=bucket)
-                    if self.store.has(digest):
-                        self.store.link_into(self.crashes_dir, digest,
-                                             name=name)
-                else:
-                    atomic_write_bytes(self.crashes_dir / name, data)
-            except OSError as e:
-                import logging
-
-                logging.getLogger(__name__).warning(
-                    "crash save failed for %r: %s", name, e)
-                self.events.emit("error", kind="crash-save", name=name,
-                                 detail=str(e))
+            self._save_crash_file(name, data, bucket)
         self.events.emit("crash", name=name, size=len(data), new=new,
                          bucket=bucket)
+
+    @functools.cached_property
+    def _crash_files(self) -> set:
+        """Names whose file stands in crashes_dir: those found there at
+        this process's first crash (an earlier run's, a resumed
+        campaign's; atomicio's tmp files are dot-names and never count)
+        and those written since.  ONE listing, not a stat a crashing
+        lane: on a network filesystem a stat is a round trip (0.2 ms on
+        the chip machine's, 0.8 s a batch; PERF.md, PR 35).  Not taken
+        from crash_names (the checkpoint's too), which also lists the
+        names whose save failed."""
+        return {name for name in os.listdir(self.crashes_dir)
+                if not name.startswith(".")}
+
+    def _save_crash_file(self, name: str, data: bytes, bucket: str) -> None:
+        """crashes/<name> is made ONCE a campaign, as upstream's and the
+        dist master's: the first testcase seen under the name stands
+        (either reproduces it).  A save that failed leaves the name to
+        its next crash: a full disk loses nothing once space returns."""
+        from wtf_tpu.utils.atomicio import atomic_write_bytes
+
+        try:
+            # atomic (tmp+fsync+rename): a kill mid-save must not
+            # leave a torn repro, and a full disk must not abort the
+            # campaign from inside the harvest loop (same contract
+            # as the dist master's crash save).  With a store the
+            # blob is journaled content-addressed (bucket-deduped)
+            # and crashes/<name> becomes a view of it — names stay
+            # reference-shaped for the single-process driver.  EVERY
+            # crash reaches the store: a held name may hide a new bucket.
+            if self.store is not None:
+                digest, _ = self.store.put(data, kind="crash",
+                                           name=name, bucket=bucket)
+            if name in self._crash_files:
+                self.stats.crash_saves_skipped += 1
+                return
+            if self.store is None:
+                atomic_write_bytes(self.crashes_dir / name, data)
+            elif self.store.has(digest):
+                self.store.link_into(self.crashes_dir, digest, name=name)
+            else:
+                # the store holds the bucket under another name and kept
+                # no blob (fleet.bucket_dedup): no file, neither counter
+                return
+        except OSError as e:
+            import logging
+
+            logging.getLogger(__name__).warning(
+                "crash save failed for %r: %s", name, e)
+            self.events.emit("error", kind="crash-save", name=name,
+                             detail=str(e))
+            return
+        self._crash_files.add(name)
+        self.stats.crash_files_written += 1
 
     def _peek(self, name: str):
         """Counter value WITHOUT registering it — the heartbeat must not
