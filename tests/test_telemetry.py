@@ -959,16 +959,22 @@ def test_sweeps_are_whole_and_bound_the_instructions_in_every_record(
 # the same campaign on the parent commit (PR 24's tree), by hand: the
 # final line without its clock fields, and a digest over the sorted
 # (path, sha256) listing of outputs/ and crashes/
-_PARENT_LINE = "#64 cov: 51 corp: 17 crash: 30 timeout: 0 cr3: 0"
+# re-taken on purpose in PR 36: a campaign RUNS what its corpus starts
+# with (this one's three inputs/ files) before it mutates, where the files
+# were added to the corpus and never run; with that one call taken out the
+# line, the finds and the names below read as PR 35 left them (17 / 30,
+# 2ed2245a..., f96e3e62..., 19 files where 25 are now).  The TLV campaign starts from an
+# empty inputs/ and its pins stand.
+_PARENT_LINE = "#64 cov: 51 corp: 21 crash: 38 timeout: 0 cr3: 0"
 # re-taken on purpose in PR 35: crashes/<name> now holds the FIRST
 # testcase seen under the name where the last writer won (2 of the 19
 # names differ in bytes here, 3 of 6 in the TLV campaign).  What did
 # not move is pinned beside it, taken on PR 35's parent: the names under
 # crashes/ and every (name, sha256) under outputs/.
-_PARENT_FINDS = ("2ed2245a5a3388cef94c21f4ffe3ee1c68bba6c592c50fb150eb7e3b5"
-                 "d0f883d")
-_PARENT_NAMES = ("f96e3e620234cfdd6da397a1e3d4a390734e15bb8c0730af9a766bc64"
-                 "3f3462d")
+_PARENT_FINDS = ("27609eba9e2665f0d08410214fb027b5158a5df56af3a5a6f37d31f6a"
+                 "c9beeb4")
+_PARENT_NAMES = ("8373ca693ba4ad0aadc6f7400fd7b54064bc2b0b4fdf1f04be403ea13"
+                 "9b43a00")
 
 
 def _final_line(campaign) -> str:
@@ -1031,7 +1037,7 @@ def test_equal_seed_tlv_campaign_writes_the_parents_line_finds_and_buckets(
 
 
 @pytest.mark.parametrize("campaign,names,files", [
-    ("kernel_campaign", _PARENT_NAMES, 19),
+    ("kernel_campaign", _PARENT_NAMES, 25),
     ("tlv_campaign", _TLV_PARENT_NAMES, 6),
 ])
 def test_a_crash_names_file_is_written_once_and_nothing_else_moved(
@@ -1228,7 +1234,7 @@ def test_xprof_dir_takes_one_bounded_trace_inside_a_batch(tmp_path):
 
 _STEP_SCOPES = ("decode-probe", "page-walk", "overlay-load",
                 "overlay-store", "ea", "alu", "unary", "shift", "mul",
-                "sse-fp", "x87", "coverage")
+                "sse-fp", "x87", "coverage", "iret")
 
 
 def test_lowered_chunk_names_every_stage_of_the_step(tpu_backend):
@@ -1244,7 +1250,7 @@ def test_lowered_chunk_names_every_stage_of_the_step(tpu_backend):
 @pytest.mark.parametrize("entry,total", [
     ("xla_step", 104), ("tenant_chunk", 104),
     ("megachunk_window", 155), ("decode_service", 248),
-    ("mesh_chunk", 1), ("tail_chunk", 472),
+    ("mesh_chunk", 1), ("tail_chunk", 478),
 ])
 def test_pinned_kernel_counts_did_not_move(entry, total):
     """analysis/budgets.json as PR 26 re-pinned it with the overlay

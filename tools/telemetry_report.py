@@ -446,6 +446,20 @@ def summarize(path) -> dict:
             "rows": metrics.get("image.rows"),
             "device_pulls": metrics.get("physmem.device_pulls", 0),
         } if metrics.get("image.bytes") is not None else None),
+        # deliveries through the guest's IDT (runner._service_exception)
+        # and what serving them cost: the span's seconds, the HostView's
+        # round trips for overlay rows
+        "exceptions": ({
+            "delivered": metrics["runner.exceptions_delivered"],
+            "undelivered": metrics.get("runner.exceptions_undelivered", 0),
+            "by_vector": dict(sorted(
+                metrics.get("runner.exceptions_by_vector", {}).items())),
+            "service_seconds": metrics.get("phase.seconds", {}).get(
+                "execute/service-exception", 0.0),
+            "view_row_pulls": metrics.get("runner.view_row_pulls", 0),
+            "view_rows": metrics.get("runner.view_rows", 0),
+        } if metrics.get("runner.exceptions_delivered") is not None
+            else None),
         "mesh": mesh,
         "triage": triage,
         "tenants": tenants,
@@ -533,6 +547,14 @@ def _print_human(s: dict) -> None:
               f"frames present under a span of {image['span_frames']}, "
               f"{image['rows']} page rows; device pulls: "
               f"{image['device_pulls']}")
+    exc = s.get("exceptions")
+    if exc:
+        by = " ".join(f"{k}={v}" for k, v in exc["by_vector"].items())
+        print(f"exceptions: {exc['delivered']} delivered through the "
+              f"guest's IDT ({by or 'none'}), {exc['undelivered']} "
+              f"undelivered; service {exc['service_seconds']:.3f} s; "
+              f"view row pulls: {exc['view_row_pulls']} "
+              f"({exc['view_rows']} rows)")
     mesh = s.get("mesh")
     if mesh:
         print(f"mesh: {mesh['devices']} devices x "

@@ -636,7 +636,8 @@ class TpuBackend(Backend):
             return self._result_from_fields(status, 0, 0, 0, "")
         r = self._result_view().r
         return self._result_from_fields(
-            status, int(r["fault_gva"][lane]), int(r["fault_write"][lane]),
+            status, int(r["fault_gva"][lane]),
+            int(r["fault_write"][lane]) & 1,    # above bit 0: iretq's latch
             int(r["rip"][lane]),
             self.runner.lane_errors.get(lane, "hard-error"))
 

@@ -829,6 +829,11 @@ def cmd_campaign(args) -> int:
         else:
             corpus = Corpus(outputs_dir=opts.paths.outputs, rng=rng,
                             store=store)
+        if opts.runs and not len(corpus) and target.seeds is not None:
+            # a self-contained target brings its seed corpus as it brings
+            # its snapshot; inputs/ wins wherever it holds a file
+            for data in target.seeds():
+                corpus.add(data)
         from wtf_tpu.fuzz.mutator import create_mutator
 
         mutator = (_mutator_for(target, rng, opts.max_len)
@@ -843,6 +848,7 @@ def cmd_campaign(args) -> int:
                         xprof_dir=args.xprof_dir,
                         xprof_seconds=args.xprof_seconds,
                         xprof_seed=opts.seed)
+        loop.run_first(corpus)      # a resume restores the queue it saved
         if opts.resume:
             from wtf_tpu.resume import load_campaign, restore_campaign
 

@@ -78,6 +78,10 @@ def has_error_code(vector: int) -> bool:
     return vector in _HAS_ERROR_CODE
 
 
+def is_canonical(gva: int) -> bool:
+    return (gva >> 47) in (0, 0x1FFFF)
+
+
 def deliver_page_fault(ctx, gva: int, write: bool, read_translates) -> None:
     """Route a memory fault to the architecturally correct vector and
     deliver it.
@@ -96,7 +100,7 @@ def deliver_page_fault(ctx, gva: int, write: bool, read_translates) -> None:
     unmapped is non-present (P=0), the demand-paging shape a real
     Windows MmAccessFault distinguishes.  U comes from the ctx's CPL.
     """
-    if (gva >> 47) not in (0, 0x1FFFF):  # non-canonical: #GP, not #PF
+    if not is_canonical(gva):  # #GP, not #PF
         deliver_exception(ctx, VEC_GP, 0)
         return
     present = read_translates(gva)

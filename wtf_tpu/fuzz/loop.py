@@ -269,6 +269,14 @@ class FuzzLoop:
                 "checkpointing requires the batched tpu backend "
                 "(--backend=tpu); this backend has no coverage_state seam")
 
+    def run_first(self, testcases) -> None:
+        """Testcases to run as they are, ahead of the mutator's: what a
+        campaign's corpus starts with (inputs/ files, a target's own
+        seeds).  It stands in outputs/, which the oracle replays, so the
+        campaign has to have run it; the reference master replays its
+        seeds before it mutates (server.h:399-414)."""
+        self._requeue.extend(testcases)
+
     def _account(self, data: bytes, result: TestcaseResult,
                  requeue: bool = False) -> bool:
         """Per-result accounting shared by fuzz and minset: counters via
@@ -396,8 +404,8 @@ class FuzzLoop:
             return self._run_one_batch_device()
         spans = self.registry.spans
         with spans.span("mutate"):
-            requeued, self._requeue = \
-                self._requeue[:self.batch_size], []
+            requeued, self._requeue = (self._requeue[:self.batch_size],
+                                       self._requeue[self.batch_size:])
             fresh = self.batch_size - len(requeued)
             if hasattr(self.mutator, "get_new_batch"):
                 # native engines mutate the whole batch in one C call

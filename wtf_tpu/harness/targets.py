@@ -18,6 +18,10 @@ bundle of callbacks —
                                  contained synthetic targets (the reference
                                  loads user-supplied crash dumps instead,
                                  wtf.cc:127-129)
+  seeds()                        optional seed corpus of such a target,
+                                 as a list of testcases: what a campaign
+                                 starts from when inputs/ holds no file
+                                 (the reference reads inputs/ alone)
   device_insert                  optional DeviceInsertSpec: the declarative
                                  equivalent of insert_testcase for the
                                  device-resident mutation path (wtf_tpu/
@@ -66,6 +70,7 @@ class Target:
     restore: Callable = lambda: True
     create_mutator: Optional[Callable] = None
     snapshot: Optional[Callable] = None
+    seeds: Optional[Callable] = None
     device_insert: Optional[DeviceInsertSpec] = None
 
     def __post_init__(self):
@@ -111,6 +116,7 @@ def load_builtin_targets() -> None:
     runs (the reference compiles fuzzer_*.cc into the binary; our
     equivalent is importing the harness modules)."""
     from wtf_tpu.harness import (  # noqa: F401
-        demo_fs, demo_ioctl, demo_kernel, demo_maze, demo_pe, demo_spin,
-        demo_tlv, demo_tlv_dump, demo_usermode,
+        demo_fs, demo_hevd, demo_hevd_dump, demo_ioctl, demo_kernel,
+        demo_maze, demo_pe, demo_spin, demo_tlv, demo_tlv_dump,
+        demo_usermode,
     )

@@ -67,6 +67,8 @@ CTR_TAIL = 4         # the lane-steps of CTR_SWEPT taken in NARROW sweeps
                      # once a level, not by step_lane
 N_CTRS = 5
 
+IRET_LATCH_SHIFT = 8   # Machine.fault_write: iretq's latch lies above bit 0
+
 
 class Machine(NamedTuple):
     """All fields carry a leading lane axis, the overlay's two flat planes
@@ -110,7 +112,11 @@ class Machine(NamedTuple):
     cr3_base: jax.Array   # uint64[L] snapshot cr3 (writes != this stop the lane)
     bp_skip: jax.Array    # int32[L] suppress bp check for one step post-resume
     fault_gva: jax.Array  # uint64[L] faulting address (PAGE_FAULT/SMC detail)
-    fault_write: jax.Array  # int32[L] 1 when the faulting access was a write
+    fault_write: jax.Array  # int32[L] bit 0: the faulting access was a write;
+                            # from IRET_LATCH_SHIFT up, iretq's latch between
+                            # its two passes (step_lane): 0, or 0x10000 | the
+                            # frame's SS once the first has probed all five
+                            # qwords (0 in any lane that has faulted)
 
     # Device-side telemetry (CTR_* indices above); folded into the host
     # metrics registry once per burst, reset on restore

@@ -54,7 +54,8 @@ from wtf_tpu.cpu.emu import MSR_ATTR
 from wtf_tpu.cpu.cpuid import CPUID_TABLE, MAX_BASIC_LEAF
 from wtf_tpu.interp import limbs as L
 from wtf_tpu.interp.machine import (
-    CTR_TAIL, MACHINE_LANE_AXES, Machine, lane_view, map_rows, put_lanes,
+    CTR_TAIL, IRET_LATCH_SHIFT, MACHINE_LANE_AXES, Machine, lane_view,
+    map_rows, put_lanes,
 )
 from wtf_tpu.interp.uoptable import (
     F_A32,
@@ -807,6 +808,18 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     is_push, is_pop = is_(U.OPC_PUSH), is_(U.OPC_POP)
     is_pushf, is_popf = is_(U.OPC_PUSHF), is_(U.OPC_POPF)
     is_call, is_ret = is_(U.OPC_CALL), is_(U.OPC_RET)
+    # iretq (REX.W CF) retires here in TWO passes of the lane: its frame is
+    # five qwords and a pass reads two 16-byte windows.  The first pass
+    # probes [rsp, rsp+32) and reads SS at rsp+32, in the oracle's order
+    # of reads, and latches SS in the spare bits of `st.fault_write`; it
+    # writes nothing else, so a #PF on any of the five reads leaves the
+    # lane as it was and the instruction restarts after delivery.  The
+    # second pass reads rip, cs, rflags and rsp and commits all five.
+    # iretd and far ret stay on the oracle (`unsupported` below).
+    is_iretq = is_(U.OPC_IRET) & (sub == 0) & (opsize == 8)
+    iret_latch = st.fault_write >> IRET_LATCH_SHIFT
+    iret_b = is_iretq & (iret_latch != 0)
+    iret_a = is_iretq & ~iret_b
     is_leave = is_(U.OPC_LEAVE) & (sub == 0)
     is_enter = is_(U.OPC_LEAVE) & (sub == 1)
     is_sse = is_(U.OPC_SSEMOV) | is_(U.OPC_SSEALU)
@@ -856,7 +869,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
         (sub == 0) | (sub == 3) | (sub == 4) | (sub == 8)
         | ((sext_f == 0) & (sub == 2)))
     unsupported = pre_live & (
-        is_(U.OPC_INVALID) | is_(U.OPC_IRET)
+        is_(U.OPC_INVALID) | (is_(U.OPC_IRET) & ~is_iretq)
         | (is_(U.OPC_MSR) & ~msr_known)
         | is_(U.OPC_SSECVT) | is_(U.OPC_PCLMUL)
         | is_(U.OPC_STACKSTR)
@@ -930,25 +943,32 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
 
     l1_need = pre_live & ~unsupported & ~rep_skip & (
         ((sk == U.K_MEM) & ~x87_store) | is_pop | is_popf | is_ret
-        | is_leave | s_movs | s_lods | s_cmps | s_scas)
+        | is_iretq | is_leave | s_movs | s_lods | s_cmps | s_scas)
     l1_addr_l = L.select64(
-        [s_movs | s_lods | s_cmps, s_scas, is_pop | is_popf | is_ret,
-         is_leave],
+        [s_movs | s_lods | s_cmps, s_scas,
+         is_pop | is_popf | is_ret | is_iretq, is_leave],
         [rsi_l, rdi_l, rsp_l, rbp_l], ea_mem_l)
     l1_addr = L.to_u64(l1_addr_l)
     l1_size = jnp.where(is_popf | is_ret | is_leave, 8,
                jnp.where(is_pop | is_string | is_sse, opsize,
                 jnp.where(is_ssefp, fp_ldsize, srcsize)))
+    with jax.named_scope("iret"):
+        # first pass: l1 spans rip..rsp (translated, not used), l2 is SS;
+        # second pass: l1 = rip, cs and l2 = rflags, rsp
+        l1_size = jnp.where(is_iretq, jnp.where(iret_b, 16, 32), l1_size)
+        iret_l2_l = L.add64_u32(rsp_l, jnp.where(
+            iret_b, jnp.uint32(16), jnp.uint32(32)))
 
     # store-only destinations (MOV/SETCC/POP write [mem] without reading it)
     # must NOT issue a dst-read load: their fault is the *store* fault, so
     # crash names report write access like the oracle's translate(write=True)
     store_only = is_(U.OPC_MOV) | is_(U.OPC_SETCC) | is_pop
     l2_need = pre_live & ~unsupported & ~rep_skip & (
-        ((dk == U.K_MEM) & ~is_sse & ~store_only) | s_cmps)
-    l2_addr_l = L.where64(s_cmps, rdi_l, ea_mem_l)
+        ((dk == U.K_MEM) & ~is_sse & ~store_only) | s_cmps | is_iretq)
+    l2_addr_l = L.select64([s_cmps, is_iretq], [rdi_l, iret_l2_l],
+                           ea_mem_l)
     l2_addr = L.to_u64(l2_addr_l)
-    l2_size = opsize
+    l2_size = jnp.where(iret_b, 16, opsize)
 
     # store address/size (the store itself commits at the end of the step;
     # computing its span here lets its translation batch with the loads')
@@ -994,7 +1014,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     lo3, hi3 = extract_pair(w3_0, w3_1, w3_2, wf)
     code_lo, code_hi = lo3[0], hi3[0]
     l1_lo, l1_hi = lo3[1], hi3[1]
-    l2_lo = lo3[2]
+    l2_lo, l2_hi = lo3[2], hi3[2]
 
     # -- SMC verdict + the final live mask --------------------------------
     lmask_lo = _size_mask(jnp.minimum(length, 8))
@@ -2050,8 +2070,9 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
 
     # rsp adjustment (push_size computed with the store span, section 4b)
     w3_cond = (is_push | is_pushf | is_call | is_pop | is_popf | is_ret
-               | is_leave | is_enter)
+               | is_leave | is_enter | is_iretq)
     w3_val = opc_list([
+        (is_iretq, l2_hi),
         (is_push | is_pushf | is_call, rsp - push_size.astype(jnp.uint64)),
         (is_pop, rsp + opsize.astype(jnp.uint64)),
         (is_popf, rsp + _u(8)),
@@ -2110,7 +2131,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     page_fault = live & ~unsupported & ~is_crash & (fault1 | fault2 | store_fault)
     fp_oracle = live & ~unsupported & ~page_fault & (fp_denorm | x87_denorm)
     commit_pre = live & ~unsupported & ~is_crash & ~de & ~page_fault \
-        & ~fp_oracle
+        & ~fp_oracle & ~iret_a
 
     overlay, store_ok, store = store_window3(
         image, overlay, ts0, ts1, st_size, st_lo, st_hi,
@@ -2152,6 +2173,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
         (is_(U.OPC_RDRAND), rdrand_rf),
         (is_(U.OPC_FLAGOP), flagop_rf),
         (is_popf, popf_rf),
+        (is_iretq, l2_lo & _u(U.RF_WRITABLE)),
         (is_(U.OPC_SYSCALL), jnp.where(syscall_entry, syscall_rf, sysret_rf)),
         (is_ssealu & (sub == U.SSE_PTEST), ptest_rf),
         (is_ssefp & fp_is_comi, ucomi_rf),
@@ -2176,7 +2198,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     rip_exec = opc_list([
         (is_(U.OPC_JMP) | is_call, jmp_target),
         (is_(U.OPC_JCC), L.to_u64(jcc_rip_l)),
-        (is_ret, ret_target),
+        (is_ret | is_iretq, ret_target),
         (is_(U.OPC_SYSCALL), jnp.where(syscall_entry, st.lstar, gpr[1])),
         (is_string, jnp.where(str_done, next_rip, rip)),
     ], next_rip)
@@ -2220,7 +2242,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
 
     # -- CS/SS selectors (CPL tracking for host exception delivery) -------
     # SYSCALL loads CPL-0 selectors from IA32_STAR[47:32]; SYSRET the CPL-3
-    # pair from IA32_STAR[63:48] (SDM).  iretq restores them on the oracle.
+    # pair from IA32_STAR[63:48] (SDM); iretq restores its frame's.
     sysc = commit & is_(U.OPC_SYSCALL)
     star_k = (st.star >> _u(32)) & _u(0xFFFC)
     star_u = (st.star >> _u(48)) & _u(0xFFFF)
@@ -2230,6 +2252,19 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     new_ss = jnp.where(
         sysc, jnp.where(syscall_entry, star_k + _u(8), (star_u + _u(8)) | _u(3)),
         st.ss)
+    with jax.named_scope("iret"):
+        iretc = commit & is_iretq
+        new_cs = jnp.where(iretc, l1_hi & _u(0xFFFF), new_cs)
+        new_ss = jnp.where(iretc, (iret_latch & 0xFFFF).astype(jnp.uint64),
+                           new_ss)
+        # the latch: set by a first pass that read its frame whole; any
+        # other pass of a live lane (the commit, a fault, a breakpoint or
+        # a decode miss in between) clears it, and the instruction then
+        # starts again from its first pass
+        new_latch = jnp.where(
+            live & iret_a & ~page_fault,
+            jnp.int32(0x10000) | (l2_lo & _u(0xFFFF)).astype(jnp.int32),
+            jnp.where(enabled, jnp.int32(0), iret_latch))
 
     # -- xmm ---------------------------------------------------------------
     wx_cond = commit & (
@@ -2314,7 +2349,8 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
         jnp.where(enabled & is_crash, rip, st.fault_gva))
     new_fault_write = jnp.where(
         enabled & page_fault & ~fault1 & ~fault2, jnp.int32(1),
-        jnp.where(enabled & page_fault, jnp.int32(0), st.fault_write))
+        jnp.where(enabled & page_fault, jnp.int32(0), st.fault_write & 1)) \
+        | (new_latch << IRET_LATCH_SHIFT)
 
     return st._replace(
         gpr_l=glimb_out,

@@ -63,6 +63,7 @@ class SyntheticSnapshotBuilder:
         self._phys: Dict[int, bytearray] = {}
         self._mappings: Dict[int, int] = {}  # gva pfn -> gpa pfn
         self._writable: Dict[int, bool] = {}
+        self._supervisor = set()             # gva pfns mapped without U
         self._next_pfn = (table_base >> PAGE_SHIFT) + 0x100
         self._table_base = table_base
         self._large = []  # (gva, gpa, size_shift) large-page mappings
@@ -84,19 +85,24 @@ class SyntheticSnapshotBuilder:
         self._phys_page(pfn)
         return pfn
 
-    def map(self, gva: int, size: int, writable: bool = True) -> None:
-        """Map [gva, gva+size) to freshly allocated physical pages."""
+    def map(self, gva: int, size: int, writable: bool = True,
+            user: bool = True) -> None:
+        """Map [gva, gva+size) to freshly allocated physical pages;
+        `user=False` leaves U/S clear in their PTEs (a kernel's pages)."""
         start = gva >> PAGE_SHIFT
         end = (gva + size + PAGE_SIZE - 1) >> PAGE_SHIFT
         for vpn in range(start, end):
             if vpn not in self._mappings:
                 self._mappings[vpn] = self.alloc_phys()
                 self._writable[vpn] = writable
+                if not user:
+                    self._supervisor.add(vpn)
 
-    def write(self, gva: int, data: bytes, map_if_needed: bool = True) -> None:
+    def write(self, gva: int, data: bytes, map_if_needed: bool = True,
+              user: bool = True) -> None:
         """Write snapshot contents at a GVA (mapping pages on demand)."""
         if map_if_needed:
-            self.map(gva, len(data))
+            self.map(gva, len(data), user=user)
         pos = 0
         while pos < len(data):
             vpn = (gva + pos) >> PAGE_SHIFT
@@ -155,7 +161,8 @@ class SyntheticSnapshotBuilder:
             pdpt = get_child(pml4_pfn, i4)
             pd = get_child(pdpt, i3)
             pt = get_child(pd, i2)
-            flags = _PTE_P | _PTE_U | (_PTE_W if self._writable.get(vpn, True) else 0)
+            flags = _PTE_P | (0 if vpn in self._supervisor else _PTE_U) \
+                | (_PTE_W if self._writable.get(vpn, True) else 0)
             entry = (gpa_pfn << PAGE_SHIFT) | flags
             self._phys_page(pt)[i1 * 8 : i1 * 8 + 8] = struct.pack("<Q", entry)
 
