@@ -1232,7 +1232,7 @@ def test_xprof_dir_takes_one_bounded_trace_inside_a_batch(tmp_path):
     assert f"open at stop: {rec['open_at_stop']}" in names
 
 
-_STEP_SCOPES = ("decode-probe", "page-walk", "overlay-load",
+_STEP_SCOPES = ("decode-probe", "tlb", "page-walk", "overlay-load",
                 "overlay-store", "ea", "alu", "unary", "shift", "mul",
                 "sse-fp", "x87", "coverage", "iret")
 
@@ -1248,9 +1248,9 @@ def test_lowered_chunk_names_every_stage_of_the_step(tpu_backend):
 
 
 @pytest.mark.parametrize("entry,total", [
-    ("xla_step", 104), ("tenant_chunk", 104),
-    ("megachunk_window", 155), ("decode_service", 248),
-    ("mesh_chunk", 1), ("tail_chunk", 478),
+    ("xla_step", 120), ("tenant_chunk", 120),
+    ("megachunk_window", 171), ("decode_service", 308),
+    ("mesh_chunk", 1), ("tail_chunk", 556),
 ])
 def test_pinned_kernel_counts_did_not_move(entry, total):
     """analysis/budgets.json as PR 26 re-pinned it with the overlay
@@ -1259,7 +1259,12 @@ def test_pinned_kernel_counts_did_not_move(entry, total):
     the file).  `tail_chunk` (PR 31) is `xla_step` with groups of 2
     lanes asked for: one body of the step, the slices that take a group's
     rows out and put them back, the sort of the rows a level and their
-    scatter back."""
+    scatter back; 478 -> 507 with the six leaves of the per-lane
+    translation cache in those slices and sorts (PR 38: the cache did
+    not move the step's own census, its walk is the same gathers at
+    K_WALK = 2 addresses where it took six), and every pin up by its
+    word reads once `overlay.image_words` / `read_data` took the two
+    limbs of a word from a gather each (PR 38: 104 -> 120 a step body)."""
     import wtf_tpu.analysis as analysis
 
     budgets = json.loads(

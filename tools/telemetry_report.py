@@ -436,6 +436,23 @@ def summarize(path) -> dict:
                 if metrics.get("device.tail_lane_steps") is not None
                 and metrics.get("device.lane_steps") else None),
         },
+        # the step's translation cache (paging.translate_cached): pages
+        # sent to the page walk per thousand instructions (6,000 was the
+        # step that walked six addresses a pass) and the share of
+        # lane-steps that only filled the cache; null for streams from
+        # before the counters
+        "tlb": ({
+            "walks": metrics["device.tlb_walks"],
+            "fill_steps": metrics.get("device.tlb_fill_steps", 0),
+            "walks_per_kinstr": (
+                round(1000 * metrics["device.tlb_walks"]
+                      / metrics["device.instructions"], 2)
+                if metrics.get("device.instructions") else None),
+            "fill_pass_share": (
+                round(metrics.get("device.tlb_fill_steps", 0)
+                      / metrics["device.lane_steps"], 6)
+                if metrics.get("device.lane_steps") else None),
+        } if metrics.get("device.tlb_walks") is not None else None),
         # the snapshot's packed image (gauges set once at snapshot-load;
         # its child spans are under nested_phases) and the host reads
         # that had to fetch image bytes from the device: 0 by design
@@ -541,6 +558,13 @@ def _print_human(s: dict) -> None:
     if dev.get("tail_share"):
         print(f"  tail: {dev['tail_share']:.2%} of lane-steps were taken "
               "in the chunk loop's narrow tail")
+    tlb = s.get("tlb")
+    if tlb:
+        print(f"tlb: {tlb['walks']} pages walked ("
+              + _or_null(tlb["walks_per_kinstr"], "{}")
+              + f" per 1000 instructions), {tlb['fill_steps']} fill "
+              "passes (" + _or_null(tlb["fill_pass_share"], "{:.3%}")
+              + " of lane-steps)")
     image = s.get("image")
     if image:
         print(f"image: {image['bytes']} bytes, {image['frames_present']} "

@@ -382,6 +382,18 @@ def count_data_dependent_ops(hlo_text: str) -> Dict[str, int]:
     return counts
 
 
+def walk_note() -> str:
+    """What a pinned chunk program's entry says of its page walk: the
+    census counts gather OPERATIONS, which the width of the walk does not
+    move, so the pin names the width (a level of the walk gathers six
+    indices an address: the frame table, the image's two limbs, the
+    overlay's valid byte and its two limbs)."""
+    from wtf_tpu.mem.paging import K_WALK
+
+    return (f" / one walk of K_WALK={K_WALK} addresses: "
+            f"{4 * 6 * K_WALK} gathered indices a lane-step")
+
+
 def check_budget(counts: Dict[str, int], budget: Dict[str, int],
                  entry: str) -> List[Finding]:
     """Exact pin: any drift (up OR down) is a finding — an improvement
@@ -569,7 +581,7 @@ def run_megachunk_rules(budgets_path: Optional[Path] = None,
 
     cfg = MEGA_CONFIG
     entry = (f"megachunk(max_batches={cfg['max_batches']}, donate=True) "
-             f"/ demo_tlv / n_lanes={cfg['n_lanes']}")
+             f"/ demo_tlv / n_lanes={cfg['n_lanes']}" + walk_note())
     lowered, args, fn = megachunk_window_lowering(
         max_batches=cfg["max_batches"], n_lanes=cfg["n_lanes"],
         donate=True, limit=cfg["limit"])
@@ -823,7 +835,7 @@ def run_tail_rules(runner, families, budgets_path: Optional[Path] = None,
     donated machine through the rows' scatter back (recompile)."""
     entry = (f"make_run_chunk({BUDGET_CONFIG['n_steps']}, "
              f"donate={BUDGET_CONFIG['donate']}, tail={TAIL_WIDTH}) / "
-             f"demo_tlv / n_lanes={BUDGET_CONFIG['n_lanes']}")
+             f"demo_tlv / n_lanes={BUDGET_CONFIG['n_lanes']}" + walk_note())
     compiled = step_executor_lowering(
         runner, n_steps=BUDGET_CONFIG["n_steps"],
         donate=BUDGET_CONFIG["donate"], tail=TAIL_WIDTH).compile()
@@ -1241,7 +1253,7 @@ def run_lint(families: Optional[Sequence[str]] = None,
         info["entries"].append(
             f"make_run_chunk({BUDGET_CONFIG['n_steps']}, "
             f"donate={BUDGET_CONFIG['donate']}) / demo_tlv / "
-            f"n_lanes={BUDGET_CONFIG['n_lanes']}")
+            f"n_lanes={BUDGET_CONFIG['n_lanes']}" + walk_note())
 
     if "dtype" in families:
         t0 = time.time()

@@ -65,7 +65,15 @@ CTR_TAIL = 4         # the lane-steps of CTR_SWEPT taken in NARROW sweeps
                      # over fewer than all groups of lanes.  Full-width
                      # sweeps = (CTR_SWEPT - CTR_TAIL) of any lane.  Added
                      # once a level, not by step_lane
-N_CTRS = 5
+CTR_TLB_WALK = 5     # pages a lane's passes sent to the page walk
+                     # (paging.translate_cached: at most K_WALK a pass;
+                     # every other translation came from the lane's cache)
+CTR_TLB_FILL = 6     # fill passes: passes of a RUNNING lane that only
+                     # filled the translation cache, because the
+                     # instruction needs more uncached pages at once than
+                     # a pass walks.  They count in CTR_SWEPT and not in
+                     # CTR_INSTR
+N_CTRS = 7
 
 IRET_LATCH_SHIFT = 8   # Machine.fault_write: iretq's latch lies above bit 0
 

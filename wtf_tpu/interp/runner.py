@@ -46,7 +46,8 @@ from wtf_tpu.cpu.interrupts import (
 )
 from wtf_tpu.interp import limbs
 from wtf_tpu.interp.machine import (
-    CTR_DECODE_MISS, CTR_INSTR, CTR_MEM_FAULT, CTR_SWEPT, CTR_TAIL, Machine,
+    CTR_DECODE_MISS, CTR_INSTR, CTR_MEM_FAULT, CTR_SWEPT, CTR_TAIL,
+    CTR_TLB_FILL, CTR_TLB_WALK, Machine,
     machine_init, machine_restore,
 )
 from wtf_tpu.interp.step import fp_on_host, make_run_chunk
@@ -560,8 +561,13 @@ def _apply_page_writes(machine: Machine, lanes, pfns, pages, ok_mask):
             jnp.where(ok & ~hit & can, 1, 0).astype(jnp.int32))
         overflow = overlay.overflow.at[lane].set(
             overlay.overflow[lane] | (ok & ~hit & ~can))
-        return overlay._replace(pfn=pfn_new, count=count,
-                                overflow=overflow), None
+        # the pushed frame may be a page table of the lane's
+        tlb = overlay.tlb
+        flags = ovl.tlb_drop(jax.tree.map(lambda x: x[lane], tlb),
+                             pfn[None], do[None]).flags
+        return overlay._replace(
+            pfn=pfn_new, count=count, overflow=overflow,
+            tlb=tlb._replace(flags=tlb.flags.at[lane].set(flags))), None
 
     overlay, _ = lax.scan(body, machine.overlay, (lanes, pfns, pages, ok_mask))
     # A host write that exceeded the lane's slots was dropped — surface the
@@ -627,6 +633,11 @@ def device_insert_impl(n_pages: int, len_gpr: int, ptr_gpr: int,
                           pfn0[li, ridx])),
             count=jnp.where(ok, start + jnp.int32(n_pages), start),
             overflow=ov.overflow | full,
+            # rows come and go under the lanes in hand: their translation
+            # caches start empty
+            tlb=ov.tlb._replace(flags=jnp.zeros_like(ov.tlb.flags)
+                                if active is None else
+                                jnp.where(active[:, None], 0, ov.tlb.flags)),
         )
         status = jnp.where(
             full & (machine.status == jnp.int32(int(StatusCode.RUNNING))),
@@ -1751,6 +1762,11 @@ class Runner:
         lane_steps = int(totals[CTR_SWEPT])
         reg.counter("device.lane_steps").inc(lane_steps)
         reg.counter("device.tail_lane_steps").inc(int(totals[CTR_TAIL]))
+        # the translation cache (paging.translate_cached): pages sent to
+        # the walk, and passes that only filled the cache (in lane_steps,
+        # not in instructions)
+        reg.counter("device.tlb_walks").inc(int(totals[CTR_TLB_WALK]))
+        reg.counter("device.tlb_fill_steps").inc(int(totals[CTR_TLB_FILL]))
         sweeps, rest = divmod(lane_steps, self.n_lanes)
         reg.counter("device.sweeps").inc(
             lane_steps / self.n_lanes if rest else sweeps)

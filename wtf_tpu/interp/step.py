@@ -68,7 +68,7 @@ from wtf_tpu.mem.overlay import (
     StoreCommit, commit_stores, extract_pair, load_windows3_vec, resident,
     store_window3,
 )
-from wtf_tpu.mem.paging import Translation, translate_vec_l
+from wtf_tpu.mem.paging import Translation, translate_cached
 from wtf_tpu.mem.physmem import IMAGE_IN_AXES, MemImage, lane_image
 
 MASK64 = (1 << 64) - 1
@@ -931,7 +931,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     bt_off = bt_signed & (bits_u - _u(1))
 
     # -- 4b. memory roles (ported: span addresses assemble in u32 limbs;
-    # the page walk itself converts at the translate_vec_l seam) ----------
+    # the translations convert to u64 at the translate_cached seam) -------
     rsp, rbp, rsi, rdi = gpr[4], gpr[5], gpr[6], gpr[7]
     rsp_l = (glimb[4, 0], glimb[4, 1])
     rbp_l = (glimb[5, 0], glimb[5, 1])
@@ -986,19 +986,32 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     # stmxcsr m32)
     st_size = jnp.where(x87_store, srcsize, push_size)
 
-    # -- 4b'. ONE vectorized page walk for all six translations, ONE
-    # batched gather for all three 16-byte windows (code/SMC, l1, l2).
-    # On TPU the step's cost is the count of unfusable gather kernels,
-    # so the walks and window reads are batched, not sequential.
+    # -- 4b'. the six translations (first and last byte of the load, of
+    # the second load and of the store) through the lane's translation
+    # cache: those the instruction does not use ask for nothing, a cached
+    # page is served from its entry, and what is left goes to ONE walk of
+    # paging.K_WALK addresses.  On TPU the step's cost is the count of
+    # gathered indices, and the walk's were 144 of them at six addresses.
+    # An instruction that needs more uncached pages than that at once takes
+    # a FILL PASS: the pass fills the cache and changes nothing else of
+    # the lane (`fill` below; `live` excludes it), and the next pass finds
+    # the entries.  `st_want` is `st_need` (below, after the ALU) as far
+    # as the decoded fields say it: a superset, on l2's address where it
+    # is wider.
     def _span_last(addr_l, size):
         return L.add64_u32(addr_l, (size - 1).astype(jnp.uint32))
 
-    gva6_l = jnp.stack([
+    st_want = pre_live & ~unsupported & ~rep_skip & (
+        (dk == U.K_MEM) | is_push | is_pushf | is_call | is_enter
+        | s_movs | s_stos | x87_store)
+    gva6 = L.pack_u64(jnp.stack([
         jnp.stack(p, axis=-1) for p in (
             l1_addr_l, _span_last(l1_addr_l, l1_size),
             l2_addr_l, _span_last(l2_addr_l, l2_size),
-            st_addr_l, _span_last(st_addr_l, st_size))])
-    t6 = translate_vec_l(image, overlay, st.cr3, gva6_l)
+            st_addr_l, _span_last(st_addr_l, st_size))]))
+    t6, overlay, tlb_walks, fill = translate_cached(
+        image, overlay, st.cr3, gva6, jnp.stack(
+            [l1_need, l1_need, l2_need, l2_need, st_want, st_want]))
 
     def _tr(i):
         return Translation(gpa=t6.gpa[i], ok=t6.ok[i],
@@ -1019,10 +1032,10 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     # -- SMC verdict + the final live mask --------------------------------
     lmask_lo = _size_mask(jnp.minimum(length, 8))
     lmask_hi = jnp.where(length > 8, _size_mask(length - 8), _u(0))
-    smc = pre_live & (
+    smc = pre_live & ~fill & (
         (((code_lo ^ mu[MU_RAW_LO]) & lmask_lo) != _u(0))
         | (((code_hi ^ mu[MU_RAW_HI]) & lmask_hi) != _u(0)))
-    live = pre_live & ~smc
+    live = pre_live & ~smc & ~fill
     is_crash = live & (is_(U.OPC_INT) | is_(U.OPC_HLT) | is_(U.OPC_INT1))
 
     # -- 4c. operand values (ported: read/extend/mask in u32 limbs; the
@@ -2260,11 +2273,12 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
         # the latch: set by a first pass that read its frame whole; any
         # other pass of a live lane (the commit, a fault, a breakpoint or
         # a decode miss in between) clears it, and the instruction then
-        # starts again from its first pass
+        # starts again from its first pass.  A fill pass is no pass of the
+        # instruction: the latch stands
         new_latch = jnp.where(
             live & iret_a & ~page_fault,
             jnp.int32(0x10000) | (l2_lo & _u(0xFFFF)).astype(jnp.int32),
-            jnp.where(enabled, jnp.int32(0), iret_latch))
+            jnp.where(enabled & ~fill, jnp.int32(0), iret_latch))
 
     # -- xmm ---------------------------------------------------------------
     wx_cond = commit & (
@@ -2309,9 +2323,11 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     # per-step host sync this exists to avoid.  page_fault/miss already
     # imply `enabled`, commit implies `live`.  CTR_SWEPT counts the pass
     # itself, whatever the lane's status; CTR_TAIL is the chunk loop's.
+    # A fill pass counts in CTR_SWEPT and CTR_TLB_FILL and not in
+    # CTR_INSTR, as iretq's latching pass counts in CTR_SWEPT alone.
     new_ctr = st.ctr + jnp.stack(
-        [commit, page_fault, miss, jnp.bool_(True),
-         jnp.bool_(False)]).astype(jnp.uint32)
+        [commit, page_fault, miss, jnp.bool_(True), jnp.bool_(False),
+         tlb_walks, fill]).astype(jnp.uint32)
     timed = commit & (limit > _u(0)) & (new_icount >= limit)
     new_rdrand = jnp.where(commit & is_(U.OPC_RDRAND), rdrand_next, st.rdrand)
     new_bp_skip = jnp.where(commit, jnp.int32(0), st.bp_skip)

@@ -84,6 +84,59 @@ def _shr(x, s):
     return jnp.where(s >= _u(64), _u(0), x >> jnp.minimum(s, _u(63)))
 
 
+# The per-lane translation cache (`Tlb`; lookup and fill are
+# paging.translate_cached).  TLB_ENTRIES is chosen from the counters
+# `device.tlb_walks` / `device.tlb_fill_steps` (PERF.md section 6, PR 38): the
+# benchmark's lanes touch a handful to a dozen pages, and an instruction
+# needs at most six entries at once.
+TLB_ENTRIES = 8
+TLB_VALID, TLB_OK, TLB_WRITABLE, TLB_USER = 1, 2, 4, 8
+
+
+class Tlb(NamedTuple):
+    """A lane's translation cache: what `paging.translate_vec` returned
+    for TLB_ENTRIES virtual pages, a failed walk included.  It lives in
+    the overlay because every path that changes what a walk reads goes
+    through this module or a function that takes the overlay whole: a
+    guest store drops the entries whose walk read a frame it writes
+    (`tlb_drop`, in `store_window3` / `scatter_span`), the host's page
+    pushes likewise (runner._apply_page_writes), the insert and the
+    restore drop the lane's entries (runner.device_insert_impl,
+    `overlay_reset`), and entries walked under another CR3 are never
+    served (`cr3`).  So the cache only ever answers what a fresh walk
+    would.  Empty is all zeros."""
+
+    vpn: jax.Array       # uint64[lanes, E]: gva >> 12
+    page: jax.Array      # uint64[lanes, E]: the 4-KiB physical page (a large
+                         # page is cached by the piece); 0 for a failed walk
+    flags: jax.Array     # int32[lanes, E]: TLB_* bits; 0 = empty
+    frames: jax.Array    # int32[lanes, E, 4]: pfn of the table page read at
+                         # each level, -1 where the walk had ended
+    next: jax.Array      # int32[lanes]: the entry the next fill replaces
+    cr3: jax.Array       # uint64[lanes]: the CR3 the entries were walked under
+
+
+def tlb_init(n_lanes: int) -> Tlb:
+    e = TLB_ENTRIES
+    return Tlb(vpn=jnp.zeros((n_lanes, e), jnp.uint64),
+               page=jnp.zeros((n_lanes, e), jnp.uint64),
+               flags=jnp.zeros((n_lanes, e), jnp.int32),
+               frames=jnp.zeros((n_lanes, e, 4), jnp.int32),
+               next=jnp.zeros((n_lanes,), jnp.int32),
+               cr3=jnp.zeros((n_lanes,), jnp.uint64))
+
+
+def tlb_drop(tlb: Tlb, pfns: jax.Array, enabled: jax.Array) -> Tlb:
+    """ONE lane's cache without the entries whose walk read a table page
+    in one of the frames `pfns[k]` being written (`enabled[k]`).
+    Compares over [E, 4] a frame: no gather, no scatter, no reduce."""
+    hit = jnp.zeros(tlb.flags.shape, bool)
+    for k in range(pfns.shape[0]):
+        read = (tlb.frames == pfns[k]) & enabled[k]
+        hit = hit | read[:, 0] | read[:, 1] | read[:, 2] | read[:, 3]
+    return tlb._replace(flags=jnp.where(hit, 0, tlb.flags))
+
+
 class DirtyOverlay(NamedTuple):
     """Every lane's dirty pages, as the machine holds them between
     dispatches (the RESIDENT layout; see the module docstring for why the
@@ -101,6 +154,7 @@ class DirtyOverlay(NamedTuple):
     valid: jax.Array     # uint8[lanes * capacity * PAGE_WORDS]; 1 = overlaid
     count: jax.Array     # int32[lanes]: allocated rows
     overflow: jax.Array  # bool[lanes]: lane ran out of overlay rows
+    tlb: Tlb             # each lane's translation cache
 
 
 class LaneOverlay(NamedTuple):
@@ -114,11 +168,12 @@ class LaneOverlay(NamedTuple):
     count: jax.Array     # int32 scalar
     overflow: jax.Array  # bool scalar
     base: jax.Array      # int32 scalar
+    tlb: Tlb             # the lane's entries (no lane axis)
 
 
 # vmap in_axes / out_axes for a `lanes(overlay)` view
 LANE_AXES = LaneOverlay(pfn=0, data=None, valid=None, count=0, overflow=0,
-                        base=0)
+                        base=0, tlb=0)
 
 
 class StoreCommit(NamedTuple):
@@ -140,6 +195,7 @@ def overlay_init(n_lanes: int, capacity: int) -> DirtyOverlay:
         valid=jnp.zeros((n_words,), dtype=jnp.uint8),
         count=jnp.zeros((n_lanes,), dtype=jnp.int32),
         overflow=jnp.zeros((n_lanes,), dtype=bool),
+        tlb=tlb_init(n_lanes),
     )
 
 
@@ -157,6 +213,8 @@ def overlay_reset(overlay: DirtyOverlay) -> DirtyOverlay:
         valid=jnp.zeros_like(overlay.valid),
         count=jnp.zeros_like(overlay.count),
         overflow=jnp.zeros_like(overlay.overflow),
+        # a restored lane walks the snapshot's tables again
+        tlb=jax.tree.map(jnp.zeros_like, overlay.tlb),
     )
 
 
@@ -168,7 +226,7 @@ def lanes(overlay: DirtyOverlay) -> LaneOverlay:
     base = jnp.arange(n_lanes, dtype=jnp.int32) * jnp.int32(
         capacity * PAGE_WORDS)
     return LaneOverlay(overlay.pfn, overlay.data, overlay.valid,
-                       overlay.count, overlay.overflow, base)
+                       overlay.count, overlay.overflow, base, overlay.tlb)
 
 
 def lane(overlay: DirtyOverlay, i) -> LaneOverlay:
@@ -176,13 +234,14 @@ def lane(overlay: DirtyOverlay, i) -> LaneOverlay:
     index ARRAY gives the batched view of those lanes."""
     v = lanes(overlay)
     return LaneOverlay(v.pfn[i], v.data, v.valid, v.count[i],
-                       v.overflow[i], v.base[i])
+                       v.overflow[i], v.base[i],
+                       jax.tree.map(lambda x: x[i], v.tlb))
 
 
 def resident(view: LaneOverlay) -> DirtyOverlay:
     """Back from a (batched) `lanes()` view to the resident overlay."""
     return DirtyOverlay(view.pfn, view.data, view.valid, view.count,
-                        view.overflow)
+                        view.overflow, view.tlb)
 
 
 def row_start(overlay: DirtyOverlay, lane_idx, row):
@@ -356,17 +415,29 @@ def read_words_vec(
     return jnp.where(use_ov_vec & ov_valid, read_data(overlay, at), base)
 
 
+def _gathered_words(lo: jax.Array, hi: jax.Array) -> jax.Array:
+    """(low limbs, high limbs) uint32[...] -> uint64[...].  The limbs come
+    from a gather each and meet elementwise: gathered as pairs
+    (`u32[..., 2]`) they are packed by a reduce over the pair, which the
+    TPU compiler made a kernel of its own wherever it laid the lanes out
+    major (the walk's `u32[lanes, 2, 2]`, the windows' `u32[lanes, 9, 2]`:
+    ten kernels that loop over the lanes one by one, PERF.md section 5,
+    PR 38).  The gathered indices are the same either way."""
+    return lo.astype(jnp.uint64) | (hi.astype(jnp.uint64) << jnp.uint64(32))
+
+
 def image_words(image: MemImage, slot: jax.Array, widx: jax.Array
                 ) -> jax.Array:
     """The u64 words `widx` of the base image's page rows `slot` (any
-    shape): one gather of their limb pairs."""
-    return pack_u64(image.pages[slot[..., None], _limb_index(widx)])
+    shape): a gather of their low limbs and one of their high limbs."""
+    return _gathered_words(image.pages[slot, 2 * widx],
+                           image.pages[slot, 2 * widx + 1])
 
 
 def read_data(overlay: LaneOverlay, at: jax.Array) -> jax.Array:
-    """The u64 data words at word indices `at` (any shape): one gather of
-    their limb pairs."""
-    return pack_u64(overlay.data[_limb_index(at)])
+    """The u64 data words at word indices `at` (any shape): a gather of
+    their low limbs and one of their high limbs."""
+    return _gathered_words(overlay.data[2 * at], overlay.data[2 * at + 1])
 
 
 def pte_read_vec(
@@ -503,6 +574,9 @@ def store_window3(
     overlay, row1, ok1 = ensure_page(image, overlay, pfn1, enabled & crosses)
     ok = ok0 & (ok1 | ~crosses)
     do = enabled & ok
+    # a store into a page table: the translations walked through it go
+    overlay = overlay._replace(tlb=tlb_drop(
+        overlay.tlb, jnp.stack([pfn0, pfn1]), jnp.stack([do, do & crosses])))
     slot0 = frame_slot(image, pfn0)
     slot1 = frame_slot(image, pfn1)
 
@@ -610,6 +684,9 @@ def scatter_span(
     overlay, idx1, ok1 = ensure_page(image, overlay, pfn1, enabled & two_pages)
     ok = ok0 & jnp.where(two_pages, ok1, True)
     do = enabled & ok
+    overlay = overlay._replace(tlb=tlb_drop(
+        overlay.tlb, jnp.stack([pfn0, pfn1]),
+        jnp.stack([do, do & two_pages])))
 
     # pack bytes into the aligned word window [w_start, w_start + W)
     head = (off0 & 7).astype(jnp.int32)

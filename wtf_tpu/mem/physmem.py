@@ -60,7 +60,7 @@ class MemImage(NamedTuple):
     cutting gather counts ~5-8x vs a byte-granular layout (a 16-byte
     unaligned access is 3 word gathers instead of 16 byte gathers; a PTE
     read is 1 instead of 8).  Each word is held as its two uint32 limbs,
-    low first (`overlay.image_words` gathers the pair): the chip has no
+    low first (`overlay.image_words` gathers each): the chip has no
     64-bit integers, and a uint64 operand is split into two uint32
     planes at the head of every dispatch, which for a 4-GiB dump was a
     second copy of the image in HBM (X64SplitLow/High, PR 34); the host's
