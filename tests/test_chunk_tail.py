@@ -1,8 +1,9 @@
 """The chunk executor's narrow sweeps (`step.make_run_chunk`: all lanes in
 groups of W while more than the groups but one would hold are RUNNING,
 then a group fewer, down to one group of W lanes) held to the full-width
-executor on the CPU, bit for bit: every leaf of the machine but the two
-counters that say how the sweeps were taken (`CTR_SWEPT`, `CTR_TAIL`).
+executor on the CPU, bit for bit: every leaf of the machine but the three
+counters that say how the sweeps were taken (`CTR_SWEPT`, `CTR_TAIL`,
+`CTR_LEVELS`).
 The default width is 0 at these lane counts (`step.TAIL_MIN_LANES`), so
 the direct cases ask `make_run_chunk` for a width and the cases through
 `Runner.run` lower the floor for the test.
@@ -20,7 +21,7 @@ from wtf_tpu.core.results import Crash, StatusCode
 from wtf_tpu.fuzz.mutator import TlvStructureMutator
 from wtf_tpu.harness import demo_kernel, demo_tlv
 from wtf_tpu.interp import step
-from wtf_tpu.interp.machine import CTR_SWEPT, CTR_TAIL
+from wtf_tpu.interp.machine import CTR_LEVELS, CTR_SWEPT, CTR_TAIL
 
 LANES = 16
 LIMIT = 100_000
@@ -86,7 +87,7 @@ def _same_but_sweep_counters(got: dict, want: dict, where: str) -> None:
         mine = got[path]
         if path == ".ctr":
             keep = [c for c in range(leaf.shape[1])
-                    if c not in (CTR_SWEPT, CTR_TAIL)]
+                    if c not in (CTR_SWEPT, CTR_TAIL, CTR_LEVELS)]
             mine, leaf = mine[:, keep], leaf[:, keep]
         np.testing.assert_array_equal(mine, leaf,
                                       err_msg=f"{where}: leaf {path}")
@@ -138,6 +139,7 @@ def tailed(name, width):
         machine, shape = operands[2], []
         swept = np.zeros(LANES, np.int64)
         tail = np.zeros(LANES, np.int64)
+        levels = np.zeros(LANES, np.int64)
         for k, want in enumerate(trajectory):
             machine = chunk(operands[0], operands[1], machine, operands[3])
             got = _host(machine)
@@ -158,6 +160,15 @@ def tailed(name, width):
             assert h + t == int(
                 want[".ctr"][0, CTR_SWEPT]
                 - (trajectory[k - 1][".ctr"][0, CTR_SWEPT] if k else 0))
+            # CTR_LEVELS: every lane carries the chunk's levels that swept;
+            # the full-width executor has no levels
+            d_levels = got[".ctr"][:, CTR_LEVELS] - levels
+            levels = got[".ctr"][:, CTR_LEVELS]
+            assert len(set(d_levels.tolist())) == 1
+            # one level for the full-width sweeps, one or more for the rest
+            assert (h > 0) + (t > 0) <= d_levels[0] <= LANES // width
+            assert t > 0 or d_levels[0] == (h > 0), (d_levels[0], h, t)
+            assert not want[".ctr"][:, CTR_LEVELS].any()
             shape.append((h, t, int((got[".status"] == RUNNING).sum())))
         _TAILED[name, width] = (n_steps, shape)
     return _TAILED[name, width]
@@ -230,7 +241,8 @@ def _run_cold(name):
     counters = {k: backend.registry.counter(k).value for k in (
         "device.instructions", "device.lane_steps", "device.tail_lane_steps",
         "device.lockstep_floor_steps", "device.decode_misses",
-        "device.mem_faults", "runner.chunks")}
+        "device.mem_faults", "runner.chunks", "device.levels_used",
+        "device.store_steps", "device.vector_steps")}
     _COLD[key] = {"machine": _host(backend.runner.machine),
                   "counters": counters,
                   "results": [str(r) for r in results]}
@@ -251,8 +263,18 @@ def test_runner_batch_resumed_after_servicing_equals_head_only(
     assert tail["results"] == plain["results"]
     for key in ("device.instructions", "device.decode_misses",
                 "device.mem_faults", "device.lockstep_floor_steps",
-                "runner.chunks"):
+                "runner.chunks", "device.store_steps",
+                "device.vector_steps"):
         assert tail["counters"][key] == plain["counters"][key], key
+    # the fold hands `device.levels_used` out per lane, as it does sweeps:
+    # a chunk's levels that swept, at least one a chunk and more than one
+    # in a chunk that narrowed; full-width chunks have no levels
+    assert plain["counters"]["device.levels_used"] == 0
+    assert tail["counters"]["device.levels_used"] == \
+        tail["machine"][".ctr"][:, CTR_LEVELS].sum() / LANES
+    assert tail["counters"]["runner.chunks"] \
+        < tail["counters"]["device.levels_used"] \
+        <= 4 * tail["counters"]["runner.chunks"]
     assert 0 < tail["counters"]["device.tail_lane_steps"] \
         <= tail["counters"]["device.lane_steps"] \
         < plain["counters"]["device.lane_steps"]

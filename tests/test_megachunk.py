@@ -35,6 +35,11 @@ def _fingerprint(loop) -> dict:
         "testcases": loop.stats.testcases,
         "timeouts": loop.stats.timeouts,
         "new_coverage": loop.stats.new_coverage,
+        # what the retired instructions did, counted in-graph: the window
+        # folds per-batch totals of the block, the batch loop the block
+        "device": {key: loop.registry.counter(f"device.{key}").value
+                   for key in ("instructions", "store_steps",
+                               "vector_steps", "levels_used")},
     }
 
 
@@ -67,6 +72,7 @@ def test_megachunk_window_bit_identical_to_batch_at_a_time():
     legacy = _fingerprint(_campaign(0, runs))
     assert legacy == fp1
     assert fp1["cov_bits"] > 0 and fp1["testcases"] == runs
+    assert 0 < fp1["device"]["store_steps"] < fp1["device"]["instructions"]
     assert fp1["new_coverage"] > 1  # finds beyond the cold-start window
 
 
@@ -81,6 +87,11 @@ def test_megachunk_batches_accounting_and_host_spans():
     assert loop.stats.testcases == loop.batches_done * BUILD["n_lanes"]
     secs = loop.registry.spans.seconds("execute/device")
     assert secs > 0.0
+    # the window's span has device-step's two children: the jit call and
+    # the fence, and nothing else
+    inside = sum(loop.registry.spans.seconds(f"execute/device/{child}")
+                 for child in ("dispatch", "wait"))
+    assert 0.8 * secs <= inside <= secs
     # megachunk consumed the whole campaign: the legacy per-batch device
     # generation span must never have fired
     assert loop.registry.spans.seconds("mutate/device") == 0.0

@@ -85,10 +85,10 @@ def test_mesh_chunk_bit_parity_and_merged_bitmaps(mesh):
 def test_mesh_chunk_through_the_tail_equals_head_only(monkeypatch):
     """Each shard narrows its sweeps at the width of ITS lanes (4 shards
     x 4 lanes, groups of 1; the floor lowered for the test): every
-    machine leaf but the two sweep counters equals the single-device
-    full-width chunk."""
+    machine leaf but the three sweep counters equals the single-device
+    full-width chunk, and a lane carries its SHARD's levels that swept."""
     from wtf_tpu.interp import step
-    from wtf_tpu.interp.machine import CTR_SWEPT, CTR_TAIL
+    from wtf_tpu.interp.machine import CTR_LEVELS, CTR_SWEPT, CTR_TAIL
 
     r1 = _runner()
     m_single = make_run_chunk(160, donate=False, tail=0)(
@@ -109,7 +109,7 @@ def test_mesh_chunk_through_the_tail_equals_head_only(monkeypatch):
         la, lb = np.asarray(la), np.asarray(lb)
         if jax.tree_util.keystr(path) == ".ctr":
             keep = [c for c in range(la.shape[1])
-                    if c not in (CTR_SWEPT, CTR_TAIL)]
+                    if c not in (CTR_SWEPT, CTR_TAIL, CTR_LEVELS)]
             la, lb = la[:, keep], lb[:, keep]
         np.testing.assert_array_equal(
             la, lb, err_msg=f"machine leaf {path} diverges in the tail")
@@ -125,6 +125,14 @@ def test_mesh_chunk_through_the_tail_equals_head_only(monkeypatch):
         tail, swept - swept.min(axis=1, keepdims=True))
     assert len({tuple(sorted(row)) for row in swept.tolist()}) > 1
     assert swept.sum() < np.asarray(m_single.ctr)[:, CTR_SWEPT].sum()
+    # CTR_LEVELS: one count a shard, on every lane of it; a shard that
+    # narrowed used more than one of its four levels
+    levels = ctr[:, :, CTR_LEVELS]
+    assert (levels == levels[:, :1]).all() and (levels >= 1).all()
+    assert ((levels[:, 0] > 1) | (tail.sum(axis=1) == 0)
+            | (swept.min(axis=1) == 0)).all()
+    assert levels.max() <= 4
+    assert not np.asarray(m_single.ctr)[:, CTR_LEVELS].any()
 
 
 def test_mesh_runner_full_run_parity(mesh):

@@ -701,6 +701,121 @@ def test_telemetry_report_host_device_wall_breakdown(tmp_path):
     assert round(wb["device_busy_seconds"], 4) == 9.9
 
 
+def test_wall_breakdown_counts_a_fenced_leaf_whole_however_it_splits():
+    """PR 39: a device leaf has a `dispatch` child (the jit call) and a
+    `wait` child (the fence).  Under --dispatch-timeout the supervisor's
+    bounded wait runs inside `dispatch`, so the host / device split reads
+    the leaf alone: one stream reads the same however its children split,
+    and the report and `wtf-tpu status` take it from ONE function."""
+    import telemetry_report
+
+    from wtf_tpu.telemetry.spans import device_seconds_by_phase
+
+    plain = {"execute": 10.0, "execute/device-step": 7.0,
+             "execute/device-step/dispatch": 0.5,
+             "execute/device-step/wait": 6.5,
+             "execute/device": 1.0, "execute/device/dispatch": 0.25,
+             "execute/device/wait": 0.75,
+             "execute/service-pull": 2.0, "harvest/wait": 3.0,
+             "harvest": 4.0}
+    supervised = dict(plain, **{"execute/device-step/dispatch": 6.9,
+                                "execute/device-step/wait": 0.1,
+                                "execute/device/dispatch": 1.0,
+                                "execute/device/wait": 0.0})
+    for seconds in (plain, supervised):
+        assert device_seconds_by_phase(seconds) == {"execute": 8.0}
+        wb = telemetry_report.wall_breakdown(seconds)
+        assert wb["by_phase"]["execute"] == {
+            "seconds": 10.0, "device_seconds": 8.0, "host_seconds": 2.0}
+        assert wb["by_phase"]["harvest"]["device_seconds"] == 0.0
+
+
+@pytest.fixture(scope="module")
+def supervised_campaign(tmp_path_factory):
+    """`kernel_campaign`'s first two batches in the RUNBOOK's supervised
+    mode: every chunk's bounded wait runs inside `supervisor.dispatch`."""
+    target = tmp_path_factory.mktemp("supervised-campaign")
+    _kernel_inputs(target / "inputs")
+    return _campaign(target, "demo_kernel", "--runs", "16", "--seed", "21",
+                     "--mutator", "mangle", "--supervise",
+                     "--dispatch-timeout", "30")
+
+
+@pytest.mark.parametrize("reader", ["report", "status"])
+@pytest.mark.parametrize("campaign", ["kernel_campaign",
+                                      "supervised_campaign"])
+def test_host_share_reads_the_parents_rule_with_and_without_a_timeout(
+        request, campaign, reader):
+    """'host share' is the accounted wall less the fenced leaves, in a
+    supervised campaign as in a plain one: there `dispatch` holds the
+    device's time (the bounded wait), and is not the host's."""
+    import telemetry_report
+
+    from wtf_tpu.cli import _derived_status_rows
+    from wtf_tpu.telemetry.spans import DEVICE_SPAN_LEAVES
+
+    metrics = request.getfixturevalue(campaign)["metrics"]
+    phase = metrics["phase.seconds"]
+    top = sum(s for p, s in phase.items() if "/" not in p)
+    leaves = sum(s for p, s in phase.items()
+                 if "/" in p and p.rsplit("/", 1)[-1] in DEVICE_SPAN_LEAVES)
+    assert 0 < phase["execute/device-step"] <= leaves < top
+    step = "execute/device-step"
+    if campaign == "supervised_campaign":
+        assert metrics["supervise.dispatches"] >= metrics["runner.chunks"]
+        assert phase[f"{step}/dispatch"] > phase[f"{step}/wait"]
+    if reader == "report":
+        wb = telemetry_report.wall_breakdown(phase)
+        assert wb["device_busy_seconds"] == pytest.approx(leaves, abs=1e-3)
+        assert wb["host_busy_seconds"] == pytest.approx(top - leaves,
+                                                        abs=1e-3)
+    else:
+        assert (f"host share: {(top - leaves) / top:.1%} of accounted wall"
+                in _derived_status_rows(metrics))
+
+
+def test_telemetry_report_prints_the_chunk_and_the_mix_lines(
+        kernel_campaign, capsys):
+    """`chunk:` (dispatch and wait a chunk, levels used of 16, the narrow
+    share) and `mix:` (stores, vector, two-page passes) from the stream's
+    own spans and counters; a stream from before them prints neither."""
+    import telemetry_report
+
+    telem = kernel_campaign["target"] / "telem"
+    summary = telemetry_report.summarize(telem / "events.jsonl")
+    metrics = kernel_campaign["metrics"]
+    seconds, chunks = metrics["phase.seconds"], metrics["runner.chunks"]
+    assert summary["chunk"]["dispatch_ms"] == round(
+        1e3 * seconds["execute/device-step/dispatch"] / chunks, 3)
+    assert summary["chunk"]["wait_ms"] == round(
+        1e3 * seconds["execute/device-step/wait"] / chunks, 3)
+    assert summary["chunk"]["levels_used"] == round(
+        metrics["device.levels_used"] / chunks, 2)
+    assert summary["mix"]["store_share"] == round(
+        metrics["device.store_steps"] / metrics["device.instructions"], 4)
+    telemetry_report._print_human(summary)
+    out = capsys.readouterr().out
+    assert "\nchunk: " in out and "\nmix: stores in " in out
+    summary["chunk"] = summary["mix"] = None
+    telemetry_report._print_human(summary)
+    out = capsys.readouterr().out
+    assert "\nchunk: " not in out and "\nmix: " not in out
+
+
+@pytest.mark.parametrize("campaign,said", [("kernel_campaign", False),
+                                           ("supervised_campaign", True)])
+def test_chunk_line_says_when_dispatch_holds_the_bounded_wait(
+        request, capsys, campaign, said):
+    import telemetry_report
+
+    telem = request.getfixturevalue(campaign)["target"] / "telem"
+    summary = telemetry_report.summarize(telem / "events.jsonl")
+    assert summary["chunk"]["supervised"] is said
+    telemetry_report._print_human(summary)
+    assert ("the bounded wait is inside dispatch"
+            in capsys.readouterr().out) is said
+
+
 # ---------------------------------------------------------------------------
 # PR 25: the closed span tree, the in-graph sweep counter, the profiler's
 # clock, named programs
@@ -764,6 +879,8 @@ def _calls(metrics, path):
 
 @pytest.mark.parametrize("path,counter", [
     ("execute/device-step", "runner.chunks"),
+    ("execute/device-step/dispatch", "runner.chunks"),
+    ("execute/device-step/wait", "runner.chunks"),
     ("execute/status-pull", "runner.chunks"),
     ("execute/results", "backend.batches"),
     ("harvest/crash-save", "backend.batches"),
@@ -915,12 +1032,16 @@ def test_result_pulls_are_one_view_pull_a_batch(kernel_campaign, claim):
     claim(kernel_campaign)
 
 
-@pytest.mark.parametrize("parent", ["execute", "harvest"])
+@pytest.mark.parametrize("parent", ["execute", "harvest",
+                                    "execute/device-step"])
 def test_direct_children_cover_most_of_the_parent_span(kernel_campaign,
                                                        parent):
+    """`execute/device-step` (PR 39): the jit call and the fence, its two
+    children, are all it does."""
     seconds = kernel_campaign["metrics"]["phase.seconds"]
+    depth = parent.count("/") + 1
     children = sum(v for k, v in seconds.items()
-                   if k.startswith(parent + "/") and k.count("/") == 1)
+                   if k.startswith(parent + "/") and k.count("/") == depth)
     assert 0.8 * seconds[parent] <= children <= seconds[parent], seconds
 
 
@@ -1196,6 +1317,7 @@ def test_profiler_trace_carries_the_span_paths_on_its_host_plane(
         jax.profiler.stop_trace()
     names = _host_event_names(tmp_path)
     assert {"execute/device-step", "harvest", "execute",
+            "execute/device-step/dispatch", "execute/device-step/wait",
             "execute/status-pull", "harvest/account"} <= names
 
 
@@ -1232,25 +1354,24 @@ def test_xprof_dir_takes_one_bounded_trace_inside_a_batch(tmp_path):
     assert f"open at stop: {rec['open_at_stop']}" in names
 
 
-_STEP_SCOPES = ("decode-probe", "tlb", "page-walk", "overlay-load",
-                "overlay-store", "ea", "alu", "unary", "shift", "mul",
-                "sse-fp", "x87", "coverage", "iret")
-
-
 def test_lowered_chunk_names_every_stage_of_the_step(tpu_backend):
+    """Every step scope of THE list (telemetry/xprof.py) is in the lowered
+    chunk program: a name the list keeps for a scope the step lost would
+    be a row of the tool's table that can never read."""
     from wtf_tpu.analysis.trace import step_executor_lowering
+    from wtf_tpu.telemetry.xprof import STEP_SCOPES
 
     lowered = step_executor_lowering(tpu_backend.runner, n_steps=16,
                                      donate=False)
     text = lowered.as_text(debug_info=True)
-    missing = [s for s in _STEP_SCOPES if f"({s})" not in text]
+    missing = [s for s in STEP_SCOPES if f"({s})" not in text]
     assert not missing, missing
 
 
 @pytest.mark.parametrize("entry,total", [
-    ("xla_step", 120), ("tenant_chunk", 120),
-    ("megachunk_window", 171), ("decode_service", 308),
-    ("mesh_chunk", 1), ("tail_chunk", 556),
+    ("xla_step", 117), ("tenant_chunk", 117),
+    ("megachunk_window", 160), ("decode_service", 308),
+    ("mesh_chunk", 1), ("tail_chunk", 555),
 ])
 def test_pinned_kernel_counts_did_not_move(entry, total):
     """analysis/budgets.json as PR 26 re-pinned it with the overlay
@@ -1264,7 +1385,15 @@ def test_pinned_kernel_counts_did_not_move(entry, total):
     not move the step's own census, its walk is the same gathers at
     K_WALK = 2 addresses where it took six), and every pin up by its
     word reads once `overlay.image_words` / `read_data` took the two
-    limbs of a word from a gather each (PR 38: 104 -> 120 a step body)."""
+    limbs of a word from a gather each (PR 38: 104 -> 120 a step body).
+    PR 39 named every indexed operation of the chunk program and moved
+    three counts on the way: rax's limbs for `mul` come by a static slice
+    (the compiler made three dynamic-slices without an `op_name` of a read
+    at a constant index: 120 -> 117 a step body), the source register's
+    two limbs are read once in `vector-file` where twelve sites read them
+    (the window's census is of the jaxpr: 171 -> 160), and the level's
+    account is one elementwise add over the counter block where
+    `CTR_TAIL` took a scatter (`tail_chunk` 556 -> 555)."""
     import wtf_tpu.analysis as analysis
 
     budgets = json.loads(
@@ -1312,3 +1441,99 @@ def test_telemetry_report_prints_lockstep_floor_share_beside_occupancy(
     summary["device"]["lockstep_floor_share"] = None
     telemetry_report._print_human(summary)
     assert "lockstep floor" not in capsys.readouterr().out
+
+
+# ---------------------------------------------------------------------------
+# PR 39: tools/xprof_scopes.py reads a window by the one scope list
+# ---------------------------------------------------------------------------
+
+def _scope_ops():
+    import xprof_scopes
+
+    ms = 1e6
+    op = xprof_scopes.Op
+    # two levels' sorts, a sweep of two groups, an 8-ms gap, the write-back
+    return [
+        op("%sort.1 = ...", 0.0, 1 * ms, "jit_run_chunk"),
+        op("%fusion.7 = ...", 1 * ms, 2 * ms, "jit_run_chunk"),
+        op("%fusion.9 = ...", 3 * ms, 0.5 * ms, "jit_run_chunk"),
+        op("%fusion.7 = ...", 3.5 * ms, 2 * ms, "jit_run_chunk"),
+        op("%sort.1 = ...", 5.5 * ms, 1 * ms, "jit_run_chunk"),
+        op("%scatter.3 = ...", 14.5 * ms, 3 * ms, "jit_run_chunk"),
+    ]
+
+
+_SCOPE_HLO = {"jit_run_chunk": {
+    "sort.1": ("jit(run_chunk)/while/body/level-sort/sort",
+               {"level-sort": 1}),
+    "fusion.7": ("jit(run_chunk)/while/body/vmap(page-walk)/gather",
+                 {"page-walk": 3, "step_lane inline": 1}),
+    "fusion.9": ("jit(run_chunk)/while/body/vmap()/add", {}),
+    "scatter.3": ("jit(run_chunk)/lane-writeback/scatter",
+                  {"lane-writeback": 1}),
+}}
+
+
+def test_xprof_scopes_takes_its_list_from_the_program():
+    import xprof_scopes
+
+    from wtf_tpu.telemetry import xprof
+
+    assert xprof_scopes.SCOPES is xprof.SCOPES
+    assert set(xprof.CHUNK_SCOPES) | set(xprof.STEP_SCOPES) <= set(
+        xprof.SCOPES)
+    assert len(set(xprof.SCOPES)) == len(xprof.SCOPES)
+    assert xprof.scope_of("jit(f)/vmap(sse-fp)/vmap(vector-file)/gather") \
+        == "vector-file"                    # the innermost
+    assert xprof.scope_of("jit(f)/vmap()/gather") is None
+    assert xprof.scope_of("jit(f)/not-a-tlb-scope/add") is None
+    assert set(xprof_scopes.PASS_OF) <= set(xprof.CHUNK_SCOPES)
+
+
+def test_xprof_scopes_sums_per_pass_and_counts_unscoped_indexed_ops():
+    import collections
+
+    import xprof_scopes
+
+    hlo = {m: {k: (name, collections.Counter(inner), not k.startswith("f"))
+               for k, (name, inner) in table.items()}
+           for m, table in _SCOPE_HLO.items()}
+    # the gather of fusion.7 that stands in no scope has a row of its own
+    hlo["jit_run_chunk"]["gather.5"] = (
+        "jit(run_chunk)/while/body/vmap()/gather",
+        collections.Counter({"step_lane inline": 1}), True)
+    out = xprof_scopes.reduce(_scope_ops(), hlo)
+    assert out["by_root"]["page-walk"][0] == pytest.approx(0.004)
+    assert out["by_root"]["step_lane inline"][0] == pytest.approx(0.0005)
+    assert out["per_pass"]["level-sort"] == {
+        "passes": 2, "of": "level", "us_per_pass": 1000.0}
+    assert out["per_pass"]["page-walk"] == {
+        "passes": 2, "of": "group sweep", "us_per_pass": 2000.0}
+    assert out["per_pass"]["lane-writeback"]["of"] == "chunk"
+    # one indexed operation of fusion.7's four stands in no scope, and is
+    # counted once; none is the compiler's own
+    assert (out["unscoped_indexed_ops"], out["unnamed_indexed_ops"]) == (1, 0)
+    assert out["unscoped_indexed_s"] == pytest.approx(0.001)
+    assert out["by_gathers"]["step_lane inline"][0] == pytest.approx(0.0015)
+
+
+def test_xprof_scopes_lays_a_gap_against_the_innermost_host_span():
+    import xprof_scopes
+
+    ms = 1e6
+    host = [("execute", 0.0, 20 * ms),
+            ("execute/status-pull", 6.6 * ms, 1.0 * ms),
+            ("execute/device-step", 7.8 * ms, 9 * ms),
+            ("execute/device-step/dispatch", 7.9 * ms, 6.4 * ms),
+            ("open at start: execute/device-step/wait", 0.0, 20 * ms)]
+    found = xprof_scopes.gaps(_scope_ops(), host, min_ms=5.0)
+    assert len(found) == 1
+    gap = found[0]
+    assert (gap["at_ms"], gap["ms"]) == (6.5, 8.0)
+    assert (gap["after"], gap["before"]) == ("sort.1", "scatter.3")
+    # the middle of the gap lies in `dispatch`, the shortest span there;
+    # the tracer thread's own row comes last
+    assert gap["host"][0] == ("execute/device-step/dispatch", 0.8)
+    assert [name for name, _ in gap["host"]][1] == "execute/device-step"
+    assert xprof_scopes.gaps(_scope_ops(), [], min_ms=5.0)[0]["host"] == []
+    assert xprof_scopes.gaps(_scope_ops(), host, min_ms=9.0) == []

@@ -7,7 +7,7 @@ Two references, both built here and neither a switch in the program:
     function that walks all six addresses of every pass and caches
     nothing (the step as it was before the cache).  A run of it must
     leave the machine leaf for leaf as the cached step leaves it, but
-    for `CTR_SWEPT`, the two tlb counters and the cache itself;
+    for `CTR_SWEPT`, the three tlb counters and the cache itself;
   * the EmuCpu oracle, which walks on every access.
 
 The guest programs store into their own page tables (through an alias
@@ -33,7 +33,8 @@ from wtf_tpu.core.results import StatusCode
 from wtf_tpu.cpu.emu import EmuCpu, EmuMem, GuestCrash, MemFault
 from wtf_tpu.interp import step as step_mod
 from wtf_tpu.interp.machine import (
-    CTR_INSTR, CTR_SWEPT, CTR_TLB_FILL, CTR_TLB_WALK, IRET_LATCH_SHIFT,
+    CTR_INSTR, CTR_STORE, CTR_SWEPT, CTR_TLB_FILL, CTR_TLB_WALK,
+    CTR_VECTOR, CTR_WALK2, IRET_LATCH_SHIFT,
 )
 from wtf_tpu.interp.runner import Runner
 from wtf_tpu.mem import overlay as ovl
@@ -141,11 +142,11 @@ def walk_every_time():
         step_mod._CHUNK_CACHE.update(live)
 
 
-_OWN_CTRS = [CTR_SWEPT, CTR_TLB_WALK, CTR_TLB_FILL]
+_OWN_CTRS = [CTR_SWEPT, CTR_TLB_WALK, CTR_TLB_FILL, CTR_WALK2]
 
 
 def _host(machine) -> dict:
-    """Every leaf that is the guest's: not the three counters, not the
+    """Every leaf that is the guest's: not the four counters, not the
     cache, and of the overlay's data plane the words that are valid (a
     pass without a store rewrites a word nobody can read, at an index
     made of whatever its unused translation held)."""
@@ -823,3 +824,92 @@ def test_two_batches_at_64_lanes_equal_the_oracle(name):
     assert "device.tlb_fill_steps" in tpu.registry.dump()
     assert counter("device.tlb_fill_steps").value \
         < counter("device.lane_steps").value // 50
+
+
+# ---------------------------------------------------------------------------
+# what a lane-step did (PR 39): CTR_STORE, CTR_WALK2, CTR_VECTOR by hand
+# counts on known programs, the oracle's lane results unchanged
+# ---------------------------------------------------------------------------
+
+_MIX_PROGRAMS = {
+    # five stores in a loop, nothing else touches memory
+    "store-loop": (f"""
+            mov rbx, {DATA_BASE}
+            mov rcx, 5
+        again:
+            mov qword ptr [rbx], rcx
+            add rbx, 8
+            dec rcx
+            jnz again
+            int3
+        """, {CTR_STORE: 5, CTR_WALK2: 0, CTR_VECTOR: 0}),
+    # a push and a call store too; a load and a pop do not
+    "stack": (f"""
+            mov rbx, {DATA_BASE}
+            push rbx
+            mov rax, qword ptr [rbx]
+            pop rdx
+            call next
+        next:
+            int3
+        """, {CTR_STORE: 2, CTR_WALK2: 0, CTR_VECTOR: 0}),
+    # one load of eight bytes over the end of a page, neither page cached:
+    # both go to the walk in that pass; the second such load finds them
+    "straddle": (f"""
+            mov rbx, {DATA_BASE + 0xFFC}
+            mov rax, qword ptr [rbx]
+            mov rdx, qword ptr [rbx]
+            int3
+        """, {CTR_STORE: 0, CTR_WALK2: 1, CTR_VECTOR: 0}),
+    # a vector load, a vector op and a vector store
+    "vector": (f"""
+            mov rbx, {DATA_BASE}
+            movdqa xmm1, xmmword ptr [rbx]
+            pxor xmm1, xmm1
+            movdqa xmmword ptr [rbx + 16], xmm1
+            int3
+        """, {CTR_STORE: 1, CTR_WALK2: 0, CTR_VECTOR: 3}),
+}
+
+
+@pytest.mark.parametrize("name", _MIX_PROGRAMS)
+def test_mix_counters_equal_a_hand_count(name):
+    asm, want = _MIX_PROGRAMS[name]
+    data = {DATA_BASE: bytes(range(256)) * 16,
+            DATA_BASE + PAGE: bytes(range(256)) * 16}
+    runner, emu, _, ctr = check(lambda: build(asm, data=data))
+    for column, count in want.items():
+        assert ctr[:, column].tolist() == [count, count], (name, column)
+    # the fold: one registry key a column, summed over the lanes
+    runner.fold_device_counters()
+    reg = runner.registry
+    assert reg.counter("device.instructions").value == 2 * emu.icount
+    for column, key in ((CTR_STORE, "device.store_steps"),
+                        (CTR_WALK2, "device.walk2_steps"),
+                        (CTR_VECTOR, "device.vector_steps")):
+        assert reg.counter(key).value == 2 * want[column], key
+    assert reg.counter("device.levels_used").value == 0  # two lanes: no levels
+
+
+@pytest.mark.parametrize("insn,stores", [("fxsave", 1), ("fxrstor", 0)])
+def test_what_the_oracle_retires_counts_in_its_columns(insn, stores):
+    """`fxsave` / `fxrstor` are the oracle's (step.py `x87_oracle`):
+    `_fallback_step` mirrors each into CTR_VECTOR, and into CTR_STORE
+    when it wrote the guest's memory, as it mirrors it into CTR_INSTR, so
+    the counts are of the retired instructions on every platform (on the
+    chip the float-valued forms are the oracle's too)."""
+    asm = f"""
+        mov rbx, {DATA_BASE}
+        {insn} [rbx]
+        pxor xmm2, xmm2
+        int3
+    """
+    runner = Runner(build(asm, data={DATA_BASE: bytes(PAGE)}), n_lanes=2,
+                    chunk_steps=64)
+    status = np.asarray(runner.run())
+    assert status.tolist() == [int(StatusCode.CRASH)] * 2
+    assert runner.stats["fallbacks"] == 2
+    ctr = runner.device_counters()
+    assert ctr[:, CTR_VECTOR].tolist() == [2, 2]
+    assert ctr[:, CTR_STORE].tolist() == [stores] * 2
+    assert ctr[:, CTR_INSTR].tolist() == [3, 3]

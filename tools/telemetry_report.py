@@ -24,7 +24,9 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from wtf_tpu.telemetry.events import read_events  # noqa: E402
-from wtf_tpu.telemetry.spans import DEVICE_SPAN_LEAVES  # noqa: E402,F401
+from wtf_tpu.telemetry.spans import (  # noqa: E402,F401
+    DEVICE_SPAN_LEAVES, device_seconds_by_phase,
+)
 
 
 def wall_breakdown(phase_seconds: dict) -> dict:
@@ -36,11 +38,7 @@ def wall_breakdown(phase_seconds: dict) -> dict:
     overhead and the generation wait shows under mutate/device."""
     top = {name: secs for name, secs in phase_seconds.items()
            if "/" not in name}
-    device_by_top: dict = {}
-    for path, secs in phase_seconds.items():
-        parts = path.split("/")
-        if len(parts) > 1 and parts[-1] in DEVICE_SPAN_LEAVES:
-            device_by_top[parts[0]] = device_by_top.get(parts[0], 0.0) + secs
+    device_by_top = device_seconds_by_phase(phase_seconds)
     by_phase = {}
     host_total = device_total = 0.0
     for name, secs in sorted(top.items(), key=lambda kv: -kv[1]):
@@ -54,6 +52,37 @@ def wall_breakdown(phase_seconds: dict) -> dict:
         "host_busy_seconds": round(host_total, 4),
         "device_busy_seconds": round(device_total, 4),
         "by_phase": by_phase,
+    }
+
+
+def _ratio(metrics: dict, num: str, den: str, digits: int,
+           scale: float = 1.0):
+    """scale * metrics[num] / metrics[den], or None without a denominator."""
+    if not metrics.get(den):
+        return None
+    return round(scale * metrics.get(num, 0) / metrics[den], digits)
+
+
+_DEVICE_STEP = "execute/device-step"
+
+
+def _chunk_summary(metrics: dict, phase_seconds: dict):
+    chunks = metrics.get("runner.chunks")
+    if not chunks or f"{_DEVICE_STEP}/wait" not in phase_seconds:
+        return None
+    return {
+        "chunks": chunks,
+        "dispatch_ms": round(
+            1e3 * phase_seconds[f"{_DEVICE_STEP}/dispatch"] / chunks, 3),
+        "wait_ms": round(
+            1e3 * phase_seconds[f"{_DEVICE_STEP}/wait"] / chunks, 3),
+        "levels_used": _ratio(metrics, "device.levels_used",
+                              "runner.chunks", 2),
+        "narrow_share": _ratio(metrics, "device.tail_lane_steps",
+                               "device.lane_steps", 4),
+        # under --dispatch-timeout the supervisor's bounded wait runs
+        # inside `dispatch`, which then holds the device's time
+        "supervised": bool(metrics.get("supervise.dispatches")),
     }
 
 
@@ -453,6 +482,22 @@ def summarize(path) -> dict:
                       / metrics["device.lane_steps"], 6)
                 if metrics.get("device.lane_steps") else None),
         } if metrics.get("device.tlb_walks") is not None else None),
+        # the inside of execute/device-step, a chunk at a time: the host's
+        # jit call, the fence, the levels of sixteen that swept
+        # (device.levels_used); null for streams from before the spans
+        "chunk": _chunk_summary(metrics, phase_seconds),
+        # what the retired instructions did (in-graph: CTR_STORE,
+        # CTR_VECTOR) and the passes that sent two pages to the walk
+        # (CTR_WALK2); null for streams from before the counters
+        "mix": ({
+            "store_share": _ratio(metrics, "device.store_steps",
+                                  "device.instructions", 4),
+            "vector_per_kinstr": _ratio(metrics, "device.vector_steps",
+                                        "device.instructions", 3, 1000),
+            "walk2_steps": metrics.get("device.walk2_steps", 0),
+            "walk2_pass_share": _ratio(metrics, "device.walk2_steps",
+                                       "device.lane_steps", 6),
+        } if metrics.get("device.store_steps") is not None else None),
         # the snapshot's packed image (gauges set once at snapshot-load;
         # its child spans are under nested_phases) and the host reads
         # that had to fetch image bytes from the device: 0 by design
@@ -564,6 +609,26 @@ def _print_human(s: dict) -> None:
               + _or_null(tlb["walks_per_kinstr"], "{}")
               + f" per 1000 instructions), {tlb['fill_steps']} fill "
               "passes (" + _or_null(tlb["fill_pass_share"], "{:.3%}")
+              + " of lane-steps)")
+    chunk = s.get("chunk")
+    if chunk:
+        print(f"chunk: {chunk['dispatch_ms']:.3f} ms dispatch and "
+              f"{chunk['wait_ms']:.3f} ms wait a chunk over "
+              f"{chunk['chunks']} chunks; levels used: "
+              + _or_null(chunk["levels_used"], "{}")
+              + " of 16 sorted; narrow: "
+              + _or_null(chunk["narrow_share"], "{:.2%}")
+              + " of lane-steps"
+              + (" (supervised: under --dispatch-timeout the bounded wait "
+                 "is inside dispatch)" if chunk["supervised"] else ""))
+    mix = s.get("mix")
+    if mix:
+        print("mix: stores in " + _or_null(mix["store_share"], "{:.2%}")
+              + " of the retired instructions, "
+              + _or_null(mix["vector_per_kinstr"], "{}")
+              + f" vector per 1000; {mix['walk2_steps']} passes sent two "
+              "pages to the walk ("
+              + _or_null(mix["walk2_pass_share"], "{:.4%}")
               + " of lane-steps)")
     image = s.get("image")
     if image:

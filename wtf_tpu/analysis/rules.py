@@ -417,6 +417,47 @@ def check_budget(counts: Dict[str, int], budget: Dict[str, int],
     return findings
 
 
+# the opcode: the first word before a "(" after the result's shape (a
+# tuple shape, as a sort of two operands has, holds spaces and no such word)
+_HLO_OP = re.compile(r" = .*?\s([a-z][\w\-]*)\(")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def unscoped_indexed_ops(hlo_text: str) -> List[Tuple[str, str]]:
+    """(opcode, instruction line) of every indexed operation of optimized
+    HLO text (`telemetry.xprof.INDEXED_OPS`: the gather class and the
+    sort) whose `op_name` holds no scope of THE list
+    (`telemetry.xprof.SCOPES`): device time that a profiler window could
+    not lay at any stage's door (tools/xprof_scopes.py)."""
+    from wtf_tpu.telemetry.xprof import INDEXED_OPS, scope_of
+
+    found = []
+    for line in hlo_text.splitlines():
+        op = _HLO_OP.search(line)
+        if op is None or op.group(1) not in INDEXED_OPS:
+            continue
+        name = _HLO_OP_NAME.search(line)
+        if scope_of(name.group(1) if name else "") is None:
+            found.append((op.group(1), line.strip()[:200]))
+    return found
+
+
+def check_indexed_ops_scoped(hlo_text: str, entry: str) -> List[Finding]:
+    """The chunk program holds no indexed operation outside a named
+    scope (PR 39: 36 gathers, 6 scatters, 3 dynamic-slices and 13
+    dynamic-update-slices of `xla_step` stood in none)."""
+    by_op: Dict[str, int] = {}
+    for opcode, _ in unscoped_indexed_ops(hlo_text):
+        by_op[opcode] = by_op.get(opcode, 0) + 1
+    return [Finding(
+        rule="budget.unscoped-indexed-op", entry=entry, primitive=opcode,
+        count=n, budget=0,
+        message=(f"{n} `{opcode}` operation(s) whose op_name holds no "
+                 "scope of telemetry.xprof.SCOPES: put the code under a "
+                 "jax.named_scope of the list (or add the name to it)"))
+        for opcode, n in sorted(by_op.items())]
+
+
 def decode_service_program(runner):
     """(fn, args) of one devdec service round (compute_blocks +
     commit_blocks) at `runner`'s shapes — the `decode_service` census
@@ -848,6 +889,7 @@ def run_tail_rules(runner, families, budgets_path: Optional[Path] = None,
             budget = load_budgets(budgets_path).get(TAIL_ENTRY, {})
             findings.extend(check_budget(info["tail_counts"], budget,
                                          entry=entry))
+        findings.extend(check_indexed_ops_scoped(text, entry=entry))
     if "recompile" in families:
         findings.extend(check_chunk_donation(compiled, text, runner, entry))
     return findings, info
@@ -1276,6 +1318,8 @@ def run_lint(families: Optional[Sequence[str]] = None,
             budget = load_budgets(budgets_path).get(BUDGET_ENTRY, {})
             findings.extend(check_budget(counts, budget,
                                          entry=info["entries"][0]))
+        findings.extend(check_indexed_ops_scoped(
+            compiled_text, entry=info["entries"][0]))
         for name, value in counts.items():
             registry.gauge("analysis.kernel_count").labels(name).set(value)
         # the triage replay core rides the same compiled ladder: its

@@ -334,7 +334,9 @@ class TpuBackend(Backend):
                                         runner.machine, self._agg_cov,
                                         self._agg_edge, wait=True)
         else:
-            with spans.span("device") as sp:
+            # a prelaunched window: its `dispatch` was timed where it was
+            # enqueued (_dispatch_window); what is left is the fence
+            with spans.span("device"), spans.span("wait") as sp:
                 sp.fence(out.batches)
         runner.machine = out.machine
         self._agg_cov = out.agg_cov
@@ -465,17 +467,22 @@ class TpuBackend(Backend):
         gva_l = jnp.asarray(np.array(
             [spec.gva & 0xFFFF_FFFF, (spec.gva >> 32) & 0xFFFF_FFFF],
             dtype=np.uint32))
-        with self.registry.spans.span("device") as sp:
-            out = runner.supervisor.dispatch(
-                "megachunk", fn,
-                runner.device_tab(), runner.image, machine,
-                runner.template, slab_first, slab_rest, seeds, pfns,
-                gva_l, jnp.uint64(finish), jnp.uint64(self.limit),
-                jnp.int32(n_batches), agg_cov, agg_edge,
-                *runner.devdec_operands(),
-                window=n_batches, wait=wait, sync=lambda o: o.batches)
+        spans = self.registry.spans
+        with spans.span("device"):
+            # `dispatch` and `wait` as under execute/device-step
+            # (Runner.run): the jit call, then the fence
+            with spans.span("dispatch"):
+                out = runner.supervisor.dispatch(
+                    "megachunk", fn,
+                    runner.device_tab(), runner.image, machine,
+                    runner.template, slab_first, slab_rest, seeds, pfns,
+                    gva_l, jnp.uint64(finish), jnp.uint64(self.limit),
+                    jnp.int32(n_batches), agg_cov, agg_edge,
+                    *runner.devdec_operands(),
+                    window=n_batches, wait=wait, sync=lambda o: o.batches)
             if wait:
-                sp.fence(out.batches)
+                with spans.span("wait") as sp:
+                    sp.fence(out.batches)
         return out
 
     def _mega_signature(self, mutator, max_batches: int, n_batches: int,

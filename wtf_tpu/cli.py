@@ -1221,11 +1221,10 @@ def _derived_status_rows(metrics: dict) -> List[str]:
                         f" dropped")
     phase = metrics.get("phase.seconds") or {}
     if isinstance(phase, dict) and phase:
-        from wtf_tpu.telemetry.spans import DEVICE_SPAN_LEAVES
+        from wtf_tpu.telemetry.spans import device_seconds_by_phase
 
         top = sum(s for p, s in phase.items() if "/" not in p)
-        dev = sum(s for p, s in phase.items()
-                  if "/" in p and p.split("/")[-1] in DEVICE_SPAN_LEAVES)
+        dev = sum(device_seconds_by_phase(phase).values())
         if top:
             rows.append(f"host share: "
                         f"{max(top - dev, 0.0) / top:.1%} of "

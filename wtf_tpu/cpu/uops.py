@@ -105,6 +105,10 @@ OPC_X87 = 50       # x87 FPU subset (sub X87_*).  Values held in double
                    # oracle-serviced
 
 N_OPC = 51
+# the classes of the vector datapath (XMM file, x87 stack): what
+# `device.vector_steps` counts (machine.CTR_VECTOR)
+VECTOR_OPCS = (OPC_SSEMOV, OPC_SSEALU, OPC_SSEFP, OPC_SSECVT, OPC_X87,
+               OPC_VZEROALL)
 
 # OPC_X87 sub-operations.  Field conventions: srcsize = memory operand
 # width, sext = number of stack pops (0/1/2), imm = st(i) index or

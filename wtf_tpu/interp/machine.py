@@ -39,41 +39,44 @@ from wtf_tpu.mem import overlay as ovl
 from wtf_tpu.mem.overlay import DirtyOverlay, overlay_init, overlay_reset
 
 # Device-side telemetry counter block: one u32 per lane per counter,
-# accumulated in-graph by step_lane and folded into host metrics once per
-# burst (no per-step host sync).  u32 (not u64 — the TPU has no native
-# 64-bit ints; a u64 counter would re-add the limb ops PR 2 removed)
-# covers every budgeted run: counters reset at each restore and the
-# BASELINE budget is 100M instructions/testcase.  Caveat: with limit=0
-# (unlimited) a single testcase retiring > 2^32 instructions wraps the
-# counter while the u64 icount keeps counting — the CTR_INSTR == icount
-# invariant holds mod 2^32 there.
-CTR_INSTR = 0        # instructions retired (commit) — the oracle fallback
-                     # mirrors its host steps in (runner._fallback_step) so
-                     # this matches icount exactly, fallback paths included
-CTR_MEM_FAULT = 1    # translation faults observed (device page walks +
-                     # oracle MemFaults), counted once per fault event
+# accumulated in-graph (by step_lane unless said otherwise) and folded into
+# host metrics once per burst (Runner.fold_counter_totals: no per-step host
+# sync).  u32, not u64: the TPU has no native 64-bit ints.  Counters reset
+# at each restore and the BASELINE budget is 100M instructions a testcase;
+# with limit=0 (unlimited) a testcase retiring over 2^32 instructions wraps
+# CTR_INSTR while the u64 icount keeps counting, and CTR_INSTR == icount
+# holds mod 2^32.  N_CTRS is the block's width: nothing else states it.
+CTR_INSTR = 0        # instructions retired (commit); the oracle fallback
+                     # mirrors its host steps in (runner._fallback_step),
+                     # so this is icount exactly, fallback paths included
+CTR_MEM_FAULT = 1    # translation faults (device page walks and oracle
+                     # MemFaults), once per fault event
 CTR_DECODE_MISS = 2  # decode-cache misses (NEED_DECODE transitions)
 CTR_SWEPT = 3        # lane-steps: every pass of the lane through step_lane,
-                     # live or not — the denominator of lane occupancy
-                     # (CTR_INSTR / CTR_SWEPT).  On one device every lane
-                     # carries the same number (the chunk loop sweeps all
-                     # lanes together); mesh shards exit on their own.
-                     # Device passes only: instructions the host oracle
-                     # retires count in CTR_INSTR and not here
-CTR_TAIL = 4         # the lane-steps of CTR_SWEPT taken in NARROW sweeps
-                     # of the chunk executor (step.make_run_chunk): sweeps
-                     # over fewer than all groups of lanes.  Full-width
-                     # sweeps = (CTR_SWEPT - CTR_TAIL) of any lane.  Added
-                     # once a level, not by step_lane
+                     # live or not; the denominator of lane occupancy.
+                     # Device passes only: what the oracle retires counts
+                     # in CTR_INSTR and not here
+CTR_TAIL = 4         # the lane-steps of CTR_SWEPT taken in sweeps of fewer
+                     # than all groups of lanes (step.make_run_chunk);
+                     # added once a level by the chunk loop
 CTR_TLB_WALK = 5     # pages a lane's passes sent to the page walk
-                     # (paging.translate_cached: at most K_WALK a pass;
-                     # every other translation came from the lane's cache)
+                     # (paging.translate_cached: at most K_WALK a pass)
 CTR_TLB_FILL = 6     # fill passes: passes of a RUNNING lane that only
-                     # filled the translation cache, because the
-                     # instruction needs more uncached pages at once than
-                     # a pass walks.  They count in CTR_SWEPT and not in
-                     # CTR_INSTR
-N_CTRS = 7
+                     # filled the translation cache (the instruction needs
+                     # more uncached pages at once than a pass walks); in
+                     # CTR_SWEPT and not in CTR_INSTR
+CTR_STORE = 7        # retired instructions that commit a store to memory,
+                     # the oracle's mirrored in as CTR_INSTR's are
+CTR_WALK2 = 8        # passes that sent two pages to the walk: each would
+                     # be a fill pass at K_WALK = 1
+CTR_VECTOR = 9       # retired instructions of the vector datapath
+                     # (uops.VECTOR_OPCS), the oracle's mirrored in as
+                     # CTR_INSTR's are
+CTR_LEVELS = 10      # levels of the chunk loop that swept at least once;
+                     # added to every lane's column once a level by the
+                     # chunk loop, so a lane carries its chunk's (its
+                     # shard's) count
+N_CTRS = 11
 
 IRET_LATCH_SHIFT = 8   # Machine.fault_write: iretq's latch lies above bit 0
 
@@ -199,6 +202,7 @@ def map_rows(fn, view: Machine, *more: Machine) -> Machine:
         data=view.overlay.data, valid=view.overlay.valid))
 
 
+@jax.named_scope("lane-writeback")
 def put_lanes(machine: Machine, lanes, view: Machine) -> Machine:
     """Back from a `lane_view` whose row `k` is lane `lanes[k]` (distinct):
     the rows scattered to their lanes of `machine`, and the planes as the

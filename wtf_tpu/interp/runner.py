@@ -46,8 +46,9 @@ from wtf_tpu.cpu.interrupts import (
 )
 from wtf_tpu.interp import limbs
 from wtf_tpu.interp.machine import (
-    CTR_DECODE_MISS, CTR_INSTR, CTR_MEM_FAULT, CTR_SWEPT, CTR_TAIL,
-    CTR_TLB_FILL, CTR_TLB_WALK, Machine,
+    CTR_DECODE_MISS, CTR_INSTR, CTR_LEVELS, CTR_MEM_FAULT, CTR_STORE,
+    CTR_SWEPT, CTR_TAIL, CTR_TLB_FILL, CTR_TLB_WALK, CTR_VECTOR, CTR_WALK2,
+    Machine,
     machine_init, machine_restore,
 )
 from wtf_tpu.interp.step import fp_on_host, make_run_chunk
@@ -442,11 +443,13 @@ class _FallbackMem:
     def __init__(self, view: HostView, lane: int):
         self.view = view
         self.lane = lane
+        self.stored = False     # a guest store went through (CTR_STORE)
 
     def phys_read(self, gpa: int, size: int) -> bytes:
         return self.view.phys_read(self.lane, gpa, size)
 
     def phys_write(self, gpa: int, data: bytes) -> None:
+        self.stored = True
         self.view.phys_write(self.lane, gpa, data)
 
     def phys_read_u64(self, gpa: int) -> int:
@@ -1286,7 +1289,8 @@ class Runner:
         by_class = self.stats["fallbacks_by_opclass"]
         by_class[opclass] = by_class.get(opclass, 0) + 1
         cpu_state = _lane_cpu_state(view, lane, self.cpu0_of(lane))
-        emu = EmuCpu(_FallbackMem(view, lane), cpu_state)
+        mem = _FallbackMem(view, lane)
+        emu = EmuCpu(mem, cpu_state)
         icount_before = int(view.r["icount"][lane])
         emu.icount = icount_before
         emu.rdrand_state = int(view.r["rdrand"][lane])
@@ -1318,7 +1322,12 @@ class Runner:
         view.r["icount"][lane] = np.uint64(emu.icount)
         # keep CTR_INSTR == icount exactly (the differential-test anchor):
         # every oracle-retired instruction lands in the device counter block
-        view.r["ctr"][lane, CTR_INSTR] += np.uint32(emu.icount - icount_before)
+        retired = np.uint32(emu.icount - icount_before)
+        view.r["ctr"][lane, CTR_INSTR] += retired
+        if mem.stored:
+            view.r["ctr"][lane, CTR_STORE] += retired
+        if uop is not None and uop.opc in U.VECTOR_OPCS:
+            view.r["ctr"][lane, CTR_VECTOR] += retired
         view.r["rdrand"][lane] = np.uint64(emu.rdrand_state)
         view.r["bp_skip"][lane] = np.int32(0)
         if emu.cr3_event is not None \
@@ -1598,15 +1607,22 @@ class Runner:
                                  image="x".join(
                                      str(d) for d in
                                      self.image.frame_table.shape))
-            with spans.span("device-step") as sp:
-                self.machine = self.supervisor.dispatch(
-                    "chunk", run_chunk,
-                    tab, self.image, self.machine, limit,
-                    steps=size, sync=lambda m: m.status)
-                # explicit fence: JAX dispatch is async; without it
-                # this span times Python dispatch and the device time
-                # leaks into whichever later span synchronizes first
-                sp.fence(self.machine.status)
+            with spans.span("device-step"):
+                # two children that sum to the span.  `dispatch`: the jit
+                # call (argument flattening, donation, enqueue; a first
+                # call's compile); `wait`: the fence.  JAX dispatch is
+                # async: without the fence the device's time leaks into
+                # whichever later span synchronizes first.  Under
+                # --dispatch-timeout the supervisor's bounded wait sits
+                # inside `dispatch`, which then holds the device's time,
+                # and `wait` nothing
+                with spans.span("dispatch"):
+                    self.machine = self.supervisor.dispatch(
+                        "chunk", run_chunk,
+                        tab, self.image, self.machine, limit,
+                        steps=size, sync=lambda m: m.status)
+                with spans.span("wait") as sp:
+                    sp.fence(self.machine.status)
             self.stats["chunks"] += 1
             # one span per chunk: the pull and the per-lane classification
             # are host time that scales with the lanes needing service
@@ -1752,24 +1768,27 @@ class Runner:
         reg.counter("device.mem_faults").inc(int(totals[CTR_MEM_FAULT]))
         reg.counter("device.decode_misses").inc(int(totals[CTR_DECODE_MISS]))
         # lane-steps (every lane's passes through the step, live or not)
-        # and sweeps = lane-steps per lane: FULL-WIDTH EQUIVALENTS.  The
-        # chunk executor (step.make_run_chunk) sweeps fewer groups of
-        # lanes as fewer run, and tail_lane_steps are the lane-steps of
-        # those narrow sweeps; full-width loop iterations =
-        # (lane_steps - tail_lane_steps) / lanes (per shard on a mesh).  With
-        # device.instructions: lane occupancy; with runner.chunks and
-        # execute/device-step: sweeps per chunk and device time per sweep.
+        # and sweeps = lane-steps per lane, per shard on a mesh: FULL-WIDTH
+        # EQUIVALENTS, not loop iterations (PERF.md section 3);
+        # tail_lane_steps are those of sweeps over fewer than all groups
         lane_steps = int(totals[CTR_SWEPT])
         reg.counter("device.lane_steps").inc(lane_steps)
         reg.counter("device.tail_lane_steps").inc(int(totals[CTR_TAIL]))
         # the translation cache (paging.translate_cached): pages sent to
-        # the walk, and passes that only filled the cache (in lane_steps,
-        # not in instructions)
+        # the walk, passes that only filled the cache (in lane_steps, not
+        # in instructions), passes that sent two pages to the walk
         reg.counter("device.tlb_walks").inc(int(totals[CTR_TLB_WALK]))
         reg.counter("device.tlb_fill_steps").inc(int(totals[CTR_TLB_FILL]))
-        sweeps, rest = divmod(lane_steps, self.n_lanes)
-        reg.counter("device.sweeps").inc(
-            lane_steps / self.n_lanes if rest else sweeps)
+        reg.counter("device.walk2_steps").inc(int(totals[CTR_WALK2]))
+        # what the retired instructions did: a store, the vector datapath
+        reg.counter("device.store_steps").inc(int(totals[CTR_STORE]))
+        reg.counter("device.vector_steps").inc(int(totals[CTR_VECTOR]))
+        # every lane carries its chunks' levels that swept: per lane, as
+        # sweeps are
+        for key, total in (("device.sweeps", lane_steps),
+                           ("device.levels_used", int(totals[CTR_LEVELS]))):
+            whole, rest = divmod(total, self.n_lanes)
+            reg.counter(key).inc(total / self.n_lanes if rest else whole)
 
     def fold_device_counters(self) -> np.ndarray:
         """Pull the counter block ONCE per burst and add the batch totals

@@ -39,7 +39,8 @@ which XLA lowers for free.
 
 from __future__ import annotations
 
-from functools import partial
+import operator
+from functools import partial, reduce
 from typing import Tuple
 
 import jax
@@ -54,8 +55,8 @@ from wtf_tpu.cpu.emu import MSR_ATTR
 from wtf_tpu.cpu.cpuid import CPUID_TABLE, MAX_BASIC_LEAF
 from wtf_tpu.interp import limbs as L
 from wtf_tpu.interp.machine import (
-    CTR_TAIL, IRET_LATCH_SHIFT, MACHINE_LANE_AXES, Machine, lane_view,
-    map_rows, put_lanes,
+    CTR_LEVELS, CTR_TAIL, IRET_LATCH_SHIFT, MACHINE_LANE_AXES, N_CTRS,
+    Machine, lane_view, map_rows, put_lanes,
 )
 from wtf_tpu.interp.uoptable import (
     F_A32,
@@ -262,6 +263,7 @@ def _flags_logic(r, opsize):
 # register file helpers
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("regfile-read")
 def _read_reg(gpr, idx, nbytes):
     high = idx >= U.REG_AH_BASE
     base = jnp.clip(jnp.where(high, idx - U.REG_AH_BASE, idx), 0, 15)
@@ -275,6 +277,7 @@ def _read64(gpr, idx):
     return jnp.where(ok, gpr[jnp.clip(idx, 0, 15)], _u(0))
 
 
+@jax.named_scope("regfile-write")
 def _gpr_write(gpr, cond, idx, val, nbytes):
     """Partial-register merge semantics of cpu/emu.py write_reg: 32-bit
     writes zero-extend, 8/16-bit merge, AH-view writes hit bits 15:8."""
@@ -299,6 +302,7 @@ def _z32():
     return jnp.uint32(0)
 
 
+@jax.named_scope("regfile-read")
 def _read64_l(gl, idx):
     """Full qword read as a limb pair; REG_NONE (out-of-file) reads 0."""
     ok = (idx >= 0) & (idx < 16)
@@ -307,6 +311,7 @@ def _read64_l(gl, idx):
     return jnp.where(ok, row[0], z), jnp.where(ok, row[1], z)
 
 
+@jax.named_scope("regfile-read")
 def _read_reg_l(gl, idx, nbytes):
     high = idx >= U.REG_AH_BASE
     base = jnp.clip(jnp.where(high, idx - U.REG_AH_BASE, idx), 0, 15)
@@ -713,10 +718,24 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     # limb-packed hot state (ported paths) + free u64 bitcast views (cold
     # paths and the memory subsystem convert at this seam)
     glimb = st.gpr_l                                  # uint32[16, 2]
-    rip_l = (st.rip_l[0], st.rip_l[1])
-    rf_lo, rf_hi = st.rflags_l[0], st.rflags_l[1]
     gpr, rip, rf = st.gpr, st.rip, st.rflags
     overlay = st.overlay
+    with jax.named_scope("regfile-read"):
+        # rip, rflags, the segment bases and the registers instructions
+        # name implicitly, at fixed indices (the chip's compiler serves a
+        # static slice as a dynamic one)
+        rip_l = (st.rip_l[0], st.rip_l[1])
+        rf_lo, rf_hi = st.rflags_l[0], st.rflags_l[1]
+        fs_base_l = (st.fs_base_l[0], st.fs_base_l[1])
+        gs_base_l = (st.gs_base_l[0], st.gs_base_l[1])
+        rax, rcx, rdx, r11 = gpr[0], gpr[1], gpr[2], gpr[11]
+        rsp, rbp, rsi, rdi = gpr[4], gpr[5], gpr[6], gpr[7]
+        rax_l = (glimb[0, 0], glimb[0, 1])
+        rcx_l = (glimb[1, 0], glimb[1, 1])
+        rsp_l = (glimb[4, 0], glimb[4, 1])
+        rbp_l = (glimb[5, 0], glimb[5, 1])
+        rsi_l = (glimb[6, 0], glimb[6, 1])
+        rdi_l = (glimb[7, 0], glimb[7, 1])
 
     # -- 1. decode-cache lookup (u32-only hash probe) -------------------
     # Heterogeneous batches (wtf_tpu/tenancy) probe a TENANT-TAGGED key:
@@ -734,28 +753,29 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     miss = enabled & (idx < 0)
     idxc = jnp.maximum(idx, 0)
 
-    f = tab.meta_i32[idxc]          # one row gather: fields + pfn0/pfn1/bp
-    mu = tab.meta_u64[idxc]         # one row gather: disp/imm/raw_lo/raw_hi
-    opc = f[F_OPC]
-    sub = f[F_SUB]
-    cond = f[F_COND]
-    length = f[F_LENGTH]
-    opsize = f[F_OPSIZE]
-    srcsize0 = f[F_SRCSIZE]
-    sext_f = f[F_SEXT]
-    dk = f[F_DST_KIND]
-    dr = f[F_DST_REG]
-    sk = f[F_SRC_KIND]
-    sr = f[F_SRC_REG]
-    breg = f[F_BASE_REG]
-    ireg = f[F_IDX_REG]
-    scale = f[F_SCALE]
-    seg = f[F_SEG]
-    rep = f[F_REP]
-    disp = mu[MU_DISP]
-    imm = mu[MU_IMM]
-    disp_l = L.pair(disp)
-    imm_l = L.pair(imm)
+    with jax.named_scope("uop-fetch"):
+        f = tab.meta_i32[idxc]          # one row gather: fields + pfn0/pfn1/bp
+        mu = tab.meta_u64[idxc]         # one row gather: disp/imm/raw_lo/raw_hi
+        opc = f[F_OPC]
+        sub = f[F_SUB]
+        cond = f[F_COND]
+        length = f[F_LENGTH]
+        opsize = f[F_OPSIZE]
+        srcsize0 = f[F_SRCSIZE]
+        sext_f = f[F_SEXT]
+        dk = f[F_DST_KIND]
+        dr = f[F_DST_REG]
+        sk = f[F_SRC_KIND]
+        sr = f[F_SRC_REG]
+        breg = f[F_BASE_REG]
+        ireg = f[F_IDX_REG]
+        scale = f[F_SCALE]
+        seg = f[F_SEG]
+        rep = f[F_REP]
+        disp = mu[MU_DISP]
+        imm = mu[MU_IMM]
+        disp_l = L.pair(disp)
+        imm_l = L.pair(imm)
 
     opmask = _size_mask(opsize)
     bits_u = opsize.astype(jnp.uint64) * _u(8)
@@ -802,7 +822,6 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     s_scas = is_string & (sub == U.STR_SCAS)
     s_cmps = is_string & (sub == U.STR_CMPS)
     rep_on = is_string & (rep != U.REP_NONE)
-    rcx = gpr[1]
     rep_skip = rep_on & (rcx == _u(0))  # rep w/ rcx=0: architectural no-op
 
     is_push, is_pop = is_(U.OPC_PUSH), is_(U.OPC_POP)
@@ -854,11 +873,10 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
         default=jnp.int32(16))
 
     # -- unsupported classes -> host oracle fallback ----------------------
-    rax, rdx = gpr[0], gpr[2]
     # MSRs the machine carries — derived from the oracle's MSR_ATTR map
     # (single source of truth; attr names are Machine field names);
     # unknown ids stay oracle-serviced
-    msr_id = gpr[1] & _u(0xFFFFFFFF)
+    msr_id = rcx & _u(0xFFFFFFFF)
     msr_known = jnp.zeros((), bool)
     for _mid in MSR_ATTR:
         msr_known = msr_known | (msr_id == _u(_mid))
@@ -913,8 +931,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     idx_val_l = _scale_idx_l(_read64_l(glimb, ireg), scale)
     seg_base_l = L.select64(
         [seg == U.SEG_FS, seg == U.SEG_GS],
-        [(st.fs_base_l[0], st.fs_base_l[1]),
-         (st.gs_base_l[0], st.gs_base_l[1])],
+        [fs_base_l, gs_base_l],
         (jnp.uint32(0), jnp.uint32(0)))
     ea_l = ea_limb(disp_l, base_val_l, idx_val_l, seg_base_l, f[F_A32])
     ea = L.to_u64(ea_l)
@@ -932,11 +949,6 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
 
     # -- 4b. memory roles (ported: span addresses assemble in u32 limbs;
     # the translations convert to u64 at the translate_cached seam) -------
-    rsp, rbp, rsi, rdi = gpr[4], gpr[5], gpr[6], gpr[7]
-    rsp_l = (glimb[4, 0], glimb[4, 1])
-    rbp_l = (glimb[5, 0], glimb[5, 1])
-    rsi_l = (glimb[6, 0], glimb[6, 1])
-    rdi_l = (glimb[7, 0], glimb[7, 1])
     # post-BT-adjust EA (the BT bit-string displacement stays u64-cold)
     ea_mem_l = L.pair(ea)
     srcsize = jnp.where(srcsize0 == 0, opsize, srcsize0)
@@ -1004,11 +1016,12 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     st_want = pre_live & ~unsupported & ~rep_skip & (
         (dk == U.K_MEM) | is_push | is_pushf | is_call | is_enter
         | s_movs | s_stos | x87_store)
-    gva6 = L.pack_u64(jnp.stack([
-        jnp.stack(p, axis=-1) for p in (
-            l1_addr_l, _span_last(l1_addr_l, l1_size),
-            l2_addr_l, _span_last(l2_addr_l, l2_size),
-            st_addr_l, _span_last(st_addr_l, st_size))]))
+    with jax.named_scope("tlb"):
+        gva6 = L.pack_u64(jnp.stack([
+            jnp.stack(p, axis=-1) for p in (
+                l1_addr_l, _span_last(l1_addr_l, l1_size),
+                l2_addr_l, _span_last(l2_addr_l, l2_size),
+                st_addr_l, _span_last(st_addr_l, st_size))]))
     t6, overlay, tlb_walks, fill = translate_cached(
         image, overlay, st.cr3, gva6, jnp.stack(
             [l1_need, l1_need, l2_need, l2_need, st_want, st_want]))
@@ -1069,7 +1082,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     # tests/test_limbs.py to pin the absence of 64-bit ops) -----------
     filler_l = _read_reg_l(glimb, sr, opsize)
     sh_r_l, sh_rf_lo, sh_writes = shift_limb(
-        sub, sext_f, dst_val_l, filler_l, glimb[1, 0], src_val_l[0],
+        sub, sext_f, dst_val_l, filler_l, rcx_l[0], src_val_l[0],
         imm_l[0], cf_in, opsize, rf_lo)
     sh_r = L.to_u64(sh_r_l)
 
@@ -1081,9 +1094,12 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     # tests/test_limbs.py to pin the absence of 64-bit ops) ----------
     rax_op = _read_reg(gpr, jnp.int32(0), opsize)
     is_mul2 = sub == U.MUL_2OP
+    # rax's limbs by a static slice: the compiler turns a register read at
+    # a constant index into a dynamic-slice and drops its `op_name`, so no
+    # scope could name it (the census in tests/test_analysis.py)
+    rax_op_l = L.zext(rax_l, opsize)
     mul_r1_l, mul_r2_l, mul_rf_lo = mul_limb(
-        sub, sext_f, dst_val_l, src_val_l,
-        _read_reg_l(glimb, jnp.int32(0), opsize), imm_l, opsize, rf_lo)
+        sub, sext_f, dst_val_l, src_val_l, rax_op_l, imm_l, opsize, rf_lo)
 
     # DIV (device path: dividend fits in 64 bits; else host fallback) --
     d_lo = rax_op
@@ -1234,7 +1250,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
         [st.tsc + st.icount if attr == "tsc" else getattr(st, attr)
          for attr in MSR_ATTR.values()],
         default=_u(0))
-    msr_wval = ((gpr[2] & _u(0xFFFFFFFF)) << _u(32)) | (gpr[0] & _u(0xFFFFFFFF))
+    msr_wval = ((rdx & _u(0xFFFFFFFF)) << _u(32)) | (rax & _u(0xFFFFFFFF))
 
     # CMPXCHG / XADD --------------------------------------------------
     cx_acc = rax_op
@@ -1258,28 +1274,29 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
                               bsw_bytes << rev_sh, _u(0)))
 
     # STRING (one element per step; REP iterates by re-executing) ------
-    df_set = (rf & _u(_DF)) != 0
-    str_delta = jnp.where(df_set, _u(0) - opsize.astype(jnp.uint64),
-                          opsize.astype(jnp.uint64))
-    str_a = jnp.where(s_cmps, l1_lo & opmask,
-                      rax_op)                       # scas: rax, cmps: [rsi]
-    str_b = jnp.where(s_cmps, l2_lo & opmask, l1_lo & opmask)  # [rdi]
-    str_cmp_r = (str_a - str_b) & opmask
-    str_rf = (rf & ~_u(FLAGS_ARITH)) | _flags_sub(
-        str_a, str_b, str_cmp_r, opsize, jnp.bool_(False))
-    str_zf_new = (str_cmp_r == _u(0))
-    rcx_dec = rcx - _u(1)
-    str_cc_done = (s_scas | s_cmps) & jnp.where(
-        rep == U.REP_REP, ~str_zf_new, str_zf_new)
-    str_done = jnp.where(rep_on,
-                         rep_skip | (rcx_dec == _u(0)) | str_cc_done,
-                         jnp.bool_(True))
-    str_upd = live & is_string & ~unsupported & ~rep_skip
+    with jax.named_scope("string"):
+        df_set = (rf & _u(_DF)) != 0
+        str_delta = jnp.where(df_set, _u(0) - opsize.astype(jnp.uint64),
+                              opsize.astype(jnp.uint64))
+        str_a = jnp.where(s_cmps, l1_lo & opmask,
+                          rax_op)                       # scas: rax, cmps: [rsi]
+        str_b = jnp.where(s_cmps, l2_lo & opmask, l1_lo & opmask)  # [rdi]
+        str_cmp_r = (str_a - str_b) & opmask
+        str_rf = (rf & ~_u(FLAGS_ARITH)) | _flags_sub(
+            str_a, str_b, str_cmp_r, opsize, jnp.bool_(False))
+        str_zf_new = (str_cmp_r == _u(0))
+        rcx_dec = rcx - _u(1)
+        str_cc_done = (s_scas | s_cmps) & jnp.where(
+            rep == U.REP_REP, ~str_zf_new, str_zf_new)
+        str_done = jnp.where(rep_on,
+                             rep_skip | (rcx_dec == _u(0)) | str_cc_done,
+                             jnp.bool_(True))
+        str_upd = live & is_string & ~unsupported & ~rep_skip
 
     # control flow (ported: condition eval + relative targets in limbs;
     # indirect targets come from registers/memory through the u64 seam) --
-    rcx_l = (glimb[1, 0], glimb[1, 1])
-    cc_true = L.eval_cond(rf_lo, rcx_l, cond)
+    with jax.named_scope("cond"):
+        cc_true = L.eval_cond(rf_lo, rcx_l, cond)
     jcc_target_l = L.add64(next_rip_l, imm_l)
     jmp_target = jnp.where(sk == U.K_IMM, L.to_u64(jcc_target_l), src_val)
     ret_target = l1_lo
@@ -1303,8 +1320,8 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     # basic leaf for out-of-range basic leaves, else zeros ---------------
     cpuid_keys = jnp.asarray(_CPUID_KEYS)
     cpuid_vals = jnp.asarray(_CPUID_VALS)
-    cp_eax = (gpr[0] & _u(0xFFFFFFFF)).astype(jnp.uint32)
-    cp_ecx = (gpr[1] & _u(0xFFFFFFFF)).astype(jnp.uint32)
+    cp_eax = (rax & _u(0xFFFFFFFF)).astype(jnp.uint32)
+    cp_ecx = (rcx & _u(0xFFFFFFFF)).astype(jnp.uint32)
     cp_exact = (cpuid_keys[:, 0] == cp_eax) & (cpuid_keys[:, 1] == cp_ecx)
     cp_leaf0 = (cpuid_keys[:, 0] == cp_eax) & (cpuid_keys[:, 1] == 0)
     cp_in_basic_fb = ((cp_eax < jnp.uint32(0x80000000))
@@ -1332,7 +1349,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     rdrand_next = _splitmix64(st.rdrand)
     rdrand_rf = (rf & ~_u(FLAGS_ARITH)) | _u(_CF)
     syscall_rf = (rf & ~(st.sfmask | _u(_TF))) | _u(0x2)
-    sysret_rf = (gpr[11] & _u(U.RF_WRITABLE)) | _u(0x2)
+    sysret_rf = (r11 & _u(U.RF_WRITABLE)) | _u(0x2)
     cr_read = jnp.select(
         [sub == 0, sub == 2, sub == 3, sub == 4, sub == 8],
         [st.cr0, st.cr2, st.cr3, st.cr4, st.cr8], default=_u(0))
@@ -1340,10 +1357,12 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     cr_wval = _read_reg(gpr, sr, jnp.int32(8))
 
     # SSE --------------------------------------------------------------
-    xmm = st.xmm
-    x_dst_lo, x_dst_hi = xmm[jnp.clip(dr, 0, 15), 0], xmm[jnp.clip(dr, 0, 15), 1]
-    x_src_lo = jnp.where(sk == U.K_XMM, xmm[jnp.clip(sr, 0, 15), 0], l1_lo)
-    x_src_hi = jnp.where(sk == U.K_XMM, xmm[jnp.clip(sr, 0, 15), 1], l1_hi)
+    with jax.named_scope("vector-file"):
+        xmm = st.xmm
+        x_dst_lo, x_dst_hi = xmm[jnp.clip(dr, 0, 15), 0], xmm[jnp.clip(dr, 0, 15), 1]
+        x_sr_lo, x_sr_hi = xmm[jnp.clip(sr, 0, 15), 0], xmm[jnp.clip(sr, 0, 15), 1]
+        x_src_lo = jnp.where(sk == U.K_XMM, x_sr_lo, l1_lo)
+        x_src_hi = jnp.where(sk == U.K_XMM, x_sr_hi, l1_hi)
     is_ssemov = is_(U.OPC_SSEMOV)
     is_ssealu = is_(U.OPC_SSEALU)
     # movd/movq gpr->xmm (sub 1): value zero-extended into the register
@@ -1369,52 +1388,52 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     ssm_hi = jnp.where(sub == 4, x_dst_hi, jnp.where(sub == 5, half5, ssm_hi))
 
     # byte-level SSE ALU on unpacked u8[16] vectors
-    ba = _unpack_bytes(x_dst_lo, x_dst_hi)
-    bb = jnp.where(sk == U.K_XMM,
-                   _unpack_bytes(xmm[jnp.clip(sr, 0, 15), 0],
-                                 xmm[jnp.clip(sr, 0, 15), 1]),
-                   _unpack_bytes(l1_lo, l1_hi))
-    i16u = jnp.arange(16, dtype=jnp.int32)
-    eq_b = (ba == bb)
-    # word/dword equality via group-reduction of byte equality
-    eq_w16 = eq_b[(i16u // 2) * 2] & eq_b[(i16u // 2) * 2 + 1]
-    eq_d16 = (eq_b[(i16u // 4) * 4] & eq_b[(i16u // 4) * 4 + 1]
-              & eq_b[(i16u // 4) * 4 + 2] & eq_b[(i16u // 4) * 4 + 3])
-    pshufd_sel = ((imm >> ((i16u // 4).astype(jnp.uint64) * _u(2))) & _u(3)
-                  ).astype(jnp.int32)
-    pshufd_idx = pshufd_sel * 4 + (i16u % 4)
-    pslldq_n = jnp.minimum(imm, _u(16)).astype(jnp.int32)
-    psll_idx = jnp.clip(i16u - pslldq_n, 0, 15)
-    psrl_idx = jnp.clip(i16u + pslldq_n, 0, 15)
-    # punpckldq: interleave the low dwords -> [a0 b0 a1 b1] (dword units)
-    punp_src_b = (i16u // 4) & 1  # odd dword slots come from src
-    punp_idx = ((i16u // 8) * 4) + (i16u % 4)
-    # pinsrw: word `cond` replaced by the gpr's low word (mem form is
-    # oracle-serviced: its 2-byte load doesn't fit the 16-byte window)
-    pinsrw_word = _read_reg(gpr, sr, jnp.int32(2))
-    pinsrw_byte = jnp.where(i16u % 2 == 0, pinsrw_word & _u(0xFF),
-                            (pinsrw_word >> _u(8)) & _u(0xFF)).astype(jnp.uint8)
-    sse_bytes = jnp.select(
-        [sub == U.SSE_PXOR, sub == U.SSE_XORPS, sub == U.SSE_POR,
-         sub == U.SSE_PAND, sub == U.SSE_PANDN,
-         sub == U.SSE_PCMPEQB, sub == U.SSE_PCMPEQW, sub == U.SSE_PCMPEQD,
-         sub == U.SSE_PSUBB, sub == U.SSE_PADDB, sub == U.SSE_PMINUB,
-         sub == U.SSE_PUNPCKLQDQ, sub == U.SSE_PSHUFD,
-         sub == U.SSE_PSLLDQ, sub == U.SSE_PSRLDQ,
-         sub == U.SSE_PUNPCKLDQ, sub == U.SSE_PINSRW],
-        [ba ^ bb, ba ^ bb, ba | bb, ba & bb, (~ba) & bb,
-         jnp.where(eq_b, jnp.uint8(0xFF), jnp.uint8(0)),
-         jnp.where(eq_w16, jnp.uint8(0xFF), jnp.uint8(0)),
-         jnp.where(eq_d16, jnp.uint8(0xFF), jnp.uint8(0)),
-         ba - bb, ba + bb, jnp.minimum(ba, bb),
-         jnp.where(i16u < 8, ba, bb[jnp.clip(i16u - 8, 0, 15)]),
-         bb[pshufd_idx],
-         jnp.where(i16u >= pslldq_n, ba[psll_idx], jnp.uint8(0)),
-         jnp.where(i16u + pslldq_n < 16, ba[psrl_idx], jnp.uint8(0)),
-         jnp.where(punp_src_b == 0, ba[punp_idx], bb[punp_idx]),
-         jnp.where(i16u // 2 == cond, pinsrw_byte, ba)],
-        default=ba)
-    sse_out_lo, sse_out_hi = _pack_pair(sse_bytes)
+    with jax.named_scope("sse-bytes"):
+        ba = _unpack_bytes(x_dst_lo, x_dst_hi)
+        bb = jnp.where(sk == U.K_XMM,
+                       _unpack_bytes(x_sr_lo, x_sr_hi),
+                       _unpack_bytes(l1_lo, l1_hi))
+        i16u = jnp.arange(16, dtype=jnp.int32)
+        eq_b = (ba == bb)
+        # word/dword equality via group-reduction of byte equality
+        eq_w16 = eq_b[(i16u // 2) * 2] & eq_b[(i16u // 2) * 2 + 1]
+        eq_d16 = (eq_b[(i16u // 4) * 4] & eq_b[(i16u // 4) * 4 + 1]
+                  & eq_b[(i16u // 4) * 4 + 2] & eq_b[(i16u // 4) * 4 + 3])
+        pshufd_sel = ((imm >> ((i16u // 4).astype(jnp.uint64) * _u(2))) & _u(3)
+                      ).astype(jnp.int32)
+        pshufd_idx = pshufd_sel * 4 + (i16u % 4)
+        pslldq_n = jnp.minimum(imm, _u(16)).astype(jnp.int32)
+        psll_idx = jnp.clip(i16u - pslldq_n, 0, 15)
+        psrl_idx = jnp.clip(i16u + pslldq_n, 0, 15)
+        # punpckldq: interleave the low dwords -> [a0 b0 a1 b1] (dword units)
+        punp_src_b = (i16u // 4) & 1  # odd dword slots come from src
+        punp_idx = ((i16u // 8) * 4) + (i16u % 4)
+        # pinsrw: word `cond` replaced by the gpr's low word (mem form is
+        # oracle-serviced: its 2-byte load doesn't fit the 16-byte window)
+        pinsrw_word = _read_reg(gpr, sr, jnp.int32(2))
+        pinsrw_byte = jnp.where(i16u % 2 == 0, pinsrw_word & _u(0xFF),
+                                (pinsrw_word >> _u(8)) & _u(0xFF)).astype(jnp.uint8)
+        sse_bytes = jnp.select(
+            [sub == U.SSE_PXOR, sub == U.SSE_XORPS, sub == U.SSE_POR,
+             sub == U.SSE_PAND, sub == U.SSE_PANDN,
+             sub == U.SSE_PCMPEQB, sub == U.SSE_PCMPEQW, sub == U.SSE_PCMPEQD,
+             sub == U.SSE_PSUBB, sub == U.SSE_PADDB, sub == U.SSE_PMINUB,
+             sub == U.SSE_PUNPCKLQDQ, sub == U.SSE_PSHUFD,
+             sub == U.SSE_PSLLDQ, sub == U.SSE_PSRLDQ,
+             sub == U.SSE_PUNPCKLDQ, sub == U.SSE_PINSRW],
+            [ba ^ bb, ba ^ bb, ba | bb, ba & bb, (~ba) & bb,
+             jnp.where(eq_b, jnp.uint8(0xFF), jnp.uint8(0)),
+             jnp.where(eq_w16, jnp.uint8(0xFF), jnp.uint8(0)),
+             jnp.where(eq_d16, jnp.uint8(0xFF), jnp.uint8(0)),
+             ba - bb, ba + bb, jnp.minimum(ba, bb),
+             jnp.where(i16u < 8, ba, bb[jnp.clip(i16u - 8, 0, 15)]),
+             bb[pshufd_idx],
+             jnp.where(i16u >= pslldq_n, ba[psll_idx], jnp.uint8(0)),
+             jnp.where(i16u + pslldq_n < 16, ba[psrl_idx], jnp.uint8(0)),
+             jnp.where(punp_src_b == 0, ba[punp_idx], bb[punp_idx]),
+             jnp.where(i16u // 2 == cond, pinsrw_byte, ba)],
+            default=ba)
+        sse_out_lo, sse_out_hi = _pack_pair(sse_bytes)
     # paddq works on the u64 limbs directly (byte-wise adds lose carries)
     is_paddq = is_ssealu & (sub == U.SSE_PADDQ)
     sse_out_lo = jnp.where(is_paddq, x_dst_lo + x_src_lo, sse_out_lo)
@@ -1434,15 +1453,12 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     sse_out_hi = jnp.where(
         is_psrlq, jnp.where(shq_zero, _u(0), x_dst_hi >> shq), sse_out_hi)
     # pmovmskb: sign bit of each src byte -> gpr bit i
-    bsrc_msk = _unpack_bytes(xmm[jnp.clip(sr, 0, 15), 0],
-                             xmm[jnp.clip(sr, 0, 15), 1])
+    bsrc_msk = _unpack_bytes(x_sr_lo, x_sr_hi)
     pmov_mask = jnp.sum(
         jnp.where((bsrc_msk & jnp.uint8(0x80)) != 0,
                   _u(1) << i16u.astype(jnp.uint64), _u(0)))
     # pextrw: word `cond` of the src register, zero-extended into the gpr
-    pextrw_val = (jnp.where(cond < 4,
-                            xmm[jnp.clip(sr, 0, 15), 0],
-                            xmm[jnp.clip(sr, 0, 15), 1])
+    pextrw_val = (jnp.where(cond < 4, x_sr_lo, x_sr_hi)
                   >> ((cond & 3).astype(jnp.uint64) * _u(16))) & _u(0xFFFF)
     # ptest
     ptest_zf = ((x_dst_lo & x_src_lo) == _u(0)) & ((x_dst_hi & x_src_hi) == _u(0))
@@ -1463,279 +1479,280 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     # oracle (see below).  Residual TPU-only caveat: div/sqrt rounding is
     # the platform's — a documented fidelity delta of the fast path,
     # mirroring the bochs-vs-KVM precision split in the reference design.
-    fp_elem8 = srcsize0 == 8       # 4 = float32 lanes, 8 = float64 lanes
-    _m32 = _u(0xFFFFFFFF)
-    fpa32 = jnp.stack([x_dst_lo & _m32, x_dst_lo >> _u(32),
-                       x_dst_hi & _m32, x_dst_hi >> _u(32)]).astype(jnp.uint32)
-    fpb32 = jnp.stack([x_src_lo & _m32, x_src_lo >> _u(32),
-                       x_src_hi & _m32, x_src_hi >> _u(32)]).astype(jnp.uint32)
-    fpa64 = jnp.stack([x_dst_lo, x_dst_hi])
-    fpb64 = jnp.stack([x_src_lo, x_src_hi])
-    fa32 = lax.bitcast_convert_type(fpa32, jnp.float32)
-    fb32 = lax.bitcast_convert_type(fpb32, jnp.float32)
-    fa64 = lax.bitcast_convert_type(fpa64, jnp.float64)
-    fb64 = lax.bitcast_convert_type(fpb64, jnp.float64)
+    with jax.named_scope("sse-fp"):
+        fp_elem8 = srcsize0 == 8       # 4 = float32 lanes, 8 = float64 lanes
+        _m32 = _u(0xFFFFFFFF)
+        fpa32 = jnp.stack([x_dst_lo & _m32, x_dst_lo >> _u(32),
+                           x_dst_hi & _m32, x_dst_hi >> _u(32)]).astype(jnp.uint32)
+        fpb32 = jnp.stack([x_src_lo & _m32, x_src_lo >> _u(32),
+                           x_src_hi & _m32, x_src_hi >> _u(32)]).astype(jnp.uint32)
+        fpa64 = jnp.stack([x_dst_lo, x_dst_hi])
+        fpb64 = jnp.stack([x_src_lo, x_src_hi])
+        fa32 = lax.bitcast_convert_type(fpa32, jnp.float32)
+        fb32 = lax.bitcast_convert_type(fpb32, jnp.float32)
+        fa64 = lax.bitcast_convert_type(fpa64, jnp.float64)
+        fb64 = lax.bitcast_convert_type(fpb64, jnp.float64)
 
-    _QBIT32, _QBIT64 = jnp.uint32(0x00400000), _u(0x0008000000000000)
-    _INDEF32, _INDEF64 = jnp.uint32(0xFFC00000), _u(0xFFF8000000000000)
+        _QBIT32, _QBIT64 = jnp.uint32(0x00400000), _u(0x0008000000000000)
+        _INDEF32, _INDEF64 = jnp.uint32(0xFFC00000), _u(0xFFF8000000000000)
 
-    def _nan32(u):
-        return (u & jnp.uint32(0x7FFFFFFF)) > jnp.uint32(0x7F800000)
+        def _nan32(u):
+            return (u & jnp.uint32(0x7FFFFFFF)) > jnp.uint32(0x7F800000)
 
-    def _nan64(u):
-        return (u & _u(0x7FFFFFFFFFFFFFFF)) > _u(0x7FF0000000000000)
+        def _nan64(u):
+            return (u & _u(0x7FFFFFFFFFFFFFFF)) > _u(0x7FF0000000000000)
 
-    def _b32(f):
-        return lax.bitcast_convert_type(f, jnp.uint32)
+        def _b32(f):
+            return lax.bitcast_convert_type(f, jnp.uint32)
 
-    _b64 = _f64_bits
+        _b64 = _f64_bits
 
-    nan_a32, nan_b32 = _nan32(fpa32), _nan32(fpb32)
-    nan_a64, nan_b64 = _nan64(fpa64), _nan64(fpb64)
+        nan_a32, nan_b32 = _nan32(fpa32), _nan32(fpb32)
+        nan_a64, nan_b64 = _nan64(fpa64), _nan64(fpb64)
 
-    # Denormals: XLA flushes them (FTZ/DAZ) on both the CPU and TPU
-    # backends, where the oracle (numpy on the host thread) honors them.
-    # Any lane whose FP op *touches* the denormal range — denormal input,
-    # or a result the hardware would flush — is routed to the oracle
-    # through the same UNSUPPORTED servicing seam, so the fast path keeps
-    # the overwhelming normal-range majority and the rare denormal op
-    # stays bit-exact.  Detection is conservative (over-flagging is only
-    # a performance event, never a correctness one).
-    def _den32(u):
-        return ((u & jnp.uint32(0x7F800000)) == 0) \
-            & ((u & jnp.uint32(0x7FFFFFFF)) != 0)
+        # Denormals: XLA flushes them (FTZ/DAZ) on both the CPU and TPU
+        # backends, where the oracle (numpy on the host thread) honors them.
+        # Any lane whose FP op *touches* the denormal range — denormal input,
+        # or a result the hardware would flush — is routed to the oracle
+        # through the same UNSUPPORTED servicing seam, so the fast path keeps
+        # the overwhelming normal-range majority and the rare denormal op
+        # stays bit-exact.  Detection is conservative (over-flagging is only
+        # a performance event, never a correctness one).
+        def _den32(u):
+            return ((u & jnp.uint32(0x7F800000)) == 0) \
+                & ((u & jnp.uint32(0x7FFFFFFF)) != 0)
 
-    def _den64(u):
-        return ((u & _u(0x7FF0000000000000)) == _u(0)) \
-            & ((u & _u(0x7FFFFFFFFFFFFFFF)) != _u(0))
+        def _den64(u):
+            return ((u & _u(0x7FF0000000000000)) == _u(0)) \
+                & ((u & _u(0x7FFFFFFFFFFFFFFF)) != _u(0))
 
-    @jax.named_scope("sse-fp")
-    def _fp_elementwise(fa, fb, ua, ub, nan_a, nan_b, bits, qbit, indef,
-                        nanf, denf, magmask, expmask):
-        """arith/minmax/sqrt/cmp over one lane-width's vector (f32[4]/f64[2]).
+        @jax.named_scope("sse-fp")
+        def _fp_elementwise(fa, fb, ua, ub, nan_a, nan_b, bits, qbit, indef,
+                            nanf, denf, magmask, expmask):
+            """arith/minmax/sqrt/cmp over one lane-width's vector (f32[4]/f64[2]).
 
-        Returns (result_bits, denormal_risk) per lane."""
-        r_arith = jnp.select(
-            [sub == U.FP_ADD, sub == U.FP_SUB, sub == U.FP_MUL,
-             sub == U.FP_DIV],
-            [fa + fb, fa - fb, fa * fb, fa / fb], default=fa)
-        r_bits = bits(r_arith)
-        arith_out = jnp.where(
-            nan_a, ua | qbit,
-            jnp.where(nan_b, ub | qbit,
-                      jnp.where(nanf(r_bits), indef, r_bits)))
-        take_a = jnp.where(sub == U.FP_MIN, fa < fb, fa > fb)
-        mm_out = jnp.where(nan_a | nan_b | (fa == fb), ub,
-                           jnp.where(take_a, ua, ub))
-        sq_out = jnp.where(
-            nan_b, ub | qbit,
-            jnp.where(fb < 0, indef, bits(jnp.sqrt(fb))))
-        unord = nan_a | nan_b
-        pred = (imm & _u(7)).astype(jnp.int32)
-        eq, lt, le = fa == fb, fa < fb, fa <= fb
-        cmp_res = jnp.select(
-            [pred == 0, pred == 1, pred == 2, pred == 3,
-             pred == 4, pred == 5, pred == 6],
-            [~unord & eq, ~unord & lt, ~unord & le, unord,
-             unord | ~eq, unord | ~lt, unord | ~le],
-            default=~unord)
-        ones = ~jnp.zeros_like(ua)
-        cmp_out = jnp.where(cmp_res, ones, jnp.zeros_like(ua))
-        out = jnp.select(
-            [(sub >= U.FP_ADD) & (sub <= U.FP_DIV),
-             (sub == U.FP_MIN) | (sub == U.FP_MAX),
-             sub == U.FP_SQRT],
-            [arith_out, mm_out, sq_out], default=cmp_out)
-        # FTZ risk: a flushed result reads as +/-0 where the true result
-        # was a nonzero denormal; true zeros are exactly the listed cases
-        r_zero = (r_bits & magmask) == 0
-        true_zero = jnp.select(
-            [sub == U.FP_ADD, sub == U.FP_SUB, sub == U.FP_MUL],
-            [fa == -fb, fa == fb,
-             ((ua & magmask) == 0) | ((ub & magmask) == 0)],
-            default=((ua & magmask) == 0) | ((ub & magmask) == expmask))
-        ftz = ((sub >= U.FP_ADD) & (sub <= U.FP_DIV)) \
-            & r_zero & ~true_zero & ~nan_a & ~nan_b
-        den_in = jnp.where(sub == U.FP_SQRT, denf(ub), denf(ua) | denf(ub))
-        return out, ftz | den_in
+            Returns (result_bits, denormal_risk) per lane."""
+            r_arith = jnp.select(
+                [sub == U.FP_ADD, sub == U.FP_SUB, sub == U.FP_MUL,
+                 sub == U.FP_DIV],
+                [fa + fb, fa - fb, fa * fb, fa / fb], default=fa)
+            r_bits = bits(r_arith)
+            arith_out = jnp.where(
+                nan_a, ua | qbit,
+                jnp.where(nan_b, ub | qbit,
+                          jnp.where(nanf(r_bits), indef, r_bits)))
+            take_a = jnp.where(sub == U.FP_MIN, fa < fb, fa > fb)
+            mm_out = jnp.where(nan_a | nan_b | (fa == fb), ub,
+                               jnp.where(take_a, ua, ub))
+            sq_out = jnp.where(
+                nan_b, ub | qbit,
+                jnp.where(fb < 0, indef, bits(jnp.sqrt(fb))))
+            unord = nan_a | nan_b
+            pred = (imm & _u(7)).astype(jnp.int32)
+            eq, lt, le = fa == fb, fa < fb, fa <= fb
+            cmp_res = jnp.select(
+                [pred == 0, pred == 1, pred == 2, pred == 3,
+                 pred == 4, pred == 5, pred == 6],
+                [~unord & eq, ~unord & lt, ~unord & le, unord,
+                 unord | ~eq, unord | ~lt, unord | ~le],
+                default=~unord)
+            ones = ~jnp.zeros_like(ua)
+            cmp_out = jnp.where(cmp_res, ones, jnp.zeros_like(ua))
+            out = jnp.select(
+                [(sub >= U.FP_ADD) & (sub <= U.FP_DIV),
+                 (sub == U.FP_MIN) | (sub == U.FP_MAX),
+                 sub == U.FP_SQRT],
+                [arith_out, mm_out, sq_out], default=cmp_out)
+            # FTZ risk: a flushed result reads as +/-0 where the true result
+            # was a nonzero denormal; true zeros are exactly the listed cases
+            r_zero = (r_bits & magmask) == 0
+            true_zero = jnp.select(
+                [sub == U.FP_ADD, sub == U.FP_SUB, sub == U.FP_MUL],
+                [fa == -fb, fa == fb,
+                 ((ua & magmask) == 0) | ((ub & magmask) == 0)],
+                default=((ua & magmask) == 0) | ((ub & magmask) == expmask))
+            ftz = ((sub >= U.FP_ADD) & (sub <= U.FP_DIV)) \
+                & r_zero & ~true_zero & ~nan_a & ~nan_b
+            den_in = jnp.where(sub == U.FP_SQRT, denf(ub), denf(ua) | denf(ub))
+            return out, ftz | den_in
 
-    ew32, ewrisk32 = _fp_elementwise(
-        fa32, fb32, fpa32, fpb32, nan_a32, nan_b32, _b32, _QBIT32,
-        _INDEF32, _nan32, _den32, jnp.uint32(0x7FFFFFFF),
-        jnp.uint32(0x7F800000))
-    ew64, ewrisk64 = _fp_elementwise(
-        fa64, fb64, fpa64, fpb64, nan_a64, nan_b64, _b64, _QBIT64,
-        _INDEF64, _nan64, _den64, _u(0x7FFFFFFFFFFFFFFF),
-        _u(0x7FF0000000000000))
+        ew32, ewrisk32 = _fp_elementwise(
+            fa32, fb32, fpa32, fpb32, nan_a32, nan_b32, _b32, _QBIT32,
+            _INDEF32, _nan32, _den32, jnp.uint32(0x7FFFFFFF),
+            jnp.uint32(0x7F800000))
+        ew64, ewrisk64 = _fp_elementwise(
+            fa64, fb64, fpa64, fpb64, nan_a64, nan_b64, _b64, _QBIT64,
+            _INDEF64, _nan64, _den64, _u(0x7FFFFFFFFFFFFFFF),
+            _u(0x7FF0000000000000))
 
-    def _limbs32(v32):
-        v = v32.astype(jnp.uint64)
-        return v[0] | (v[1] << _u(32)), v[2] | (v[3] << _u(32))
+        def _limbs32(v32):
+            v = v32.astype(jnp.uint64)
+            return v[0] | (v[1] << _u(32)), v[2] | (v[3] << _u(32))
 
-    ew_lo32, ew_hi32 = _limbs32(ew32)
-    ew_lo = jnp.where(fp_elem8, ew64[0], ew_lo32)
-    ew_hi = jnp.where(fp_elem8, ew64[1], ew_hi32)
+        ew_lo32, ew_hi32 = _limbs32(ew32)
+        ew_lo = jnp.where(fp_elem8, ew64[0], ew_lo32)
+        ew_hi = jnp.where(fp_elem8, ew64[1], ew_hi32)
 
-    fp_is_f2i = (sub == U.FP_CVT_F2I) | (sub == U.FP_CVT_F2I_T)
-    fp_is_comi = (sub == U.FP_UCOMI) | (sub == U.FP_COMI)
+        fp_is_f2i = (sub == U.FP_CVT_F2I) | (sub == U.FP_CVT_F2I_T)
+        fp_is_comi = (sub == U.FP_UCOMI) | (sub == U.FP_COMI)
 
-    # lanes an op actually reads (scalar forms must not flag junk in the
-    # upper lanes of the destination register)
-    used32 = jnp.where(sext_f == 1, jnp.ones(4, bool),
-                       jnp.arange(4) == 0)
-    used64 = jnp.where(sext_f == 1, jnp.ones(2, bool),
-                       jnp.arange(2) == 0)
-    ew_risk = jnp.where(fp_elem8, jnp.any(ewrisk64 & used64),
-                        jnp.any(ewrisk32 & used32))
-    comi_risk = jnp.where(fp_elem8,
-                          _den64(fpa64[0]) | _den64(fpb64[0]),
-                          _den32(fpa32[0]) | _den32(fpb32[0]))
-    # f2f: s2d flags denormal f32 inputs (DAZ); d2s flags any f64 input
-    # small enough that the f32 result lands at/under the normal minimum
-    d2s_small = (((fpb64 >> _u(52)) & _u(0x7FF)) <= _u(897)) \
-        & ((fpb64 & _u(0x7FFFFFFFFFFFFFFF)) != _u(0))
-    f2f_risk = jnp.where(fp_elem8, jnp.any(d2s_small & used64),
-                         jnp.any(_den32(fpb32)
-                                 & jnp.where(sext_f == 1,
-                                             jnp.arange(4) < 2,
-                                             jnp.arange(4) == 0)))
-    fp_denorm = is_ssefp & jnp.select(
-        [fp_is_ew, fp_is_comi, sub == U.FP_CVT_F2F],
-        [ew_risk, comi_risk, f2f_risk], default=jnp.bool_(False))
+        # lanes an op actually reads (scalar forms must not flag junk in the
+        # upper lanes of the destination register)
+        used32 = jnp.where(sext_f == 1, jnp.ones(4, bool),
+                           jnp.arange(4) == 0)
+        used64 = jnp.where(sext_f == 1, jnp.ones(2, bool),
+                           jnp.arange(2) == 0)
+        ew_risk = jnp.where(fp_elem8, jnp.any(ewrisk64 & used64),
+                            jnp.any(ewrisk32 & used32))
+        comi_risk = jnp.where(fp_elem8,
+                              _den64(fpa64[0]) | _den64(fpb64[0]),
+                              _den32(fpa32[0]) | _den32(fpb32[0]))
+        # f2f: s2d flags denormal f32 inputs (DAZ); d2s flags any f64 input
+        # small enough that the f32 result lands at/under the normal minimum
+        d2s_small = (((fpb64 >> _u(52)) & _u(0x7FF)) <= _u(897)) \
+            & ((fpb64 & _u(0x7FFFFFFFFFFFFFFF)) != _u(0))
+        f2f_risk = jnp.where(fp_elem8, jnp.any(d2s_small & used64),
+                             jnp.any(_den32(fpb32)
+                                     & jnp.where(sext_f == 1,
+                                                 jnp.arange(4) < 2,
+                                                 jnp.arange(4) == 0)))
+        fp_denorm = is_ssefp & jnp.select(
+            [fp_is_ew, fp_is_comi, sub == U.FP_CVT_F2F],
+            [ew_risk, comi_risk, f2f_risk], default=jnp.bool_(False))
 
-    # ucomis/comis flag image: unordered -> ZF=PF=CF=1; else ZF=(a==b),
-    # CF=(a<b), PF=0; OF/AF/SF cleared (oracle set_flags call)
-    uc_unord = jnp.where(fp_elem8, nan_a64[0] | nan_b64[0],
-                         nan_a32[0] | nan_b32[0])
-    uc_eq = jnp.where(fp_elem8, fa64[0] == fb64[0], (fa32[0] == fb32[0]))
-    uc_lt = jnp.where(fp_elem8, fa64[0] < fb64[0], (fa32[0] < fb32[0]))
-    ucomi_rf = (rf & ~_u(FLAGS_ARITH)) | _mkflags(
-        uc_unord | (~uc_unord & uc_lt), uc_unord, jnp.bool_(False),
-        uc_unord | (~uc_unord & uc_eq), jnp.bool_(False), jnp.bool_(False))
+        # ucomis/comis flag image: unordered -> ZF=PF=CF=1; else ZF=(a==b),
+        # CF=(a<b), PF=0; OF/AF/SF cleared (oracle set_flags call)
+        uc_unord = jnp.where(fp_elem8, nan_a64[0] | nan_b64[0],
+                             nan_a32[0] | nan_b32[0])
+        uc_eq = jnp.where(fp_elem8, fa64[0] == fb64[0], (fa32[0] == fb32[0]))
+        uc_lt = jnp.where(fp_elem8, fa64[0] < fb64[0], (fa32[0] < fb32[0]))
+        ucomi_rf = (rf & ~_u(FLAGS_ARITH)) | _mkflags(
+            uc_unord | (~uc_unord & uc_lt), uc_unord, jnp.bool_(False),
+            uc_unord | (~uc_unord & uc_eq), jnp.bool_(False), jnp.bool_(False))
 
-    # int -> fp scalar (cvtsi2ss/sd): integer comes from a GPR or an
-    # opsize-wide memory load, sign-extended, rounded ONCE by the convert
-    i2f_raw = jnp.where(sk == U.K_REG, _read_reg(gpr, sr, opsize),
-                        l1_lo & _size_mask(opsize))
-    i2f_int = _sext(i2f_raw, opsize).astype(jnp.int64)
-    i2f_b32 = _b32(i2f_int.astype(jnp.float32)).astype(jnp.uint64)
-    i2f_b64 = _b64(i2f_int.astype(jnp.float64))
-    i2f_lo = jnp.where(fp_elem8, i2f_b64, i2f_b32)
+        # int -> fp scalar (cvtsi2ss/sd): integer comes from a GPR or an
+        # opsize-wide memory load, sign-extended, rounded ONCE by the convert
+        i2f_raw = jnp.where(sk == U.K_REG, _read_reg(gpr, sr, opsize),
+                            l1_lo & _size_mask(opsize))
+        i2f_int = _sext(i2f_raw, opsize).astype(jnp.int64)
+        i2f_b32 = _b32(i2f_int.astype(jnp.float32)).astype(jnp.uint64)
+        i2f_b64 = _b64(i2f_int.astype(jnp.float64))
+        i2f_lo = jnp.where(fp_elem8, i2f_b64, i2f_b32)
 
-    # fp -> int (cvt/cvtt to gpr, and the packed PS2DQ/PD2DQ families):
-    # rounding/range logic runs in f64 exactly like the oracle's to_int
-    # (f32 widens losslessly first), indefinite = 1 << (bits-1)
-    @jax.named_scope("sse-fp")
-    def _fp_to_int(v64, int_bits, truncate, src_nan):
-        limit = jnp.float64(2.0) ** (int_bits - 1)
-        r = jnp.where(truncate, jnp.trunc(v64),
-                      lax.round(v64, lax.RoundingMethod.TO_NEAREST_EVEN))
-        bad = src_nan | jnp.isnan(v64) | (r >= limit) | (r < -limit)
-        indef = _u(1) << jnp.uint64(int_bits - 1)
-        safe = jnp.clip(r, -limit, limit - 1)
-        return jnp.where(bad, indef,
-                         safe.astype(jnp.int64).astype(jnp.uint64)
-                         & _size_mask(jnp.int32(int_bits // 8)))
+        # fp -> int (cvt/cvtt to gpr, and the packed PS2DQ/PD2DQ families):
+        # rounding/range logic runs in f64 exactly like the oracle's to_int
+        # (f32 widens losslessly first), indefinite = 1 << (bits-1)
+        @jax.named_scope("sse-fp")
+        def _fp_to_int(v64, int_bits, truncate, src_nan):
+            limit = jnp.float64(2.0) ** (int_bits - 1)
+            r = jnp.where(truncate, jnp.trunc(v64),
+                          lax.round(v64, lax.RoundingMethod.TO_NEAREST_EVEN))
+            bad = src_nan | jnp.isnan(v64) | (r >= limit) | (r < -limit)
+            indef = _u(1) << jnp.uint64(int_bits - 1)
+            safe = jnp.clip(r, -limit, limit - 1)
+            return jnp.where(bad, indef,
+                             safe.astype(jnp.int64).astype(jnp.uint64)
+                             & _size_mask(jnp.int32(int_bits // 8)))
 
-    f2i_src64 = jnp.where(fp_elem8, fb64[0], fb32[0].astype(jnp.float64))
-    f2i_nan = jnp.where(fp_elem8, nan_b64[0], nan_b32[0])
-    f2i_trunc = sub == U.FP_CVT_F2I_T
-    f2i_val = jnp.where(
-        opsize >= 8,
-        _fp_to_int(f2i_src64, 64, f2i_trunc, f2i_nan),
-        _fp_to_int(f2i_src64, 32, f2i_trunc, f2i_nan))
+        f2i_src64 = jnp.where(fp_elem8, fb64[0], fb32[0].astype(jnp.float64))
+        f2i_nan = jnp.where(fp_elem8, nan_b64[0], nan_b32[0])
+        f2i_trunc = sub == U.FP_CVT_F2I_T
+        f2i_val = jnp.where(
+            opsize >= 8,
+            _fp_to_int(f2i_src64, 64, f2i_trunc, f2i_nan),
+            _fp_to_int(f2i_src64, 32, f2i_trunc, f2i_nan))
 
-    # f32 <-> f64 converts: NaNs rebuilt at the bit level (payload shifted,
-    # quiet bit forced) so the device never depends on platform NaN rules
-    @jax.named_scope("sse-fp")
-    def _cvt_s2d(u32v, f32v, isnan):
-        sign = (u32v.astype(jnp.uint64) >> _u(31)) << _u(63)
-        frac = (u32v.astype(jnp.uint64) & _u(0x7FFFFF)) << _u(29)
-        nan_bits = sign | _u(0x7FF0000000000000) | _QBIT64 | frac
-        return jnp.where(isnan, nan_bits, _b64(f32v.astype(jnp.float64)))
+        # f32 <-> f64 converts: NaNs rebuilt at the bit level (payload shifted,
+        # quiet bit forced) so the device never depends on platform NaN rules
+        @jax.named_scope("sse-fp")
+        def _cvt_s2d(u32v, f32v, isnan):
+            sign = (u32v.astype(jnp.uint64) >> _u(31)) << _u(63)
+            frac = (u32v.astype(jnp.uint64) & _u(0x7FFFFF)) << _u(29)
+            nan_bits = sign | _u(0x7FF0000000000000) | _QBIT64 | frac
+            return jnp.where(isnan, nan_bits, _b64(f32v.astype(jnp.float64)))
 
-    @jax.named_scope("sse-fp")
-    def _cvt_d2s(u64v, f64v, isnan):
-        sign = (u64v >> _u(63)).astype(jnp.uint32) << jnp.uint32(31)
-        frac = ((u64v >> _u(29)) & _u(0x3FFFFF)).astype(jnp.uint32)
-        nan_bits = sign | jnp.uint32(0x7F800000) | _QBIT32 | frac
-        return jnp.where(isnan, nan_bits, _b32(f64v.astype(jnp.float32)))
+        @jax.named_scope("sse-fp")
+        def _cvt_d2s(u64v, f64v, isnan):
+            sign = (u64v >> _u(63)).astype(jnp.uint32) << jnp.uint32(31)
+            frac = ((u64v >> _u(29)) & _u(0x3FFFFF)).astype(jnp.uint32)
+            nan_bits = sign | jnp.uint32(0x7F800000) | _QBIT32 | frac
+            return jnp.where(isnan, nan_bits, _b32(f64v.astype(jnp.float32)))
 
-    s2d = _cvt_s2d(fpb32, fb32, nan_b32)          # u64[4], lanes 0/1 used
-    d2s = _cvt_d2s(fpb64, fb64, nan_b64)          # u32[2]
-    f2f_packed_lo4 = s2d[0]                        # cvtps2pd
-    f2f_packed_hi4 = s2d[1]
-    f2f_packed_lo8 = (d2s[0].astype(jnp.uint64)
-                      | (d2s[1].astype(jnp.uint64) << _u(32)))  # cvtpd2ps
-    f2f_lo = jnp.where(fp_elem8, f2f_packed_lo8, f2f_packed_lo4)
-    f2f_hi = jnp.where(fp_elem8, _u(0), f2f_packed_hi4)
+        s2d = _cvt_s2d(fpb32, fb32, nan_b32)          # u64[4], lanes 0/1 used
+        d2s = _cvt_d2s(fpb64, fb64, nan_b64)          # u32[2]
+        f2f_packed_lo4 = s2d[0]                        # cvtps2pd
+        f2f_packed_hi4 = s2d[1]
+        f2f_packed_lo8 = (d2s[0].astype(jnp.uint64)
+                          | (d2s[1].astype(jnp.uint64) << _u(32)))  # cvtpd2ps
+        f2f_lo = jnp.where(fp_elem8, f2f_packed_lo8, f2f_packed_lo4)
+        f2f_hi = jnp.where(fp_elem8, _u(0), f2f_packed_hi4)
 
-    # packed int <-> fp families (each writes the full register)
-    dq2ps = _b32(fpb32.astype(jnp.int32).astype(jnp.float32))
-    ps2dq_t = sub == U.FP_CVT_PS2DQ_T
-    ps2dq = jnp.stack([
-        _fp_to_int(fb32[i].astype(jnp.float64), 32, ps2dq_t, nan_b32[i])
-        for i in range(4)]).astype(jnp.uint32)
-    dq2pd_lo = _b64(fpb32[0].astype(jnp.int32).astype(jnp.float64))
-    dq2pd_hi = _b64(fpb32[1].astype(jnp.int32).astype(jnp.float64))
-    pd2dq_t = sub == U.FP_CVT_PD2DQ_T
-    pd2dq = jnp.stack([
-        _fp_to_int(fb64[i], 32, pd2dq_t, nan_b64[i]) for i in range(2)])
-    pd2dq_lo = (pd2dq[0] & _m32) | ((pd2dq[1] & _m32) << _u(32))
+        # packed int <-> fp families (each writes the full register)
+        dq2ps = _b32(fpb32.astype(jnp.int32).astype(jnp.float32))
+        ps2dq_t = sub == U.FP_CVT_PS2DQ_T
+        ps2dq = jnp.stack([
+            _fp_to_int(fb32[i].astype(jnp.float64), 32, ps2dq_t, nan_b32[i])
+            for i in range(4)]).astype(jnp.uint32)
+        dq2pd_lo = _b64(fpb32[0].astype(jnp.int32).astype(jnp.float64))
+        dq2pd_hi = _b64(fpb32[1].astype(jnp.int32).astype(jnp.float64))
+        pd2dq_t = sub == U.FP_CVT_PD2DQ_T
+        pd2dq = jnp.stack([
+            _fp_to_int(fb64[i], 32, pd2dq_t, nan_b64[i]) for i in range(2)])
+        pd2dq_lo = (pd2dq[0] & _m32) | ((pd2dq[1] & _m32) << _u(32))
 
-    # shufps/shufpd, unpckl/h ps/pd: pure lane shuffles
-    shuf_sel = imm
-    sh32_src = jnp.concatenate([fpa32, fpb32])    # picks: dst,dst,src,src
-    shufps = jnp.stack([
-        sh32_src[jnp.where(jnp.int32(i) < 2, jnp.int32(0), jnp.int32(4))
-                 + ((shuf_sel >> _u(2 * i)) & _u(3)).astype(jnp.int32)]
-        for i in range(4)])
-    shufpd_lo = jnp.where((shuf_sel & _u(1)) != 0, x_dst_hi, x_dst_lo)
-    shufpd_hi = jnp.where((shuf_sel & _u(2)) != 0, x_src_hi, x_src_lo)
-    shufps_lo, shufps_hi = _limbs32(shufps)
-    unp_h = sub == U.FP_UNPCKH
-    unpck32 = jnp.stack([
-        jnp.where(unp_h, fpa32[2], fpa32[0]), jnp.where(unp_h, fpb32[2], fpb32[0]),
-        jnp.where(unp_h, fpa32[3], fpa32[1]), jnp.where(unp_h, fpb32[3], fpb32[1])])
-    unpck32_lo, unpck32_hi = _limbs32(unpck32)
-    unpck64_lo = jnp.where(unp_h, x_dst_hi, x_dst_lo)
-    unpck64_hi = jnp.where(unp_h, x_src_hi, x_src_lo)
+        # shufps/shufpd, unpckl/h ps/pd: pure lane shuffles
+        shuf_sel = imm
+        sh32_src = jnp.concatenate([fpa32, fpb32])    # picks: dst,dst,src,src
+        shufps = jnp.stack([
+            sh32_src[jnp.where(jnp.int32(i) < 2, jnp.int32(0), jnp.int32(4))
+                     + ((shuf_sel >> _u(2 * i)) & _u(3)).astype(jnp.int32)]
+            for i in range(4)])
+        shufpd_lo = jnp.where((shuf_sel & _u(1)) != 0, x_dst_hi, x_dst_lo)
+        shufpd_hi = jnp.where((shuf_sel & _u(2)) != 0, x_src_hi, x_src_lo)
+        shufps_lo, shufps_hi = _limbs32(shufps)
+        unp_h = sub == U.FP_UNPCKH
+        unpck32 = jnp.stack([
+            jnp.where(unp_h, fpa32[2], fpa32[0]), jnp.where(unp_h, fpb32[2], fpb32[0]),
+            jnp.where(unp_h, fpa32[3], fpa32[1]), jnp.where(unp_h, fpb32[3], fpb32[1])])
+        unpck32_lo, unpck32_hi = _limbs32(unpck32)
+        unpck64_lo = jnp.where(unp_h, x_dst_hi, x_dst_lo)
+        unpck64_hi = jnp.where(unp_h, x_src_hi, x_src_lo)
 
-    fp_sub_sel = [
-        sub == U.FP_CVT_I2F,
-        sub == U.FP_CVT_F2F,
-        sub == U.FP_CVT_DQ2PS,
-        (sub == U.FP_CVT_PS2DQ) | (sub == U.FP_CVT_PS2DQ_T),
-        sub == U.FP_CVT_DQ2PD,
-        (sub == U.FP_CVT_PD2DQ) | (sub == U.FP_CVT_PD2DQ_T),
-        sub == U.FP_SHUF,
-        (sub == U.FP_UNPCKL) | (sub == U.FP_UNPCKH),
-    ]
-    dq2ps_lo, dq2ps_hi = _limbs32(dq2ps)
-    ps2dq_lo, ps2dq_hi = _limbs32(ps2dq)
-    fp_res_lo = jnp.select(fp_sub_sel, [
-        i2f_lo, f2f_lo, dq2ps_lo, ps2dq_lo, dq2pd_lo, pd2dq_lo,
-        jnp.where(fp_elem8, shufpd_lo, shufps_lo),
-        jnp.where(fp_elem8, unpck64_lo, unpck32_lo),
-    ], default=ew_lo)
-    fp_res_hi = jnp.select(fp_sub_sel, [
-        _u(0), f2f_hi, dq2ps_hi, ps2dq_hi, dq2pd_hi, _u(0),
-        jnp.where(fp_elem8, shufpd_hi, shufps_hi),
-        jnp.where(fp_elem8, unpck64_hi, unpck32_hi),
-    ], default=ew_hi)
-    # destination write width: 16 = whole register, else low bytes merge
-    fp_wsz = jnp.select(
-        [sub == U.FP_CVT_I2F,
-         sub == U.FP_CVT_F2F,
-         fp_is_ew],
-        [srcsize0,
-         jnp.where(sext_f == 1, jnp.int32(16), 12 - srcsize0),
-         jnp.where(sext_f == 1, jnp.int32(16), srcsize0)],
-        default=jnp.int32(16))
-    fp_wlo_mask = _size_mask(jnp.minimum(fp_wsz, 8))
-    fp_out_lo = (x_dst_lo & ~fp_wlo_mask) | (fp_res_lo & fp_wlo_mask)
-    fp_out_hi = jnp.where(fp_wsz >= 16, fp_res_hi, x_dst_hi)
-    fp_writes_xmm = is_ssefp & ~fp_is_f2i & ~fp_is_comi
+        fp_sub_sel = [
+            sub == U.FP_CVT_I2F,
+            sub == U.FP_CVT_F2F,
+            sub == U.FP_CVT_DQ2PS,
+            (sub == U.FP_CVT_PS2DQ) | (sub == U.FP_CVT_PS2DQ_T),
+            sub == U.FP_CVT_DQ2PD,
+            (sub == U.FP_CVT_PD2DQ) | (sub == U.FP_CVT_PD2DQ_T),
+            sub == U.FP_SHUF,
+            (sub == U.FP_UNPCKL) | (sub == U.FP_UNPCKH),
+        ]
+        dq2ps_lo, dq2ps_hi = _limbs32(dq2ps)
+        ps2dq_lo, ps2dq_hi = _limbs32(ps2dq)
+        fp_res_lo = jnp.select(fp_sub_sel, [
+            i2f_lo, f2f_lo, dq2ps_lo, ps2dq_lo, dq2pd_lo, pd2dq_lo,
+            jnp.where(fp_elem8, shufpd_lo, shufps_lo),
+            jnp.where(fp_elem8, unpck64_lo, unpck32_lo),
+        ], default=ew_lo)
+        fp_res_hi = jnp.select(fp_sub_sel, [
+            _u(0), f2f_hi, dq2ps_hi, ps2dq_hi, dq2pd_hi, _u(0),
+            jnp.where(fp_elem8, shufpd_hi, shufps_hi),
+            jnp.where(fp_elem8, unpck64_hi, unpck32_hi),
+        ], default=ew_hi)
+        # destination write width: 16 = whole register, else low bytes merge
+        fp_wsz = jnp.select(
+            [sub == U.FP_CVT_I2F,
+             sub == U.FP_CVT_F2F,
+             fp_is_ew],
+            [srcsize0,
+             jnp.where(sext_f == 1, jnp.int32(16), 12 - srcsize0),
+             jnp.where(sext_f == 1, jnp.int32(16), srcsize0)],
+            default=jnp.int32(16))
+        fp_wlo_mask = _size_mask(jnp.minimum(fp_wsz, 8))
+        fp_out_lo = (x_dst_lo & ~fp_wlo_mask) | (fp_res_lo & fp_wlo_mask)
+        fp_out_hi = jnp.where(fp_wsz >= 16, fp_res_hi, x_dst_hi)
+        fp_writes_xmm = is_ssefp & ~fp_is_f2i & ~fp_is_comi
 
     # -- x87 (OPC_X87) device execution -----------------------------------
     # The same f64-value model as the oracle (emu._exec_x87; bit-exact vs
@@ -1745,206 +1762,208 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     # block (dst-NaN-wins, quieting, real-indefinite).  FXSAVE-class
     # state movers stay oracle-serviced (x87_oracle above); denormal-
     # touching lanes divert like SSE-FP lanes do.
-    fpst_v, fpcw_v, fpsw_v, fptw_v = st.fpst, st.fpcw, st.fpsw, st.fptw
-    x_top = (fpsw_v >> _u(11)) & _u(7)
-    x_i = imm & _u(7)
+    with jax.named_scope("x87"):
+        fpst_v, fpcw_v, fpsw_v, fptw_v = st.fpst, st.fpcw, st.fpsw, st.fptw
+        x_top = (fpsw_v >> _u(11)) & _u(7)
+        x_i = imm & _u(7)
 
-    @jax.named_scope("x87")
-    def _xphys(k):
-        return ((x_top + k) & _u(7)).astype(jnp.int32)
+        @jax.named_scope("x87")
+        def _xphys(k):
+            return ((x_top + k) & _u(7)).astype(jnp.int32)
 
-    x_ph0 = _xphys(_u(0))
-    x_phi = _xphys(x_i)
-    # one two-row gather instead of two scalar gathers (kernel-count
-    # currency: the step wall tracks gather-class kernels, not bytes)
-    x_st_pair = fpst_v[jnp.stack([x_ph0, x_phi])]
-    st0_b = x_st_pair[0]
-    sti_b = x_st_pair[1]
-    st0_f = lax.bitcast_convert_type(st0_b, jnp.float64)
+        x_ph0 = _xphys(_u(0))
+        x_phi = _xphys(x_i)
+        # one two-row gather instead of two scalar gathers (kernel-count
+        # currency: the step wall tracks gather-class kernels, not bytes)
+        with jax.named_scope("vector-file"):
+            x_st_pair = fpst_v[jnp.stack([x_ph0, x_phi])]
+        st0_b = x_st_pair[0]
+        sti_b = x_st_pair[1]
+        st0_f = lax.bitcast_convert_type(st0_b, jnp.float64)
 
-    # memory operand -> f64 bits: m64 is a raw bit move, m32 converts
-    # with the NaN-safe widening, integers convert exactly like the
-    # oracle's int64 -> float64
-    xm32_u = (l1_lo & _m32).astype(jnp.uint32)
-    xm32_f = lax.bitcast_convert_type(xm32_u, jnp.float32)
-    x_mem_b = jnp.where(srcsize0 >= 8, l1_lo,
-                        _cvt_s2d(xm32_u, xm32_f, _nan32(xm32_u)))
-    x_fild_b = _b64(_sext(l1_lo, srcsize0).astype(jnp.int64)
-                    .astype(jnp.float64))
+        # memory operand -> f64 bits: m64 is a raw bit move, m32 converts
+        # with the NaN-safe widening, integers convert exactly like the
+        # oracle's int64 -> float64
+        xm32_u = (l1_lo & _m32).astype(jnp.uint32)
+        xm32_f = lax.bitcast_convert_type(xm32_u, jnp.float32)
+        x_mem_b = jnp.where(srcsize0 >= 8, l1_lo,
+                            _cvt_s2d(xm32_u, xm32_f, _nan32(xm32_u)))
+        x_fild_b = _b64(_sext(l1_lo, srcsize0).astype(jnp.int64)
+                        .astype(jnp.float64))
 
-    # arithmetic (ADD/MUL/SUB/SUBR/DIV/DIVR by the encoded digit; COM/
-    # COMP digits compare instead)
-    x_arith_m = sub == U.X87_ARITH_M
-    x_arith_st = sub == U.X87_ARITH_ST
-    x_dsti = x_arith_st & (dr == 1)       # DC/DE: st(i) is the destination
-    xa_b = jnp.where(x_dsti, sti_b, st0_b)
-    xb_b = jnp.where(x_arith_m, x_mem_b,
-                     jnp.where(x_dsti, st0_b, sti_b))
-    xa_f = lax.bitcast_convert_type(xa_b, jnp.float64)
-    xb_f = lax.bitcast_convert_type(xb_b, jnp.float64)
-    x_r = jnp.select(
-        [cond == U.X87_OP_ADD, cond == U.X87_OP_MUL,
-         cond == U.X87_OP_SUB, cond == U.X87_OP_SUBR,
-         cond == U.X87_OP_DIV],
-        [xa_f + xb_f, xa_f * xb_f, xa_f - xb_f, xb_f - xa_f, xa_f / xb_f],
-        default=xb_f / xa_f)  # X87_OP_DIVR
-    x_r_b = _b64(x_r)
-    nan_xa, nan_xb = _nan64(xa_b), _nan64(xb_b)
-    # NaN routing follows the OPERATION's operand order: hardware
-    # propagates the first source operand's NaN, and for the reversed
-    # forms (fsubr/fdivr: b OP a) that is xb — matching the oracle's
-    # `bn - an` / `bn / an`
-    x_rev = (cond == U.X87_OP_SUBR) | (cond == U.X87_OP_DIVR)
-    x_n1 = jnp.where(x_rev, nan_xb, nan_xa)
-    x_n1_b = jnp.where(x_rev, xb_b, xa_b)
-    x_n2 = jnp.where(x_rev, nan_xa, nan_xb)
-    x_n2_b = jnp.where(x_rev, xa_b, xb_b)
-    x_arith_out = jnp.where(
-        x_n1, x_n1_b | _QBIT64,
-        jnp.where(x_n2, x_n2_b | _QBIT64,
-                  jnp.where(_nan64(x_r_b), _INDEF64, x_r_b)))
-    x_is_com_digit = (cond == U.X87_OP_COM) | (cond == U.X87_OP_COMP)
-    x_arith_writes = (x_arith_m | x_arith_st) & ~x_is_com_digit
+        # arithmetic (ADD/MUL/SUB/SUBR/DIV/DIVR by the encoded digit; COM/
+        # COMP digits compare instead)
+        x_arith_m = sub == U.X87_ARITH_M
+        x_arith_st = sub == U.X87_ARITH_ST
+        x_dsti = x_arith_st & (dr == 1)       # DC/DE: st(i) is the destination
+        xa_b = jnp.where(x_dsti, sti_b, st0_b)
+        xb_b = jnp.where(x_arith_m, x_mem_b,
+                         jnp.where(x_dsti, st0_b, sti_b))
+        xa_f = lax.bitcast_convert_type(xa_b, jnp.float64)
+        xb_f = lax.bitcast_convert_type(xb_b, jnp.float64)
+        x_r = jnp.select(
+            [cond == U.X87_OP_ADD, cond == U.X87_OP_MUL,
+             cond == U.X87_OP_SUB, cond == U.X87_OP_SUBR,
+             cond == U.X87_OP_DIV],
+            [xa_f + xb_f, xa_f * xb_f, xa_f - xb_f, xb_f - xa_f, xa_f / xb_f],
+            default=xb_f / xa_f)  # X87_OP_DIVR
+        x_r_b = _b64(x_r)
+        nan_xa, nan_xb = _nan64(xa_b), _nan64(xb_b)
+        # NaN routing follows the OPERATION's operand order: hardware
+        # propagates the first source operand's NaN, and for the reversed
+        # forms (fsubr/fdivr: b OP a) that is xb — matching the oracle's
+        # `bn - an` / `bn / an`
+        x_rev = (cond == U.X87_OP_SUBR) | (cond == U.X87_OP_DIVR)
+        x_n1 = jnp.where(x_rev, nan_xb, nan_xa)
+        x_n1_b = jnp.where(x_rev, xb_b, xa_b)
+        x_n2 = jnp.where(x_rev, nan_xa, nan_xb)
+        x_n2_b = jnp.where(x_rev, xa_b, xb_b)
+        x_arith_out = jnp.where(
+            x_n1, x_n1_b | _QBIT64,
+            jnp.where(x_n2, x_n2_b | _QBIT64,
+                      jnp.where(_nan64(x_r_b), _INDEF64, x_r_b)))
+        x_is_com_digit = (cond == U.X87_OP_COM) | (cond == U.X87_OP_COMP)
+        x_arith_writes = (x_arith_m | x_arith_st) & ~x_is_com_digit
 
-    # compares: fcom/fucom (C3/C2/C0 in the status word), fcomi/fucomi
-    # (ZF/PF/CF in rflags) — same unordered rules as ucomis
-    x_cmp_b = jnp.where(x_arith_m, x_mem_b, sti_b)
-    x_cmp_bf = lax.bitcast_convert_type(x_cmp_b, jnp.float64)
-    x_unord = _nan64(st0_b) | _nan64(x_cmp_b)
-    x_eq = st0_f == x_cmp_bf
-    x_lt = st0_f < x_cmp_bf
-    x87_comi_rf = (rf & ~_u(FLAGS_ARITH)) | _mkflags(
-        x_unord | (~x_unord & x_lt), x_unord, jnp.bool_(False),
-        x_unord | (~x_unord & x_eq), jnp.bool_(False), jnp.bool_(False))
-    x_com_bits = (jnp.where(x_unord | (~x_unord & x_eq), _u(0x4000), _u(0))
-                  | jnp.where(x_unord, _u(0x400), _u(0))
-                  | jnp.where(x_unord | (~x_unord & x_lt), _u(0x100), _u(0)))
-    x_is_com = is_x87 & ((sub == U.X87_COM)
-                         | ((x_arith_m | x_arith_st) & x_is_com_digit))
+        # compares: fcom/fucom (C3/C2/C0 in the status word), fcomi/fucomi
+        # (ZF/PF/CF in rflags) — same unordered rules as ucomis
+        x_cmp_b = jnp.where(x_arith_m, x_mem_b, sti_b)
+        x_cmp_bf = lax.bitcast_convert_type(x_cmp_b, jnp.float64)
+        x_unord = _nan64(st0_b) | _nan64(x_cmp_b)
+        x_eq = st0_f == x_cmp_bf
+        x_lt = st0_f < x_cmp_bf
+        x87_comi_rf = (rf & ~_u(FLAGS_ARITH)) | _mkflags(
+            x_unord | (~x_unord & x_lt), x_unord, jnp.bool_(False),
+            x_unord | (~x_unord & x_eq), jnp.bool_(False), jnp.bool_(False))
+        x_com_bits = (jnp.where(x_unord | (~x_unord & x_eq), _u(0x4000), _u(0))
+                      | jnp.where(x_unord, _u(0x400), _u(0))
+                      | jnp.where(x_unord | (~x_unord & x_lt), _u(0x100), _u(0)))
+        x_is_com = is_x87 & ((sub == U.X87_COM)
+                             | ((x_arith_m | x_arith_st) & x_is_com_digit))
 
-    # fist(p)/fisttp: fpcw.RC rounding (fisttp always chops), integer
-    # indefinite on NaN/overflow — the oracle's _exec_x87 FIST logic
-    x_rc = jnp.where(sub == U.X87_FIST_T, _u(3), (fpcw_v >> _u(10)) & _u(3))
-    x_bits_n = srcsize0 * 8
-    x_limit = jnp.exp2((x_bits_n - 1).astype(jnp.float64))
-    x_round = jnp.select(
-        [x_rc == _u(0), x_rc == _u(1), x_rc == _u(2)],
-        [lax.round(st0_f, lax.RoundingMethod.TO_NEAREST_EVEN),
-         jnp.floor(st0_f), jnp.ceil(st0_f)],
-        default=jnp.trunc(st0_f))
-    x_fist_bad = _nan64(st0_b) | (x_round >= x_limit) | (x_round < -x_limit)
-    x_fist_safe = jnp.clip(x_round, -x_limit, x_limit - 1)
-    x_fist_val = jnp.where(
-        x_fist_bad,
-        _shl(_u(1), (x_bits_n - 1).astype(jnp.uint64)),
-        x_fist_safe.astype(jnp.int64).astype(jnp.uint64)
-        ) & _size_mask(srcsize0)
+        # fist(p)/fisttp: fpcw.RC rounding (fisttp always chops), integer
+        # indefinite on NaN/overflow — the oracle's _exec_x87 FIST logic
+        x_rc = jnp.where(sub == U.X87_FIST_T, _u(3), (fpcw_v >> _u(10)) & _u(3))
+        x_bits_n = srcsize0 * 8
+        x_limit = jnp.exp2((x_bits_n - 1).astype(jnp.float64))
+        x_round = jnp.select(
+            [x_rc == _u(0), x_rc == _u(1), x_rc == _u(2)],
+            [lax.round(st0_f, lax.RoundingMethod.TO_NEAREST_EVEN),
+             jnp.floor(st0_f), jnp.ceil(st0_f)],
+            default=jnp.trunc(st0_f))
+        x_fist_bad = _nan64(st0_b) | (x_round >= x_limit) | (x_round < -x_limit)
+        x_fist_safe = jnp.clip(x_round, -x_limit, x_limit - 1)
+        x_fist_val = jnp.where(
+            x_fist_bad,
+            _shl(_u(1), (x_bits_n - 1).astype(jnp.uint64)),
+            x_fist_safe.astype(jnp.int64).astype(jnp.uint64)
+            ) & _size_mask(srcsize0)
 
-    # fst m32: NaN-safe narrowing of st0
-    x_fst32 = _cvt_d2s(st0_b, st0_f, _nan64(st0_b)).astype(jnp.uint64)
-    x87_store_val = jnp.select(
-        [sub == U.X87_FST_M,
-         (sub == U.X87_FIST) | (sub == U.X87_FIST_T),
-         sub == U.X87_FNSTCW,
-         sub == U.X87_FNSTSW_M],
-        [jnp.where(srcsize0 >= 8, st0_b, x_fst32),
-         x_fist_val, fpcw_v & _u(0xFFFF), fpsw_v & _u(0xFFFF)],
-        default=st.mxcsr & _u(0xFFFFFFFF))  # STMXCSR
+        # fst m32: NaN-safe narrowing of st0
+        x_fst32 = _cvt_d2s(st0_b, st0_f, _nan64(st0_b)).astype(jnp.uint64)
+        x87_store_val = jnp.select(
+            [sub == U.X87_FST_M,
+             (sub == U.X87_FIST) | (sub == U.X87_FIST_T),
+             sub == U.X87_FNSTCW,
+             sub == U.X87_FNSTSW_M],
+            [jnp.where(srcsize0 >= 8, st0_b, x_fst32),
+             x_fist_val, fpcw_v & _u(0xFFFF), fpsw_v & _u(0xFFFF)],
+            default=st.mxcsr & _u(0xFFFFFFFF))  # STMXCSR
 
-    # pushes
-    x_is_push = is_x87 & (
-        (sub == U.X87_FLD_M) | (sub == U.X87_FILD)
-        | (sub == U.X87_FLD_STI) | (sub == U.X87_FLD_CONST))
-    x_push_b = jnp.select(
-        [sub == U.X87_FLD_M, sub == U.X87_FILD, sub == U.X87_FLD_STI],
-        [x_mem_b, x_fild_b, sti_b],
-        default=jnp.where(imm == _u(0), _u(0x3FF0000000000000), _u(0)))
-    x_push_slot = ((x_top - _u(1)) & _u(7)).astype(jnp.int32)
+        # pushes
+        x_is_push = is_x87 & (
+            (sub == U.X87_FLD_M) | (sub == U.X87_FILD)
+            | (sub == U.X87_FLD_STI) | (sub == U.X87_FLD_CONST))
+        x_push_b = jnp.select(
+            [sub == U.X87_FLD_M, sub == U.X87_FILD, sub == U.X87_FLD_STI],
+            [x_mem_b, x_fild_b, sti_b],
+            default=jnp.where(imm == _u(0), _u(0x3FF0000000000000), _u(0)))
+        x_push_slot = ((x_top - _u(1)) & _u(7)).astype(jnp.int32)
 
-    # register-stack writes: one generic write + the FXCH partner write
-    x_fxch = sub == U.X87_FXCH
-    x_w1_en = is_x87 & (
-        x_is_push | x_arith_writes | (sub == U.X87_FST_STI)
-        | (sub == U.X87_FCHS) | (sub == U.X87_FABS) | x_fxch)
-    x_w1_idx = jnp.select(
-        [x_is_push, x_arith_writes & x_dsti, sub == U.X87_FST_STI],
-        [x_push_slot, x_phi, x_phi], default=x_ph0)
-    x_w1_val = jnp.select(
-        [x_is_push, x_arith_writes, sub == U.X87_FST_STI,
-         sub == U.X87_FCHS, sub == U.X87_FABS],
-        [x_push_b, x_arith_out, st0_b,
-         st0_b ^ _u(1 << 63), st0_b & _u((1 << 63) - 1)],
-        default=sti_b)  # FXCH: st0 <- st(i)
+        # register-stack writes: one generic write + the FXCH partner write
+        x_fxch = sub == U.X87_FXCH
+        x_w1_en = is_x87 & (
+            x_is_push | x_arith_writes | (sub == U.X87_FST_STI)
+            | (sub == U.X87_FCHS) | (sub == U.X87_FABS) | x_fxch)
+        x_w1_idx = jnp.select(
+            [x_is_push, x_arith_writes & x_dsti, sub == U.X87_FST_STI],
+            [x_push_slot, x_phi, x_phi], default=x_ph0)
+        x_w1_val = jnp.select(
+            [x_is_push, x_arith_writes, sub == U.X87_FST_STI,
+             sub == U.X87_FCHS, sub == U.X87_FABS],
+            [x_push_b, x_arith_out, st0_b,
+             st0_b ^ _u(1 << 63), st0_b & _u((1 << 63) - 1)],
+            default=sti_b)  # FXCH: st0 <- st(i)
 
-    # stack top / tag word / control+status words
-    x_pops = jnp.where(is_x87, sext_f, jnp.int32(0))
-    x_fninit = sub == U.X87_FNINIT
-    x_new_top = jnp.where(
-        x_fninit, _u(0),
-        jnp.where(x_is_push, (x_top - _u(1)) & _u(7),
-                  (x_top + x_pops.astype(jnp.uint64)) & _u(7)))
+        # stack top / tag word / control+status words
+        x_pops = jnp.where(is_x87, sext_f, jnp.int32(0))
+        x_fninit = sub == U.X87_FNINIT
+        x_new_top = jnp.where(
+            x_fninit, _u(0),
+            jnp.where(x_is_push, (x_top - _u(1)) & _u(7),
+                      (x_top + x_pops.astype(jnp.uint64)) & _u(7)))
 
-    @jax.named_scope("x87")
-    def _tag_set(tw, phys_i32, val):
-        sh = phys_i32.astype(jnp.uint64) * _u(2)
-        return (tw & ~(_u(3) << sh)) | (_u(val) << sh)
+        @jax.named_scope("x87")
+        def _tag_set(tw, phys_i32, val):
+            sh = phys_i32.astype(jnp.uint64) * _u(2)
+            return (tw & ~(_u(3) << sh)) | (_u(val) << sh)
 
-    x_tw = fptw_v
-    x_tw = jnp.where(x_is_push, _tag_set(x_tw, x_push_slot, 0), x_tw)
-    x_tw = jnp.where(is_x87 & (sub == U.X87_FST_STI),
-                     _tag_set(x_tw, x_phi, 0), x_tw)
-    x_tw = jnp.where(is_x87 & (x_pops >= 1), _tag_set(x_tw, x_ph0, 3), x_tw)
-    x_tw = jnp.where(is_x87 & (x_pops >= 2),
-                     _tag_set(x_tw, _xphys(_u(1)), 3), x_tw)
-    x_tw = jnp.where(is_x87 & (sub == U.X87_FFREE),
-                     _tag_set(x_tw, x_phi, 3), x_tw)
-    x_tw = jnp.where(is_x87 & (x_fninit | (sub == U.X87_EMMS)),
-                     _u(0xFFFF), x_tw)
+        x_tw = fptw_v
+        x_tw = jnp.where(x_is_push, _tag_set(x_tw, x_push_slot, 0), x_tw)
+        x_tw = jnp.where(is_x87 & (sub == U.X87_FST_STI),
+                         _tag_set(x_tw, x_phi, 0), x_tw)
+        x_tw = jnp.where(is_x87 & (x_pops >= 1), _tag_set(x_tw, x_ph0, 3), x_tw)
+        x_tw = jnp.where(is_x87 & (x_pops >= 2),
+                         _tag_set(x_tw, _xphys(_u(1)), 3), x_tw)
+        x_tw = jnp.where(is_x87 & (sub == U.X87_FFREE),
+                         _tag_set(x_tw, x_phi, 3), x_tw)
+        x_tw = jnp.where(is_x87 & (x_fninit | (sub == U.X87_EMMS)),
+                         _u(0xFFFF), x_tw)
 
-    x_cw = jnp.where(is_x87 & (sub == U.X87_FLDCW), l1_lo & _u(0xFFFF),
-                     jnp.where(is_x87 & x_fninit, _u(0x37F), fpcw_v))
-    x_sw = fpsw_v
-    x_sw = jnp.where(x_is_com, (x_sw & ~_u(0x4500)) | x_com_bits, x_sw)
-    x_sw = jnp.where(is_x87 & (sub == U.X87_FNCLEX), x_sw & ~_u(0x80FF), x_sw)
-    x_sw = jnp.where(is_x87 & x_fninit, _u(0), x_sw)
-    x_sw = jnp.where(is_x87,
-                     (x_sw & ~_u(0x3800)) | (x_new_top << _u(11)), x_sw)
+        x_cw = jnp.where(is_x87 & (sub == U.X87_FLDCW), l1_lo & _u(0xFFFF),
+                         jnp.where(is_x87 & x_fninit, _u(0x37F), fpcw_v))
+        x_sw = fpsw_v
+        x_sw = jnp.where(x_is_com, (x_sw & ~_u(0x4500)) | x_com_bits, x_sw)
+        x_sw = jnp.where(is_x87 & (sub == U.X87_FNCLEX), x_sw & ~_u(0x80FF), x_sw)
+        x_sw = jnp.where(is_x87 & x_fninit, _u(0), x_sw)
+        x_sw = jnp.where(is_x87,
+                         (x_sw & ~_u(0x3800)) | (x_new_top << _u(11)), x_sw)
 
-    # denormal / FTZ risk -> oracle divert, same policy as the SSE block
-    x_r_zero = (x_r_b & _u(0x7FFFFFFFFFFFFFFF)) == _u(0)
-    x_true_zero = jnp.select(
-        [cond == U.X87_OP_ADD,
-         (cond == U.X87_OP_SUB) | (cond == U.X87_OP_SUBR),
-         cond == U.X87_OP_MUL],
-        [xa_f == -xb_f, xa_f == xb_f,
-         ((xa_b & _u(0x7FFFFFFFFFFFFFFF)) == _u(0))
-         | ((xb_b & _u(0x7FFFFFFFFFFFFFFF)) == _u(0))],
-        default=((jnp.where(cond == U.X87_OP_DIV, xa_b, xb_b)
-                  & _u(0x7FFFFFFFFFFFFFFF)) == _u(0))
-        | ((jnp.where(cond == U.X87_OP_DIV, xb_b, xa_b)
-            & _u(0x7FFFFFFFFFFFFFFF)) == _u(0x7FF0000000000000)))
-    x_ftz = (x_arith_m | x_arith_st) & ~x_is_com_digit \
-        & x_r_zero & ~x_true_zero & ~nan_xa & ~nan_xb
-    # an m32 arith operand needs the f32-level denormal check: DAZ in
-    # the widening flushes it before _den64 could ever see it (a
-    # converted f32 denormal is a NORMAL f64)
-    x_den_arith = (x_arith_m | x_arith_st) & (
-        _den64(xa_b) | _den64(xb_b)
-        | (x_arith_m & (srcsize0 < 8) & _den32(xm32_u)))
-    x_fst32_small = (((st0_b >> _u(52)) & _u(0x7FF)) <= _u(897)) \
-        & ((st0_b & _u(0x7FFFFFFFFFFFFFFF)) != _u(0))
-    x87_denorm = is_x87 & ~x87_oracle & jnp.select(
-        [x_arith_m | x_arith_st,
-         (sub == U.X87_FLD_M) & (srcsize0 < 8),
-         (sub == U.X87_FST_M) & (srcsize0 < 8),
-         (sub == U.X87_FIST) | (sub == U.X87_FIST_T),
-         (sub == U.X87_COM) | (sub == U.X87_COMI)],
-        [x_ftz | x_den_arith,
-         _den32(xm32_u),
-         x_fst32_small,
-         _den64(st0_b),
-         _den64(st0_b) | _den64(x_cmp_b)],
-        default=jnp.bool_(False))
+        # denormal / FTZ risk -> oracle divert, same policy as the SSE block
+        x_r_zero = (x_r_b & _u(0x7FFFFFFFFFFFFFFF)) == _u(0)
+        x_true_zero = jnp.select(
+            [cond == U.X87_OP_ADD,
+             (cond == U.X87_OP_SUB) | (cond == U.X87_OP_SUBR),
+             cond == U.X87_OP_MUL],
+            [xa_f == -xb_f, xa_f == xb_f,
+             ((xa_b & _u(0x7FFFFFFFFFFFFFFF)) == _u(0))
+             | ((xb_b & _u(0x7FFFFFFFFFFFFFFF)) == _u(0))],
+            default=((jnp.where(cond == U.X87_OP_DIV, xa_b, xb_b)
+                      & _u(0x7FFFFFFFFFFFFFFF)) == _u(0))
+            | ((jnp.where(cond == U.X87_OP_DIV, xb_b, xa_b)
+                & _u(0x7FFFFFFFFFFFFFFF)) == _u(0x7FF0000000000000)))
+        x_ftz = (x_arith_m | x_arith_st) & ~x_is_com_digit \
+            & x_r_zero & ~x_true_zero & ~nan_xa & ~nan_xb
+        # an m32 arith operand needs the f32-level denormal check: DAZ in
+        # the widening flushes it before _den64 could ever see it (a
+        # converted f32 denormal is a NORMAL f64)
+        x_den_arith = (x_arith_m | x_arith_st) & (
+            _den64(xa_b) | _den64(xb_b)
+            | (x_arith_m & (srcsize0 < 8) & _den32(xm32_u)))
+        x_fst32_small = (((st0_b >> _u(52)) & _u(0x7FF)) <= _u(897)) \
+            & ((st0_b & _u(0x7FFFFFFFFFFFFFFF)) != _u(0))
+        x87_denorm = is_x87 & ~x87_oracle & jnp.select(
+            [x_arith_m | x_arith_st,
+             (sub == U.X87_FLD_M) & (srcsize0 < 8),
+             (sub == U.X87_FST_M) & (srcsize0 < 8),
+             (sub == U.X87_FIST) | (sub == U.X87_FIST_T),
+             (sub == U.X87_COM) | (sub == U.X87_COMI)],
+            [x_ftz | x_den_arith,
+             _den32(xm32_u),
+             x_fst32_small,
+             _den64(st0_b),
+             _den64(st0_b) | _den64(x_cmp_b)],
+            default=jnp.bool_(False))
 
     # -- 5. result routing -------------------------------------------------
     cc01 = jnp.where(cc_true, _u(1), _u(0))
@@ -2028,7 +2047,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
         (is_(U.OPC_SYSCALL), rf & ~_u(0x10000)),
         (is_(U.OPC_MOVCR), cr_read),
         (is_(U.OPC_XCHG), src_val),
-        (is_ssemov, xmm[jnp.clip(sr, 0, 15), 0]),
+        (is_ssemov, x_sr_lo),
         (is_ssealu, jnp.where(sub == U.SSE_PEXTRW, pextrw_val, pmov_mask)),
         (is_ssefp, f2i_val),
         (is_x87, fpsw_v & _u(0xFFFF)),
@@ -2131,11 +2150,10 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
         (s_movs, l1_lo),
         # movhps-store (sub 5) writes the HIGH xmm limb; everything else
         # in the class stores from the low limb
-        (is_ssemov, jnp.where(sub == 5, xmm[jnp.clip(sr, 0, 15), 1],
-                              xmm[jnp.clip(sr, 0, 15), 0])),
+        (is_ssemov, jnp.where(sub == 5, x_sr_hi, x_sr_lo)),
         (is_x87, x87_store_val),
     ], _u(0))
-    st_hi = jnp.where(is_ssemov, xmm[jnp.clip(sr, 0, 15), 1],
+    st_hi = jnp.where(is_ssemov, x_sr_hi,
                       jnp.where(s_movs, l1_hi, _u(0)))
 
     # store translations (ts0/ts1) come from the step's single batched walk
@@ -2153,57 +2171,59 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     commit = commit_pre & ~ovf
 
     # -- register file application (order: rsp/rsi/rdi/rcx, aux, primary) --
-    new_gpr = gpr
-    new_gpr = new_gpr.at[4].set(jnp.where(commit & w3_cond, w3_val, new_gpr[4]))
-    new_gpr = new_gpr.at[6].set(jnp.where(commit & w4_cond,
-                                          rsi + str_delta, new_gpr[6]))
-    new_gpr = new_gpr.at[7].set(jnp.where(commit & w5_cond,
-                                          rdi + str_delta, new_gpr[7]))
-    new_gpr = new_gpr.at[1].set(jnp.where(commit & w6_cond,
-                                          rcx_dec, new_gpr[1]))
-    new_gpr = _gpr_write(new_gpr, commit & w2_cond, w2_idx, w2_val, w2_size)
-    new_gpr = _gpr_write(new_gpr, commit & w1_cond, w1_idx, w1_val, w1_size)
-    # CPUID writes all four GPRs (32-bit, zero-extending), one more than
-    # the generic two-write router carries (oracle: emu.py OPC_CPUID)
-    cpw = commit & is_(U.OPC_CPUID)
-    for ridx, col in ((0, 0), (3, 1), (1, 2), (2, 3)):  # eax, ebx, ecx, edx
-        new_gpr = new_gpr.at[ridx].set(
-            jnp.where(cpw, cpuid_out[col], new_gpr[ridx]))
+    with jax.named_scope("regfile-write"):
+        new_gpr = gpr
+        new_gpr = new_gpr.at[4].set(jnp.where(commit & w3_cond, w3_val, new_gpr[4]))
+        new_gpr = new_gpr.at[6].set(jnp.where(commit & w4_cond,
+                                              rsi + str_delta, new_gpr[6]))
+        new_gpr = new_gpr.at[7].set(jnp.where(commit & w5_cond,
+                                              rdi + str_delta, new_gpr[7]))
+        new_gpr = new_gpr.at[1].set(jnp.where(commit & w6_cond,
+                                              rcx_dec, new_gpr[1]))
+        new_gpr = _gpr_write(new_gpr, commit & w2_cond, w2_idx, w2_val, w2_size)
+        new_gpr = _gpr_write(new_gpr, commit & w1_cond, w1_idx, w1_val, w1_size)
+        # CPUID writes all four GPRs (32-bit, zero-extending), one more than
+        # the generic two-write router carries (oracle: emu.py OPC_CPUID)
+        cpw = commit & is_(U.OPC_CPUID)
+        for ridx, col in ((0, 0), (3, 1), (1, 2), (2, 3)):  # eax, ebx, ecx, edx
+            new_gpr = new_gpr.at[ridx].set(
+                jnp.where(cpw, cpuid_out[col], new_gpr[ridx]))
 
-    # all writes (hot values entered the chains as bitcasts) land through
-    # the one shared u64 scatter; the limb file is a free bitcast back
-    glimb_out = L.unpack_u64(new_gpr)
+        # all writes (hot values entered the chains as bitcasts) land through
+        # the one shared u64 scatter; the limb file is a free bitcast back
+        glimb_out = L.unpack_u64(new_gpr)
 
     # -- rflags ------------------------------------------------------------
-    # Ported classes (ALU/UNARY) produce a u32 low-limb image; everything
-    # else rides the u64 chain and splits at the seam below.
-    rf_exec = opc_list([
-        (is_(U.OPC_BT), bt_rf),
-        (is_(U.OPC_BITSCAN), bs_rf),
-        (is_string, jnp.where((s_scas | s_cmps) & ~rep_skip, str_rf, rf)),
-        (is_(U.OPC_CMPXCHG), cx_rf),
-        (is_(U.OPC_XADD), xadd_rf),
-        (is_(U.OPC_RDRAND), rdrand_rf),
-        (is_(U.OPC_FLAGOP), flagop_rf),
-        (is_popf, popf_rf),
-        (is_iretq, l2_lo & _u(U.RF_WRITABLE)),
-        (is_(U.OPC_SYSCALL), jnp.where(syscall_entry, syscall_rf, sysret_rf)),
-        (is_ssealu & (sub == U.SSE_PTEST), ptest_rf),
-        (is_ssefp & fp_is_comi, ucomi_rf),
-        (is_x87 & (sub == U.X87_COMI), x87_comi_rf),
-        (is_(U.OPC_PEXT), bmi_rf),
-    ], rf)
-    hot_rf = (is_(U.OPC_ALU) | is_(U.OPC_UNARY) | is_(U.OPC_SHIFT)
-              | is_mul)
-    rf_cold_lo, rf_cold_hi = L.pair(rf_exec)
-    rf_exec_lo = jnp.where(
-        hot_rf,
-        L.sel([is_(U.OPC_ALU), is_(U.OPC_UNARY), is_(U.OPC_SHIFT)],
-              [alu_rf_lo, un_rf_lo, sh_rf_lo], mul_rf_lo),
-        rf_cold_lo)
-    new_rf_lo = jnp.where(commit, rf_exec_lo | jnp.uint32(0x2), rf_lo)
-    # hot classes never touch bits 32+ (arith flags live in the low limb)
-    new_rf_hi = jnp.where(commit & ~hot_rf, rf_cold_hi, rf_hi)
+    with jax.named_scope("flags-status"):
+        # Ported classes (ALU/UNARY) produce a u32 low-limb image; everything
+        # else rides the u64 chain and splits at the seam below.
+        rf_exec = opc_list([
+            (is_(U.OPC_BT), bt_rf),
+            (is_(U.OPC_BITSCAN), bs_rf),
+            (is_string, jnp.where((s_scas | s_cmps) & ~rep_skip, str_rf, rf)),
+            (is_(U.OPC_CMPXCHG), cx_rf),
+            (is_(U.OPC_XADD), xadd_rf),
+            (is_(U.OPC_RDRAND), rdrand_rf),
+            (is_(U.OPC_FLAGOP), flagop_rf),
+            (is_popf, popf_rf),
+            (is_iretq, l2_lo & _u(U.RF_WRITABLE)),
+            (is_(U.OPC_SYSCALL), jnp.where(syscall_entry, syscall_rf, sysret_rf)),
+            (is_ssealu & (sub == U.SSE_PTEST), ptest_rf),
+            (is_ssefp & fp_is_comi, ucomi_rf),
+            (is_x87 & (sub == U.X87_COMI), x87_comi_rf),
+            (is_(U.OPC_PEXT), bmi_rf),
+        ], rf)
+        hot_rf = (is_(U.OPC_ALU) | is_(U.OPC_UNARY) | is_(U.OPC_SHIFT)
+                  | is_mul)
+        rf_cold_lo, rf_cold_hi = L.pair(rf_exec)
+        rf_exec_lo = jnp.where(
+            hot_rf,
+            L.sel([is_(U.OPC_ALU), is_(U.OPC_UNARY), is_(U.OPC_SHIFT)],
+                  [alu_rf_lo, un_rf_lo, sh_rf_lo], mul_rf_lo),
+            rf_cold_lo)
+        new_rf_lo = jnp.where(commit, rf_exec_lo | jnp.uint32(0x2), rf_lo)
+        # hot classes never touch bits 32+ (arith flags live in the low limb)
+        new_rf_hi = jnp.where(commit & ~hot_rf, rf_cold_hi, rf_hi)
 
     # -- rip ---------------------------------------------------------------
     # ported: fallthrough and Jcc targets come from the limb adder
@@ -2212,7 +2232,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
         (is_(U.OPC_JMP) | is_call, jmp_target),
         (is_(U.OPC_JCC), L.to_u64(jcc_rip_l)),
         (is_ret | is_iretq, ret_target),
-        (is_(U.OPC_SYSCALL), jnp.where(syscall_entry, st.lstar, gpr[1])),
+        (is_(U.OPC_SYSCALL), jnp.where(syscall_entry, st.lstar, rcx)),
         (is_string, jnp.where(str_done, next_rip, rip)),
     ], next_rip)
     new_rip = jnp.where(commit, rip_exec, rip)
@@ -2281,54 +2301,60 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
             jnp.where(enabled & ~fill, jnp.int32(0), iret_latch))
 
     # -- xmm ---------------------------------------------------------------
-    wx_cond = commit & (
-        (is_ssemov & (sub != 2) & (dk == U.K_XMM))
-        | (is_ssealu & (sub != U.SSE_PMOVMSKB) & (sub != U.SSE_PTEST)
-           & (sub != U.SSE_PEXTRW))
-        | fp_writes_xmm)
-    wx_lo = jnp.where(is_ssefp, fp_out_lo,
-                      jnp.where(is_ssealu, sse_out_lo, ssm_lo))
-    wx_hi = jnp.where(is_ssefp, fp_out_hi,
-                      jnp.where(is_ssealu, sse_out_hi, ssm_hi))
-    xr = jnp.clip(dr, 0, 15)
-    # limbs 0-1 only: upper YMM halves (limbs 2-3) are carried state the
-    # legacy-SSE subset never computes on (AVX snapshots round-trip;
-    # reference CpuState_t holds 32xZMM, globals.h:1020-1159)
-    new_xmm = xmm.at[xr, 0].set(jnp.where(wx_cond, wx_lo, xmm[xr, 0]))
-    new_xmm = new_xmm.at[xr, 1].set(jnp.where(wx_cond, wx_hi, new_xmm[xr, 1]))
-    # vzeroall (sub 0) zeroes the whole file; vzeroupper (sub 1) the
-    # upper halves only — whole-file writes, no dst register
-    vz = commit & is_(U.OPC_VZEROALL)
-    vz_limb = jnp.where(vz & (sub == 0), jnp.arange(4) >= 0,
-                        jnp.where(vz, jnp.arange(4) >= 2,
-                                  jnp.zeros(4, bool)))
-    new_xmm = jnp.where(vz_limb[None, :], _u(0), new_xmm)
+    with jax.named_scope("vector-file"):
+        wx_cond = commit & (
+            (is_ssemov & (sub != 2) & (dk == U.K_XMM))
+            | (is_ssealu & (sub != U.SSE_PMOVMSKB) & (sub != U.SSE_PTEST)
+               & (sub != U.SSE_PEXTRW))
+            | fp_writes_xmm)
+        wx_lo = jnp.where(is_ssefp, fp_out_lo,
+                          jnp.where(is_ssealu, sse_out_lo, ssm_lo))
+        wx_hi = jnp.where(is_ssefp, fp_out_hi,
+                          jnp.where(is_ssealu, sse_out_hi, ssm_hi))
+        xr = jnp.clip(dr, 0, 15)
+        # limbs 0-1 only: upper YMM halves (limbs 2-3) are carried state the
+        # legacy-SSE subset never computes on (AVX snapshots round-trip;
+        # reference CpuState_t holds 32xZMM, globals.h:1020-1159)
+        new_xmm = xmm.at[xr, 0].set(jnp.where(wx_cond, wx_lo, xmm[xr, 0]))
+        new_xmm = new_xmm.at[xr, 1].set(jnp.where(wx_cond, wx_hi, new_xmm[xr, 1]))
+        # vzeroall (sub 0) zeroes the whole file; vzeroupper (sub 1) the
+        # upper halves only — whole-file writes, no dst register
+        vz = commit & is_(U.OPC_VZEROALL)
+        vz_limb = jnp.where(vz & (sub == 0), jnp.arange(4) >= 0,
+                            jnp.where(vz, jnp.arange(4) >= 2,
+                                      jnp.zeros(4, bool)))
+        new_xmm = jnp.where(vz_limb[None, :], _u(0), new_xmm)
 
     # -- x87 state application --------------------------------------------
-    x87c = commit & is_x87
-    new_fpst = fpst_v.at[x_w1_idx].set(
-        jnp.where(x87c & x_w1_en, x_w1_val, fpst_v[x_w1_idx]))
-    new_fpst = new_fpst.at[x_phi].set(
-        jnp.where(x87c & x_fxch, st0_b, new_fpst[x_phi]))
-    new_fpcw = jnp.where(x87c, x_cw, fpcw_v)
-    new_fpsw = jnp.where(x87c, x_sw, fpsw_v)
-    new_fptw = jnp.where(x87c, x_tw, fptw_v)
-    new_mxcsr = jnp.where(x87c & (sub == U.X87_LDMXCSR),
-                          l1_lo & _u(0xFFFFFFFF), st.mxcsr)
+    with jax.named_scope("vector-file"):
+        x87c = commit & is_x87
+        new_fpst = fpst_v.at[x_w1_idx].set(
+            jnp.where(x87c & x_w1_en, x_w1_val, fpst_v[x_w1_idx]))
+        new_fpst = new_fpst.at[x_phi].set(
+            jnp.where(x87c & x_fxch, st0_b, new_fpst[x_phi]))
+        new_fpcw = jnp.where(x87c, x_cw, fpcw_v)
+        new_fpsw = jnp.where(x87c, x_sw, fpsw_v)
+        new_fptw = jnp.where(x87c, x_tw, fptw_v)
+        new_mxcsr = jnp.where(x87c & (sub == U.X87_LDMXCSR),
+                              l1_lo & _u(0xFFFFFFFF), st.mxcsr)
 
     # -- bookkeeping -------------------------------------------------------
-    new_icount = st.icount + jnp.where(commit, _u(1), _u(0))
-    # device-side telemetry block (machine.CTR_* order): accumulated
-    # in-graph every step, folded into host metrics once per burst — the
-    # per-step host sync this exists to avoid.  page_fault/miss already
-    # imply `enabled`, commit implies `live`.  CTR_SWEPT counts the pass
-    # itself, whatever the lane's status; CTR_TAIL is the chunk loop's.
-    # A fill pass counts in CTR_SWEPT and CTR_TLB_FILL and not in
-    # CTR_INSTR, as iretq's latching pass counts in CTR_SWEPT alone.
-    new_ctr = st.ctr + jnp.stack(
-        [commit, page_fault, miss, jnp.bool_(True), jnp.bool_(False),
-         tlb_walks, fill]).astype(jnp.uint32)
-    timed = commit & (limit > _u(0)) & (new_icount >= limit)
+    with jax.named_scope("counters"):
+        new_icount = st.icount + jnp.where(commit, _u(1), _u(0))
+        # device-side telemetry block (machine.CTR_* order): accumulated
+        # in-graph every step, folded into host metrics once per burst — the
+        # per-step host sync this exists to avoid.  page_fault/miss already
+        # imply `enabled`, commit implies `live`.  CTR_SWEPT counts the pass
+        # itself, whatever the lane's status; CTR_TAIL and CTR_LEVELS are
+        # the chunk loop's.  A fill pass counts in CTR_SWEPT and
+        # CTR_TLB_FILL and not in CTR_INSTR, as iretq's latching pass counts
+        # in CTR_SWEPT alone.
+        is_vector = reduce(operator.or_, (is_(o) for o in U.VECTOR_OPCS))
+        new_ctr = st.ctr + jnp.stack(
+            [commit, page_fault, miss, jnp.bool_(True), jnp.bool_(False),
+             tlb_walks, fill, commit & st_need, tlb_walks == 2,
+             commit & is_vector, jnp.bool_(False)]).astype(jnp.uint32)
+        timed = commit & (limit > _u(0)) & (new_icount >= limit)
     new_rdrand = jnp.where(commit & is_(U.OPC_RDRAND), rdrand_next, st.rdrand)
     new_bp_skip = jnp.where(commit, jnp.int32(0), st.bp_skip)
 
@@ -2337,36 +2363,37 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
                                commit & is_branch, rip, rip_exec)
 
     # -- status ------------------------------------------------------------
-    S = StatusCode
-    status_chain = jnp.select(
-        [miss, at_bp, smc, unsupported, page_fault, fp_oracle, de, is_crash,
-         ovf, cr3_changed, timed],
-        [jnp.int32(int(S.NEED_DECODE)), jnp.int32(int(S.BREAKPOINT)),
-         jnp.int32(int(S.SMC)), jnp.int32(int(S.UNSUPPORTED)),
-         jnp.int32(int(S.PAGE_FAULT)), jnp.int32(int(S.UNSUPPORTED)),
-         jnp.int32(int(S.DIVIDE_ERROR)),
-         jnp.int32(int(S.CRASH)), jnp.int32(int(S.OVERLAY_FULL)),
-         jnp.int32(int(S.CR3_CHANGE)), jnp.int32(int(S.TIMEDOUT))],
-        default=jnp.int32(int(S.RUNNING)))
-    new_status = jnp.where(enabled, status_chain, st.status)
+    with jax.named_scope("flags-status"):
+        S = StatusCode
+        status_chain = jnp.select(
+            [miss, at_bp, smc, unsupported, page_fault, fp_oracle, de, is_crash,
+             ovf, cr3_changed, timed],
+            [jnp.int32(int(S.NEED_DECODE)), jnp.int32(int(S.BREAKPOINT)),
+             jnp.int32(int(S.SMC)), jnp.int32(int(S.UNSUPPORTED)),
+             jnp.int32(int(S.PAGE_FAULT)), jnp.int32(int(S.UNSUPPORTED)),
+             jnp.int32(int(S.DIVIDE_ERROR)),
+             jnp.int32(int(S.CRASH)), jnp.int32(int(S.OVERLAY_FULL)),
+             jnp.int32(int(S.CR3_CHANGE)), jnp.int32(int(S.TIMEDOUT))],
+            default=jnp.int32(int(S.RUNNING)))
+        new_status = jnp.where(enabled, status_chain, st.status)
 
-    # faulting address: when the access's first page translates but the
-    # access straddles into a bad page, the faulting byte is at the next
-    # page boundary (the oracle's per-page walk reports it there)
-    def _fault_at(addr, first_ok):
-        return jnp.where(first_ok, (addr & ~_u(0xFFF)) + _u(0x1000), addr)
+        # faulting address: when the access's first page translates but the
+        # access straddles into a bad page, the faulting byte is at the next
+        # page boundary (the oracle's per-page walk reports it there)
+        def _fault_at(addr, first_ok):
+            return jnp.where(first_ok, (addr & ~_u(0xFFF)) + _u(0x1000), addr)
 
-    st_first_ok = ts0.ok & ts0.writable
-    new_fault_gva = jnp.where(
-        enabled & page_fault,
-        jnp.where(fault1, _fault_at(l1_addr, l1t0.ok),
-                  jnp.where(fault2, _fault_at(l2_addr, l2t0.ok),
-                            _fault_at(st_addr, st_first_ok))),
-        jnp.where(enabled & is_crash, rip, st.fault_gva))
-    new_fault_write = jnp.where(
-        enabled & page_fault & ~fault1 & ~fault2, jnp.int32(1),
-        jnp.where(enabled & page_fault, jnp.int32(0), st.fault_write & 1)) \
-        | (new_latch << IRET_LATCH_SHIFT)
+        st_first_ok = ts0.ok & ts0.writable
+        new_fault_gva = jnp.where(
+            enabled & page_fault,
+            jnp.where(fault1, _fault_at(l1_addr, l1t0.ok),
+                      jnp.where(fault2, _fault_at(l2_addr, l2t0.ok),
+                                _fault_at(st_addr, st_first_ok))),
+            jnp.where(enabled & is_crash, rip, st.fault_gva))
+        new_fault_write = jnp.where(
+            enabled & page_fault & ~fault1 & ~fault2, jnp.int32(1),
+            jnp.where(enabled & page_fault, jnp.int32(0), st.fault_write & 1)) \
+            | (new_latch << IRET_LATCH_SHIFT)
 
     return st._replace(
         gpr_l=glimb_out,
@@ -2528,15 +2555,17 @@ def make_run_chunk(n_steps: int, donate: bool = None, jit: bool = True,
             """One sweep of the first `n_active` groups of `width` rows."""
             def one(g, view):
                 at = g * width
-                rows = map_rows(
-                    lambda x: lax.dynamic_slice_in_dim(x, at, width), view)
-                rows = _sweep(
-                    tab, image._replace(
-                        tenant=lax.dynamic_slice_in_dim(tenant, at, width)),
-                    rows, limit, fp_host)
-                return map_rows(
-                    lambda part, full: lax.dynamic_update_slice_in_dim(
-                        full, part, at, 0), rows, view)
+                with jax.named_scope("group-slice"):
+                    rows = map_rows(
+                        lambda x: lax.dynamic_slice_in_dim(x, at, width),
+                        view)
+                    tenants = lax.dynamic_slice_in_dim(tenant, at, width)
+                rows = _sweep(tab, image._replace(tenant=tenants), rows,
+                              limit, fp_host)
+                with jax.named_scope("group-slice"):
+                    return map_rows(
+                        lambda part, full: lax.dynamic_update_slice_in_dim(
+                            full, part, at, 0), rows, view)
 
             return lax.fori_loop(0, n_active, one, view)
 
@@ -2548,12 +2577,13 @@ def make_run_chunk(n_steps: int, donate: bool = None, jit: bool = True,
             what lets the rows behind go unswept: step_lane passes a
             parked lane through."""
             i, lanes, tenant, view = carry
-            order = jnp.argsort(view.status != running,
-                                stable=True).astype(jnp.int32)
-            lanes, tenant = lanes[order], tenant[order]
-            view = map_rows(
-                lambda x: x.at[order].get(mode="promise_in_bounds",
-                                          unique_indices=True), view)
+            with jax.named_scope("level-sort"):
+                order = jnp.argsort(view.status != running,
+                                    stable=True).astype(jnp.int32)
+                lanes, tenant = lanes[order], tenant[order]
+                view = map_rows(
+                    lambda x: x.at[order].get(mode="promise_in_bounds",
+                                              unique_indices=True), view)
             n_active = groups - e
             floor = (n_active - 1) * width
 
@@ -2566,9 +2596,17 @@ def make_run_chunk(n_steps: int, donate: bool = None, jit: bool = True,
                 return j + 1, sweep_groups(n_active, tenant, v)
 
             j, view = lax.while_loop(cond, body, (i, view))
-            narrow = (jnp.arange(n_lanes) < n_active * width) & (e > 0)
-            view = view._replace(ctr=view.ctr.at[:, CTR_TAIL].add(
-                jnp.where(narrow, j - i, 0).astype(jnp.uint32)))
+            with jax.named_scope("level-account"):
+                narrow = (jnp.arange(n_lanes) < n_active * width) & (e > 0)
+                # one elementwise add over the block: the level's sweeps
+                # to the narrow lanes' CTR_TAIL, and to every lane's
+                # CTR_LEVELS whether the level swept at all
+                col = jnp.arange(N_CTRS)[None, :]
+                delta = jnp.where(
+                    col == CTR_TAIL, jnp.where(narrow, j - i, 0)[:, None],
+                    ((col == CTR_LEVELS) & (j > i)).astype(jnp.int32))
+                view = view._replace(
+                    ctr=view.ctr + delta.astype(jnp.uint32))
             return j, lanes, tenant, view
 
         _, lanes, _, view = lax.fori_loop(

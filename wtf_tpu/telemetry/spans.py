@@ -10,9 +10,11 @@ of it).  A Span therefore exposes `fence(value)` — an explicit
 just produced, so the span's end time is taken AFTER the device work is
 actually done:
 
-    with spans.span("device-step") as sp:
-        machine = run_chunk(tab, image, machine, limit)
-        sp.fence(machine.status)
+    with spans.span("device-step"):
+        with spans.span("dispatch"):
+            machine = run_chunk(tab, image, machine, limit)
+        with spans.span("wait") as sp:
+            sp.fence(machine.status)
 
 Spans nest: a span opened inside another records under the joined path
 ("execute/device-step"), so a report can both account top-level phases
@@ -50,6 +52,24 @@ CALLS = "phase.calls"
 DEVICE_SPAN_LEAVES = frozenset((
     "device", "device-step", "overlay-restore", "cov-readback",
 ))
+# A device leaf that calls a chunk or a window program has two children
+# that sum to it (interp/runner.py `Runner.run`, backend/tpu.py):
+# `dispatch`, the jit call, and `wait`, the fence.  The split below does
+# not read them: under --dispatch-timeout the supervisor's bounded wait
+# runs inside `dispatch`, so the leaf alone is the device's time in both
+# modes (the children are `chunk_dispatch_ms`'s and `telemetry_report`'s
+# "chunk:" line's to read).
+
+
+def device_seconds_by_phase(phase_seconds: dict) -> dict:
+    """{top-level phase: seconds in the fenced device spans nested under
+    it}."""
+    out: dict = {}
+    for path, secs in phase_seconds.items():
+        parts = path.split("/")
+        if len(parts) > 1 and parts[-1] in DEVICE_SPAN_LEAVES:
+            out[parts[0]] = out.get(parts[0], 0.0) + secs
+    return out
 
 
 def block_until_ready(value) -> None:

@@ -25,10 +25,38 @@ campaign's end (`finish`).
 
 from __future__ import annotations
 
+import re
 import threading
 import time
 from pathlib import Path
 from typing import Callable, Optional
+
+# Every `jax.named_scope` of the device programs, by what one pass of it
+# covers: THE list (PERF.md section 3).  tools/xprof_scopes.py sums a
+# window's device time by it, and the census in tests/test_analysis.py
+# holds the chunk program to it: no indexed operation outside a scope.
+CHUNK_SCOPES = (              # make_run_chunk: once a level or a chunk
+    "level-sort", "group-slice", "level-account", "lane-writeback")
+STEP_SCOPES = (               # step_lane and below: once a lane-step
+    "decode-probe", "uop-fetch", "regfile-read", "regfile-write",
+    "tlb", "page-walk", "overlay-load", "overlay-store", "ea", "alu",
+    "unary", "shift", "mul", "cond", "string", "vector-file", "sse-bytes",
+    "sse-fp", "x87", "iret", "flags-status", "counters", "coverage")
+OTHER_SCOPES = (              # the other device programs
+    "device-insert", "restore", "run-quiesce", "decode-service",
+    "cov-merge", "devmut-generate")
+SCOPES = CHUNK_SCOPES + STEP_SCOPES + OTHER_SCOPES
+# the operations a sweep is priced by (PERF.md section 5), and the sort
+INDEXED_OPS = ("gather", "scatter", "dynamic-slice",
+               "dynamic-update-slice", "sort")
+_SCOPE_RE = re.compile(
+    r"(?<![\w-])(" + "|".join(map(re.escape, SCOPES)) + r")(?![\w-])")
+
+
+def scope_of(op_name: str) -> Optional[str]:
+    """The innermost listed scope in an HLO `op_name`, or None."""
+    found = _SCOPE_RE.findall(op_name or "")
+    return found[-1] if found else None
 
 
 class ProfilerWindow:
