@@ -423,39 +423,79 @@ _HLO_OP = re.compile(r" = .*?\s([a-z][\w\-]*)\(")
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
-def unscoped_indexed_ops(hlo_text: str) -> List[Tuple[str, str]]:
-    """(opcode, instruction line) of every indexed operation of optimized
-    HLO text (`telemetry.xprof.INDEXED_OPS`: the gather class and the
-    sort) whose `op_name` holds no scope of THE list
-    (`telemetry.xprof.SCOPES`): device time that a profiler window could
-    not lay at any stage's door (tools/xprof_scopes.py)."""
-    from wtf_tpu.telemetry.xprof import INDEXED_OPS, scope_of
+def _indexed_ops(hlo_text: str):
+    """(opcode, op_name, instruction line) of every indexed operation of
+    optimized HLO text (`telemetry.xprof.INDEXED_OPS`: the gather class
+    and the sort)."""
+    from wtf_tpu.telemetry.xprof import INDEXED_OPS
 
-    found = []
     for line in hlo_text.splitlines():
         op = _HLO_OP.search(line)
         if op is None or op.group(1) not in INDEXED_OPS:
             continue
         name = _HLO_OP_NAME.search(line)
-        if scope_of(name.group(1) if name else "") is None:
-            found.append((op.group(1), line.strip()[:200]))
-    return found
+        yield op.group(1), name.group(1) if name else "", line.strip()[:200]
+
+
+def unscoped_indexed_ops(hlo_text: str) -> List[Tuple[str, str]]:
+    """(opcode, instruction line) of every indexed operation whose
+    `op_name` holds no scope of THE list (`telemetry.xprof.SCOPES`):
+    device time that a profiler window could not lay at any stage's door
+    (tools/xprof_scopes.py)."""
+    from wtf_tpu.telemetry.xprof import scope_of
+
+    return [(op, line) for op, name, line in _indexed_ops(hlo_text)
+            if scope_of(name) is None]
+
+
+def _findings_by_opcode(ops, rule: str, entry: str, message) -> List[Finding]:
+    """One finding an opcode of `ops` ((opcode, line) pairs), budget 0;
+    `message(n, opcode)` words it."""
+    by_op: Dict[str, int] = {}
+    for opcode, _ in ops:
+        by_op[opcode] = by_op.get(opcode, 0) + 1
+    return [Finding(rule=rule, entry=entry, primitive=opcode, count=n,
+                    budget=0, message=message(n, opcode))
+            for opcode, n in sorted(by_op.items())]
 
 
 def check_indexed_ops_scoped(hlo_text: str, entry: str) -> List[Finding]:
     """The chunk program holds no indexed operation outside a named
     scope (PR 39: 36 gathers, 6 scatters, 3 dynamic-slices and 13
     dynamic-update-slices of `xla_step` stood in none)."""
-    by_op: Dict[str, int] = {}
-    for opcode, _ in unscoped_indexed_ops(hlo_text):
-        by_op[opcode] = by_op.get(opcode, 0) + 1
-    return [Finding(
-        rule="budget.unscoped-indexed-op", entry=entry, primitive=opcode,
-        count=n, budget=0,
-        message=(f"{n} `{opcode}` operation(s) whose op_name holds no "
-                 "scope of telemetry.xprof.SCOPES: put the code under a "
-                 "jax.named_scope of the list (or add the name to it)"))
-        for opcode, n in sorted(by_op.items())]
+    return _findings_by_opcode(
+        unscoped_indexed_ops(hlo_text), "budget.unscoped-indexed-op", entry,
+        lambda n, opcode: (
+            f"{n} `{opcode}` operation(s) whose op_name holds no "
+            "scope of telemetry.xprof.SCOPES: put the code under a "
+            "jax.named_scope of the list (or add the name to it)"))
+
+
+def vector_unit_indexed_ops(hlo_text: str) -> List[Tuple[str, str]]:
+    """(opcode, instruction line) of every indexed operation whose
+    `op_name` holds a scope of the vector unit
+    (`telemetry.xprof.VECTOR_SCOPES`; interp/step.py reads and writes its
+    files by selects over their rows): a gather or a scatter there is
+    priced by the lane in every sweep, whether or not a lane is at a
+    vector instruction (PERF.md section 6, PR 41)."""
+    from wtf_tpu.telemetry.xprof import VECTOR_SCOPES
+
+    unit = re.compile(
+        r"(?<![\w-])(" + "|".join(VECTOR_SCOPES) + r")(?![\w-])")
+    return [(op, line) for op, name, line in _indexed_ops(hlo_text)
+            if unit.search(name)]
+
+
+def check_vector_unit(hlo_text: str, entry: str) -> List[Finding]:
+    """The vector unit indexes nothing (PR 41: 25 gathers and 4 scatters
+    stood under its scopes, 309 us of a 1,260-us group sweep)."""
+    return _findings_by_opcode(
+        vector_unit_indexed_ops(hlo_text), "budget.vector-unit-indexed",
+        entry,
+        lambda n, opcode: (
+            f"{n} `{opcode}` operation(s) under the vector unit's "
+            "scopes: a table indexed by the lane where a select over "
+            "its rows would do"))
 
 
 def decode_service_program(runner):
@@ -890,6 +930,7 @@ def run_tail_rules(runner, families, budgets_path: Optional[Path] = None,
             findings.extend(check_budget(info["tail_counts"], budget,
                                          entry=entry))
         findings.extend(check_indexed_ops_scoped(text, entry=entry))
+        findings.extend(check_vector_unit(text, entry=entry))
     if "recompile" in families:
         findings.extend(check_chunk_donation(compiled, text, runner, entry))
     return findings, info
@@ -1319,6 +1360,8 @@ def run_lint(families: Optional[Sequence[str]] = None,
             findings.extend(check_budget(counts, budget,
                                          entry=info["entries"][0]))
         findings.extend(check_indexed_ops_scoped(
+            compiled_text, entry=info["entries"][0]))
+        findings.extend(check_vector_unit(
             compiled_text, entry=info["entries"][0]))
         for name, value in counts.items():
             registry.gauge("analysis.kernel_count").labels(name).set(value)

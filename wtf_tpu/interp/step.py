@@ -701,6 +701,25 @@ def fp_on_host() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _row(table, idx):
+    """Row `idx` of a lane's small table (the XMM file, the x87 stack, a
+    vector's elements) by a select over its rows and a sum: under `vmap`
+    an elementwise pass over the file, where `table[idx]` is a gather
+    priced by the lane (PERF.md section 5)."""
+    hot = jnp.arange(table.shape[0]) == idx
+    hot = hot.reshape(hot.shape + (1,) * (table.ndim - 1))
+    return jnp.sum(jnp.where(hot, table, jnp.zeros_like(table)), axis=0,
+                   dtype=table.dtype)
+
+
+def _set_row(table, idx, on, row):
+    """`table` with row `idx` replaced by `row` where `on`: a select over
+    the rows, no scatter."""
+    hot = (jnp.arange(table.shape[0]) == idx) & on
+    hot = hot.reshape(hot.shape + (1,) * (table.ndim - 1))
+    return jnp.where(hot, row, table)
+
+
 def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
               fp_host: bool = None) -> Tuple[Machine, StoreCommit]:
     """Advance one lane by one instruction (vmapped over the batch by
@@ -1359,8 +1378,10 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     # SSE --------------------------------------------------------------
     with jax.named_scope("vector-file"):
         xmm = st.xmm
-        x_dst_lo, x_dst_hi = xmm[jnp.clip(dr, 0, 15), 0], xmm[jnp.clip(dr, 0, 15), 1]
-        x_sr_lo, x_sr_hi = xmm[jnp.clip(sr, 0, 15), 0], xmm[jnp.clip(sr, 0, 15), 1]
+        x_dst = _row(xmm, jnp.clip(dr, 0, 15))
+        x_sr = _row(xmm, jnp.clip(sr, 0, 15))
+        x_dst_lo, x_dst_hi = x_dst[0], x_dst[1]
+        x_sr_lo, x_sr_hi = x_sr[0], x_sr[1]
         x_src_lo = jnp.where(sk == U.K_XMM, x_sr_lo, l1_lo)
         x_src_hi = jnp.where(sk == U.K_XMM, x_sr_hi, l1_hi)
     is_ssemov = is_(U.OPC_SSEMOV)
@@ -1396,18 +1417,23 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
         i16u = jnp.arange(16, dtype=jnp.int32)
         eq_b = (ba == bb)
         # word/dword equality via group-reduction of byte equality
-        eq_w16 = eq_b[(i16u // 2) * 2] & eq_b[(i16u // 2) * 2 + 1]
-        eq_d16 = (eq_b[(i16u // 4) * 4] & eq_b[(i16u // 4) * 4 + 1]
-                  & eq_b[(i16u // 4) * 4 + 2] & eq_b[(i16u // 4) * 4 + 3])
-        pshufd_sel = ((imm >> ((i16u // 4).astype(jnp.uint64) * _u(2))) & _u(3)
-                      ).astype(jnp.int32)
-        pshufd_idx = pshufd_sel * 4 + (i16u % 4)
+        eq_w16 = jnp.repeat(jnp.all(eq_b.reshape(8, 2), axis=1), 2)
+        eq_d16 = jnp.repeat(jnp.all(eq_b.reshape(4, 4), axis=1), 4)
+        # the shuffles and the byte shifts as selects over the sixteen
+        # bytes (one-hot, then a max: the others are 0): no table gather
+        pshufd_sel = ((imm >> (jnp.arange(4, dtype=jnp.uint64) * _u(2)))
+                      & _u(3)).astype(jnp.int32)
+        pshufd_out = jnp.max(
+            jnp.where((pshufd_sel[:, None] == jnp.arange(4)[None, :])[:, :, None],
+                      bb.reshape(4, 4)[None, :, :], jnp.uint8(0)),
+            axis=1).reshape(16)
         pslldq_n = jnp.minimum(imm, _u(16)).astype(jnp.int32)
-        psll_idx = jnp.clip(i16u - pslldq_n, 0, 15)
-        psrl_idx = jnp.clip(i16u + pslldq_n, 0, 15)
-        # punpckldq: interleave the low dwords -> [a0 b0 a1 b1] (dword units)
-        punp_src_b = (i16u // 4) & 1  # odd dword slots come from src
-        punp_idx = ((i16u // 8) * 4) + (i16u % 4)
+        pslldq_out = jnp.max(
+            jnp.where(i16u[:, None] - pslldq_n == i16u[None, :],
+                      ba[None, :], jnp.uint8(0)), axis=1)
+        psrldq_out = jnp.max(
+            jnp.where(i16u[:, None] + pslldq_n == i16u[None, :],
+                      ba[None, :], jnp.uint8(0)), axis=1)
         # pinsrw: word `cond` replaced by the gpr's low word (mem form is
         # oracle-serviced: its 2-byte load doesn't fit the 16-byte window)
         pinsrw_word = _read_reg(gpr, sr, jnp.int32(2))
@@ -1426,11 +1452,12 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
              jnp.where(eq_w16, jnp.uint8(0xFF), jnp.uint8(0)),
              jnp.where(eq_d16, jnp.uint8(0xFF), jnp.uint8(0)),
              ba - bb, ba + bb, jnp.minimum(ba, bb),
-             jnp.where(i16u < 8, ba, bb[jnp.clip(i16u - 8, 0, 15)]),
-             bb[pshufd_idx],
-             jnp.where(i16u >= pslldq_n, ba[psll_idx], jnp.uint8(0)),
-             jnp.where(i16u + pslldq_n < 16, ba[psrl_idx], jnp.uint8(0)),
-             jnp.where(punp_src_b == 0, ba[punp_idx], bb[punp_idx]),
+             jnp.concatenate([ba[:8], bb[:8]]),
+             pshufd_out,
+             pslldq_out,
+             psrldq_out,
+             # punpckldq: interleave the low dwords -> [a0 b0 a1 b1]
+             jnp.concatenate([ba[0:4], bb[0:4], ba[4:8], bb[4:8]]),
              jnp.where(i16u // 2 == cond, pinsrw_byte, ba)],
             default=ba)
         sse_out_lo, sse_out_hi = _pack_pair(sse_bytes)
@@ -1704,8 +1731,8 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
         shuf_sel = imm
         sh32_src = jnp.concatenate([fpa32, fpb32])    # picks: dst,dst,src,src
         shufps = jnp.stack([
-            sh32_src[jnp.where(jnp.int32(i) < 2, jnp.int32(0), jnp.int32(4))
-                     + ((shuf_sel >> _u(2 * i)) & _u(3)).astype(jnp.int32)]
+            _row(sh32_src, (0 if i < 2 else 4)
+                 + ((shuf_sel >> _u(2 * i)) & _u(3)).astype(jnp.int32))
             for i in range(4)])
         shufpd_lo = jnp.where((shuf_sel & _u(1)) != 0, x_dst_hi, x_dst_lo)
         shufpd_hi = jnp.where((shuf_sel & _u(2)) != 0, x_src_hi, x_src_lo)
@@ -1773,12 +1800,9 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
 
         x_ph0 = _xphys(_u(0))
         x_phi = _xphys(x_i)
-        # one two-row gather instead of two scalar gathers (kernel-count
-        # currency: the step wall tracks gather-class kernels, not bytes)
         with jax.named_scope("vector-file"):
-            x_st_pair = fpst_v[jnp.stack([x_ph0, x_phi])]
-        st0_b = x_st_pair[0]
-        sti_b = x_st_pair[1]
+            st0_b = _row(fpst_v, x_ph0)
+            sti_b = _row(fpst_v, x_phi)
         st0_f = lax.bitcast_convert_type(st0_b, jnp.float64)
 
         # memory operand -> f64 bits: m64 is a raw bit move, m32 converts
@@ -2315,8 +2339,10 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
         # limbs 0-1 only: upper YMM halves (limbs 2-3) are carried state the
         # legacy-SSE subset never computes on (AVX snapshots round-trip;
         # reference CpuState_t holds 32xZMM, globals.h:1020-1159)
-        new_xmm = xmm.at[xr, 0].set(jnp.where(wx_cond, wx_lo, xmm[xr, 0]))
-        new_xmm = new_xmm.at[xr, 1].set(jnp.where(wx_cond, wx_hi, new_xmm[xr, 1]))
+        new_xmm = _set_row(
+            xmm, xr, wx_cond,
+            jnp.where(jnp.arange(4) < 2,
+                      jnp.where(jnp.arange(4) == 0, wx_lo, wx_hi), x_dst))
         # vzeroall (sub 0) zeroes the whole file; vzeroupper (sub 1) the
         # upper halves only — whole-file writes, no dst register
         vz = commit & is_(U.OPC_VZEROALL)
@@ -2328,10 +2354,8 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     # -- x87 state application --------------------------------------------
     with jax.named_scope("vector-file"):
         x87c = commit & is_x87
-        new_fpst = fpst_v.at[x_w1_idx].set(
-            jnp.where(x87c & x_w1_en, x_w1_val, fpst_v[x_w1_idx]))
-        new_fpst = new_fpst.at[x_phi].set(
-            jnp.where(x87c & x_fxch, st0_b, new_fpst[x_phi]))
+        new_fpst = _set_row(fpst_v, x_w1_idx, x87c & x_w1_en, x_w1_val)
+        new_fpst = _set_row(new_fpst, x_phi, x87c & x_fxch, st0_b)
         new_fpcw = jnp.where(x87c, x_cw, fpcw_v)
         new_fpsw = jnp.where(x87c, x_sw, fpsw_v)
         new_fptw = jnp.where(x87c, x_tw, fptw_v)

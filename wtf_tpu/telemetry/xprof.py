@@ -37,11 +37,13 @@ from typing import Callable, Optional
 # holds the chunk program to it: no indexed operation outside a scope.
 CHUNK_SCOPES = (              # make_run_chunk: once a level or a chunk
     "level-sort", "group-slice", "level-account", "lane-writeback")
+VECTOR_SCOPES = (             # the vector unit: XMM file, SSE classes, x87
+    "vector-file", "sse-bytes", "sse-fp", "x87")
 STEP_SCOPES = (               # step_lane and below: once a lane-step
     "decode-probe", "uop-fetch", "regfile-read", "regfile-write",
     "tlb", "page-walk", "overlay-load", "overlay-store", "ea", "alu",
-    "unary", "shift", "mul", "cond", "string", "vector-file", "sse-bytes",
-    "sse-fp", "x87", "iret", "flags-status", "counters", "coverage")
+    "unary", "shift", "mul", "cond", "string") + VECTOR_SCOPES + (
+    "iret", "flags-status", "counters", "coverage")
 OTHER_SCOPES = (              # the other device programs
     "device-insert", "restore", "run-quiesce", "decode-service",
     "cov-merge", "devmut-generate")
