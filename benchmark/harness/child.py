@@ -14,16 +14,25 @@ wake-up pipe by a thread, which writes the report and ends the process:
 nothing is needed from the campaign after the window's last heartbeat
 (records are flushed one by one, finds are written atomically).
 
-<trace> is `0`, or `<slice seconds>:<offset>,<offset>,...`: a thread waits
-for the file <report.json>.trace to appear (the parent makes it when the
-measured window opens), then takes one short device trace with JAX's
-profiler, the Python tracer off, at each offset (seconds after that
-moment) into <report.json>.xprof/<NN>, and writes <report.json>.traced.
-The slices are spread over the whole window, so that they meet the chip
-running and the chip waiting in the shares the window has them; one trace
-of a whole loop iteration cannot be had (writing a trace out takes some
-30 s for each second in which the chip ran, PERF.md section 3).  Only the
-process that holds the chip can trace it.
+<trace> is `0`, or `<slice s>:<budget s>:<least slices>:<offset>,<offset>,...`:
+a thread waits for the file <report.json>.trace to appear (the parent writes
+the moment into it when the measured window opens), then takes one short
+device trace with JAX's profiler, the Python tracer off, at each offset
+(seconds after that moment) into <report.json>.xprof/<NN>, and writes
+<report.json>.traced.  The slices are spread over the whole window, so that
+they meet the chip running and the chip waiting in the shares the window
+has them; one trace of a whole loop iteration cannot be had: writing a
+slice out costs seconds for each tenth of a second in which the chip ran
+in it, and more for every further chip the process holds (PERF.md section
+3).  So the write-out has a budget: before each slice the thread sums what
+the slices so far cost (`start_took_s` + `stop_took_s`) and, once it has
+<least slices>, takes no further slice when the sum has reached <budget s>;
+`.traced` lists every due slice, taken or not.  The first slice that meets
+the chunk program running costs some 15 s more than a later one, and no
+session taken ahead pays that for it (an empty one at the child's start,
+or one around a small program of its own, reads 0.3 s and changes nothing:
+PERF.md section 6, PR 42); it is part of the budget.  Only the process
+that holds the chip can trace it.
 
 Exit 3, before the campaign starts, when JAX finds another platform or
 fewer chips than asked for.
@@ -55,33 +64,50 @@ def _report(path: str, devices, final: bool) -> None:
     os.replace(tmp, path)
 
 
-def _trace_when_asked(report: str, slice_s: float, offsets: list) -> None:
+def take_slices(profiler, xprof: str, slice_s: float, budget_s: float,
+                least: int, offsets: list, opened: float, options=None,
+                clock=time.time, sleep=time.sleep) -> list:
+    """One entry per due slice, in order.  A slice whose turn has passed
+    (the one before took long to write) starts at once; once `least`
+    slices are taken and they have cost `budget_s` of starting and
+    stopping, the rest are listed as not taken."""
+    slices, spent, taken = [], 0.0, 0
+    for i, offset in enumerate(offsets):
+        if taken >= least and spent >= budget_s:
+            slices.append({"dir": None, "due_s": offset, "taken": False})
+            continue
+        taken += 1
+        sleep(max(0.0, opened + offset - clock()))
+        t0 = clock()
+        profiler.start_trace(f"{xprof}/{i:02d}", profiler_options=options)
+        t1 = clock()
+        sleep(slice_s)
+        t2 = clock()
+        profiler.stop_trace()
+        t3 = clock()
+        spent += (t1 - t0) + (t3 - t2)
+        slices.append({"dir": f"{i:02d}", "due_s": offset, "taken": True,
+                       "start_ts": t1, "stop_ts": t2,
+                       "start_took_s": t1 - t0, "stop_took_s": t3 - t2})
+    return slices
+
+
+def _trace_when_asked(report: str, slice_s: float, budget_s: float,
+                      least: int, offsets: list) -> None:
     import jax
 
-    while not os.path.exists(report + ".trace"):
-        time.sleep(0.1)
-    opened = time.time()
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     options.host_tracer_level = 1
-    slices = []
-    for i, offset in enumerate(offsets):
-        # a slice whose turn has passed (the one before took long to
-        # write) starts at once
-        time.sleep(max(0.0, opened + offset - time.time()))
-        t0 = time.time()
-        jax.profiler.start_trace(f"{report}.xprof/{i:02d}",
-                                 profiler_options=options)
-        t1 = time.time()
-        time.sleep(slice_s)
-        t2 = time.time()
-        jax.profiler.stop_trace()
-        slices.append({"dir": f"{i:02d}", "due_s": offset,
-                       "start_ts": t1, "stop_ts": t2,
-                       "start_took_s": t1 - t0,
-                       "stop_took_s": time.time() - t2})
+    while not os.path.exists(report + ".trace"):
+        time.sleep(0.1)
+    with open(report + ".trace") as fh:
+        opened = float(fh.read())   # the parent's clock, which is this one
+    slices = take_slices(jax.profiler, report + ".xprof", slice_s, budget_s,
+                         least, offsets, opened, options)
     with open(report + ".traced.tmp", "w") as fh:
-        json.dump({"opened_ts": opened, "slices": slices}, fh)
+        json.dump({"opened_ts": opened, "budget_s": budget_s,
+                   "slices": slices}, fh)
     os.replace(report + ".traced.tmp", report + ".traced")
 
 
@@ -115,10 +141,10 @@ def main(argv: list) -> int:
 
     threading.Thread(target=on_signal, daemon=True).start()
     if trace != "0":
-        slice_s, offsets = trace.split(":")
+        slice_s, budget_s, least, offsets = trace.split(":")
         threading.Thread(
             target=_trace_when_asked, daemon=True,
-            args=(report, float(slice_s),
+            args=(report, float(slice_s), float(budget_s), int(least),
                   [float(o) for o in offsets.split(",")])).start()
 
     from wtf_tpu.cli import main as cli_main

@@ -6,7 +6,11 @@ The campaign's answers are its finds: crashes/<name>, one file per crash
 name the device reported, and outputs/, every testcase the device said
 brought new coverage, which together carry the coverage count `cov` of its
 heartbeat line.  All of them, not a sample, are replayed once through
-`wtf_tpu run --backend emu` after the campaign has stopped:
+`wtf_tpu run --backend emu` after the campaign has stopped: outputs/ by
+ONE process in `run`'s order (the coverage union is that process's own
+aggregate), crashes/, whose lines are compared by name alone, dealt into
+disjoint directories that a process each replays at the same time
+(`parts`, `gather`; the listings are merged by file):
 
 - every crashes/ file has to crash on the oracle under the name the
   device saved it as;
@@ -24,6 +28,7 @@ Every comparison is exact: limit 0.
 
 from __future__ import annotations
 
+import os
 import re
 import shutil
 from pathlib import Path
@@ -35,6 +40,17 @@ CRASH_RE = re.compile(r"^crash\((.*)\)$")
 # the oracle's instruction limit in the control: a testcase is cut off
 # after 8 instructions where every configuration states 100000
 CONTROL_LIMIT = 8
+MAX_PARTS = 16
+# a process is not started for fewer crashes than replay in the time it
+# takes to start and to build the configuration's image: 64, and one more
+# for every 8 MiB of image (an oracle process over the 4-GiB dump builds it
+# for 11-16 s on the chip machine's host and replays a testcase in 3-25 ms)
+MIN_CRASHES_A_PART = 64
+IMAGE_BYTES_A_CRASH = 8 << 20
+# room for what an oracle process holds besides the image's host rows
+# (its peak is 0.16 GB on the 128-KiB image and 4.28 GB over the 4-GiB
+# one: the sandbox's CPU, PR 42)
+PROCESS_BYTES = 1 << 30
 
 
 def campaign_cov(heartbeat: dict) -> Optional[int]:
@@ -52,24 +68,78 @@ def _finds(directory: Path, not_after: Optional[float] = None) -> list:
             and (not_after is None or p.stat().st_mtime <= not_after)]
 
 
-def gather(work: Path, dest: Path, last_heartbeat_ts: float) -> list:
-    """Copies the campaign's finds into `dest` and returns the manifest,
-    one `{"file", "origin", "name"}` per testcase.  outputs/ come first in
-    `run`'s order (it sorts by name), and only those written up to the
-    last heartbeat: the batches after it have no `cov` to be held to.
-    crashes/ are all in: whenever it was saved, a crash has to reproduce."""
+def gather(work: Path, dest: Path, last_heartbeat_ts: float,
+           parts=None) -> list:
+    """Lays the campaign's finds out for the replay and returns the
+    manifest, one `{"file", "origin", "name", "dir"}` per testcase.
+    outputs/ come first in `run`'s order (it sorts by name), and only those
+    written up to the last heartbeat: the batches after it have no `cov` to
+    be held to.  crashes/ are all in: whenever it was saved, a crash has to
+    reproduce.  With `parts` None everything lies in `dest` itself;
+    otherwise `parts(number of crashes)` says into how many directories
+    `dest/crashes-NN` the crashes are dealt, one by one, so that the names
+    the campaign saved side by side do not land in one part, and the
+    outputs lie whole in `dest/outputs`.  A file is a hard link to the
+    campaign's own (a copy where the filesystem refuses: a find costs the
+    chip machine's checkout 1.7 ms to copy, and a run has 25,000)."""
     shutil.rmtree(dest, ignore_errors=True)
     dest.mkdir(parents=True)
+    outputs = _finds(work / "outputs", last_heartbeat_ts)
+    crashes = _finds(work / "crashes")
+    n_parts = parts(len(crashes)) if parts else 0
+    placed = ([("outputs", p, "outputs" if n_parts else ".")
+               for p in outputs]
+              + [("crashes", p, f"crashes-{k % n_parts:02d}" if n_parts
+                  else ".") for k, p in enumerate(crashes)])
+    for sub in dict.fromkeys(sub for _, _, sub in placed):
+        (dest / sub).mkdir(exist_ok=True)
     manifest = []
-    for origin, paths in (
-            ("outputs", _finds(work / "outputs", last_heartbeat_ts)),
-            ("crashes", _finds(work / "crashes"))):
-        for path in paths:
-            file = f"{len(manifest):06d}.bin"
-            shutil.copyfile(path, dest / file)
-            manifest.append({"file": file, "origin": origin,
-                             "name": path.name})
+    for i, (origin, path, sub) in enumerate(placed):
+        file = f"{i:06d}.bin"
+        try:
+            os.link(path, dest / sub / file)
+        except OSError:
+            shutil.copyfile(path, dest / sub / file)
+        manifest.append({"file": file, "origin": origin, "name": path.name,
+                         "dir": sub})
     return manifest
+
+
+def part_dirs(manifest: list, dest: Path) -> list:
+    """The directories a replay process each reads, outputs' first."""
+    return [dest / sub for sub in dict.fromkeys(m["dir"] for m in manifest)]
+
+
+def free_memory_bytes() -> "int | None":
+    """MemAvailable of /proc/meminfo; None where there is no such file."""
+    try:
+        with open("/proc/meminfo") as fh:
+            for line in fh:
+                if line.startswith("MemAvailable:"):
+                    return int(line.split()[1]) * 1024
+    except OSError:
+        pass
+    return None
+
+
+def parts(config: dict, n_crashes: int, cpus: "int | None" = None,
+          free_bytes: "int | None" = None) -> int:
+    """Into how many parts crashes/ is cut: what the host has (its cores
+    and free memory, read here when not given), never the cell's name.
+    One core and one image stay with the outputs' process; every process
+    holds the configuration's image as host rows beside its own
+    PROCESS_BYTES, and is worth starting for MIN_CRASHES_A_PART crashes and
+    one more for every IMAGE_BYTES_A_CRASH of image."""
+    cpus = cpus or os.cpu_count() or 1
+    if free_bytes is None:
+        free_bytes = free_memory_bytes()
+    image = int(config.get("image_bytes")
+                or config.get("image_pages", 0) * 4096)
+    by_memory = MAX_PARTS
+    if free_bytes is not None:
+        by_memory = free_bytes // (image + PROCESS_BYTES) - 1
+    worth = MIN_CRASHES_A_PART + image // IMAGE_BYTES_A_CRASH
+    return max(1, min(MAX_PARTS, cpus - 1, by_memory, n_crashes // worth))
 
 
 def parse_listing(stdout: str) -> dict:

@@ -130,10 +130,11 @@ def busy_and_extent(chips: dict) -> tuple:
     return busy / 1e9, (end - start) / 1e9
 
 
-def reduce_slices(slices: list) -> dict:
+def reduce_slices(slices: list, names: "list | None" = None) -> dict:
     """`slices` is a list of (chips, host_s): the leaf operation rows of a
     slice by chip, and the seconds the host slept between starting and
-    stopping that trace.  The profiler records from somewhere inside
+    stopping that trace; `names` says what the gaps call each slice (its
+    place in the list when None).  The profiler records from somewhere inside
     start_trace to somewhere inside stop_trace, so both the sleep and the
     extent of the operations seen are lower bounds of a slice's length: it
     is the longer of the two.  busy_s and window_s are sums over the
@@ -145,7 +146,7 @@ def reduce_slices(slices: list) -> dict:
     ops: dict = {}
     gaps = []
     n_chips = max(len(chips) for chips, _ in slices)
-    for i, (chips, host_s) in enumerate(slices):
+    for i, (chips, host_s) in zip(names or range(len(slices)), slices):
         busy, extent = busy_and_extent(chips)
         length = max(host_s, extent)
         busy_s += busy
@@ -164,6 +165,35 @@ def reduce_slices(slices: list) -> dict:
     return {"busy_s": busy_s, "window_s": window_s,
             "breakdown": {"device_ops": [list(kv) for kv in ranked[:10]],
                           "idle_gaps": gaps[:10]}}
+
+
+def reduce_traced(traced: dict, load, min_taken: int) -> dict:
+    """The child's `.traced` document reduced: `reduce_slices` over the
+    slices it took (those its write-out budget cut are due and not taken),
+    with how many were due and taken, what starting and stopping them cost,
+    and what each held.  `load(dir)` gives a taken slice's device rows.
+    Fewer than `min_taken` slices are no reading of a window."""
+    due = traced["slices"]
+    taken = [sl for sl in due if sl["taken"]]
+    if len(taken) < min_taken:
+        raise TraceError(
+            f"{len(taken)} of {len(due)} due slices were taken before the "
+            f"write-out budget of {traced.get('budget_s')} s was spent; "
+            f"fewer than {min_taken} are no reading of the window")
+    slices, lines, per_slice = [], set(), []
+    for sl in taken:
+        rows = load(sl["dir"])
+        lines |= {(r.plane, r.line) for r in rows}
+        chips = device_op_rows(rows)
+        slices.append((chips, sl["stop_ts"] - sl["start_ts"]))
+        per_slice.append({
+            "dir": sl["dir"], "busy_s": busy_and_extent(chips)[0],
+            "device_rows": len(rows),
+            "cost_s": sl["start_took_s"] + sl["stop_took_s"]})
+    return dict(reduce_slices(slices, [sl["dir"] for sl in taken]),
+                slices_due=len(due), slices_taken=len(taken),
+                writeout_s=sum(s["cost_s"] for s in per_slice),
+                per_slice=per_slice, lines=sorted(lines))
 
 
 def longest_gaps(chips: dict, n: int = 10) -> list:

@@ -20,15 +20,18 @@ The measured window starts at the first heartbeat at which the cell's
 `warm_batches` are done and ends at the first heartbeat at least
 --seconds later; then the child is stopped.  Outside the window every
 find the campaign wrote, crashes/ and outputs/, is replayed on the EmuCpu
-oracle, and `correct` is whether the oracle reproduces each crash under
-its name and counts the coverage the campaign counted (harness/oracle.py).
+oracle (outputs/ by one process, crashes/ in as many parts at once as the
+host has cores and memory for), and `correct` is whether the oracle
+reproduces each crash under its name and counts the coverage the campaign
+counted (harness/oracle.py).
 
 The last stdout line is the result object; the lines before it say what
-was compared against which limit, how long each part took, and what the
-campaign last printed.  Without a TPU, with a child that dies, or with a
-window that keeps compiling, the exit code is not 0 and there is no result
-line.  `--rehearse-cpu` is for the sandbox: 16 lanes, whatever
-platform JAX finds, no result line.
+was compared against which limit, how long each part took against the
+run's limit of RUN_LIMIT_S, and what the campaign last printed; the
+numbers compared are also the last lines of standard error.  Without a
+TPU, with a child that dies, or with a window that keeps compiling, the
+exit code is not 0 and there is no result line.  `--rehearse-cpu` is for
+the sandbox: 16 lanes, whatever platform JAX finds, no result line.
 """
 
 from __future__ import annotations
@@ -55,10 +58,16 @@ HEARTBEAT_TIMEOUT_S = 600   # a cold megachunk window compiles for ~4 min
 REPLAY_TIMEOUT_S = 900
 RUNS_NO_WINDOW_REACHES = 10 ** 12
 RC_RUN_OK = (0, 2)          # `run` exits 2 when a replayed testcase crashed
-# a traced run: 8 slices of a quarter of a second, one in each eighth of
-# the window, at a moment of its first half drawn from the seed
-TRACE_SLICES = 8
-TRACE_SLICE_S = 0.25
+# a traced run: 16 slices of a twentieth of a second, one in each
+# sixteenth of the window, at a moment of its first half drawn from the
+# seed; once it has TRACE_MIN_SLICES the child takes no further slice when
+# starting and stopping the ones it took has cost TRACE_BUDGET_S, and a
+# run with fewer is no reading (PERF.md section 3 has the costs)
+TRACE_SLICES = 16
+TRACE_SLICE_S = 0.05
+TRACE_BUDGET_S = 45
+TRACE_MIN_SLICES = 4
+RUN_LIMIT_S = 360           # what the driver gives a run that compiles nothing
 
 
 class RunFailed(Exception):
@@ -109,11 +118,12 @@ def out_dir(root: Path) -> Path:
 
 
 def trace_spec(seed: int, seconds: float) -> str:
-    """`<slice seconds>:<offsets>` for the child: the moments, in seconds
-    after the window opens, at which it takes its slices."""
+    """`<slice s>:<budget s>:<least slices>:<offsets>` for the child: the
+    moments, in seconds after the window opens, at which its slices are
+    due."""
     rng = random.Random(seed)
     stratum = seconds / TRACE_SLICES
-    return f"{TRACE_SLICE_S}:" + ",".join(
+    return f"{TRACE_SLICE_S}:{TRACE_BUDGET_S}:{TRACE_MIN_SLICES}:" + ",".join(
         f"{(i + rng.random() / 2) * stratum:.3f}"
         for i in range(TRACE_SLICES))
 
@@ -160,8 +170,13 @@ def measure(cell, argv: list, env: dict, work: Path, seconds: float,
                 if record.get("type") == "heartbeat":
                     last_sign = time.time()
                 picker.feed(record)
-                if traced and picker.first is not None:
-                    trigger.touch()   # the child traces inside the window
+                if (traced and picker.first is not None
+                        and not trigger.exists()):
+                    # the child traces inside the window: its slices are
+                    # due so long after this moment
+                    fresh = work / "device.json.trace.tmp"
+                    fresh.write_text(repr(time.time()))
+                    fresh.replace(trigger)
             elif time.time() - last_sign > HEARTBEAT_TIMEOUT_S:
                 raise RunFailed(
                     f"no heartbeat for {HEARTBEAT_TIMEOUT_S} s; the "
@@ -182,24 +197,57 @@ def measure(cell, argv: list, env: dict, work: Path, seconds: float,
             "traced": cells.load_json(traced_doc) if traced else None}
 
 
-def replay(cell, finds_dir: Path, limit: int, root: Path) -> dict:
-    """The oracle's listing of the finds: `wtf_tpu run --backend emu`, off
-    the chip (JAX_PLATFORMS=cpu)."""
-    proc = subprocess.Popen(
-        replay_argv(cell, finds_dir, limit), stdout=subprocess.PIPE,
-        stderr=subprocess.PIPE, text=True, env=child_env(root, "cpu"),
-        cwd=str(root), start_new_session=True)
-    try:
-        out, err = proc.communicate(timeout=REPLAY_TIMEOUT_S)
-    except subprocess.TimeoutExpired:
-        raise RunFailed(f"the oracle's replay did not end within "
-                        f"{REPLAY_TIMEOUT_S} s")
-    finally:
-        stop(proc, grace=2.0)
-    if proc.returncode not in RC_RUN_OK:
-        raise RunFailed(f"the oracle's replay exited {proc.returncode}:\n"
-                        + "\n".join(err.splitlines()[-30:]))
-    return oracle.parse_listing(out)
+class Replay:
+    """The oracle's listing of the finds: one `wtf_tpu run --backend emu`
+    process for each directory of `part_dirs`, off the chip
+    (JAX_PLATFORMS=cpu), all started at once; leaving the `with` stops and
+    reaps them.  `listing()` waits for them and merges what they printed by
+    file; a part that exits with anything but RC_RUN_OK, or outlasts
+    REPLAY_TIMEOUT_S, fails the run."""
+
+    def __init__(self, cell, part_dirs: list, limit: int, root: Path):
+        self.started = time.time()
+        self.parts = []
+        try:
+            for part in part_dirs:
+                out = open(f"{part}.listing", "w+")
+                err = open(f"{part}.err", "w+")
+                self.parts.append((subprocess.Popen(
+                    replay_argv(cell, part, limit), stdout=out, stderr=err,
+                    env=child_env(root, "cpu"), cwd=str(root),
+                    start_new_session=True), part, out, err))
+        except BaseException:
+            self.__exit__()
+            raise
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> None:
+        for proc, _, out, err in self.parts:
+            stop(proc, grace=2.0)
+            out.close()
+            err.close()
+
+    def listing(self) -> dict:
+        merged = {}
+        for proc, part, out, err in self.parts:
+            left = self.started + REPLAY_TIMEOUT_S - time.time()
+            try:
+                proc.wait(timeout=max(left, 0.0))
+            except subprocess.TimeoutExpired:
+                raise RunFailed(
+                    f"the oracle's replay of {part.name}/ did not end "
+                    f"within {REPLAY_TIMEOUT_S} s")
+            if proc.returncode not in RC_RUN_OK:
+                err.seek(0)
+                raise RunFailed(
+                    f"the oracle's replay of {part.name}/ exited "
+                    f"{proc.returncode}:\n"
+                    + "\n".join(err.read().splitlines()[-30:]))
+            out.seek(0)
+            merged.update(oracle.parse_listing(out.read()))
+        return merged
 
 
 def last_heartbeat(events: Path) -> dict:
@@ -216,25 +264,18 @@ def last_heartbeat(events: Path) -> dict:
 
 
 def reduce_trace(run: dict, work: Path, first: dict, last: dict) -> dict:
-    """busy_s and window_s summed over the slices, the breakdown, and the
-    window's own length and guest instructions, which the slices sample.
-    The traces themselves are deleted (tens of MB)."""
+    """busy_s and window_s summed over the slices the child took, the
+    breakdown, and the window's own length and guest instructions, which
+    the slices sample.  The traces themselves are deleted (tens of MB)."""
     xdir = work / "device.json.xprof"
     try:
-        slices = []
-        lines = set()
-        for sl in run["traced"]["slices"]:
-            rows = trace.load_device_rows(trace.find_xplane(xdir / sl["dir"]))
-            lines |= {(r.plane, r.line) for r in rows}
-            slices.append((trace.device_op_rows(rows),
-                           sl["stop_ts"] - sl["start_ts"]))
-        return dict(trace.reduce_slices(slices),
-                    span_s=last["ts"] - first["ts"],
-                    instructions=window.delta(first, last,
-                                              "backend.instructions"),
-                    busy_s_by_slice=[trace.busy_and_extent(c)[0]
-                                     for c, _ in slices],
-                    profiler=run["traced"], lines=sorted(lines))
+        return dict(
+            trace.reduce_traced(
+                run["traced"], lambda d: trace.load_device_rows(
+                    trace.find_xplane(xdir / d)), TRACE_MIN_SLICES),
+            span_s=last["ts"] - first["ts"],
+            instructions=window.delta(first, last, "backend.instructions"),
+            profiler=run["traced"])
     finally:
         shutil.rmtree(xdir, ignore_errors=True)
 
@@ -319,32 +360,39 @@ def main(argv=None) -> int:
         t_replay = time.time()
         final = last_heartbeat(work / "telem" / "events.jsonl")
         finds_dir = work / "replay"
-        manifest = oracle.gather(work, finds_dir, final["ts"])
+        manifest = oracle.gather(work, finds_dir, final["ts"],
+                                 lambda n: oracle.parts(config, n))
+        part_dirs = oracle.part_dirs(manifest, finds_dir)
+        n_parts = len(part_dirs) - 1          # beside the outputs' process
+        gather_s = time.time() - t_replay
         held_to = (oracle.campaign_cov(final), window.read_key(
             final, "devdec.crosscheck_mismatches") or 0)
-        verdict = oracle.compare(
-            manifest, replay(cell, finds_dir, config["limit"], root),
-            *held_to)
+        traced_numbers = {}
+        with Replay(cell, part_dirs, config["limit"], root) as replaying:
+            # the trace is read while the oracle's processes run
+            t_reduce = time.time()
+            if traced:
+                try:
+                    traced_numbers = reduce_trace(run, work, first, last)
+                except trace.TraceError as e:
+                    if not args.rehearse_cpu:
+                        raise RunFailed(f"trace: {e}")
+                    say(trace_error=str(e), profiler=run["traced"])
+            reduce_s = time.time() - t_reduce
+            verdict = oracle.compare(manifest, replaying.listing(),
+                                     *held_to)
         correct = verdict["correct"]
         replay_s = time.time() - t_replay
-        say(compared=verdict["compared"],
+        say(compared=verdict["compared"], replay_parts=n_parts,
             differences=verdict["differences"][:20])
         if args.control:
-            control = oracle.compare(
-                manifest, replay(cell, finds_dir, oracle.CONTROL_LIMIT, root),
-                *held_to)
+            with Replay(cell, part_dirs, oracle.CONTROL_LIMIT,
+                        root) as cut_short:
+                control = oracle.compare(manifest, cut_short.listing(),
+                                         *held_to)
             say(control_limit=oracle.CONTROL_LIMIT,
                 control_correct=control["correct"],
                 control_compared=control["compared"])
-
-        traced_numbers = {}
-        if traced:
-            try:
-                traced_numbers = reduce_trace(run, work, first, last)
-            except trace.TraceError as e:
-                if not args.rehearse_cpu:
-                    raise RunFailed(f"trace: {e}")
-                say(trace_error=str(e))
     except (RunFailed, window.WindowError, cells.BenchmarkDataError) as e:
         print(f"benchmark run failed: {e}", file=sys.stderr)
         return 1
@@ -363,12 +411,17 @@ def main(argv=None) -> int:
                      first, last, "supervise.batch_retries") or 0))
     finals = [ln for ln in tail(run["log"], 400).splitlines()
               if ln.startswith("#")]
+    total_s = time.time() - T0
     say(window_s=last["ts"] - first["ts"],
         heartbeats_in_window=run["heartbeats_in_window"],
         batches_in_window=window.delta(first, last, "backend.batches"),
         compile_restarts=run["restarts"],
-        warm_s=first["ts"] - T0, replay_s=replay_s,
-        stop_s=run["stop_s"], total_s=time.time() - T0,
+        warm_s=first["ts"] - T0, replay_s=replay_s, gather_s=gather_s,
+        reduce_s=reduce_s,
+        replay_parts=n_parts, trace_writeout_s=traced_numbers.get(
+            "writeout_s"), stop_s=run["stop_s"],
+        total_s=total_s, limit_s=RUN_LIMIT_S,
+        headroom_s=RUN_LIMIT_S - total_s,
         native_mutator_built=(root / "wtf_tpu/native/_build"
                               / "libwtfmangle.so").is_file(),
         campaign_last_line=finals[-1] if finals else "",
@@ -394,9 +447,18 @@ def main(argv=None) -> int:
                    "memory_peak_bytes": device["memory_peak_bytes"]},
     }
     if traced:
-        result["device"].update(busy_s=traced_numbers["busy_s"],
-                                window_s=traced_numbers["window_s"])
+        result["device"].update(
+            busy_s=traced_numbers["busy_s"],
+            window_s=traced_numbers["window_s"],
+            slices_due=traced_numbers["slices_due"],
+            slices_taken=traced_numbers["slices_taken"])
         result["breakdown"] = traced_numbers["breakdown"]
+    # each number compared beside its limit: the last lines of standard
+    # error, and the result's last key
+    result["compared"] = verdict["compared"]
+    for name, number in verdict["compared"].items():
+        print(f"compared {name}: {json.dumps(number)}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

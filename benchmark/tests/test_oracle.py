@@ -90,7 +90,87 @@ def test_gather_takes_every_find_outputs_first_up_to_the_heartbeat(tmp_path):
     files = sorted(p.name for p in (tmp_path / "g").iterdir())
     assert files == [m["file"] for m in got]          # `run` sorts by name
     assert (tmp_path / "g" / got[1]["file"]).read_bytes() == b"\x01\x01"
+    # without `parts` everything lies in the one directory
+    assert oracle.part_dirs(got, tmp_path / "g") == [tmp_path / "g"]
+    # a find is a link to the campaign's own file, not a second copy
+    assert (tmp_path / "g" / got[0]["file"]).samefile(
+        tmp_path / "outputs" / "d0")
 
 
 def test_gather_of_a_campaign_that_wrote_nothing_is_empty(tmp_path):
     assert oracle.gather(tmp_path, tmp_path / "g", 1.0) == []
+
+
+GIB = 1 << 30
+SMALL = {"image_pages": 32}
+DUMP = {"image_bytes": 4 * GIB}
+
+
+@pytest.mark.parametrize("config, crashes, cpus, free, want", [
+    (SMALL, 3000, 13, 36 * GIB, 12),     # a core stays with outputs/
+    (SMALL, 3000, 30, 130 * GIB, 16),    # never more than MAX_PARTS
+    (SMALL, 200, 13, 36 * GIB, 3),       # nor than 64 crashes a part give
+    (SMALL, 5, 13, 36 * GIB, 1),
+    (SMALL, 0, 13, 36 * GIB, 1),
+    (SMALL, 3000, 1, 36 * GIB, 1),
+    # a process over a dump builds 4 GiB before its first testcase: it is
+    # worth 64 + 512 crashes
+    (DUMP, 3000, 13, 36 * GIB, 5),
+    (DUMP, 1610, 13, 36 * GIB, 2),
+    (DUMP, 230, 13, 36 * GIB, 1),
+    # and holds them beside PROCESS_BYTES: 36 GiB hold seven processes,
+    # one of them the outputs'
+    (DUMP, 30000, 13, 36 * GIB, 6),
+    (DUMP, 30000, 30, 130 * GIB, 16),
+    (DUMP, 30000, 13, 6 * GIB, 1),
+])
+def test_parts_follow_from_the_hosts_cores_and_memory(config, crashes, cpus,
+                                                      free, want):
+    assert oracle.parts(config, crashes, cpus, free) == want
+
+
+def test_cores_and_free_memory_are_the_hosts_own_when_not_given():
+    free = oracle.free_memory_bytes()
+    assert free is None or free > 0
+    assert oracle.parts(SMALL, 3000) == oracle.parts(
+        SMALL, 3000, os.cpu_count(), free)
+
+
+def test_gather_keeps_outputs_whole_and_deals_crashes_to_disjoint_parts(
+        tmp_path):
+    work = tmp_path / "work"
+    for sub, names in (("outputs", ["o0", "o1", "o2"]),
+                       ("crashes", [f"crash-{i}" for i in range(7)])):
+        (work / sub).mkdir(parents=True)
+        for name in names:
+            (work / sub / name).write_text(name)
+    dest = tmp_path / "g"
+    asked = []
+    manifest = oracle.gather(work, dest, 2e9,
+                             lambda n: asked.append(n) or 3)
+    assert asked == [7]                    # the number of crashes
+    dirs = oracle.part_dirs(manifest, dest)
+    assert [d.name for d in dirs] == ["outputs", "crashes-00", "crashes-01",
+                                      "crashes-02"]
+    held = {d.name: sorted(p.name for p in d.iterdir()) for d in dirs}
+    # outputs/ in `run`'s order, in one directory
+    assert held["outputs"] == ["000000.bin", "000001.bin", "000002.bin"]
+    # crashes dealt one by one: 3 + 2 + 2, every file in exactly one part
+    assert [len(held[f"crashes-0{k}"]) for k in range(3)] == [3, 2, 2]
+    everywhere = sorted(f for files in held.values() for f in files)
+    assert everywhere == [m["file"] for m in manifest]
+    assert held["crashes-00"] == ["000003.bin", "000006.bin", "000009.bin"]
+    assert [m["dir"] for m in manifest[:4]] == ["outputs"] * 3 + [
+        "crashes-00"]
+    assert not [p for p in dest.iterdir() if p.is_file()]
+    assert (dest / "crashes-01" / "000004.bin").read_text() == "crash-1"
+
+
+def test_a_campaign_without_crashes_is_laid_out_as_its_outputs_alone(
+        tmp_path):
+    (tmp_path / "work" / "outputs").mkdir(parents=True)
+    (tmp_path / "work" / "outputs" / "o0").write_text("x")
+    manifest = oracle.gather(tmp_path / "work", tmp_path / "g", 2e9,
+                             lambda n: max(1, n))
+    assert [d.name for d in oracle.part_dirs(manifest, tmp_path / "g")] == [
+        "outputs"]

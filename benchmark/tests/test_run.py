@@ -70,9 +70,13 @@ REPLAY = textwrap.dedent('''
     import sys
     from pathlib import Path
     short = sys.argv[1]
-    files = sorted(Path(sys.argv[2]).iterdir())
-    if short == "1":
+    part = Path(sys.argv[2])
+    files = sorted(part.iterdir())
+    if short == "1" and part.name == "outputs":
         files = files[:-1]
+    if short == "dies" and part.name.startswith("crashes"):
+        print("Traceback: the oracle fell over", file=sys.stderr)
+        sys.exit(7)
     seen = 0
     for p in files:
         data = p.read_bytes()
@@ -111,11 +115,22 @@ def result_of(capsys):
     return json.loads(lines[-1]), [json.loads(ln) for ln in lines[:-1]]
 
 
+def said(earlier, key):
+    return next(e[key] for e in earlier if key in e)
+
+
 def test_a_sound_run_prints_the_contracts_line(stand_ins, capsys):
     assert bench_run.main(ARGS) == 0
-    result, earlier = result_of(capsys)
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    captured = capsys.readouterr()
+    lines = captured.out.strip().splitlines()
+    result, earlier = json.loads(lines[-1]), [json.loads(ln)
+                                              for ln in lines[:-1]]
+    # the numbers compared come last in the line and last on stderr
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "compared"]
+    assert result["compared"] == said(earlier, "compared")
+    assert captured.err.strip().splitlines()[-1] == (
+        'compared devdec_crosscheck_mismatches: {"value": 0, "limit": 0}')
     assert result["correct"] is True, json.dumps(earlier[1])
     assert result["attempted"] == 20 * 1024 and result["failed"] == 0
     assert set(result["metrics"]) == {"execs_per_s", "guest_instr_per_s",
@@ -127,12 +142,19 @@ def test_a_sound_run_prints_the_contracts_line(stand_ins, capsys):
     assert 0 < result["metrics"]["setup_s"]["value"] < 60
     assert result["device"] == {"platform": "tpu", "kind": "TPU v5 lite",
                                 "count": 1, "memory_peak_bytes": 1500000000}
-    compared = next(e["compared"] for e in earlier if "compared" in e)
+    compared = said(earlier, "compared")
     # three outputs up to the last heartbeat and one crash; `late` is out
     assert compared["testcases"] == 4
     assert compared["campaign_cov"] == compared["oracle_cov"] == 9
     assert all(c == {"value": 0, "limit": 0}
                for c in compared.values() if isinstance(c, dict))
+    # the run says where it stands against its limit, and how the replay
+    # was cut: one crash, so one part beside the outputs' process
+    where = next(e for e in earlier if "total_s" in e)
+    assert where["limit_s"] == bench_run.RUN_LIMIT_S == 360
+    assert where["headroom_s"] == pytest.approx(360 - where["total_s"])
+    assert where["replay_parts"] == said(earlier, "replay_parts") == 1
+    assert where["trace_writeout_s"] is None          # an untraced run
 
 
 @pytest.mark.parametrize("fault, counter", [
@@ -151,16 +173,19 @@ def test_a_broken_timed_path_comes_out_not_correct(stand_ins, capsys, fault,
     assert bench_run.main(ARGS) == 0
     result, earlier = result_of(capsys)
     assert result["correct"] is False
-    assert next(e["differences"] for e in earlier if "differences" in e)
-    compared = next(e["compared"] for e in earlier if "compared" in e)
-    assert compared[counter]["value"] >= 1
+    assert said(earlier, "differences")
+    assert said(earlier, "compared")[counter]["value"] >= 1
+    assert result["compared"][counter]["value"] >= 1
 
 
-@pytest.mark.parametrize("mode, says", [
-    ("dies", "exited with code 7"), ("compiles", "compile number 4")])
-def test_a_dead_child_or_a_compiling_window_prints_no_result(
-        stand_ins, capsys, mode, says):
-    stand_ins["mode"] = mode
+@pytest.mark.parametrize("fault, says", [
+    ({"mode": "dies"}, "exited with code 7"),
+    ({"mode": "compiles"}, "compile number 4"),
+    # one part of the replay dies: no listing of its testcases, no verdict
+    ({"short": "dies"}, "replay of crashes-00/ exited 7")])
+def test_a_dead_child_a_compiling_window_or_a_dead_part_prints_no_result(
+        stand_ins, capsys, fault, says):
+    stand_ins.update(fault)
     assert bench_run.main(ARGS) != 0
     captured = capsys.readouterr()
     assert says in captured.err
@@ -216,9 +241,11 @@ def test_the_campaigns_seed_is_the_cells_own_or_the_runs(tmp_path):
 def test_trace_slices_are_seeded_one_to_each_part_of_the_window():
     spec = bench_run.trace_spec(7, 51.0)
     assert spec == bench_run.trace_spec(7, 51.0) != bench_run.trace_spec(8, 51.0)
-    slice_s, offsets = spec.split(":")
+    slice_s, budget_s, least, offsets = spec.split(":")
     offsets = [float(o) for o in offsets.split(",")]
     assert float(slice_s) == bench_run.TRACE_SLICE_S
+    assert float(budget_s) == bench_run.TRACE_BUDGET_S
+    assert int(least) == bench_run.TRACE_MIN_SLICES
     assert len(offsets) == bench_run.TRACE_SLICES
     part = 51.0 / len(offsets)
     assert all(i * part <= o < (i + 0.5) * part + 1e-3
@@ -243,8 +270,12 @@ def test_the_control_is_not_correct_on_the_oracle_itself(tmp_path):
         # kernel buffer, 4 jumps to an address of the input's
         (work / "outputs" / f"t{i:02d}").write_bytes(
             bytes([1 + i % 4]) + rng.randbytes(rng.randint(16, 64)))
+    def replay(directory, limit):
+        with bench_run.Replay(cell, [directory], limit, root) as replaying:
+            return replaying.listing()
+
     drawn = oracle.gather(work, tmp_path / "g0", time.time() + 1)
-    listing = bench_run.replay(cell, tmp_path / "g0", limit, root)
+    listing = replay(tmp_path / "g0", limit)
     assert len(listing) == 12
     for item in drawn:
         crashed = oracle.CRASH_RE.match(listing[item["file"]][0])
@@ -255,8 +286,8 @@ def test_the_control_is_not_correct_on_the_oracle_itself(tmp_path):
     assert cov > 0 and any((work / "crashes").iterdir())
 
     manifest = oracle.gather(work, tmp_path / "g1", time.time() + 1)
-    full = bench_run.replay(cell, tmp_path / "g1", limit, root)
-    cut = bench_run.replay(cell, tmp_path / "g1", oracle.CONTROL_LIMIT, root)
+    full = replay(tmp_path / "g1", limit)
+    cut = replay(tmp_path / "g1", oracle.CONTROL_LIMIT)
     assert oracle.compare(manifest, full, cov)["correct"]
     verdict = oracle.compare(manifest, cut, cov)
     assert not verdict["correct"]

@@ -118,3 +118,55 @@ def test_loader_finds_no_chip_in_a_trace_taken_without_one(tmp_path):
 def test_no_trace_written_is_an_error(tmp_path):
     with pytest.raises(trace.TraceError, match="no .xplane.pb"):
         trace.find_xplane(tmp_path)
+
+
+def traced_doc(taken):
+    """A child's `.traced`: slice i was due at i seconds; the taken ones
+    slept 0.05 s and cost 0.05 s to start and `taken[i]` s to stop."""
+    return {"opened_ts": 100.0, "budget_s": 45, "slices": [
+        {"dir": f"{i:02d}", "due_s": float(i), "taken": True,
+         "start_ts": 100.0 + i, "stop_ts": 100.05 + i,
+         "start_took_s": 0.05, "stop_took_s": cost}
+        if cost is not None else
+        {"dir": None, "due_s": float(i), "taken": False}
+        for i, cost in enumerate(taken)]}
+
+
+def test_a_traced_document_with_skipped_slices_reduces_the_ones_taken():
+    """Six due, the budget cut the last two: busy and traced time are
+    sums over the four taken, and the result says how many were due."""
+    doc = traced_doc([4.0, 0.4, 3.5, 0.4, None, None])
+    loaded = []
+
+    def load(directory):
+        loaded.append(directory)
+        # slices 00 and 02 met the chip running, 01 and 03 met it waiting
+        return ROWS if directory in ("00", "02") else []
+
+    got = trace.reduce_traced(doc, load, min_taken=4)
+    assert loaded == ["00", "01", "02", "03"]
+    assert (got["slices_due"], got["slices_taken"]) == (6, 4)
+    assert got["busy_s"] == pytest.approx(2 * 2200e-9)
+    assert got["window_s"] == pytest.approx(4 * 0.05)
+    assert got["writeout_s"] == pytest.approx(4 * 0.05 + 4.0 + 0.4 + 3.5 + 0.4)
+    assert [s["dir"] for s in got["per_slice"]] == ["00", "01", "02", "03"]
+    assert got["per_slice"][0]["busy_s"] == pytest.approx(2200e-9)
+    assert got["per_slice"][0]["cost_s"] == pytest.approx(4.05)
+    assert got["per_slice"][1]["busy_s"] == 0.0
+    # a gap is named after the slice's directory, not its place in a list
+    gaps = [g[0] for g in got["breakdown"]["idle_gaps"]]
+    assert "unattributed: slice 03, no operation" in gaps
+    assert any(g.startswith("unattributed: slice 02, after") for g in gaps)
+    assert ("/device:TPU:0", trace.OPS_LINE) in got["lines"]
+
+
+def test_fewer_slices_than_the_least_is_an_error_that_names_the_budget():
+    doc = traced_doc([50.0, None, None, None, None, None, None, None])
+    with pytest.raises(trace.TraceError, match="1 of 8 due slices.*45"):
+        trace.reduce_traced(doc, lambda d: ROWS, min_taken=4)
+
+
+def test_taken_slices_without_an_operation_are_still_no_chip():
+    doc = traced_doc([0.4] * 4)
+    with pytest.raises(trace.TraceError, match="no slice"):
+        trace.reduce_traced(doc, lambda d: [], min_taken=4)
