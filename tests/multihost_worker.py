@@ -56,7 +56,7 @@ def main() -> None:
     image = replicate(runner.physmem.image, mesh)
     # the mesh chunk's in-graph coverage all-reduce IS the cross-process
     # collective under test: its output is replicated on every host
-    machine, cov, _edge = make_mesh_chunk(8, mesh, donate=False)(
+    machine, cov, _edge, _steps = make_mesh_chunk(8, mesh, donate=False)(
         tab, image, machine, jnp.uint64(500))
 
     from jax.experimental import multihost_utils

@@ -435,32 +435,54 @@ def test_mesh_window_compiles_for_four_v5e(topo, compile_for):
             getattr(x, "sharding", None), NamedSharding) else P()),
         operands)
     fn = make_mesh_megachunk(*seam_args, deliver=runner.deliver_exceptions,
-                             mesh=mesh, devdec=True, interpret=False,
-                             donate=False)
+                             mesh=mesh, devdec=True, donate=False)
     compile_for(fn, operands, placed)
 
 
 @pytest.mark.slow
 def test_mesh_chunk_compiles_for_four_v5e(topo, compile_for):
-    """make_mesh_chunk on a Mesh of four described chips at the README's
-    512 lanes per chip: one all-reduce, and per-device argument bytes
-    that say the lane planes are split, not parked on the first chip."""
-    from wtf_tpu.meshrun.executor import IMAGE_SPEC, make_mesh_chunk
+    """make_mesh_chunk on a Mesh of four described chips at the shape of
+    the deployment `hevd-fulldump-mesh4`: 16,384 lanes, 4,096 a chip, the
+    campaign's default runner (128 overlay slots: a data plane of 2**31
+    limbs, 2**29 a chip), the image at the size of the 4-GiB dump on every
+    chip.  One all-reduce, the lane planes split and not parked on the
+    first chip, and every chip's bytes under its 16 GB.  The machine's
+    shapes are a four-lane runner's with the lane axis scaled: nothing of
+    that size is built here."""
+    from wtf_tpu.harness import demo_hevd
+    from wtf_tpu.interp.runner import Runner
+    from wtf_tpu.mem.physmem import (
+        ROW_GRANULE, SPAN_GRANULE, MemImage, padded,
+    )
+    from wtf_tpu.meshrun.executor import make_mesh_chunk
     from wtf_tpu.meshrun.mesh import LANE_AXIS
 
-    lanes = 4 * 512
-    runner = _campaign(lanes).backend.runner
+    lanes = 4 * 4096
+    small = Runner(demo_hevd.build_snapshot(), 4)
     mesh = Mesh(np.array(topo.devices[:4]), (LANE_AXIS,))
     rep, split = NamedSharding(mesh, P()), NamedSharding(mesh, P(LANE_AXIS))
-    tab, image, machine, limit = runner.executor_operands()
-    image_sh = jax.tree.map(lambda spec: NamedSharding(mesh, spec),
-                            IMAGE_SPEC, is_leaf=lambda x: isinstance(x, P))
-    compiled = compile_for(make_mesh_chunk(64, mesh, jit=False),
-                           (tab, image, machine, limit),
-                           (rep, image_sh, split, rep))
+    machine = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            (x.shape[0] * lanes // 4,) + x.shape[1:], x.dtype),
+        small.machine)
+    image = MemImage(
+        pages=jax.ShapeDtypeStruct(
+            (padded((1 << 20) + 1, ROW_GRANULE), 1024), jnp.uint32),
+        frame_table=jax.ShapeDtypeStruct(
+            (1, padded(0x108000, SPAN_GRANULE)), jnp.int32))
+    assert machine.overlay.data.shape == (1 << 31,)
+    compiled = compile_for(
+        make_mesh_chunk(256, mesh, jit=False),
+        (small.cache.device(), image, machine, jnp.uint64(0)),
+        (rep, rep, split, rep), donate_argnums=(2,))
     assert compiled.as_text().count("all-reduce(") + \
         compiled.as_text().count("all-reduce-start(") == 1
-    whole = sum(x.nbytes for x in jax.tree.leaves(machine))
-    per_device = compiled.memory_analysis().argument_size_in_bytes
-    shared = sum(x.nbytes for x in jax.tree.leaves((tab, image)))
-    assert per_device < shared + whole / 4 * 1.1, (per_device, whole)
+    mem = compiled.memory_analysis()
+    whole = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                for x in jax.tree.leaves(machine))
+    shared = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                 for x in jax.tree.leaves((small.cache.device(), image)))
+    assert mem.argument_size_in_bytes < shared + whole / 4 * 1.1, mem
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes) \
+        < HBM_BYTES / 2, mem

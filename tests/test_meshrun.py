@@ -66,7 +66,7 @@ def test_mesh_chunk_bit_parity_and_merged_bitmaps(mesh):
     machine = shard_machine(r2.machine, mesh)
     tab = replicate(r2.cache.device(), mesh)
     image = replicate(r2.physmem.image, mesh)
-    m_mesh, cov, edge = make_mesh_chunk(8, mesh, donate=False)(
+    m_mesh, cov, edge, _ = make_mesh_chunk(8, mesh, donate=False)(
         tab, image, machine, jnp.uint64(500))
 
     for name in m_single._fields:
@@ -98,7 +98,7 @@ def test_mesh_chunk_through_the_tail_equals_head_only(monkeypatch):
     monkeypatch.setattr(step, "TAIL_SHARE", 4)
     mesh4 = make_mesh(4)
     r2 = _runner()
-    m_mesh, cov, edge = jax.jit(
+    m_mesh, cov, edge, _ = jax.jit(
         make_mesh_chunk(160, mesh4, donate=False, jit=False))(
         replicate(r2.cache.device(), mesh4),
         replicate(r2.physmem.image, mesh4),
@@ -335,9 +335,14 @@ def test_telemetry_report_mesh_section(tmp_path):
         "device.mem_faults": 0,
         "device.decode_misses": 32,
         "device.shard_instructions": {"0": 1000, "1": 792},
+        "device.shard_lane_steps": {"1": 900, "0": 1100},
+        "device.lane_steps": 2000,
+        "mesh.wait_lane_steps": 500,
         "mesh.devices": 2,
         "mesh.lanes_per_shard": 8,
-        "phase.seconds": {"execute": 1.0},
+        "image.replicas": 2,
+        "phase.seconds": {"execute": 1.0, "execute/cov-readback": 0.5,
+                          "execute/cov-readback/mesh-merge": 0.25},
     }
     with events.open("w") as fh:
         fh.write(json.dumps({"ts": 1.0, "seq": 0, "type": "run-start"}) + "\n")
@@ -348,6 +353,9 @@ def test_telemetry_report_mesh_section(tmp_path):
         "devices": 2, "lanes_per_shard": 8,
         "shard_instructions": {"0": 1000, "1": 792},
         "shard_instructions_sum": 1792, "merged_instructions": 1792,
+        "shard_lane_steps": {"0": 1100, "1": 900},
+        "wait_lane_steps": 500, "wait_share": 0.2,
+        "merge_seconds": 0.25, "image_replicas": 2,
     }
 
 

@@ -1045,6 +1045,17 @@ def test_direct_children_cover_most_of_the_parent_span(kernel_campaign,
     assert 0.8 * seconds[parent] <= children <= seconds[parent], seconds
 
 
+def test_dispatch_and_wait_close_the_device_step_span(kernel_campaign):
+    """What `device_step_spanned_share` read (99.99 in every line of the
+    ledger) before PR 42 retired it: the span's two children sum to it.
+    (Held by `benchmark/tests/test_split_replay.py` until PR 43.)"""
+    seconds = kernel_campaign["metrics"]["phase.seconds"]
+    closure = 100 * (seconds["execute/device-step/dispatch"]
+                     + seconds["execute/device-step/wait"]) \
+        / seconds["execute/device-step"]
+    assert 99.0 <= closure <= 100.0 + 1e-9
+
+
 def test_top_level_spans_cover_the_wall_clock_between_heartbeats(
         kernel_campaign):
     """mutate + execute + harvest + restore + heartbeat (+ checkpoint)

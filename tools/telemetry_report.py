@@ -170,12 +170,26 @@ def summarize(path) -> dict:
             shard_instr = {}
         per_shard = dict(sorted(shard_instr.items(),
                                 key=lambda kv: int(kv[0])))
+        shard_steps = metrics.get("device.shard_lane_steps", {})
+        # what the shards waited for each other at the chunks' all-reduce
+        # (lane-steps short of the chunk's busiest shard), beside the
+        # lane-steps they took; the batch merge's own span
+        waited = metrics.get("mesh.wait_lane_steps", 0)
+        taken = metrics.get("device.lane_steps", 0)
         mesh = {
             "devices": metrics.get("mesh.devices"),
             "lanes_per_shard": metrics.get("mesh.lanes_per_shard"),
             "shard_instructions": per_shard,
             "shard_instructions_sum": sum(per_shard.values()),
             "merged_instructions": metrics.get("device.instructions", 0),
+            "shard_lane_steps": dict(sorted(
+                shard_steps.items(), key=lambda kv: int(kv[0]))),
+            "wait_lane_steps": waited,
+            "wait_share": (round(waited / (waited + taken), 6)
+                           if waited + taken else None),
+            "merge_seconds": round(phase_seconds.get(
+                "execute/cov-readback/mesh-merge", 0.0), 4),
+            "image_replicas": metrics.get("image.replicas"),
         }
 
     # triage (wtf_tpu/triage): candidate volume and what it bought —
@@ -657,6 +671,16 @@ def _print_human(s: dict) -> None:
                           "DISAGREES)")
             print(f"  per-shard instructions: {per} "
                   f"(sum {mesh['shard_instructions_sum']}{agree})")
+        if mesh["shard_lane_steps"]:
+            per = " ".join(f"{k}={v}"
+                           for k, v in mesh["shard_lane_steps"].items())
+            print(f"  per-shard lane-steps: {per}; waited at the chunks' "
+                  f"all-reduce: {mesh['wait_lane_steps']} lane-steps ("
+                  + _or_null(mesh["wait_share"], "{:.2%}")
+                  + " of the mesh's)")
+        print(f"  batch merge (execute/cov-readback/mesh-merge): "
+              f"{mesh['merge_seconds']:.3f} s; image on "
+              f"{mesh['image_replicas']} chip(s)")
     tri = s.get("triage")
     if tri:
         per_min = (f" ({tri['dispatches_per_minimization']} "

@@ -1427,12 +1427,12 @@ def run_lint(families: Optional[Sequence[str]] = None,
             lowered.as_text(), perturbed.as_text(), entry=entry))
         # overlay restore: same stability contract, cheap trace (fresh
         # jit wrappers — the memoized executor would hit the trace cache)
-        from wtf_tpu.interp.machine import _machine_restore_impl
+        from wtf_tpu.interp.machine import machine_restore_impl
         from wtf_tpu.analysis.trace import lower_jit
 
-        ra = lower_jit(lambda m, t: _machine_restore_impl(m, t),
+        ra = lower_jit(lambda m, t: machine_restore_impl(m, t),
                        runner.machine, runner.template).as_text()
-        rb = lower_jit(lambda m, t: _machine_restore_impl(m, t),
+        rb = lower_jit(lambda m, t: machine_restore_impl(m, t),
                        runner.machine._replace(
                            icount=runner.machine.icount + 3),
                        runner.template).as_text()

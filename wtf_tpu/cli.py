@@ -555,6 +555,16 @@ def _telemetry_for(args):
         events.close()
 
 
+def _image_sharding(mesh_devices: Optional[int]):
+    """Where a campaign's image lies: on the default device, or replicated
+    over the mesh that `--mesh-devices` makes (0: every device)."""
+    if mesh_devices is None:
+        return None
+    from wtf_tpu.meshrun.mesh import make_mesh, replicated_sharding
+
+    return replicated_sharding(make_mesh(mesh_devices))
+
+
 def _build_backend(target, backend_name: str, paths: TargetPaths,
                    limit: int, lanes: int, registry=None, events=None,
                    tuning: Optional[dict] = None):
@@ -579,9 +589,13 @@ def _build_backend(target, backend_name: str, paths: TargetPaths,
         physmem.pull_counter = registry.counter("physmem.device_pulls")
         if backend_name == "tpu":
             # the oracle backend reads the host arrays and never builds
-            # the device image
+            # the device image; under --mesh-devices every chip's copy
+            # goes up here, from the host rows, as the one replicated
+            # array the runner takes
+            mesh = (tuning or {}).get("mesh_devices")
             with spans.span("upload") as sp:
-                sp.fence(physmem.image)
+                sp.fence(physmem.upload(_image_sharding(mesh)))
+            registry.gauge("image.replicas").set(physmem.replicas)
         for name, value in (("bytes", physmem.nbytes),
                             ("frames_present", physmem.frames_present),
                             ("span_frames", len(physmem.table)),

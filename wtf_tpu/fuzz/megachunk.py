@@ -67,7 +67,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from wtf_tpu.core.results import StatusCode
-from wtf_tpu.interp.machine import Machine, N_CTRS, _machine_restore_impl
+from wtf_tpu.interp.machine import Machine, N_CTRS, machine_restore_impl
 from wtf_tpu.interp.runner import device_insert_impl
 from wtf_tpu.interp.step import step_lanes
 from wtf_tpu.interp.uoptable import UopTable
@@ -263,7 +263,7 @@ def _make_body(max_batches: int, n_pages: int, len_gpr: int, ptr_gpr: int,
             data = jnp.where(first, slab_first[0], slab_rest[0])
             lens_s = jnp.where(first, slab_first[1], slab_rest[1])
             cumw = jnp.where(first, slab_first[2], slab_rest[2])
-            m = _machine_restore_impl(m, template)
+            m = machine_restore_impl(m, template)
             words, lens = generate(data, lens_s, cumw, seeds[b],
                                    rounds=rounds)
             m = insert(m, words, lens, pfns, gva_l)

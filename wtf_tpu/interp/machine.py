@@ -36,7 +36,9 @@ from wtf_tpu.core.cpustate import CpuState
 from wtf_tpu.core.results import StatusCode
 from wtf_tpu.interp.limbs import pack_u64, unpack_np
 from wtf_tpu.mem import overlay as ovl
-from wtf_tpu.mem.overlay import DirtyOverlay, overlay_init, overlay_reset
+from wtf_tpu.mem.overlay import (
+    DirtyOverlay, filled, overlay_init, overlay_reset,
+)
 
 # Device-side telemetry counter block: one u32 per lane per counter,
 # accumulated in-graph (by step_lane unless said otherwise) and folded into
@@ -233,15 +235,28 @@ def machine_init(
     uop_capacity: int,
     overlay_slots: int = 128,
     edge_bits: int = 17,
+    sharding=None,
 ) -> Machine:
-    """Build the batch with every lane at the snapshot state."""
+    """Build the batch with every lane at the snapshot state.
+
+    `sharding` places every leaf as it is made: None is the default
+    device; a mesh runner passes its lane sharding, and then a leaf
+    built on the host goes to the devices part by part and a zeroed one
+    is allocated by each device for its own lanes (`overlay.filled`), so
+    no leaf is ever whole on one device of several."""
     ones = np.ones(n_lanes, dtype=np.uint64)
 
+    def put(host: np.ndarray) -> jax.Array:
+        return jax.device_put(host, sharding)
+
+    def zeros(shape: tuple, dtype) -> jax.Array:
+        return filled(shape, 0, dtype, sharding)
+
     def bcast(value: int) -> jax.Array:
-        return jnp.asarray(ones * np.uint64(value & (1 << 64) - 1))
+        return put(ones * np.uint64(value & (1 << 64) - 1))
 
     def bcast_l(value: int) -> jax.Array:
-        return jnp.asarray(unpack_np(ones * np.uint64(value & (1 << 64) - 1)))
+        return put(unpack_np(ones * np.uint64(value & (1 << 64) - 1)))
 
     gpr = np.tile(np.array(cpu.gpr_list(), dtype=np.uint64), (n_lanes, 1))
     xmm = np.zeros((n_lanes, 16, 4), dtype=np.uint64)
@@ -250,10 +265,10 @@ def machine_init(
             xmm[:, i, limb] = np.uint64(cpu.zmm[i][limb])
 
     return Machine(
-        gpr_l=jnp.asarray(unpack_np(gpr)),
+        gpr_l=put(unpack_np(gpr)),
         rip_l=bcast_l(cpu.rip),
         rflags_l=bcast_l(cpu.rflags | 0x2),
-        xmm_l=jnp.asarray(unpack_np(xmm).reshape(n_lanes, 16, 8)),
+        xmm_l=put(unpack_np(xmm).reshape(n_lanes, 16, 8)),
         fs_base_l=bcast_l(cpu.fs.base),
         gs_base_l=bcast_l(cpu.gs.base),
         kernel_gs_base=bcast(cpu.kernel_gs_base),
@@ -269,30 +284,31 @@ def machine_init(
         sfmask=bcast(cpu.sfmask),
         efer=bcast(cpu.efer),
         tsc=bcast(cpu.tsc),
-        fpst=jnp.asarray(np.tile(np.array(
+        fpst=put(np.tile(np.array(
             [_fpst_f64_bits(v) for v in cpu.fpst[:8]],
             dtype=np.uint64), (n_lanes, 1))),
         fpcw=bcast(cpu.fpcw),
         fpsw=bcast(cpu.fpsw),
         fptw=bcast(cpu.fptw),
         mxcsr=bcast(cpu.mxcsr),
-        status=jnp.full((n_lanes,), int(StatusCode.RUNNING), dtype=jnp.int32),
-        icount=jnp.zeros((n_lanes,), dtype=jnp.uint64),
-        rdrand=jnp.zeros((n_lanes,), dtype=jnp.uint64),
+        status=filled((n_lanes,), int(StatusCode.RUNNING), jnp.int32,
+                      sharding),
+        icount=zeros((n_lanes,), jnp.uint64),
+        rdrand=zeros((n_lanes,), jnp.uint64),
         cr3_base=bcast(cpu.cr3),
-        bp_skip=jnp.zeros((n_lanes,), dtype=jnp.int32),
-        fault_gva=jnp.zeros((n_lanes,), dtype=jnp.uint64),
-        fault_write=jnp.zeros((n_lanes,), dtype=jnp.int32),
-        ctr=jnp.zeros((n_lanes, N_CTRS), dtype=jnp.uint32),
-        cov=jnp.zeros((n_lanes, (uop_capacity + 31) // 32), dtype=jnp.uint32),
-        edge=jnp.zeros((n_lanes, (1 << edge_bits) // 32), dtype=jnp.uint32),
-        overlay=overlay_init(n_lanes, overlay_slots),
+        bp_skip=zeros((n_lanes,), jnp.int32),
+        fault_gva=zeros((n_lanes,), jnp.uint64),
+        fault_write=zeros((n_lanes,), jnp.int32),
+        ctr=zeros((n_lanes, N_CTRS), jnp.uint32),
+        cov=zeros((n_lanes, (uop_capacity + 31) // 32), jnp.uint32),
+        edge=zeros((n_lanes, (1 << edge_bits) // 32), jnp.uint32),
+        overlay=overlay_init(n_lanes, overlay_slots, sharding),
     )
 
 
 @jax.named_scope("restore")
-def _machine_restore_impl(machine: Machine,
-                          snapshot_template: Machine) -> Machine:
+def machine_restore_impl(machine: Machine,
+                         snapshot_template: Machine) -> Machine:
     return snapshot_template._replace(
         # Keep the overlay *storage* from the live machine so no new buffers
         # are allocated; overlay_reset rebuilds just the indexing state.
@@ -308,8 +324,8 @@ def _machine_restore_impl(machine: Machine,
 # pairing them with outputs: the zeroed planes (overlay.valid, cov, edge)
 # would then be NEW buffers beside the old ones instead of memsets in place
 _machine_restore_donated = partial(
-    jax.jit, donate_argnums=(0,), keep_unused=True)(_machine_restore_impl)
-_machine_restore_plain = jax.jit(_machine_restore_impl)
+    jax.jit, donate_argnums=(0,), keep_unused=True)(machine_restore_impl)
+_machine_restore_plain = jax.jit(machine_restore_impl)
 
 
 def machine_restore(machine: Machine, snapshot_template: Machine,

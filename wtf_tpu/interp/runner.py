@@ -224,7 +224,7 @@ class HostView:
         if row is None:
             return None
         self._count_row_pull(1)
-        data, valid = ovl.fetch_row(self.runner.machine.overlay, lane, row)
+        data, valid = self.runner.fetch_overlay_row(lane, row)
         return self._merge_row(lane, pfn, data, valid)
 
     def prefetch(self, pages) -> None:
@@ -737,16 +737,17 @@ class Runner:
         self.n_lanes = n_lanes
         self.cache = DecodeCache(capacity=uop_capacity)
         if tenants is None:
-            # the image operand executors dispatch against (a mesh runner
-            # re-points this at a replicated placement; host-side page
-            # reads keep going through self.physmem)
-            self.image = snapshot.physmem.image
+            # the image operand executors dispatch against, and the batch:
+            # both placed as they are made (a mesh runner's image on every
+            # chip, its lanes split over them); host-side page reads keep
+            # going through self.physmem
+            self.image = snapshot.physmem.upload(self.image_sharding())
             self.machine = machine_init(
                 snapshot.cpu, n_lanes, uop_capacity, overlay_slots,
-                edge_bits)
+                edge_bits, sharding=self.lane_sharding())
             self.template = machine_init(
                 snapshot.cpu, n_lanes, uop_capacity, overlay_slots=0,
-                edge_bits=edge_bits)
+                edge_bits=edge_bits, sharding=self.lane_sharding())
             self.tenant_of_lane = np.zeros(n_lanes, dtype=np.int32)
             self._physmems = [snapshot.physmem]
             self._cpu0s = [snapshot.cpu]
@@ -865,6 +866,24 @@ class Runner:
 
     def _deliver_lane(self, lane: int) -> bool:
         return self._cpu0s[self.tenant_of(lane)].idtr.limit > 0
+
+    # -- placement (None: the default device; MeshRunner names its mesh) ---
+    def lane_sharding(self):
+        """Where an array with a leading lane axis lies."""
+        return None
+
+    @property
+    def lane_groups(self) -> int:
+        """The parts the lane axis is held in, a device each."""
+        sharding = self.lane_sharding()
+        return 1 if sharding is None else sharding.num_devices
+
+    def image_sharding(self):
+        """Where the snapshot image lies."""
+        return None
+
+    def _put_lanes(self, host: np.ndarray) -> jax.Array:
+        return jax.device_put(host, self.lane_sharding())
 
     # -- device dispatch surface (the seams MeshRunner re-points) ----------
     def device_tab(self):
@@ -993,6 +1012,11 @@ class Runner:
         per shard)."""
         return ovl.read_lane_rows_jit
 
+    def fetch_overlay_row(self, lane: int, row: int):
+        """One overlay row of one lane: `HostView.page`'s device seam
+        outside a round -> (np.uint64[PAGE_WORDS], np.uint8[PAGE_WORDS])."""
+        return ovl.fetch_row(self.machine.overlay, lane, row)
+
     # -- mutate-on-device insert seam (wtf_tpu/devmut) ---------------------
     def device_insert(self, words, lens, pfns, gva: int,
                       len_gpr: int, ptr_gpr: int, active=None) -> None:
@@ -1011,8 +1035,8 @@ class Runner:
                 f"have only {capacity} overlay slots — raise "
                 f"overlay_slots or shrink the mutator/spec max_len")
         masked = active is not None
-        fn = _make_device_insert(n_pages, words.shape[1], len_gpr, ptr_gpr,
-                                 self._donate, masked=masked)
+        fn = self._inserter(n_pages, words.shape[1], len_gpr, ptr_gpr,
+                            masked)
         key = ("devins", n_pages, words.shape[1], len_gpr, ptr_gpr,
                self.n_lanes, self._donate, masked, self.exec_sig)
         if key not in _DISPATCHED_EXECUTORS:
@@ -1028,11 +1052,18 @@ class Runner:
             jnp.asarray(np.asarray(pfns, dtype=np.int32)),
             jnp.asarray(gva_l), *extra, sync=lambda m: m.status)
 
+    def _inserter(self, n_pages: int, n_words: int, len_gpr: int,
+                  ptr_gpr: int, masked: bool):
+        """The compiled `device_insert_impl` (the mesh runner's runs per
+        shard)."""
+        return _make_device_insert(n_pages, n_words, len_gpr, ptr_gpr,
+                                   self._donate, masked=masked)
+
     def push(self, view: HostView) -> None:
         """Apply a HostView's mutations (registers + buffered page writes +
         burst coverage bits) back to the device batch."""
         updates = {
-            _LIMB_FIELDS.get(name, name): jnp.asarray(
+            _LIMB_FIELDS.get(name, name): self._put_lanes(
                 _unpack_mirror(name, view.r[name])
                 if name in _LIMB_FIELDS else view.r[name])
             for name in _MIRROR_FIELDS
@@ -1059,26 +1090,43 @@ class Runner:
                 edge=_apply_bits(self.machine.edge, self._pending_edge))
             self._pending_edge.clear()
         if view.pending:
+            # dealt by lane group (one on a single device; a mesh's
+            # shards, each of which applies its own lanes' writes to the
+            # planes it holds): group g's writes fill row g of each array,
+            # their lanes counted from the group's first
             items = sorted(view.pending.items())
-            k = len(items)
+            keys = np.array([key for key, _ in items], dtype=np.int64)
+            per_group = self.n_lanes // self.lane_groups
+            group = keys[:, 0] // per_group
+            first = np.searchsorted(group, np.arange(self.lane_groups + 1))
+            at = (group, np.arange(len(items)) - first[group])
             bucket = 8
-            while bucket < k:
+            while bucket < np.diff(first).max():
                 bucket *= 2
-            lanes = np.zeros(bucket, dtype=np.int32)
-            pfns = np.full(bucket, -2, dtype=np.int32)
-            pages = np.zeros((bucket, PAGE_SIZE), dtype=np.uint8)
-            valid = np.zeros(bucket, dtype=bool)
-            for j, ((lane, pfn), page) in enumerate(items):
-                lanes[j] = lane
-                pfns[j] = pfn
-                pages[j] = np.frombuffer(bytes(page), dtype=np.uint8)
-                valid[j] = True
-            apply_writes = (_apply_page_writes_donated if self._donate
-                            else _apply_page_writes_plain)
-            self.machine = apply_writes(
-                self.machine, jnp.asarray(lanes), jnp.asarray(pfns),
-                jnp.asarray(pages.view(np.uint64)), jnp.asarray(valid))
+            shape = (self.lane_groups, bucket)
+            lanes = np.zeros(shape, dtype=np.int32)
+            pfns = np.full(shape, -2, dtype=np.int32)
+            pages = np.zeros(shape + (PAGE_SIZE,), dtype=np.uint8)
+            valid = np.zeros(shape, dtype=bool)
+            lanes[at] = keys[:, 0] - group * per_group
+            pfns[at] = keys[:, 1]
+            pages[at] = np.frombuffer(
+                b"".join(page for _, page in items),
+                dtype=np.uint8).reshape(len(items), PAGE_SIZE)
+            valid[at] = True
+            flat = self.lane_groups * bucket
+            self.machine = self._page_writer()(
+                self.machine, self._put_lanes(lanes.reshape(flat)),
+                self._put_lanes(pfns.reshape(flat)),
+                self._put_lanes(pages.view(np.uint64).reshape(flat, -1)),
+                self._put_lanes(valid.reshape(flat)))
             view.pending.clear()
+
+    def _page_writer(self):
+        """The compiled `_apply_page_writes` (the mesh runner's runs per
+        shard)."""
+        return (_apply_page_writes_donated if self._donate
+                else _apply_page_writes_plain)
 
     # -- servicing ---------------------------------------------------------
     @staticmethod
@@ -1736,8 +1784,7 @@ class Runner:
         broadcast (replaces the reference's dirty-page rewrite loops,
         SURVEY.md §5.4)."""
         with self.registry.spans.span("overlay-restore") as sp:
-            self.machine = machine_restore(self.machine, self.template,
-                                           donate=self._donate)
+            self.machine = self._restorer()(self.machine, self.template)
             sp.fence(self.machine.status)
         self.lane_errors.clear()
         self._pending_cov.clear()
@@ -1746,6 +1793,11 @@ class Runner:
         # times within ONE run falls back to the oracle, but the count must
         # not accumulate across the campaign (fresh-run behavior parity)
         self._smc_updates.clear()
+
+    def _restorer(self):
+        """The compiled `machine_restore` (the mesh runner's runs per
+        shard)."""
+        return partial(machine_restore, donate=self._donate)
 
     def statuses(self) -> np.ndarray:
         # copy, not a view — see the donation note in run()

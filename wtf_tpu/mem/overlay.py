@@ -52,6 +52,7 @@ the caller applies ONE flat scatter per plane for all lanes with
 
 from __future__ import annotations
 
+from functools import lru_cache
 from typing import NamedTuple, Tuple
 
 import jax
@@ -116,14 +117,32 @@ class Tlb(NamedTuple):
     cr3: jax.Array       # uint64[lanes]: the CR3 the entries were walked under
 
 
-def tlb_init(n_lanes: int) -> Tlb:
+@lru_cache(maxsize=None)
+def _filler(shape: tuple, value: int, dtype, sharding):
+    return jax.jit(lambda: jnp.full(shape, value, dtype),
+                   out_shardings=sharding)
+
+
+def filled(shape: tuple, value: int, dtype, sharding=None) -> jax.Array:
+    """`jnp.full` that every device of `sharding` runs for its own part
+    (None: the default device).  A mesh's machine is born so, leaf by
+    leaf: at 16,384 lanes the data plane alone is 8.6 GB, and an array
+    made on one device and sharded afterwards would be whole on it first
+    (`interp/machine.py` `machine_init`).  An empty array (a template's
+    planes) has no part to allocate, and XLA would call it replicated."""
+    if 0 in shape:
+        return jax.device_put(np.full(shape, value, dtype), sharding)
+    return _filler(tuple(shape), value, jnp.dtype(dtype), sharding)()
+
+
+def tlb_init(n_lanes: int, sharding=None) -> Tlb:
     e = TLB_ENTRIES
-    return Tlb(vpn=jnp.zeros((n_lanes, e), jnp.uint64),
-               page=jnp.zeros((n_lanes, e), jnp.uint64),
-               flags=jnp.zeros((n_lanes, e), jnp.int32),
-               frames=jnp.zeros((n_lanes, e, 4), jnp.int32),
-               next=jnp.zeros((n_lanes,), jnp.int32),
-               cr3=jnp.zeros((n_lanes,), jnp.uint64))
+    return Tlb(vpn=filled((n_lanes, e), 0, jnp.uint64, sharding),
+               page=filled((n_lanes, e), 0, jnp.uint64, sharding),
+               flags=filled((n_lanes, e), 0, jnp.int32, sharding),
+               frames=filled((n_lanes, e, 4), 0, jnp.int32, sharding),
+               next=filled((n_lanes,), 0, jnp.int32, sharding),
+               cr3=filled((n_lanes,), 0, jnp.uint64, sharding))
 
 
 def tlb_drop(tlb: Tlb, pfns: jax.Array, enabled: jax.Array) -> Tlb:
@@ -185,17 +204,22 @@ class StoreCommit(NamedTuple):
     valid: jax.Array     # uint8[K]
 
 
-def overlay_init(n_lanes: int, capacity: int) -> DirtyOverlay:
-    """Allocate the batched overlay store for `n_lanes` lanes."""
+def overlay_init(n_lanes: int, capacity: int,
+                 sharding=None) -> DirtyOverlay:
+    """Allocate the batched overlay store for `n_lanes` lanes; under a
+    mesh's lane `sharding` each device allocates its own lanes' part of
+    every leaf, and a plane's indices are local to that part (`lanes`)."""
     n_words = n_lanes * capacity * PAGE_WORDS
-    assert 2 * n_words < 1 << 31, "overlay plane indices are int32"
+    part = (2 * n_words if sharding is None
+            else sharding.shard_shape((2 * n_words,))[0])
+    assert part < 1 << 31, "overlay plane indices are int32"
     return DirtyOverlay(
-        pfn=jnp.full((n_lanes, capacity), -1, dtype=jnp.int32),
-        data=jnp.zeros((2 * n_words,), dtype=jnp.uint32),
-        valid=jnp.zeros((n_words,), dtype=jnp.uint8),
-        count=jnp.zeros((n_lanes,), dtype=jnp.int32),
-        overflow=jnp.zeros((n_lanes,), dtype=bool),
-        tlb=tlb_init(n_lanes),
+        pfn=filled((n_lanes, capacity), -1, jnp.int32, sharding),
+        data=filled((2 * n_words,), 0, jnp.uint32, sharding),
+        valid=filled((n_words,), 0, jnp.uint8, sharding),
+        count=filled((n_lanes,), 0, jnp.int32, sharding),
+        overflow=filled((n_lanes,), 0, bool, sharding),
+        tlb=tlb_init(n_lanes, sharding),
     )
 
 

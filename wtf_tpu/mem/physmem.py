@@ -93,6 +93,14 @@ def alloc_rows(n_present: int) -> np.ndarray:
     return np.frombuffer(buf, dtype=np.uint64).reshape(n_rows, PAGE_WORDS)
 
 
+def _lies_under(array: jax.Array, sharding) -> bool:
+    """Is `array` placed as `jax.device_put(..., sharding)` would place it
+    (None: on one device)?"""
+    if sharding is None:
+        return len(array.sharding.device_set) == 1
+    return array.sharding.is_equivalent_to(sharding, array.ndim)
+
+
 class PhysMem:
     """Host-side container of the packed image.
 
@@ -182,12 +190,29 @@ class PhysMem:
     # -- the two halves ---------------------------------------------------
     @property
     def image(self) -> MemImage:
-        """The device image, uploaded on first use."""
-        if self._image is None:
+        """The device image as it is held, uploaded on first use."""
+        return self._image if self._image is not None else self.upload()
+
+    def upload(self, sharding=None) -> MemImage:
+        """The device image under `sharding` (None: the default device):
+        placed from the host rows on first use, and again only when what
+        is held lies elsewhere.  A mesh runner passes its replicated
+        sharding, so every chip's copy comes from the host once and no
+        chip holds a second one; `image` is then that array."""
+        if self._image is None or not _lies_under(self._image.pages,
+                                                  sharding):
+            rows, table = self._host()
             self._image = MemImage(
-                pages=jnp.asarray(self._rows.view(np.uint32)),
-                frame_table=jnp.asarray(self._table[None, :]))  # [1, span]
+                pages=jax.device_put(rows.view(np.uint32), sharding),
+                frame_table=jax.device_put(table[None, :],   # [1, span]
+                                           sharding))
         return self._image
+
+    @property
+    def replicas(self) -> int:
+        """How many devices hold the image (0: not uploaded)."""
+        return (0 if self._image is None
+                else len(self._image.pages.sharding.device_set))
 
     @property
     def device_pulls(self) -> int:

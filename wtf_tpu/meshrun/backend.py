@@ -14,9 +14,11 @@ Deltas against the plain TpuBackend, all behind existing seams:
     replicated on every chip — per-batch interconnect bytes are the
     [shards, words] union gather, nothing else;
   * `mesh.devices` / `mesh.lanes_per_shard` gauges join the telemetry
-    registry, and the per-shard `device.shard_instructions` counters
-    (MeshRunner.fold_device_counters) feed tools/telemetry_report.py's
-    mesh section.
+    registry, the merge has a span of its own
+    (`execute/cov-readback/mesh-merge`), and the per-shard
+    `device.shard_instructions` / `device.shard_lane_steps` counters and
+    `mesh.wait_lane_steps` (MeshRunner.fold_device_counters) feed
+    tools/telemetry_report.py's mesh section.
 """
 
 from __future__ import annotations
@@ -57,7 +59,17 @@ class MeshBackend(TpuBackend):
             jnp.zeros(m.cov.shape[1:], m.cov.dtype), rep)
         self._agg_edge = jax.device_put(
             jnp.zeros(m.edge.shape[1:], m.edge.dtype), rep)
-        self._merge = make_mesh_merge(self.mesh)
+        merge = make_mesh_merge(self.mesh)
+
+        def spanned_merge(*operands):
+            # a child of the cov-readback span it runs in, fenced on its
+            # outputs: the all-gather and the prefix credit by themselves
+            with self.registry.spans.span("mesh-merge") as sp:
+                out = merge(*operands)
+                sp.fence(out)
+            return out
+
+        self._merge = spanned_merge
         self.registry.gauge("mesh.devices").set(self.mesh.size)
         self.registry.gauge("mesh.lanes_per_shard").set(
             self.n_lanes // self.mesh.size)

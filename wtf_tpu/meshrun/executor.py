@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from wtf_tpu.interp.machine import CTR_SWEPT
 from wtf_tpu.interp.step import make_run_chunk
 from wtf_tpu.mem.physmem import MemImage
 from wtf_tpu.meshrun.mesh import LANE_AXIS
@@ -41,24 +42,42 @@ _MESH_CHUNK_CACHE: dict = {}
 def _chunk_with_coverage(body):
     """Wrap a machine->machine chunk body so the program also emits the
     cross-shard merged cov/edge bitmaps (shard-local OR, then one
-    boolean all-reduce over the concatenated planes)."""
+    boolean all-reduce over the concatenated planes) and, riding the same
+    all-reduce, the lane-steps every shard took in this chunk: what the
+    shards that ended early waited for at the all-reduce is the busiest
+    shard's count less their own (`MeshRunner.fold_device_counters`)."""
 
     def local(tab, image, machine, limit):
+        swept = machine.ctr[:, CTR_SWEPT]
         m = body(tab, image, machine, limit)
-        loc = jnp.bitwise_or.reduce(
-            jnp.concatenate([m.cov, m.edge], axis=1), axis=0)
-        merged = bitplane_or(loc, LANE_AXIS)
+        with jax.named_scope("cov-allreduce"):
+            loc = jnp.bitwise_or.reduce(
+                jnp.concatenate([m.cov, m.edge], axis=1), axis=0)
+            merged, shard_steps = bitplane_or(
+                loc, LANE_AXIS, mine=jnp.sum(m.ctr[:, CTR_SWEPT] - swept))
         wc = m.cov.shape[1]
-        return m, merged[:wc], merged[wc:]
+        return m, merged[:wc], merged[wc:], shard_steps
 
     return local
+
+
+def per_shard(fn, mesh, in_specs, out_specs, **jit_kwargs):
+    """`fn` compiled to run on every shard's part of its operands, with
+    no collective: the programs that index a lane's own state (the
+    restore, the host's page pushes and row reads, the device insert)
+    take machine leaves split on the lane axis, `P(LANE_AXIS)`, whose
+    plane indices are local to the part in hand (`overlay.lanes`)."""
+    return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False),
+                   **jit_kwargs)
 
 
 def make_mesh_chunk(n_steps: int, mesh, donate: Optional[bool] = None,
                     jit: bool = True):
     """Build (or fetch) the mesh chunk executor:
-    (tab, image, machine, limit) -> (machine', merged_cov, merged_edge)
-    with tab/image replicated, machine lane-sharded, merged replicated.
+    (tab, image, machine, limit) ->
+    (machine', merged_cov, merged_edge, shard_lane_steps)
+    with tab/image replicated, machine lane-sharded, the rest replicated.
 
     Same memoization/donation policy as step.make_run_chunk (donation is
     unsound on the XLA CPU backend — see that docstring).  jit=False
@@ -75,7 +94,7 @@ def make_mesh_chunk(n_steps: int, mesh, donate: Optional[bool] = None,
     fn = shard_map(
         _chunk_with_coverage(body), mesh=mesh,
         in_specs=(P(), IMAGE_SPEC, P(LANE_AXIS), P()),
-        out_specs=(P(LANE_AXIS), P(), P()),
+        out_specs=(P(LANE_AXIS), P(), P(), P()),
         check_vma=False)
     if not jit:
         return fn

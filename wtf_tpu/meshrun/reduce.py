@@ -75,14 +75,25 @@ def merged_coverage(machine, groups: Optional[int] = None):
             or_reduce_lanes(machine.edge, groups))
 
 
-def bitplane_or(words, axis_name: str):
+def bitplane_or(words, axis_name: str, mine):
     """Cross-shard bitwise OR of a [W] u32 bitmap via the boolean
     bit-plane all-reduce: expand to the [W, 32] 0/1 plane, pmax across
-    the named axis (max of 0/1 == OR), repack.  ONE collective."""
+    the named axis (max of 0/1 == OR), repack.  ONE collective.
+
+    `mine` (a u32 scalar) rides that collective: every shard puts its own
+    into its slot of a zeroed row of the plane (a row for every 32
+    shards), and the maximum of the slots is every shard's number, by
+    shard.  Returns the merged bitmap and the u32 vector of the shards'
+    numbers (zeros past the last shard)."""
     shifts = jnp.arange(32, dtype=jnp.uint32)
     bits = (words[:, None] >> shifts) & jnp.uint32(1)     # [W, 32]
-    merged = lax.pmax(bits, axis_name)
-    return jnp.sum(merged << shifts, axis=-1, dtype=jnp.uint32)
+    rows = -(-lax.axis_size(axis_name) // 32)
+    slots = jnp.arange(rows * 32, dtype=jnp.int32).reshape(rows, 32)
+    own = jnp.where(slots == lax.axis_index(axis_name),
+                    mine.astype(jnp.uint32), jnp.uint32(0))
+    merged = lax.pmax(jnp.concatenate([bits, own]), axis_name)
+    return (jnp.sum(merged[:-rows] << shifts, axis=-1, dtype=jnp.uint32),
+            merged[-rows:].reshape(-1))
 
 
 def _prefix_scan(x_in, prev):

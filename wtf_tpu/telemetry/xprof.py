@@ -46,7 +46,7 @@ STEP_SCOPES = (               # step_lane and below: once a lane-step
     "iret", "flags-status", "counters", "coverage")
 OTHER_SCOPES = (              # the other device programs
     "device-insert", "restore", "run-quiesce", "decode-service",
-    "cov-merge", "devmut-generate")
+    "cov-merge", "cov-allreduce", "devmut-generate")
 SCOPES = CHUNK_SCOPES + STEP_SCOPES + OTHER_SCOPES
 # the operations a sweep is priced by (PERF.md section 5), and the sort
 INDEXED_OPS = ("gather", "scatter", "dynamic-slice",
