@@ -128,9 +128,15 @@ def test_budget_rule_fires_on_improvement_too():
 
 def test_checked_in_budget_matches_perf_record():
     """analysis/budgets.json pins the step ladder at what PERF.md
-    records (CPU compile of the installed XLA): 89 surviving
+    records (CPU compile of the installed XLA): 91 surviving
     data-dependent ops, 68 gathers / no dynamic-slice / 13
-    dynamic-update-slices / 8 scatters.  PR 41 took 24 gathers and 4
+    dynamic-update-slices / 10 scatters.  PR 44 added two scatters, the
+    taken side of the `store-spill` conditional (the store's third word,
+    one a plane): the census counts operations, and the indices every
+    pass pays FELL (the entry lines; tests/test_chip_compile.py counts
+    them: 21 -> 16 gathered, 9 -> 6 scattered by the commit; here the
+    CPU's compile keeps the two words' reads in the gathers that read
+    three).  PR 41 took 24 gathers and 4
     scatters out with the vector unit's (117: 92/0/13/12 before): the XMM
     file, the x87 stack and the byte class's shuffles are selects over
     their rows and their sixteen bytes.  PR 39 took the three
@@ -147,12 +153,12 @@ def test_checked_in_budget_matches_perf_record():
     the same step under an older XLA, which kept as slices what this one
     lowers to scatters or folds away."""
     budget = load_budgets()["xla_step"]
-    assert budget["total"] == 89
+    assert budget["total"] == 91
     assert (budget["gather"], budget["dynamic-slice"],
             budget["dynamic-update-slice"], budget["scatter"]) == (
-        68, 0, 13, 8)
+        68, 0, 13, 10)
     # the tenant ladder is the SAME program over a stacked image table
-    assert load_budgets()["tenant_chunk"]["total"] == 89
+    assert load_budgets()["tenant_chunk"]["total"] == 91
     # the in-graph decode service compiles as its own pinned graph
     # (round 18) so decoder growth is a lint finding, not silent fusion
     assert load_budgets()["decode_service"]["total"] == 308

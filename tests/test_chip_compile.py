@@ -101,6 +101,8 @@ _HLO_INSTRUCTION = re.compile(
     r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(")
 _HLO_ARRAY = re.compile(r"\b[a-z]+\d+\[([\d,]*)\]")
 _HLO_CALLEE = re.compile(r"(?:body|condition|calls|to_apply)=%?([\w.\-]+)")
+_HLO_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_HLO_OPERANDS = re.compile(r"%([\w.\-]+)")
 
 
 def _computations(hlo_text):
@@ -120,6 +122,13 @@ def _computations(hlo_text):
     return comps, entry
 
 
+def _branches(line):
+    """The computations a conditional's line names as its sides."""
+    return [name.strip().lstrip("%")
+            for sides in _HLO_BRANCHES.findall(line)
+            for name in sides.split(",")]
+
+
 def _reached(comps, root):
     """Lines of every computation `root` reaches (conditions, nested
     loops, fusions and so their roots), itself included."""
@@ -130,7 +139,7 @@ def _reached(comps, root):
             continue
         seen.add(comp)
         for line in comps[comp]:
-            todo += _HLO_CALLEE.findall(line)
+            todo += _HLO_CALLEE.findall(line) + _branches(line)
             yield line
 
 
@@ -243,6 +252,87 @@ def test_chunk_program_compiles_for_v5e(chunk_program):
     machine_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(operands[2]))
     assert mem.alias_size_in_bytes >= machine_bytes, mem
     assert mem.temp_size_in_bytes < ov.data.nbytes, mem
+
+
+def indices_by_scope(hlo_text, within):
+    """{scope: [gathered, scattered]}: the index vectors of every gather
+    and scatter whose `op_name` holds `within`, by the innermost scope of
+    THE list (`telemetry.xprof.scope_of`).  An operation's indices are
+    its second operand, less the dimension that holds one index's
+    coordinates: what a sweep is priced by (PERF.md section 5)."""
+    from wtf_tpu.telemetry.xprof import scope_of
+
+    counts, shapes = {}, {}
+    for line in hlo_text.splitlines():
+        if _HLO_COMPUTATION.match(line):
+            shapes = {}
+        ins = _HLO_INSTRUCTION.match(line)
+        if not ins:
+            continue
+        name = line.split("=")[0].replace("ROOT", "").strip().lstrip("%")
+        dims = _HLO_ARRAY.findall(ins.group(1))
+        if dims:
+            shapes[name] = [int(d) for d in dims[0].split(",") if d]
+        if ins.group(2) not in ("gather", "scatter"):
+            continue
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        if within not in op_name:
+            continue
+        operands = _HLO_OPERANDS.findall(line[ins.end():].split(")")[0])
+        index = shapes[operands[1]]
+        vector_dim = int(re.search(r"index_vector_dim=(\d+)", line).group(1))
+        n = int(np.prod(index)) // (
+            index[vector_dim] if vector_dim < len(index) else 1)
+        row = counts.setdefault(scope_of(op_name), [0, 0])
+        row[ins.group(2) == "scatter"] += n
+    return counts
+
+
+def test_the_store_commits_two_words_a_pass_and_spills_under_one_conditional(
+        chunk_program):
+    """PR 44: the per-pass store reads, merges and commits TWO words; the
+    third word of its 16-byte window stands under the sweep's ONE
+    conditional (`store-spill`).  By the chip's compile, a lane-step's
+    `overlay-store` gathers 16 indices where it gathered 21 (a word is
+    five: its valid byte, the overlay word's two limbs, the image word's
+    two; the frame table's two and the row claims' four stay) and
+    scatters 8 where it scattered 11 (the commit's 6 where 9 were, the
+    row claims' two), and `store-spill` holds the third word's 5 and 3
+    on its taken side alone.  The conditional copies no plane: its other
+    side is the planes it was handed and nothing else, neither side nor
+    the loop around them holds a copy or relayout as large as a plane
+    (`slab_relayouts` follows a conditional's sides), so both alias the
+    planes as the sweep's loops do."""
+    from wtf_tpu.interp import step
+
+    compiled, operands = chunk_program
+    text = compiled.as_text()
+    width = step.tail_width(LANES)
+    sweep = "while/body/while/body"         # levels / sweeps / groups
+    counts = indices_by_scope(text, sweep)
+    assert counts["overlay-store"] == [16 * width, 8 * width], counts
+    assert counts["store-spill"] == [5 * width, 3 * width], counts
+    # what EVERY pass indexes: the parent's 149 gathered and 15 scattered
+    # less the third word's
+    every_pass = [sum(row[k] for scope, row in counts.items()
+                      if scope != "store-spill") for k in (0, 1)]
+    assert every_pass == [144 * width, 12 * width], counts
+
+    comps, _ = entry_loop_bodies(text)
+    conditionals = [line for lines in comps.values() for line in lines
+                    if " conditional(" in line]
+    assert len(conditionals) == 1 and "store-spill" in conditionals[0]
+    sides = [comps[name] for name in _branches(conditionals[0])]
+    assert len(sides) == 2
+    handed_on = [side for side in sides if all(
+        _HLO_INSTRUCTION.match(line).group(2)
+        in ("parameter", "get-tuple-element", "tuple")
+        for line in side if _HLO_INSTRUCTION.match(line))]
+    assert len(handed_on) == 1, "one side hands the planes on"
+    spilled = "\n".join(line for side in sides for line in side)
+    assert "branch_1_fun/scatter" in spilled
+    plane_words = operands[2].overlay.valid.size
+    assert not slab_relayouts(text, plane_words)
 
 
 def test_the_vector_unit_indexes_nothing_on_the_chip(chunk_program):

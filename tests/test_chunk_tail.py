@@ -242,7 +242,8 @@ def _run_cold(name):
         "device.instructions", "device.lane_steps", "device.tail_lane_steps",
         "device.lockstep_floor_steps", "device.decode_misses",
         "device.mem_faults", "runner.chunks", "device.levels_used",
-        "device.store_steps", "device.vector_steps")}
+        "device.store_steps", "device.vector_steps",
+        "device.store3_steps")}
     _COLD[key] = {"machine": _host(backend.runner.machine),
                   "counters": counters,
                   "results": [str(r) for r in results]}
@@ -269,6 +270,9 @@ def test_runner_batch_resumed_after_servicing_equals_head_only(
     # the fold hands `device.levels_used` out per lane, as it does sweeps:
     # a chunk's levels that swept, at least one a chunk and more than one
     # in a chunk that narrowed; full-width chunks have no levels
+    # neither target stores more than eight bytes at once
+    assert plain["counters"]["device.store3_steps"] \
+        == tail["counters"]["device.store3_steps"] == 0
     assert plain["counters"]["device.levels_used"] == 0
     assert tail["counters"]["device.levels_used"] == \
         tail["machine"][".ctr"][:, CTR_LEVELS].sum() / LANES

@@ -692,6 +692,8 @@ def test_equal_seeds_give_equal_campaigns_from_the_targets_seeds(tmp_path):
     assert last["runner.exceptions_undelivered"] == 0
     assert last["runner.fallbacks"] == 0
     assert last["phase.seconds"]["execute/service-exception"] > 0
+    # pushes, an IRP's fields, trap frames: no store wider than 8 bytes
+    assert last["device.store3_steps"] == 0 < last["device.store_steps"]
 
 
 def test_a_file_in_inputs_wins_over_the_targets_seeds(tmp_path):

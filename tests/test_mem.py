@@ -8,6 +8,7 @@ import pytest
 from wtf_tpu.core.gxa import PAGE_SIZE
 from wtf_tpu.mem.overlay import (
     LANE_AXES,
+    commit_spills,
     commit_stores,
     lane,
     lanes,
@@ -429,15 +430,16 @@ def _store_fns(image):
 
     @jax.jit
     def store_lanes(ov, *args):
-        view, ok, commit = jax.vmap(
+        view, ok, commit, spill = jax.vmap(
             store_one, in_axes=(LANE_AXES,) + (0,) * 6,
-            out_axes=(LANE_AXES, 0, 0))(lanes(ov), *args)
-        return commit_stores(resident(view), commit), ok
+            out_axes=(LANE_AXES, 0, 0, 0))(lanes(ov), *args)
+        return commit_spills(
+            image, commit_stores(resident(view), commit), spill), ok
 
     @jax.jit
     def store_jit(ov, *args):
-        one, ok, commit = store_one(ov, *args)
-        return commit_stores(one, commit), ok
+        one, ok, commit, spill = store_one(ov, *args)
+        return commit_spills(image, commit_stores(one, commit), spill), ok
 
     def store_each(ov, *args):
         oks = []
@@ -593,3 +595,161 @@ def test_store_window3_matches_emu_memory(store_mem, store_fns, case):
             back = (int(lo[i]).to_bytes(8, "little")
                     + int(hi[i]).to_bytes(8, "little"))
             assert back[:size] == val[:size], lanes[i]
+
+
+# ---------------------------------------------------------------------------
+# the store's third word (PR 44): `store_window3` commits two words a pass
+# and hands the third to `commit_spills`, which touches the planes only
+# when a lane in hand spills.  Against a byte-wise reference.
+# ---------------------------------------------------------------------------
+
+_STATES = ("valid", "claimed", "fresh")
+
+
+def _spill_span(placement, off, size):
+    """A store of `size` bytes whose first byte has `gpa & 15 == off`:
+    inside page A, or over its last sixteen bytes into A + 1 ("end") or
+    into the far frame ("far")."""
+    if placement == "mid":
+        first = _PFN_A * PAGE_SIZE + 0x100 + off
+        return first, first + size - 1
+    first = (_PFN_A + 1) * PAGE_SIZE - 16 + off
+    over = off + size - 16             # bytes past the end of page A
+    if over <= 0 or placement == "end":
+        return first, first + size - 1
+    return first, _PFN_FAR * PAGE_SIZE + over - 1
+
+
+def _byte_gpas(first, last, size):
+    """The physical address of every byte of a span, one by one."""
+    head = min(size, PAGE_SIZE - (first & (PAGE_SIZE - 1)))
+    return ([first + i for i in range(head)]
+            + [last - (size - 1 - i) for i in range(head, size)])
+
+
+def _window_words(first, last, size):
+    """(gpa, gpa + 7) of the three aligned words of a store's window, the
+    second page's taken where the store's own bytes lie."""
+    gpas = _byte_gpas(first, last, size)
+    next_page = ((last if last >> 12 != first >> 12 else first + PAGE_SIZE)
+                 & ~(PAGE_SIZE - 1))
+    words = []
+    for k in range(3):
+        at = (first & ~7) + 8 * k
+        if at >> 12 != first >> 12:
+            at = next_page + (at & (PAGE_SIZE - 1))
+        words.append((at, at + 7))
+    assert all(any(w[0] <= g <= w[1] for w in words) for g in gpas)
+    return words
+
+
+class _ByteRef:
+    """Guest memory byte by byte: the base pages, and what was stored."""
+
+    def __init__(self, physmem):
+        from wtf_tpu.cpu.emu import EmuMem
+
+        base = EmuMem(physmem)
+        self.base = {pfn: base.phys_read(pfn * PAGE_SIZE, PAGE_SIZE)
+                     for pfn in (_PFN_A, _PFN_NEXT, _PFN_FAR, _PFN_B)}
+
+    def lane(self):
+        return {}
+
+    def store(self, mem, first, last, data):
+        for gpa, byte in zip(_byte_gpas(first, last, len(data)), data):
+            mem[gpa] = byte
+
+    def read(self, mem, gpa, size):
+        return bytes(
+            mem.get(g, self.base[g >> 12][g & (PAGE_SIZE - 1)])
+            for g in range(gpa, gpa + size))
+
+
+@pytest.fixture(scope="module")
+def byte_ref(store_mem):
+    return _ByteRef(store_mem)
+
+
+@pytest.fixture(scope="module")
+def spill_flags(store_mem):
+    """`spill` of every lane's `StoreSpill`, by `store_window3` alone."""
+    from wtf_tpu.mem.overlay import store_window3
+
+    @jax.jit
+    def flags(ov, first, last, size, lo, hi, en):
+        def one(ov, first, last, size, lo, hi, en):
+            return store_window3(store_mem.image, ov, _T(first), _T(last),
+                                 size, lo, hi, en)[3].spill
+
+        return jax.vmap(one, in_axes=(LANE_AXES,) + (0,) * 6)(
+            lanes(ov), first, last, size, lo, hi, en)
+
+    return flags
+
+
+@pytest.mark.parametrize("size", range(1, 17))
+def test_store_spill_matches_bytewise_reference(store_mem, store_fns,
+                                                byte_ref, spill_flags, size):
+    """Sizes 1-16 at every `gpa & 15`, inside a page and over a page's end
+    into the next frame and into a far one, into words that are valid
+    already, into a claimed row's words that are not, and into rows this
+    store claims: what memory reads afterwards is what a byte-wise store
+    leaves, a lane spills exactly when `(gpa & 7) + size > 16`, and a
+    batch in which no lane does (every size up to 9) takes the
+    conditional's other side."""
+    store_lanes, _, probe, _ = store_fns
+    rng = np.random.default_rng(4400 + size)
+    cases = [(p, off, state) for p in _PLACEMENTS for off in range(16)
+             for state in _STATES]
+    spans = [_spill_span(p, off, size) for p, off, _ in cases]
+    mems = [byte_ref.lane() for _ in cases]
+    ov = overlay_init(len(cases), 4)
+
+    def store_all(stores):
+        """One batched store: stores[i] = (first, last, bytes) or None."""
+        nonlocal ov
+        live = [s is not None for s in stores]
+        stores = [s or (spans[0][0], spans[0][0], b"\0") for s in stores]
+        args = _args([(f, l) for f, l, _ in stores],
+                     [len(d) for _, _, d in stores],
+                     [d + bytes(16 - len(d)) for _, _, d in stores], live)
+        ov, ok = store_lanes(ov, *args)
+        assert bool(np.all(np.asarray(ok)[np.asarray(live)]))
+        for mem, (f, l, d), on in zip(mems, stores, live):
+            if on:
+                byte_ref.store(mem, f, l, d)
+        return args
+
+    # the states: every word of the window stored whole beforehand; or one
+    # byte elsewhere in each page, so that the rows are claimed and the
+    # window's words are not valid; or nothing
+    for k in range(3):
+        store_all([
+            (*_window_words(*span, size)[k],
+             bytes(rng.integers(0, 256, 8, dtype=np.uint8)))
+            if state == "valid" else None
+            for span, (_, _, state) in zip(spans, cases)])
+    for which in (0, 1):
+        store_all([
+            ((span[which] & ~(PAGE_SIZE - 1)) + 0x800,) * 2 + (b"\xa5",)
+            if state == "claimed" else None
+            for span, (_, _, state) in zip(spans, cases)])
+
+    values = [bytes(rng.integers(0, 256, size, dtype=np.uint8))
+              for _ in cases]
+    the_store = [(f, l, v) for (f, l), v in zip(spans, values)]
+    before = np.asarray(ov.count)
+    args = store_all(the_store)
+    claimed = np.asarray(ov.count) - before
+    spilled = np.asarray(spill_flags(ov, *args))
+    got = np.asarray(probe(ov))
+    for i, ((placement, off, state), (first, last)) in enumerate(
+            zip(cases, spans)):
+        assert bool(spilled[i]) == ((first & 7) + size > 16), cases[i]
+        pages = 1 + (last >> 12 != first >> 12)
+        assert claimed[i] == (pages if state == "fresh" else 0), cases[i]
+        for j, at in enumerate(_PROBES):
+            assert bytes(got[i, j]) == byte_ref.read(
+                mems[i], at, _PROBE_LEN), (cases[i], hex(at))
+    assert bool(spilled.any()) == (size > 9)

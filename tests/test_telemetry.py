@@ -1375,14 +1375,16 @@ def test_lowered_chunk_names_every_stage_of_the_step(tpu_backend):
     lowered = step_executor_lowering(tpu_backend.runner, n_steps=16,
                                      donate=False)
     text = lowered.as_text(debug_info=True)
-    missing = [s for s in STEP_SCOPES if f"({s})" not in text]
+    # under the lanes' vmap, or over the lanes in hand (`store-spill`)
+    missing = [s for s in STEP_SCOPES
+               if f"({s})" not in text and f"/{s}/" not in text]
     assert not missing, missing
 
 
 @pytest.mark.parametrize("entry,total", [
-    ("xla_step", 89), ("tenant_chunk", 89),
-    ("megachunk_window", 131), ("decode_service", 308),
-    ("mesh_chunk", 1), ("tail_chunk", 525),
+    ("xla_step", 91), ("tenant_chunk", 91),
+    ("megachunk_window", 133), ("decode_service", 308),
+    ("mesh_chunk", 1), ("tail_chunk", 527),
 ])
 def test_pinned_kernel_counts_did_not_move(entry, total):
     """analysis/budgets.json as PR 26 re-pinned it with the overlay
@@ -1406,7 +1408,12 @@ def test_pinned_kernel_counts_did_not_move(entry, total):
     account is one elementwise add over the counter block where
     `CTR_TAIL` took a scatter (`tail_chunk` 556 -> 555).  PR 41 took the
     vector unit's indexed operations out (rows and bytes by selects): 117
-    -> 89 a step body, the window 160 -> 131, `tail_chunk` 555 -> 525."""
+    -> 89 a step body, the window 160 -> 131, `tail_chunk` 555 -> 525.
+    PR 44 gave the store's third word a conditional of its own: the
+    census counts OPERATIONS, and the taken side's two scatters are new
+    ones (89 -> 91, 131 -> 133, 525 -> 527), while the indices every
+    pass pays fell (the entry lines say so; tests/test_chip_compile.py
+    counts them on the chip's compile)."""
     import wtf_tpu.analysis as analysis
 
     budgets = json.loads(

@@ -255,6 +255,8 @@ def test_campaign_runs_three_batches_on_the_batch_loop(campaign):
     assert metrics["backend.batches"] == 3
     assert metrics["campaign.testcases"] == 3 * LANES
     assert "megachunk.windows" not in metrics
+    # a parser's integer stores: none reaches its window's third word
+    assert metrics["device.store3_steps"] == 0 < metrics["device.store_steps"]
     assert _files(campaign["target"] / "crashes")
     assert _files(campaign["target"] / "outputs")
     for beat in campaign["heartbeats"]:

@@ -117,6 +117,132 @@ def test_shufps_picks_by_immediate(imm):
 
 
 # ---------------------------------------------------------------------------
+# a vector store's third word (PR 44): a store commits two words a pass,
+# and the third word of a 16-byte store to an address that is not
+# 8-aligned goes through the sweep's one conditional (`store-spill`).
+# Lanes of one group store to addresses of their own (rbx by lane), each
+# against an oracle run of its own, memory compared byte for byte.
+# ---------------------------------------------------------------------------
+
+_RBX = 3                                  # GPR_NAMES order
+_PAGE = 0x1000
+_STORE_DATA = bytes((29 * i + 7) & 0xFF for i in range(2 * _PAGE))
+
+
+def _store_by_lane(insn, addresses, data):
+    """Every lane runs `<insn> [rbx], xmm0` with its own rbx -> (runner,
+    statuses, the oracle of each lane, or the MemFault it raised)."""
+    from tests.emurunner import run_emu
+    from tests.test_step import make_runner
+    from wtf_tpu.cpu.emu import MemFault
+
+    asm = f"""
+        movdqu xmm0, [rip + value]
+        {insn} [rbx], xmm0
+        hlt
+        value: .quad 0x1716151413121110, 0x2f2e2d2c2b2a2928"""
+    runner = make_runner(asm, data=data, n_lanes=len(addresses))
+    view = runner.view()
+    for lane, rbx in enumerate(addresses):
+        view.set_reg(lane, _RBX, rbx)
+    runner.push(view)
+    status = runner.run()
+    emus = []
+    for rbx in addresses:
+        try:
+            emus.append(run_emu(asm, data=data, regs={"rbx": rbx}))
+        except MemFault as fault:
+            emus.append(fault)
+    return runner, status, emus
+
+
+def _assert_lanes_match(runner, status, emus):
+    from wtf_tpu.core.results import StatusCode
+
+    view = runner.view()
+    for lane, emu in enumerate(emus):
+        assert StatusCode(int(status[lane])) == StatusCode.CRASH, lane
+        assert int(np.asarray(runner.machine.rip)[lane]) == emu.rip
+        dirty = emu.mem.dirty_pfns()
+        assert dirty, "the store dirtied a page"
+        for pfn in dirty:
+            got, want = view.page(lane, pfn), bytes(emu.mem.overlay[pfn])
+            assert got == want, (lane, hex(pfn), next(
+                i for i in range(_PAGE) if got[i] != want[i]))
+        assert sum(view.page_dirty(lane, pfn) for pfn in range(
+            min(dirty) - 1, max(dirty) + 2)) == len(dirty), lane
+
+
+def _store3(runner):
+    from wtf_tpu.interp.machine import CTR_STORE3
+
+    return runner.device_counters()[:, CTR_STORE3].tolist()
+
+
+@pytest.mark.parametrize("insn", ["movdqu", "movups"])
+@pytest.mark.parametrize("offset", range(1, 16))
+def test_unaligned_vector_store_one_lane_of_the_group(insn, offset):
+    """Lane 0 stores sixteen bytes at `offset` past a 16-byte boundary,
+    lane 1 at an aligned address: only lane 0's store can reach a third
+    word (every offset but 8), so the group takes the conditional's taken
+    side for one spilling lane and one that rewrites what was there; at
+    offset 8 no lane spills and it takes the other side.  `CTR_STORE3`
+    counts exactly the spilling passes."""
+    runner, status, emus = _store_by_lane(
+        insn, [DATA_BASE + 0x40 + offset, DATA_BASE + 0x200],
+        {DATA_BASE: _STORE_DATA})
+    _assert_lanes_match(runner, status, emus)
+    assert _store3(runner) == [int(offset != 8), 0]
+
+
+@pytest.mark.parametrize("offset", [1, 7, 8, 9, 15])
+def test_unaligned_vector_store_across_a_page(offset):
+    """The same over the end of a page: the third word lies in the second
+    page's row (claimed by this store) for offsets past 8, in the first
+    page's for those before."""
+    runner, status, emus = _store_by_lane(
+        "movdqu", [DATA_BASE + _PAGE - 16 + offset, DATA_BASE + 0x200],
+        {DATA_BASE: _STORE_DATA})
+    _assert_lanes_match(runner, status, emus)
+    assert _store3(runner) == [int(offset != 8), 0]
+    assert len(emus[0].mem.dirty_pfns()) == 2
+
+
+def test_no_lane_of_the_group_spills():
+    """Two aligned vector stores and the pushes of a call: the
+    conditional's pass-through side alone, `CTR_STORE3` 0."""
+    runner, status, emus = _store_by_lane(
+        "movdqa", [DATA_BASE + 0x40, DATA_BASE + _PAGE + 0x10],
+        {DATA_BASE: _STORE_DATA})
+    _assert_lanes_match(runner, status, emus)
+    assert _store3(runner) == [0, 0]
+
+
+def test_a_store_that_spills_and_faults_writes_nothing():
+    """A spilling store whose second page is not mapped faults as a whole
+    (the fault check is on the span, in the same pass): neither its two
+    words nor its third are written, no row is claimed, the pass counts
+    no spill; the lane beside it, whose store spills inside a mapped
+    page, retires."""
+    from wtf_tpu.core.results import StatusCode
+    from wtf_tpu.cpu.emu import MemFault
+
+    at = DATA_BASE + _PAGE - 7             # nine bytes into the hole
+    runner, status, emus = _store_by_lane(
+        "movdqu", [at, DATA_BASE + 0x41], {DATA_BASE: _STORE_DATA[:_PAGE]})
+    assert isinstance(emus[0], MemFault)
+    assert StatusCode(int(status[0])) == StatusCode.PAGE_FAULT
+    assert int(np.asarray(runner.machine.fault_gva)[0]) == DATA_BASE + _PAGE
+    assert int(np.asarray(runner.machine.overlay.count)[0]) == 0
+    view = runner.view()
+    assert view.virt_read(0, at - 9, 16) == _STORE_DATA[_PAGE - 16:_PAGE]
+    assert StatusCode(int(status[1])) == StatusCode.CRASH
+    assert view.virt_read(1, DATA_BASE + 0x40, 0x20) \
+        == emus[1].virt_read(DATA_BASE + 0x40, 0x20)
+    assert _store3(runner) == [0, 1]
+
+
+# ---------------------------------------------------------------------------
 # the set-up's guard: what a chunk program's first dispatch traces
 # (PERF.md section 6, PR 41: PR 40 was refused for 29 s of tracing)
 # ---------------------------------------------------------------------------

@@ -501,8 +501,9 @@ def summarize(path) -> dict:
         # (device.levels_used); null for streams from before the spans
         "chunk": _chunk_summary(metrics, phase_seconds),
         # what the retired instructions did (in-graph: CTR_STORE,
-        # CTR_VECTOR) and the passes that sent two pages to the walk
-        # (CTR_WALK2); null for streams from before the counters
+        # CTR_VECTOR), the passes that sent two pages to the walk
+        # (CTR_WALK2) and those whose store reached its window's third
+        # word (CTR_STORE3); null for streams from before the counters
         "mix": ({
             "store_share": _ratio(metrics, "device.store_steps",
                                   "device.instructions", 4),
@@ -511,6 +512,9 @@ def summarize(path) -> dict:
             "walk2_steps": metrics.get("device.walk2_steps", 0),
             "walk2_pass_share": _ratio(metrics, "device.walk2_steps",
                                        "device.lane_steps", 6),
+            "store3_steps": metrics.get("device.store3_steps", 0),
+            "store3_pass_share": _ratio(metrics, "device.store3_steps",
+                                        "device.lane_steps", 6),
         } if metrics.get("device.store_steps") is not None else None),
         # the snapshot's packed image (gauges set once at snapshot-load;
         # its child spans are under nested_phases) and the host reads
@@ -643,7 +647,10 @@ def _print_human(s: dict) -> None:
               + f" vector per 1000; {mix['walk2_steps']} passes sent two "
               "pages to the walk ("
               + _or_null(mix["walk2_pass_share"], "{:.4%}")
-              + " of lane-steps)")
+              + f" of lane-steps), {mix['store3_steps']} stored a third "
+              "word ("
+              + _or_null(mix["store3_pass_share"], "{:.4%}")
+              + ")")
     image = s.get("image")
     if image:
         print(f"image: {image['bytes']} bytes, {image['frames_present']} "

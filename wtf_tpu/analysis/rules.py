@@ -394,6 +394,17 @@ def walk_note() -> str:
             f"{4 * 6 * K_WALK} gathered indices a lane-step")
 
 
+def step_note() -> str:
+    """`walk_note`, and what the entry says of the store: the census
+    counts OPERATIONS, which rise when the store's third word gets a
+    conditional of its own, so the pin names the indices every pass
+    pays (two words of five, the frame table's two, the row claims'
+    four; the commit's two limbs and valid byte a word), as the chip's
+    compile counts them (tests/test_chip_compile.py)."""
+    return (walk_note() + " / store: 16 gathered and 6 scattered indices "
+            "a lane-step, the third word under `store-spill`")
+
+
 def check_budget(counts: Dict[str, int], budget: Dict[str, int],
                  entry: str) -> List[Finding]:
     """Exact pin: any drift (up OR down) is a finding — an improvement
@@ -662,7 +673,7 @@ def run_megachunk_rules(budgets_path: Optional[Path] = None,
 
     cfg = MEGA_CONFIG
     entry = (f"megachunk(max_batches={cfg['max_batches']}, donate=True) "
-             f"/ demo_tlv / n_lanes={cfg['n_lanes']}" + walk_note())
+             f"/ demo_tlv / n_lanes={cfg['n_lanes']}" + step_note())
     lowered, args, fn = megachunk_window_lowering(
         max_batches=cfg["max_batches"], n_lanes=cfg["n_lanes"],
         donate=True, limit=cfg["limit"])
@@ -867,7 +878,8 @@ def run_tenant_rules(budgets_path: Optional[Path] = None,
     measured counts for run_lint's rebaseline merge."""
     cfg = TENANT_CONFIG
     entry = (f"make_run_chunk({cfg['n_steps']}, donate=False) / "
-             f"{'+'.join(cfg['order'])} / quotas={list(cfg['quotas'])}")
+             f"{'+'.join(cfg['order'])} / quotas={list(cfg['quotas'])}"
+             + step_note())
     kwargs = dict(chunk_steps=cfg["n_steps"],
                   uop_capacity=cfg["uop_capacity"],
                   overlay_slots=cfg["overlay_slots"],
@@ -916,7 +928,7 @@ def run_tail_rules(runner, families, budgets_path: Optional[Path] = None,
     donated machine through the rows' scatter back (recompile)."""
     entry = (f"make_run_chunk({BUDGET_CONFIG['n_steps']}, "
              f"donate={BUDGET_CONFIG['donate']}, tail={TAIL_WIDTH}) / "
-             f"demo_tlv / n_lanes={BUDGET_CONFIG['n_lanes']}" + walk_note())
+             f"demo_tlv / n_lanes={BUDGET_CONFIG['n_lanes']}" + step_note())
     compiled = step_executor_lowering(
         runner, n_steps=BUDGET_CONFIG["n_steps"],
         donate=BUDGET_CONFIG["donate"], tail=TAIL_WIDTH).compile()
@@ -1259,7 +1271,7 @@ def run_mesh_family(budgets_path: Optional[Path] = None,
         return _mesh_family_subprocess(budgets_path, rebaseline)
     entry = (f"make_mesh_chunk({MESH_CONFIG['n_steps']}, donate=False) / "
              f"demo_tlv / {MESH_DEVICES} shards x "
-             f"{MESH_CONFIG['lanes_per_shard']} lanes")
+             f"{MESH_CONFIG['lanes_per_shard']} lanes" + step_note())
     text_full = _mesh_chunk_compiled(MESH_DEVICES)
     text_half = _mesh_chunk_compiled(MESH_DEVICES // 2)
     counts = count_collective_ops(text_full)
@@ -1336,7 +1348,7 @@ def run_lint(families: Optional[Sequence[str]] = None,
         info["entries"].append(
             f"make_run_chunk({BUDGET_CONFIG['n_steps']}, "
             f"donate={BUDGET_CONFIG['donate']}) / demo_tlv / "
-            f"n_lanes={BUDGET_CONFIG['n_lanes']}" + walk_note())
+            f"n_lanes={BUDGET_CONFIG['n_lanes']}" + step_note())
 
     if "dtype" in families:
         t0 = time.time()

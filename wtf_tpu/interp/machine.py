@@ -78,7 +78,10 @@ CTR_LEVELS = 10      # levels of the chunk loop that swept at least once;
                      # added to every lane's column once a level by the
                      # chunk loop, so a lane carries its chunk's (its
                      # shard's) count
-N_CTRS = 11
+CTR_STORE3 = 11      # passes whose store reached the third word of its
+                     # 16-byte window (overlay.StoreSpill): what takes the
+                     # sweep's `store-spill` conditional
+N_CTRS = 12
 
 IRET_LATCH_SHIFT = 8   # Machine.fault_write: iretq's latch lies above bit 0
 

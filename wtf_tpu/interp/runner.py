@@ -47,7 +47,8 @@ from wtf_tpu.cpu.interrupts import (
 from wtf_tpu.interp import limbs
 from wtf_tpu.interp.machine import (
     CTR_DECODE_MISS, CTR_INSTR, CTR_LEVELS, CTR_MEM_FAULT, CTR_STORE,
-    CTR_SWEPT, CTR_TAIL, CTR_TLB_FILL, CTR_TLB_WALK, CTR_VECTOR, CTR_WALK2,
+    CTR_STORE3, CTR_SWEPT, CTR_TAIL, CTR_TLB_FILL, CTR_TLB_WALK, CTR_VECTOR,
+    CTR_WALK2,
     Machine,
     machine_init, machine_restore,
 )
@@ -1832,9 +1833,11 @@ class Runner:
         reg.counter("device.tlb_walks").inc(int(totals[CTR_TLB_WALK]))
         reg.counter("device.tlb_fill_steps").inc(int(totals[CTR_TLB_FILL]))
         reg.counter("device.walk2_steps").inc(int(totals[CTR_WALK2]))
-        # what the retired instructions did: a store, the vector datapath
+        # what the retired instructions did: a store, the vector datapath;
+        # the passes whose store reached its window's third word
         reg.counter("device.store_steps").inc(int(totals[CTR_STORE]))
         reg.counter("device.vector_steps").inc(int(totals[CTR_VECTOR]))
+        reg.counter("device.store3_steps").inc(int(totals[CTR_STORE3]))
         # every lane carries its chunks' levels that swept: per lane, as
         # sweeps are
         for key, total in (("device.sweeps", lane_steps),

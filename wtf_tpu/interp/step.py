@@ -66,8 +66,8 @@ from wtf_tpu.interp.uoptable import (
     MU_RAW_LO, PROBES, UopTable,
 )
 from wtf_tpu.mem.overlay import (
-    StoreCommit, commit_stores, extract_pair, load_windows3_vec, resident,
-    store_window3,
+    StoreCommit, StoreSpill, commit_spills, commit_stores, extract_pair,
+    load_windows3_vec, resident, store_window3,
 )
 from wtf_tpu.mem.paging import Translation, translate_cached
 from wtf_tpu.mem.physmem import IMAGE_IN_AXES, MemImage, lane_image
@@ -721,11 +721,14 @@ def _set_row(table, idx, on, row):
 
 
 def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
-              fp_host: bool = None) -> Tuple[Machine, StoreCommit]:
+              fp_host: bool = None
+              ) -> Tuple[Machine, StoreCommit, StoreSpill]:
     """Advance one lane by one instruction (vmapped over the batch by
     `step_lanes`, which hands it a `lane_view` and commits the store).
-    Returns (lane', store): the overlay's planes pass through untouched,
-    the words the instruction stores come back as a `StoreCommit`.
+    Returns (lane', store, spill): the overlay's planes pass through
+    untouched, the two words every pass commits come back as a
+    `StoreCommit`, and the third word of the store's window, which only
+    an unaligned store wider than 8 bytes reaches, as a `StoreSpill`.
 
     Lanes whose status != RUNNING are a no-op.  `limit` is the instruction
     budget (u64; 0 = unlimited) -> TIMEDOUT, the deterministic equivalent of
@@ -2188,7 +2191,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
     commit_pre = live & ~unsupported & ~is_crash & ~de & ~page_fault \
         & ~fp_oracle & ~iret_a
 
-    overlay, store_ok, store = store_window3(
+    overlay, store_ok, store, spill = store_window3(
         image, overlay, ts0, ts1, st_size, st_lo, st_hi,
         st_need & commit_pre)
     ovf = st_need & commit_pre & ~store_ok
@@ -2372,12 +2375,14 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
         # itself, whatever the lane's status; CTR_TAIL and CTR_LEVELS are
         # the chunk loop's.  A fill pass counts in CTR_SWEPT and
         # CTR_TLB_FILL and not in CTR_INSTR, as iretq's latching pass counts
-        # in CTR_SWEPT alone.
+        # in CTR_SWEPT alone.  `spill.spill` implies commit: the store was
+        # enabled (st_need & commit_pre) and claimed its rows (~ovf).
         is_vector = reduce(operator.or_, (is_(o) for o in U.VECTOR_OPCS))
         new_ctr = st.ctr + jnp.stack(
             [commit, page_fault, miss, jnp.bool_(True), jnp.bool_(False),
              tlb_walks, fill, commit & st_need, tlb_walks == 2,
-             commit & is_vector, jnp.bool_(False)]).astype(jnp.uint32)
+             commit & is_vector, jnp.bool_(False),
+             spill.spill]).astype(jnp.uint32)
         timed = commit & (limit > _u(0)) & (new_icount >= limit)
     new_rdrand = jnp.where(commit & is_(U.OPC_RDRAND), rdrand_next, st.rdrand)
     new_bp_skip = jnp.where(commit, jnp.int32(0), st.bp_skip)
@@ -2436,7 +2441,7 @@ def step_lane(tab: UopTable, image: MemImage, st: Machine, limit,
         status=new_status, icount=new_icount, rdrand=new_rdrand,
         bp_skip=new_bp_skip, fault_gva=new_fault_gva,
         fault_write=new_fault_write, ctr=new_ctr, cov=new_cov,
-        edge=new_edge, overlay=overlay), store
+        edge=new_edge, overlay=overlay), store, spill
 
 
 # ---------------------------------------------------------------------------
@@ -2447,17 +2452,20 @@ def _sweep(tab: UopTable, image: MemImage, view: Machine, limit,
            fp_host: bool = None) -> Machine:
     """ONE sweep over the lanes of a `lane_view` (all of them, or one
     group of its rows): `step_lane` over every lane in hand, then every
-    lane's store in one flat scatter per overlay plane.  The planes ride
+    lane's store in one flat scatter per overlay plane, and under one
+    conditional the third words of the stores that spill, if any lane's
+    does (`overlay.commit_spills`).  The planes ride
     through the vmap unmapped (each lane gathers from the resident buffer
     at its own `base`), so inside a while loop the carry is updated in
     place and no operation reads or writes a whole plane (mem/overlay.py,
     "resident layout").  `image` is a `lane_image` of the same lanes."""
-    m, store = jax.vmap(
+    m, store, spill = jax.vmap(
         partial(step_lane, fp_host=fp_host),
         in_axes=(None, IMAGE_IN_AXES, MACHINE_LANE_AXES, None),
-        out_axes=(MACHINE_LANE_AXES, 0),
+        out_axes=(MACHINE_LANE_AXES, 0, 0),
     )(tab, image, view, limit)
-    return m._replace(overlay=commit_stores(m.overlay, store))
+    return m._replace(overlay=commit_spills(
+        image, commit_stores(m.overlay, store), spill))
 
 
 def step_lanes(tab: UopTable, image: MemImage, machine: Machine, limit,

@@ -12,10 +12,13 @@ plane — no page data ever moves.
 
 Layout: page data is uint64 WORDS (little-endian), matching MemImage.  The
 hot primitives operate on aligned word windows — `pte_read` (1 word),
-`load_window3` (3 words cover any <=16-byte span), `store_window3` (3-word
-read-modify-write) — so a memory access costs a handful of word gathers
-instead of per-byte gathers.  Byte-granular `gather_bytes`/`scatter_span`
-remain for host-driven paths (testcase insertion, traces, tests).
+`load_window3` (3 words cover any <=16-byte span), `store_window3` (a
+two-word read-modify-write; the window's third word, which only a store
+wider than 8 bytes to an address that is not 8-aligned reaches, goes
+through `commit_spills`) — so a memory access costs a handful of word
+gathers instead of per-byte gathers.  Byte-granular `gather_bytes` /
+`scatter_span` remain for host-driven paths (testcase insertion, traces,
+tests).
 
 RESIDENT LAYOUT: the two big planes (`data`, `valid`) of the batched
 `DirtyOverlay` are FLAT and lane-major: word `w` of row `r` of lane `l`
@@ -45,8 +48,9 @@ unmapped: every lane gathers from the one resident buffer).  Because the
 planes are unmapped, a vmapped store cannot return them: `store_window3`
 and `scatter_span` return a `StoreCommit` (plane indices + words), and
 the caller applies ONE flat scatter per plane for all lanes with
-`commit_stores` OUTSIDE the vmap.  The two sweeping engines
-(step.make_run_chunk, the megachunk ladder) do that through
+`commit_stores` OUTSIDE the vmap (and `commit_spills` after it, which
+touches the planes only when a lane in hand spills).  The two sweeping
+engines (step.make_run_chunk, the megachunk ladder) do that through
 `step.step_lanes`.
 """
 
@@ -202,6 +206,23 @@ class StoreCommit(NamedTuple):
     idx: jax.Array       # int32[K] word index (the limbs' is twice that)
     data: jax.Array      # uint64[K]
     valid: jax.Array     # uint8[K]
+
+
+class StoreSpill(NamedTuple):
+    """A word of a store's window as far as it costs no index (what
+    `_merged_word` reads and merges); `store_window3` hands the THIRD
+    word of a lane's 16-byte window out so: only a store wider than 8
+    bytes to an address that is not 8-aligned reaches it (`spill`), so
+    its read, merge and commit are `commit_spills`', which every
+    lane-step pays a conditional for and not eight indices (PERF.md
+    section 6, PR 44)."""
+
+    idx: jax.Array       # int32 plane index of the word
+    slot: jax.Array      # int32 row of the image's pages under it
+    widx: jax.Array      # int32 word index within the page
+    mask: jax.Array      # uint64 bits of the word the store writes
+    value: jax.Array     # uint64 the store's bits, in place
+    spill: jax.Array     # bool: an enabled store reaches the word
 
 
 def overlay_init(n_lanes: int, capacity: int,
@@ -378,16 +399,42 @@ def fill_rows(overlay: DirtyOverlay, first_row, words,
                   jnp.ones(words.shape, jnp.uint8)))
 
 
-@jax.named_scope("overlay-store")
-def commit_stores(overlay, commit: StoreCommit):
-    """Apply a (possibly lane-batched) `StoreCommit` to the planes of a
-    `DirtyOverlay` or `LaneOverlay`: one flat scatter per plane."""
+def _scatter_words(overlay, commit: StoreCommit):
+    """One flat scatter per plane; the caller names the scope."""
     idx = commit.idx.reshape(-1)
     return overlay._replace(
         data=overlay.data.at[_limb_index(idx)].set(
             unpack_u64(commit.data.reshape(-1)), unique_indices=True),
         valid=overlay.valid.at[idx].set(commit.valid.reshape(-1),
                                         unique_indices=True))
+
+
+@jax.named_scope("overlay-store")
+def commit_stores(overlay, commit: StoreCommit):
+    """Apply a (possibly lane-batched) `StoreCommit` to the planes of a
+    `DirtyOverlay` or `LaneOverlay`: one flat scatter per plane."""
+    return _scatter_words(overlay, commit)
+
+
+@jax.named_scope("store-spill")
+def commit_spills(image: MemImage, overlay, spill: StoreSpill):
+    """The third words of the stores of the lanes in hand (a lane-batched
+    `StoreSpill`, or one lane's), after `commit_stores`: ONE conditional
+    on whether any lane spills.  Its taken side reads, merges and
+    scatters one word a lane (a lane that does not spill rewrites what
+    was there, as a disabled store does); the other side hands the planes
+    on, and both alias them as the sweep's loops do
+    (tests/test_chip_compile.py)."""
+
+    def taken(planes):
+        view = overlay._replace(data=planes[0], valid=planes[1])
+        new, vnew = _merged_word(image, view, spill)
+        view = _scatter_words(view, StoreCommit(spill.idx, new, vnew))
+        return view.data, view.valid
+
+    data, valid = lax.cond(jnp.any(spill.spill), taken, lambda planes: planes,
+                           (overlay.data, overlay.valid))
+    return overlay._replace(data=data, valid=valid)
 
 
 def split_gpa(image: MemImage, gpa: jax.Array):
@@ -575,6 +622,20 @@ def extract_pair(w0, w1, w2, gpa_first):
     return lo, hi
 
 
+def _merged_word(image: MemImage, overlay, word: StoreSpill):
+    """Read-modify-write of the words `word` describes (any shape) ->
+    (word, valid byte).  Delta rows: a partial write to a not-yet-valid
+    word merges with the base image word; the stored word is then
+    complete -> valid.  An untouched word comes back as it was."""
+    was_valid = overlay.valid[word.idx] != 0
+    old = jnp.where(was_valid, read_data(overlay, word.idx),
+                    image_words(image, word.slot, word.widx))
+    return (jnp.where(word.spill,
+                      (old & ~word.mask) | (word.value & word.mask), old),
+            jnp.where(word.spill, jnp.uint8(1),
+                      was_valid.astype(jnp.uint8)))
+
+
 @jax.named_scope("overlay-store")
 def store_window3(
     image: MemImage,
@@ -585,12 +646,14 @@ def store_window3(
     lo: jax.Array,
     hi: jax.Array,
     enabled: jax.Array,
-) -> Tuple[LaneOverlay, jax.Array, StoreCommit]:
+) -> Tuple[LaneOverlay, jax.Array, StoreCommit, StoreSpill]:
     """Store up to 16 bytes (value (lo, hi), little-endian) through the
     lane overlay: claim the one or two touched pages' rows, then a
-    3-word read-modify-write with per-word bitmasks.  Returns
-    (overlay', ok, commit): overlay' carries the claimed rows, !ok =
-    overlay full, and `commit` is the three words for `commit_stores`."""
+    read-modify-write with per-word bitmasks of the first TWO words of
+    the 3-word window.  Returns (overlay', ok, commit, spill): overlay'
+    carries the claimed rows, !ok = overlay full, `commit` is the two
+    words for `commit_stores`, and `spill` what `commit_spills` needs of
+    the third."""
     pfn0, off0 = split_gpa(image, t_first.gpa)
     pfn1, _ = split_gpa(image, t_last.gpa)
     crosses = (off0 + size) > PAGE_SIZE
@@ -614,9 +677,7 @@ def store_window3(
     end_bit = sh + size.astype(jnp.uint64) * _u(8)
 
     w_start = off0 >> 3
-    ats = []
-    news = []
-    vnews = []
+    words = []
     for j, vj in enumerate((v0, v1, v2)):
         on_first = (w_start + j) < PAGE_WORDS
         widx = jnp.where(on_first, w_start + j, w_start + j - PAGE_WORDS)
@@ -631,18 +692,12 @@ def store_window3(
         off_in = jnp.where(has, start_in - lo_bit, _u(0))
         # n_bits == 64 wraps (1 << 64 -> 0) to the all-ones mask, correct
         mask = _shl(_shl(_u(1), n_bits) - _u(1), off_in)
-        # delta rows: a partial write to a not-yet-valid word merges with
-        # the base image word; the stored word is then complete -> valid
         at = overlay.base + row * PAGE_WORDS + widx
-        was_valid = overlay.valid[at] != 0
-        old = jnp.where(was_valid, read_data(overlay, at),
-                        image_words(image, slot, widx))
-        touched = do & (mask != _u(0))
-        new = jnp.where(touched, (old & ~mask) | (vj & mask), old)
-        ats.append(at)
-        news.append(new)
-        vnews.append(jnp.where(touched, jnp.uint8(1),
-                               was_valid.astype(jnp.uint8)))
+        words.append(StoreSpill(at, slot, widx, mask, vj,
+                                do & (mask != _u(0))))
+    # words 0 and 1 are read, merged and committed in every pass; the
+    # third costs no index here
+    news, vnews = zip(*(_merged_word(image, overlay, w) for w in words[:2]))
     # the three plane indices are distinct by construction (word indices
     # strictly increase within a page and the straddle moves to another
     # row), and lanes own disjoint spans of the planes: the caller commits
@@ -650,8 +705,9 @@ def store_window3(
     # per lane under vmap, into [lanes, capacity, PAGE_WORDS] planes, the
     # v5e compile relayouted both whole planes on the way in and out of
     # the scatter in every sweep (module docstring)
-    return overlay, ok, StoreCommit(jnp.stack(ats), jnp.stack(news),
-                                    jnp.stack(vnews))
+    return overlay, ok, StoreCommit(
+        jnp.stack([w.idx for w in words[:2]]), jnp.stack(news),
+        jnp.stack(vnews)), words[2]
 
 
 # ---------------------------------------------------------------------------
@@ -737,17 +793,12 @@ def scatter_span(
         widx = jnp.where(on_first, w_start + j, w_start + j - PAGE_WORDS)
         row = jnp.where(on_first, idx0, jnp.where(two_pages, idx1, idx0))
         slot = jnp.where(on_first, slot0, jnp.where(two_pages, slot1, slot0))
-        # delta rows: merge partial words with the base image word
         at = overlay.base + row * PAGE_WORDS + widx
-        was_valid = overlay.valid[at] != 0
-        old = jnp.where(was_valid, read_data(overlay, at),
-                        image_words(image, slot, widx))
-        touched = do & (mask != 0)
+        new, vnew = _merged_word(image, overlay, StoreSpill(
+            at, slot, widx, mask, word_val, do & (mask != 0)))
         ats.append(at)
-        news.append(jnp.where(touched,
-                              (old & ~mask) | (word_val & mask), old))
-        vnews.append(jnp.where(touched, jnp.uint8(1),
-                               was_valid.astype(jnp.uint8)))
+        news.append(new)
+        vnews.append(vnew)
     # plane indices are distinct (word indices strictly increase within
     # each page; the straddle changes row)
     return overlay, ok, StoreCommit(jnp.stack(ats), jnp.stack(news),

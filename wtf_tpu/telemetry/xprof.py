@@ -40,9 +40,10 @@ CHUNK_SCOPES = (              # make_run_chunk: once a level or a chunk
 VECTOR_SCOPES = (             # the vector unit: XMM file, SSE classes, x87
     "vector-file", "sse-bytes", "sse-fp", "x87")
 STEP_SCOPES = (               # step_lane and below: once a lane-step
+                              # (`store-spill`: once a sweep, over its lanes)
     "decode-probe", "uop-fetch", "regfile-read", "regfile-write",
-    "tlb", "page-walk", "overlay-load", "overlay-store", "ea", "alu",
-    "unary", "shift", "mul", "cond", "string") + VECTOR_SCOPES + (
+    "tlb", "page-walk", "overlay-load", "overlay-store", "store-spill",
+    "ea", "alu", "unary", "shift", "mul", "cond", "string") + VECTOR_SCOPES + (
     "iret", "flags-status", "counters", "coverage")
 OTHER_SCOPES = (              # the other device programs
     "device-insert", "restore", "run-quiesce", "decode-service",
@@ -53,11 +54,14 @@ INDEXED_OPS = ("gather", "scatter", "dynamic-slice",
                "dynamic-update-slice", "sort")
 _SCOPE_RE = re.compile(
     r"(?<![\w-])(" + "|".join(map(re.escape, SCOPES)) + r")(?![\w-])")
+# `lax.cond` names its own operation `cond` and its sides
+# `cond/branch_N_fun`: the primitive, not the step's scope of that name
+_LAX_COND = re.compile(r"(?<=/)cond(?=/branch_\d+_fun|$)")
 
 
 def scope_of(op_name: str) -> Optional[str]:
     """The innermost listed scope in an HLO `op_name`, or None."""
-    found = _SCOPE_RE.findall(op_name or "")
+    found = _SCOPE_RE.findall(_LAX_COND.sub("", op_name or ""))
     return found[-1] if found else None
 
 
